@@ -141,8 +141,8 @@ class EventStore:
 
         Reads through the pushed-down columnar fold when the backend has
         one (C++ / SQL tiers in `storage/sqlite.py` — no per-event Python
-        object; 11.3× the per-event path at 2M property events, see
-        BASELINE.md), falling back to the per-event
+        object; 11.3× the per-event path at 2M property events, round 4),
+        falling back to the per-event
         `data/datamap.py::aggregate_properties` fold, which is the
         semantics oracle the pushdown tiers are tested against.
         `PIO_AGG_PUSHDOWN=0` forces the per-event fold (ops escape

@@ -30,7 +30,7 @@ namespace {
 // Cap ladder: min_cap, then ceil(prev*growth/8)*8 — growth 2.0 reproduces
 // the round-1 power-of-two caps exactly; smaller growth (e.g. 1.5) trades
 // more bucket shapes (compile time) for less padding in the gather
-// (measured 1.08x epoch at 2M rank-64, BASELINE.md). The arithmetic is
+// (measured 1.08x epoch at 2M rank-64, round 2). The arithmetic is
 // IEEE double, identical to the numpy path's — bit-identical caps.
 std::vector<int64_t> build_ladder(int64_t max_count, int64_t min_cap,
                                   double growth) {

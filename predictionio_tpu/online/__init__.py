@@ -23,6 +23,7 @@ from predictionio_tpu.online.foldin import (  # noqa: F401
     solve_rows,
 )
 from predictionio_tpu.online.plane import (  # noqa: F401
+    DeviceUnavailable,
     OnlineConfig,
     OnlinePlane,
 )
@@ -30,7 +31,7 @@ from predictionio_tpu.online.session import SessionFold  # noqa: F401
 from predictionio_tpu.online.swap import DeltaSwapper, StaleState  # noqa: F401
 
 __all__ = [
-    "ALSFold", "DeltaSwapper", "FoldModel", "FoldStats", "OnlineConfig",
-    "OnlinePlane", "SeenOverlay", "SessionFold", "StaleState",
+    "ALSFold", "DeltaSwapper", "DeviceUnavailable", "FoldModel", "FoldStats",
+    "OnlineConfig", "OnlinePlane", "SeenOverlay", "SessionFold", "StaleState",
     "fold_model", "solve_rows",
 ]

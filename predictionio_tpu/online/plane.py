@@ -64,6 +64,12 @@ from predictionio_tpu.utils import faults
 log = logging.getLogger(__name__)
 
 
+class DeviceUnavailable(RuntimeError):
+    """JAX could not bring up the platform this process was told to use
+    (`JAX_PLATFORMS`) — on a chip host, the chip belongs to another
+    process."""
+
+
 def _truthy(v: str) -> bool:
     return v.strip().lower() in ("1", "true", "on", "yes")
 
@@ -247,6 +253,20 @@ class OnlinePlane:
 
     # -- lifecycle ------------------------------------------------------------
     def start(self) -> None:
+        # Folds solve on this process's JAX backend, and a chip belongs
+        # to one process at a time. Bring the backend up now and say
+        # what it is, so a process that cannot have its platform fails
+        # its start — a pool worker before it reports ready — instead of
+        # finding out at the first event.
+        import jax
+
+        from predictionio_tpu.parallel.mesh import describe_devices
+
+        try:
+            devices = jax.devices()
+        except RuntimeError as e:
+            raise DeviceUnavailable(str(e)) from e
+        log.info("online: folds solve on %s", describe_devices(devices))
         for t in self._tailers:
             t.start()
         if self.config.parity_every_s > 0 and self._parity_thread is None:

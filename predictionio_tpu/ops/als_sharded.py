@@ -40,6 +40,7 @@ from predictionio_tpu.ops.als import (
     ALSConfig,
     _bucket_chunk_rows,
     _walk_bucket_chunks,
+    normal_eq_einsum,
 )
 from predictionio_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
 
@@ -96,6 +97,7 @@ def get_train_loop_sharded(
     k = cfg.rank
     f32 = jnp.float32
     cdtype = jnp.dtype(cfg.compute_dtype)
+    ne_einsum = normal_eq_einsum(cdtype)
 
     def bucket_specs(flags):
         return [
@@ -137,9 +139,7 @@ def get_train_loop_sharded(
 
         if cfg.implicit:
             op_c = opposing_local.astype(cdtype)
-            gram = lax.psum(
-                jnp.einsum("ck,cl->kl", op_c, op_c,
-                           preferred_element_type=f32), MODEL_AXIS)
+            gram = lax.psum(ne_einsum("ck,cl->kl", op_c, op_c), MODEL_AXIS)
 
         def finalize(a, b, n):
             if cfg.implicit:
@@ -157,18 +157,13 @@ def get_train_loop_sharded(
             ym = (y * mask_c[..., None]).astype(cdtype)
             if cfg.implicit:
                 conf = cfg.alpha * vals_c
-                a_part = jnp.einsum("rck,rc,rcl->rkl", ym,
-                                    conf.astype(cdtype), ym,
-                                    preferred_element_type=f32)
-                b_part = jnp.einsum("rck,rc->rk", ym,
-                                    (1.0 + conf).astype(cdtype),
-                                    preferred_element_type=f32)
+                a_part = ne_einsum("rck,rc,rcl->rkl", ym,
+                                   conf.astype(cdtype), ym)
+                b_part = ne_einsum("rck,rc->rk", ym,
+                                   (1.0 + conf).astype(cdtype))
             else:
-                a_part = jnp.einsum("rck,rcl->rkl", ym, ym,
-                                    preferred_element_type=f32)
-                b_part = jnp.einsum("rck,rc->rk", ym,
-                                    vals_c.astype(cdtype),
-                                    preferred_element_type=f32)
+                a_part = ne_einsum("rck,rcl->rkl", ym, ym)
+                b_part = ne_einsum("rck,rc->rk", ym, vals_c.astype(cdtype))
             rows_eff = rows_c
             if segmap_c is not None:
                 acc_a, acc_b, acc_n = accs

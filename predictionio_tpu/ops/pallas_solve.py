@@ -48,7 +48,7 @@ Mosaic lessons baked in (round-1 findings, kept so nobody re-learns them):
 Gauss-Jordan does ~2·K³ useful FLOPs per system (vs Cholesky's K³/3) but
 they are perfectly batch-parallel VPU FMAs instead of a sequential
 custom-call — measured 3.4× faster than the Cholesky path at rank 64 on
-v5e (110 ms → 32 ms on a [12664, 64, 64] batch; BASELINE.md). No
+v5e (110 ms → 32 ms on a [12664, 64, 64] batch, round 1). No
 pivoting: A = YᵀWY + λ(n)I is SPD (hence symmetric) with strictly
 positive diagonal, the same assumption MLlib's dppsv Cholesky makes.
 All-zero systems (bucket padding rows) short-circuit to x = 0 via the

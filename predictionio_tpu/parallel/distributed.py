@@ -74,13 +74,6 @@ def initialize_from_env() -> bool:
     multi-host run; no-op (False) otherwise. Idempotent."""
     import jax
 
-    from predictionio_tpu.parallel.mesh import _apply_platform_override
-
-    # honor PIO_JAX_PLATFORM before any backend use: multi-process CPU
-    # testing (and CPU-only hosts next to a busy chip) must pick the
-    # platform before the distributed client pins it
-    _apply_platform_override()
-
     addr = os.environ.get("PIO_COORDINATOR_ADDRESS")
     if not addr:
         return False
@@ -106,13 +99,12 @@ def global_mesh(mesh_shape: Optional[dict[str, int]] = None):
     """Build the global (all-hosts) mesh; shape from PIO_MESH_SHAPE or all
     devices on the data axis. THE mesh-shape resolution — WorkflowContext
     delegates here so the env contract lives in one place."""
-    from predictionio_tpu.parallel.mesh import _apply_platform_override, make_mesh
+    from predictionio_tpu.parallel.mesh import make_mesh
 
     if mesh_shape is None:
         spec = os.environ.get("PIO_MESH_SHAPE")
         if spec:
             mesh_shape = parse_mesh_shape(spec)
-    _apply_platform_override()
     import jax
 
     return make_mesh(mesh_shape, devices=jax.devices())

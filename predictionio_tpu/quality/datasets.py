@@ -10,8 +10,8 @@ real thing where it matters for ALS:
   heavy-tailed on both axes — uniform draws would understate the ragged
   bucketing the solvers face);
 - noise tuned so the best achievable held-out RMSE lands in the
-  literature-anchor band for real ML-20M (~0.78–0.85, BASELINE.md
-  "External anchors") — i.e. the recoverable-signal regime is realistic,
+  literature-anchor band for real ML-20M (~0.78–0.85) — i.e. the
+  recoverable-signal regime is realistic,
   not a noiseless matrix-completion toy.
 
 Both ALS implementations (quality/mllib_als.py and ops/als.py) see the
@@ -84,7 +84,7 @@ def _sample_pairs(rng, n_users, n_items, n_target):
 
 
 def synth_explicit(
-    scale: str = "100k",
+    scale: "str | tuple[int, int, int]" = "100k",
     rank_true: int = 32,
     noise: float = 0.78,
     test_frac: float = 0.1,
@@ -96,8 +96,13 @@ def synth_explicit(
     With `noise=0.78` the best achievable held-out RMSE is ≈0.80 at
     ML-100K scale (measured via quality/parity.py), matching the
     real-ML-20M literature anchor band.
+
+    `scale` names an entry of `SCALES`, or gives (n_users, n_items,
+    n_ratings) directly — how a run keeps a named scale's table heights
+    while cutting its ratings count.
     """
-    n_users, n_items, n_ratings = SCALES[scale]
+    n_users, n_items, n_ratings = (
+        SCALES[scale] if isinstance(scale, str) else scale)
     rng = np.random.default_rng(seed)
     ui, ii = _sample_pairs(rng, n_users, n_items, n_ratings)
 

@@ -591,11 +591,13 @@ def memory_payload() -> Tuple[int, Dict]:
                      "error": "jax not loaded in this process"}
     import jax
 
-    out: Dict = {"backend": None, "devices": [], "live_buffers": {},
-                 "top_buffers": [], "memory_stats": {}}
+    out: Dict = {"backend": None, "device_kind": None, "devices": [],
+                 "live_buffers": {}, "top_buffers": [], "memory_stats": {}}
     try:
+        devices = jax.devices()
         out["backend"] = jax.default_backend()
-        out["devices"] = [str(d) for d in jax.devices()]
+        out["device_kind"] = devices[0].device_kind
+        out["devices"] = [str(d) for d in devices]
     except Exception:  # noqa: BLE001
         pass
     try:

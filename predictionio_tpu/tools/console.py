@@ -610,6 +610,10 @@ def main(argv=None) -> int:
     import logging
     import os
 
+    from predictionio_tpu.utils import compile_cache
+
+    # before any verb imports jax; forked/spawned workers inherit it
+    compile_cache.configure()
     args = build_parser().parse_args(argv)
     # Wire log levels like the reference's `pio --verbose` / log4j.properties
     # (SURVEY.md §5): WARNING by default, INFO at --verbose 1, DEBUG at ≥2;

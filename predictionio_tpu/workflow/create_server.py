@@ -29,7 +29,11 @@ from predictionio_tpu.experiment import (
     RewardTailer,
     VariantRouter,
 )
-from predictionio_tpu.online import OnlineConfig, OnlinePlane
+from predictionio_tpu.online import (
+    DeviceUnavailable,
+    OnlineConfig,
+    OnlinePlane,
+)
 from predictionio_tpu.plugins import PluginRejection
 from predictionio_tpu.serving import (
     DeadlineExceeded,
@@ -234,13 +238,21 @@ class PredictionServer(HttpService):
         # events out of the durable store, folds the dirty factor rows,
         # and hot-swaps the served state per variant — bandit arms keep
         # learning mid-experiment. A plane that fails to start must not
-        # take serving down: the server just stays batch-fresh.
+        # take serving down: the server just stays batch-fresh. The one
+        # exception is a process that cannot have its device: it must
+        # not come up at all (a pool worker then never reports ready)
+        # rather than serve answers no fold will ever refresh.
         self.online: Optional[OnlinePlane] = None
         online_cfg = online if online is not None else OnlineConfig.from_env()
         if online_cfg is not None:
             try:
                 self.online = OnlinePlane(self, online_cfg)
                 self.online.start()
+            except DeviceUnavailable:
+                if self._tailer is not None:
+                    self._tailer.stop()
+                self.serving.close()
+                raise
             except Exception:  # noqa: BLE001
                 log.exception("online plane failed to start; serving "
                               "continues without fold-in")

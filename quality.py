@@ -167,10 +167,12 @@ def main():
 
         return run_gate()
 
-    if args.cpu:
-        import jax
+    from predictionio_tpu.utils import compile_cache
 
-        jax.config.update("jax_platforms", "cpu")
+    # both read when jax is first imported, which nothing above has done
+    compile_cache.configure()
+    if args.cpu:
+        os.environ["JAX_PLATFORMS"] = "cpu"
 
     from predictionio_tpu.quality.parity import run_parity
 

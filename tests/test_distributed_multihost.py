@@ -23,8 +23,6 @@ WORKER = textwrap.dedent("""
     import numpy as np
     from predictionio_tpu.parallel import distributed
 
-    # PIO_JAX_PLATFORM=cpu in the env exercises the platform override
-    # inside initialize_from_env (the production path on CPU-only hosts)
     assert distributed.initialize_from_env()
     import jax
     import jax.numpy as jnp
@@ -60,7 +58,7 @@ def _run_global_mesh_world(tmp_path, n_procs, dev_per_proc):
         env = dict(os.environ)
         env.pop("PIO_CONF_DIR", None)
         env.update(
-            PIO_JAX_PLATFORM="cpu",
+            JAX_PLATFORMS="cpu",
             XLA_FLAGS=f"--xla_force_host_platform_device_count={dev_per_proc}",
             PIO_COORDINATOR_ADDRESS=f"127.0.0.1:{port}",
             PIO_NUM_PROCESSES=str(n_procs),
@@ -159,7 +157,7 @@ def _train_env(db, basedir, n_local_devices, **extra):
         TRAIN_ENV_KEYS,
         PIO_STORAGE_SOURCES_SQL_PATH=str(db),
         PIO_FS_BASEDIR=str(basedir),
-        PIO_JAX_PLATFORM="cpu",
+        JAX_PLATFORMS="cpu",
         XLA_FLAGS=f"--xla_force_host_platform_device_count={n_local_devices}",
         PYTHONPATH=f"{REPO}{os.pathsep}" + os.environ.get("PYTHONPATH", ""),
     )

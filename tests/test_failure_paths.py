@@ -30,8 +30,6 @@ REPO = pathlib.Path(__file__).resolve().parent.parent
 TRAIN_WORKER = textwrap.dedent("""
     import os, sys
     sys.path.insert(0, os.environ["PIO_TEST_REPO"])
-    import jax
-    jax.config.update("jax_platforms", "cpu")
     import numpy as np
     from predictionio_tpu.ops.als import ALSConfig, als_train
 
@@ -547,7 +545,7 @@ class TestRankDeath:
         env = dict(os.environ)
         env.pop("PIO_CONF_DIR", None)
         env.update(
-            PIO_JAX_PLATFORM="cpu",
+            JAX_PLATFORMS="cpu",
             PIO_COORDINATOR_ADDRESS=f"127.0.0.1:{port}",
             PIO_NUM_PROCESSES="2",
             PIO_PROCESS_ID="0",
@@ -584,7 +582,7 @@ class TestRankDeath:
             env = dict(os.environ)
             env.pop("PIO_CONF_DIR", None)
             env.update(
-                PIO_JAX_PLATFORM="cpu",
+                JAX_PLATFORMS="cpu",
                 XLA_FLAGS="--xla_force_host_platform_device_count=4",
                 PIO_COORDINATOR_ADDRESS=f"127.0.0.1:{port}",
                 PIO_NUM_PROCESSES="2",

@@ -241,7 +241,7 @@ def test_native_import_normalizations(tmp_path):
 
 def test_native_import_speed_sanity(tmp_path):
     """The fast path must actually import a bulk file (count integrity at
-    a non-trivial size; speed itself is recorded in BASELINE.md)."""
+    a non-trivial size; speed itself is a benchmark's business)."""
     f = tmp_path / "bulk.jsonl"
     n = 20_000
     with open(f, "w") as fh:

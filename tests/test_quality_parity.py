@@ -1,7 +1,7 @@
 """Quality parity: the TPU ALS (ops/als.py) must match an independent
 MLlib-faithful CPU reference (quality/mllib_als.py) on held-out metrics
 over identical data (VERDICT r1 #1; the north star's "at matching MAP@10"
-half). Full-scale runs live in quality.py / BASELINE.md; these tests prove
+half). Full-scale runs live in quality.py; these tests prove
 the harness and the agreement at CI-sized scale."""
 
 import numpy as np

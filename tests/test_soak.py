@@ -1,7 +1,7 @@
 """Short-window soak mechanism drill (VERDICT r4 next #6).
 
-The full receipt is `bench.py --soak --duration 600` (recorded in
-BASELINE.md); the suite runs the same machinery — concurrent ingest +
+The full receipt is `bench.py --soak --duration 600`; the suite runs
+the same machinery — concurrent ingest +
 serving + background retrain/reload with RSS/fd/thread probes and the
 starvation/error gates — over a window short enough for CI. The
 flatness assertions themselves execute either way (bench_soak raises on
