@@ -27,6 +27,7 @@ from predictionio_tpu.controller.base import (
 )
 from predictionio_tpu.controller.context import WorkflowContext
 from predictionio_tpu.controller.params import Params, params_from_dict
+from predictionio_tpu.telemetry.spans import span
 
 log = logging.getLogger(__name__)
 
@@ -154,18 +155,20 @@ class Engine:
     ) -> list[Any]:
         ds, prep, algos, _ = self.components(engine_params)
         log.info("Engine.train: reading training data (%s)", type(ds).__name__)
-        td = ds.read_training(ctx)
+        with span("dase.read"):
+            td = ds.read_training(ctx)
         if sanity_check:
             run_sanity_check(td, "training data")
         log.info("Engine.train: preparing data (%s)", type(prep).__name__)
-        pd = prep.prepare(ctx, td)
+        with span("dase.prepare"):
+            pd = prep.prepare(ctx, td)
         if sanity_check:
             run_sanity_check(pd, "prepared data")
         models = []
         for (name, algo), suffix in zip(algos, _ckpt_suffixes(algos)):
             log.info("Engine.train: training algorithm %r (%s)",
                      name, type(algo).__name__)
-            with ctx.algo_checkpoint_scope(suffix):
+            with ctx.algo_checkpoint_scope(suffix), span("dase.train"):
                 model = algo.train(ctx, pd)
             if sanity_check:
                 run_sanity_check(model, f"model[{name}]")

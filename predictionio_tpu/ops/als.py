@@ -29,15 +29,42 @@ from __future__ import annotations
 import dataclasses
 import functools
 import logging
-from typing import Optional, Sequence
+from typing import NamedTuple, Optional, Sequence
 
 import numpy as np
 
+from predictionio_tpu.telemetry.registry import REGISTRY
+from predictionio_tpu.telemetry.spans import span
 from predictionio_tpu.utils import faults
 
 log = logging.getLogger(__name__)
 
 MIN_CAP = 8  # smallest bucket width (sublane-friendly)
+
+# Counted where the buckets are made or loaded, per side (`user` | `item`).
+# entries / cells is the share of the gather + Gram work that is not
+# padding: `cap_growth` and `splitCap` set it.
+BUCKET_ENTRIES = REGISTRY.gauge(
+    "als_bucket_entries",
+    "Ratings placed into the side's buckets by the last als_train",
+    labelnames=("side",))
+BUCKET_CELLS = REGISTRY.gauge(
+    "als_bucket_cells",
+    "Cells (rows x cap, summed over buckets, before chunk padding) of the "
+    "side's buckets in the last als_train",
+    labelnames=("side",))
+
+
+def _spanned(name: str):
+    """Run the function inside `telemetry.spans.span(name)`: a timeline
+    record under `pio train`, a trace annotation under the profiler."""
+    def deco(fn):
+        @functools.wraps(fn)
+        def wrapper(*args, **kwargs):
+            with span(name):
+                return fn(*args, **kwargs)
+        return wrapper
+    return deco
 
 
 @dataclasses.dataclass
@@ -310,6 +337,7 @@ def _bucket_cache_keep() -> int:
     return max(1, int(os.environ.get("PIO_BUCKET_CACHE_KEEP", "4")))
 
 
+@_spanned("als.digest")
 def _arrays_digest(*arrays, extra: str = "") -> str:
     import hashlib
 
@@ -320,6 +348,7 @@ def _arrays_digest(*arrays, extra: str = "") -> str:
     return h.hexdigest()
 
 
+@_spanned("als.bucket_cache.save")
 def _bucket_cache_save(cache_dir: str, key: str,
                        user_buckets: list, u_split: np.ndarray,
                        item_buckets: list, i_split: np.ndarray) -> None:
@@ -373,6 +402,7 @@ def _bucket_cache_save(cache_dir: str, key: str,
             pass
 
 
+@_spanned("als.bucket_cache.load")
 def _bucket_cache_load(cache_dir: str, key: str):
     """(user_buckets, u_split, item_buckets, i_split) or None on miss."""
     import os
@@ -452,12 +482,14 @@ def bucketize_cached(
         log.info("als_train: bucket cache hit %s (host bucketize skipped)",
                  bucket_key)
     else:
-        user_buckets, u_split = bucket_ragged_split(
-            user_idx, item_idx, ratings, n_users, row_multiple, split_cap,
-            cap_growth=cap_growth)
-        item_buckets, i_split = bucket_ragged_split(
-            item_idx, user_idx, ratings, n_items, row_multiple, split_cap,
-            cap_growth=cap_growth)
+        with span("als.bucketize"):
+            user_buckets, u_split = bucket_ragged_split(
+                user_idx, item_idx, ratings, n_users, row_multiple,
+                split_cap, cap_growth=cap_growth)
+        with span("als.bucketize"):
+            item_buckets, i_split = bucket_ragged_split(
+                item_idx, user_idx, ratings, n_items, row_multiple,
+                split_cap, cap_growth=cap_growth)
         if bucket_cache_dir:
             try:
                 # atomic write: concurrent ranks race safely (same bytes)
@@ -572,13 +604,21 @@ def _solve_buckets_device(
 
     import jax
 
+    # Every op below sits in one `jax.named_scope` of a fixed set
+    # (als.gather_gram, als.yty, als.solve, als.split_merge, als.scatter;
+    # als.rmse in the loop): the names reach the profiler's trace in the
+    # ops' metadata and survive a refactor, which compiler names such as
+    # `%fusion.1067` do not. Metadata only: no op or fusion changes.
+    scope = jax.named_scope
     k = opposing.shape[-1]
-    new = jnp.zeros((out_rows, k), dtype=opposing.dtype)
+    with scope("als.scatter"):
+        new = jnp.zeros((out_rows, k), dtype=opposing.dtype)
     n_split = 0 if split_rows is None else split_rows.shape[0]
     if n_split:
-        acc_a = jnp.zeros((n_split, k, k), dtype=jnp.float32)
-        acc_b = jnp.zeros((n_split, k), dtype=jnp.float32)
-        acc_n = jnp.zeros((n_split,), dtype=jnp.float32)
+        with scope("als.split_merge"):
+            acc_a = jnp.zeros((n_split, k, k), dtype=jnp.float32)
+            acc_b = jnp.zeros((n_split, k), dtype=jnp.float32)
+            acc_n = jnp.zeros((n_split,), dtype=jnp.float32)
 
     interpret = cfg.pallas == "interpret"
     cdtype = jnp.dtype(cfg.compute_dtype)
@@ -649,8 +689,9 @@ def _solve_buckets_device(
         # global Gram over real (non-sentinel-pad) opposing rows (f32: it
         # is summed into per-row partials that may accumulate across
         # segments)
-        op_c = opposing.astype(cdtype)
-        gram = ne_einsum("ck,cl->kl", op_c, op_c)
+        with scope("als.yty"):
+            op_c = opposing.astype(cdtype)
+            gram = ne_einsum("ck,cl->kl", op_c, op_c)
 
     def partial_gram(cols_c, vals_c, mask_c):
         """Raw per-row partial normal equations (no global Gram, no reg):
@@ -679,20 +720,24 @@ def _solve_buckets_device(
                          row_sharded)
 
     def process(rows_c, cols_c, vals_c, mask_c, segmap_c, new, accs):
-        n = mask_c.sum(-1)
-        a, b = partial_gram(cols_c, vals_c, mask_c)
+        with scope("als.gather_gram"):
+            n = mask_c.sum(-1)
+            a, b = partial_gram(cols_c, vals_c, mask_c)
         rows_eff = rows_c
         if segmap_c is not None:
-            acc_a, acc_b, acc_n = accs
-            accs = (acc_a.at[segmap_c].add(a, mode="drop"),
-                    acc_b.at[segmap_c].add(b, mode="drop"),
-                    acc_n.at[segmap_c].add(n, mode="drop"))
-            # segment rows are combined+solved after the loop; drop their
-            # inline (partial) solutions from the scatter
-            rows_eff = jnp.where(segmap_c < n_split, out_rows, rows_c)
-        x = finalize(a, b, n)
+            with scope("als.split_merge"):
+                acc_a, acc_b, acc_n = accs
+                accs = (acc_a.at[segmap_c].add(a, mode="drop"),
+                        acc_b.at[segmap_c].add(b, mode="drop"),
+                        acc_n.at[segmap_c].add(n, mode="drop"))
+                # segment rows are combined+solved after the loop; drop
+                # their inline (partial) solutions from the scatter
+                rows_eff = jnp.where(segmap_c < n_split, out_rows, rows_c)
+        with scope("als.solve"):
+            x = finalize(a, b, n)
         # sentinel row ids (== out_rows) fall outside and are dropped
-        new = new.at[rows_eff].set(x.astype(new.dtype), mode="drop")
+        with scope("als.scatter"):
+            new = new.at[rows_eff].set(x.astype(new.dtype), mode="drop")
         return new, accs
 
     accs = (acc_a, acc_b, acc_n) if n_split else ()
@@ -703,8 +748,10 @@ def _solve_buckets_device(
             lambda sliced, carry: process(*sliced, *carry), (new, accs))
 
     if n_split:
-        x_u = finalize(*accs, row_sharded=False)
-        new = new.at[split_rows].set(x_u.astype(new.dtype), mode="drop")
+        with scope("als.solve"):
+            x_u = finalize(*accs, row_sharded=False)
+        with scope("als.scatter"):
+            new = new.at[split_rows].set(x_u.astype(new.dtype), mode="drop")
     return new
 
 
@@ -754,9 +801,11 @@ def _get_train_loop(n_users: int, n_items: int, cfg: ALSConfig,
             item_f = _solve_buckets_device(user_f, n_items, ib_dev, cfg,
                                            i_split, row_multiple, mesh)
             if compute_rmse:
-                total, count = _predict_sq_err(user_f, item_f, ub_dev,
-                                               row_multiple, mesh)
-                rmse = jnp.sqrt(jnp.maximum(total, 0.0) / jnp.maximum(count, 1.0))
+                with jax.named_scope("als.rmse"):
+                    total, count = _predict_sq_err(user_f, item_f, ub_dev,
+                                                   row_multiple, mesh)
+                    rmse = jnp.sqrt(jnp.maximum(total, 0.0)
+                                    / jnp.maximum(count, 1.0))
             else:
                 rmse = jnp.zeros((), dtype=jnp.float32)
             if checked:
@@ -819,12 +868,61 @@ class ALSResult:
     item_factors: np.ndarray  # [n_items, K]
     rmse_history: list[float]
     epoch_times: list[float] = dataclasses.field(default_factory=list)
-    # wall seconds per iteration *executed in this call* (includes compile;
-    # empty when a checkpointed run was already complete and fully resumed)
+    # seconds per iteration, one entry per iteration *executed in this
+    # call*, from `loop_chunks` (see `epoch_times_of`); empty when a
+    # checkpointed run was already complete and fully resumed
     start_epoch: int = 0
     # first epoch executed in this call (>0 when resumed from a checkpoint)
+    loop_chunks: list["LoopChunk"] = dataclasses.field(default_factory=list)
+    # one per dispatch of the train loop
 
 
+class LoopChunk(NamedTuple):
+    """One dispatch of the train loop, by the stamps around its
+    `als.loop.dispatch` and `als.loop.wait` spans."""
+
+    steps: int
+    dispatch_s: float
+    wait_s: float
+    compiled: bool  # the dispatch traced + compiled (or loaded a cache)
+
+
+def epoch_times_of(chunks: Sequence[LoopChunk]) -> list[float]:
+    """Seconds per iteration from the train loop's own stamps: dispatch +
+    fence of the chunks that ran warm, over their steps. A chunk whose
+    dispatch compiled (trace, lower, compile or cache load: 5-70 s at
+    ML-20M) is left out; when every chunk compiled, their fences alone
+    stand in, since the program runs after the dispatch has returned.
+    Checkpoint saves and host gathers lie outside both stamps."""
+    executed = sum(c.steps for c in chunks)
+    warm = [c for c in chunks if not c.compiled]
+    if warm:
+        seconds = sum(c.dispatch_s + c.wait_s for c in warm)
+    else:
+        seconds = sum(c.wait_s for c in chunks)
+    steps = sum(c.steps for c in (warm or chunks))
+    return [seconds / steps] * executed if executed else []
+
+
+def emit_train_metrics(metrics, result: ALSResult) -> None:
+    """One `train/als` record per iteration executed in this call
+    (`epoch_time_s`, and `rmse` where it was tracked) into a
+    `MetricsLogger`: what every ALS template's `train` reports."""
+    import math
+
+    # a resumed run skips its first start_epoch epochs; rmse_history
+    # covers all of them
+    for off, t in enumerate(result.epoch_times):
+        step = result.start_epoch + off + 1
+        rec = {"epoch_time_s": t}
+        if result.rmse_history and step <= len(result.rmse_history):
+            rmse = result.rmse_history[step - 1]
+            if not math.isnan(rmse):  # NaN = epoch predates RMSE tracking
+                rec["rmse"] = rmse
+        metrics.emit("train/als", step=step, **rec)
+
+
+@_spanned("als.train")
 def als_train(
     user_idx: np.ndarray,
     item_idx: np.ndarray,
@@ -925,6 +1023,12 @@ def als_train(
         len(item_buckets), [b.cap for b in item_buckets], len(i_split),
         cfg.rank, dict(mesh.shape),
     )
+    for side, buckets in (("user", user_buckets), ("item", item_buckets)):
+        # the split bucketizer truncates nothing: every rating is placed
+        # once a side
+        BUCKET_ENTRIES.labels(side=side).set(len(ratings))
+        BUCKET_CELLS.labels(side=side).set(
+            sum(b.cols.shape[0] * b.cols.shape[1] for b in buckets))
 
     dtype = jnp.dtype(cfg.dtype)
     row_shard = NamedSharding(mesh, P(DATA_AXIS))
@@ -965,10 +1069,11 @@ def als_train(
             ))
         return out
 
-    ub_dev = put_buckets(user_buckets, n_users, len(u_split))
-    ib_dev = put_buckets(item_buckets, n_items, len(i_split))
-    u_split_dev = jax.device_put(u_split, rep)
-    i_split_dev = jax.device_put(i_split, rep)
+    with span("als.put_buckets"):
+        ub_dev = put_buckets(user_buckets, n_users, len(u_split))
+        ib_dev = put_buckets(item_buckets, n_items, len(i_split))
+        u_split_dev = jax.device_put(u_split, rep)
+        i_split_dev = jax.device_put(i_split, rep)
 
     # factor sharding: replicated on a data-only mesh; row-sharded over
     # the `model` axis otherwise (VERDICT r1 #3 — config 5's capability)
@@ -1005,17 +1110,19 @@ def als_train(
         replicated through the jitted identity first — a collective, so
         ALL ranks must call this (rank-0-only callers would deadlock the
         world; see the checkpoint block below)."""
-        uf, vf = user_factors, item_factors
-        if jax.process_count() > 1 and not uf.is_fully_replicated:
-            uf, vf = replicate(uf), replicate(vf)
-        return np.asarray(uf)[:n_users], np.asarray(vf)[:n_items]
+        with span("als.readback"):
+            uf, vf = user_factors, item_factors
+            if jax.process_count() > 1 and not uf.is_fully_replicated:
+                uf, vf = replicate(uf), replicate(vf)
+            return np.asarray(uf)[:n_users], np.asarray(vf)[:n_items]
 
     # init item factors ~ N(0, 1/sqrt(rank)) like MLlib; users solved first
-    key = jax.random.key(cfg.seed)
-    item_init = (jax.random.normal(key, (n_items, cfg.rank), dtype=dtype)
-                 / np.sqrt(cfg.rank))
-    user_factors, item_factors = place_factors(
-        jnp.zeros((n_users, cfg.rank), dtype=dtype), item_init)
+    with span("als.init_factors"):
+        key = jax.random.key(cfg.seed)
+        item_init = (jax.random.normal(key, (n_items, cfg.rank), dtype=dtype)
+                     / np.sqrt(cfg.rank))
+        user_factors, item_factors = place_factors(
+            jnp.zeros((n_users, cfg.rank), dtype=dtype), item_init)
 
     import time
 
@@ -1055,23 +1162,27 @@ def als_train(
         if resume:
             usable = [s for s in manager.all_steps() if s <= cfg.iterations]
             if usable:
-                tree, meta = manager.restore(usable[-1])
-                uf = tree.get("user_factors") if isinstance(tree, dict) else None
-                vf = tree.get("item_factors") if isinstance(tree, dict) else None
-                if (meta.get("fingerprint") == fingerprint
-                        and uf is not None and vf is not None
-                        and uf.shape == (n_users, cfg.rank)
-                        and vf.shape == (n_items, cfg.rank)):
-                    user_factors, item_factors = place_factors(uf, vf)
-                    restore_step = start_iter = usable[-1]
-                    rmse_history = list(meta.get("rmse_history", []))[:start_iter]
-                    log.info("als_train: resumed from checkpoint step %d",
-                             restore_step)
-                else:
-                    log.warning(
-                        "als_train: checkpoint at %s is from different data/"
-                        "config (or a foreign tree) — training from scratch",
-                        checkpoint_dir)
+                with span("als.init_factors"):
+                    tree, meta = manager.restore(usable[-1])
+                    uf = (tree.get("user_factors")
+                          if isinstance(tree, dict) else None)
+                    vf = (tree.get("item_factors")
+                          if isinstance(tree, dict) else None)
+                    if (meta.get("fingerprint") == fingerprint
+                            and uf is not None and vf is not None
+                            and uf.shape == (n_users, cfg.rank)
+                            and vf.shape == (n_items, cfg.rank)):
+                        user_factors, item_factors = place_factors(uf, vf)
+                        restore_step = start_iter = usable[-1]
+                        rmse_history = list(
+                            meta.get("rmse_history", []))[:start_iter]
+                        log.info("als_train: resumed from checkpoint step "
+                                 "%d", restore_step)
+                    else:
+                        log.warning(
+                            "als_train: checkpoint at %s is from different "
+                            "data/config (or a foreign tree) — training "
+                            "from scratch", checkpoint_dir)
         if not compute_rmse:
             rmse_history = []
         elif len(rmse_history) < start_iter:
@@ -1083,9 +1194,9 @@ def als_train(
     # One dispatch for the whole run (or per checkpoint chunk): the
     # iteration loop is a lax.scan inside a single jitted program, so
     # there are no per-epoch host round trips (a sync per epoch would
-    # dwarf the compute at quickstart scale). Epoch time = wall /
-    # iterations.
-    t_start = time.perf_counter()
+    # dwarf the compute at quickstart scale). Epoch times come from the
+    # stamps around each chunk's dispatch and fence (`epoch_times_of`).
+    loop_chunks: list[LoopChunk] = []
     done = start_iter
     first_save_done = False
     host_copies = None  # (uf, vf) from the last checkpoint save, if any
@@ -1108,13 +1219,23 @@ def als_train(
                                     compute_rmse, n_steps, row_multiple,
                                     mesh if mesh.size > 1 else None,
                                     checked=_checks.enabled())
-        user_factors, item_factors, rmses = train(item_factors, user_factors,
-                                                  ub_dev, ib_dev,
-                                                  u_split_dev, i_split_dev)
+        # `metered_jit` knows whether a call compiled; the checked loop
+        # (a debug path) is not metered and counts as warm
+        compile_count = getattr(train, "compile_count", lambda: 0)
+        compiles_before = compile_count()
+        t0 = time.monotonic()
+        with span("als.loop.dispatch"):
+            user_factors, item_factors, rmses = train(
+                item_factors, user_factors, ub_dev, ib_dev,
+                u_split_dev, i_split_dev)
+        t1 = time.monotonic()
         # execution fence: the scalar readback waits for the dispatch
         # (whether block_until_ready would do on the current chip is not
         # measured; timing is S1's)
-        float(item_factors[0, 0])
+        with span("als.loop.wait"):
+            float(item_factors[0, 0])
+        loop_chunks.append(LoopChunk(n_steps, t1 - t0, time.monotonic() - t1,
+                                     compile_count() > compiles_before))
         done += n_steps
         # elastic-recovery drill point (SURVEY.md §5): a rank hard-dying
         # between a computed chunk and its checkpoint save is the worst
@@ -1160,9 +1281,6 @@ def als_train(
         # (restore_step=None with no saves means a degenerate run, e.g.
         # iterations=0 — leave the directory untouched.)
         manager.keep_only(restore_step)
-    wall = time.perf_counter() - t_start
-    executed = cfg.iterations - start_iter
-    epoch_times = [wall / executed] * executed if executed > 0 else []
     if compute_rmse and rmse_history:
         log.info("als_train: rmse %.4f → %.4f over %d iters",
                  rmse_history[0], rmse_history[-1], cfg.iterations)
@@ -1173,6 +1291,7 @@ def als_train(
         user_factors=uf_host,
         item_factors=vf_host,
         rmse_history=rmse_history,
-        epoch_times=epoch_times,
+        epoch_times=epoch_times_of(loop_chunks),
         start_epoch=start_iter,
+        loop_chunks=loop_chunks,
     )

@@ -98,6 +98,7 @@ def get_train_loop_sharded(
     f32 = jnp.float32
     cdtype = jnp.dtype(cfg.compute_dtype)
     ne_einsum = normal_eq_einsum(cdtype)
+    scope = jax.named_scope  # the same fixed names as ops/als.py's loop
 
     def bucket_specs(flags):
         return [
@@ -131,15 +132,19 @@ def get_train_loop_sharded(
         opp_off = m_idx * opp_size
 
         dtype = opposing_local.dtype
-        new_local = jnp.zeros((out_size, k), dtype=dtype)
+        with scope("als.scatter"):
+            new_local = jnp.zeros((out_size, k), dtype=dtype)
         if n_split:
-            acc_a = jnp.zeros((n_split, k, k), f32)
-            acc_b = jnp.zeros((n_split, k), f32)
-            acc_n = jnp.zeros((n_split,), f32)
+            with scope("als.split_merge"):
+                acc_a = jnp.zeros((n_split, k, k), f32)
+                acc_b = jnp.zeros((n_split, k), f32)
+                acc_n = jnp.zeros((n_split,), f32)
 
         if cfg.implicit:
-            op_c = opposing_local.astype(cdtype)
-            gram = lax.psum(ne_einsum("ck,cl->kl", op_c, op_c), MODEL_AXIS)
+            with scope("als.yty"):
+                op_c = opposing_local.astype(cdtype)
+                gram = lax.psum(ne_einsum("ck,cl->kl", op_c, op_c),
+                                MODEL_AXIS)
 
         def finalize(a, b, n):
             if cfg.implicit:
@@ -151,46 +156,53 @@ def get_train_loop_sharded(
         def process(sliced, carry):
             rows_c, cols_c, vals_c, mask_c, segmap_c = sliced
             new, accs = carry
-            n = mask_c.sum(-1)
-            y, _ = _masked_local_gather(opposing_local, cols_c, opp_off,
-                                        opp_size, k)
-            ym = (y * mask_c[..., None]).astype(cdtype)
-            if cfg.implicit:
-                conf = cfg.alpha * vals_c
-                a_part = ne_einsum("rck,rc,rcl->rkl", ym,
-                                   conf.astype(cdtype), ym)
-                b_part = ne_einsum("rck,rc->rk", ym,
-                                   (1.0 + conf).astype(cdtype))
-            else:
-                a_part = ne_einsum("rck,rcl->rkl", ym, ym)
-                b_part = ne_einsum("rck,rc->rk", ym, vals_c.astype(cdtype))
+            with scope("als.gather_gram"):
+                n = mask_c.sum(-1)
+                y, _ = _masked_local_gather(opposing_local, cols_c, opp_off,
+                                            opp_size, k)
+                ym = (y * mask_c[..., None]).astype(cdtype)
+                if cfg.implicit:
+                    conf = cfg.alpha * vals_c
+                    a_part = ne_einsum("rck,rc,rcl->rkl", ym,
+                                       conf.astype(cdtype), ym)
+                    b_part = ne_einsum("rck,rc->rk", ym,
+                                       (1.0 + conf).astype(cdtype))
+                else:
+                    a_part = ne_einsum("rck,rcl->rkl", ym, ym)
+                    b_part = ne_einsum("rck,rc->rk", ym,
+                                       vals_c.astype(cdtype))
             rows_eff = rows_c
             if segmap_c is not None:
-                acc_a, acc_b, acc_n = accs
-                # model-partial (A, b) accumulate as-is (psum'd over both
-                # axes before the segment solve); counts are replicated
-                # over `model`, so only shard 0 contributes them
-                accs = (acc_a.at[segmap_c].add(a_part, mode="drop"),
-                        acc_b.at[segmap_c].add(b_part, mode="drop"),
-                        acc_n.at[segmap_c].add(
-                            jnp.where(m_idx == 0, n, 0.0), mode="drop"))
-                rows_eff = jnp.where(segmap_c < n_split, out_pad, rows_c)
+                with scope("als.split_merge"):
+                    acc_a, acc_b, acc_n = accs
+                    # model-partial (A, b) accumulate as-is (psum'd over
+                    # both axes before the segment solve); counts are
+                    # replicated over `model`, so only shard 0
+                    # contributes them
+                    accs = (acc_a.at[segmap_c].add(a_part, mode="drop"),
+                            acc_b.at[segmap_c].add(b_part, mode="drop"),
+                            acc_n.at[segmap_c].add(
+                                jnp.where(m_idx == 0, n, 0.0), mode="drop"))
+                    rows_eff = jnp.where(segmap_c < n_split, out_pad,
+                                         rows_c)
 
             r_chunk = rows_c.shape[0]
             # combine shard contributions; each model shard solves a
             # distinct R/m slice of the chunk, then the solved rows rejoin
-            a = lax.psum_scatter(a_part, MODEL_AXIS, scatter_dimension=0,
-                                 tiled=True)
-            b = lax.psum_scatter(b_part, MODEL_AXIS, scatter_dimension=0,
-                                 tiled=True)
-            n_loc = lax.dynamic_slice_in_dim(
-                n, m_idx * (r_chunk // n_model), r_chunk // n_model)
-            x = lax.all_gather(finalize(a, b, n_loc), MODEL_AXIS,
-                               axis=0, tiled=True)
-            local = rows_eff - out_off
-            idx = jnp.where((local >= 0) & (local < out_size), local,
-                            out_size)
-            new = new.at[idx].set(x.astype(dtype), mode="drop")
+            with scope("als.solve"):
+                a = lax.psum_scatter(a_part, MODEL_AXIS,
+                                     scatter_dimension=0, tiled=True)
+                b = lax.psum_scatter(b_part, MODEL_AXIS,
+                                     scatter_dimension=0, tiled=True)
+                n_loc = lax.dynamic_slice_in_dim(
+                    n, m_idx * (r_chunk // n_model), r_chunk // n_model)
+                x = lax.all_gather(finalize(a, b, n_loc), MODEL_AXIS,
+                                   axis=0, tiled=True)
+            with scope("als.scatter"):
+                local = rows_eff - out_off
+                idx = jnp.where((local >= 0) & (local < out_size), local,
+                                out_size)
+                new = new.at[idx].set(x.astype(dtype), mode="drop")
             return new, accs
 
         accs = (acc_a, acc_b, acc_n) if n_split else ()
@@ -202,23 +214,27 @@ def get_train_loop_sharded(
                 (new_local, accs))
 
         if n_split:
-            acc_a = lax.psum(lax.psum(accs[0], DATA_AXIS), MODEL_AXIS)
-            acc_b = lax.psum(lax.psum(accs[1], DATA_AXIS), MODEL_AXIS)
-            acc_n = lax.psum(lax.psum(accs[2], DATA_AXIS), MODEL_AXIS)
-            x_u = finalize(acc_a, acc_b, acc_n)  # [U, K], replicated
-            local = split_rows - out_off
-            # x_u is replicated over `data`, but the final psum over
-            # `data` merges the per-shard scatters — write it on data
-            # shard 0 only or it would be summed n_data times
-            d_idx = lax.axis_index(DATA_AXIS)
-            idx = jnp.where(
-                (local >= 0) & (local < out_size) & (d_idx == 0),
-                local, out_size)
-            new_local = new_local.at[idx].set(x_u.astype(dtype),
-                                              mode="drop")
+            with scope("als.split_merge"):
+                acc_a = lax.psum(lax.psum(accs[0], DATA_AXIS), MODEL_AXIS)
+                acc_b = lax.psum(lax.psum(accs[1], DATA_AXIS), MODEL_AXIS)
+                acc_n = lax.psum(lax.psum(accs[2], DATA_AXIS), MODEL_AXIS)
+            with scope("als.solve"):
+                x_u = finalize(acc_a, acc_b, acc_n)  # [U, K], replicated
+            with scope("als.scatter"):
+                local = split_rows - out_off
+                # x_u is replicated over `data`, but the final psum over
+                # `data` merges the per-shard scatters — write it on data
+                # shard 0 only or it would be summed n_data times
+                d_idx = lax.axis_index(DATA_AXIS)
+                idx = jnp.where(
+                    (local >= 0) & (local < out_size) & (d_idx == 0),
+                    local, out_size)
+                new_local = new_local.at[idx].set(x_u.astype(dtype),
+                                                  mode="drop")
         # distinct data shards solved distinct rows into disjoint slots;
         # psum over `data` merges them (empty slots are zero)
-        return lax.psum(new_local, DATA_AXIS)
+        with scope("als.scatter"):
+            return lax.psum(new_local, DATA_AXIS)
 
     def sq_err(u_local, i_local, buckets):
         m_idx = lax.axis_index(MODEL_AXIS)
@@ -257,9 +273,10 @@ def get_train_loop_sharded(
             user_f = half_step(item_f, n_users_pad, ub, u_split, n_usplit)
             item_f = half_step(user_f, n_items_pad, ib, i_split, n_isplit)
             if compute_rmse:
-                total, count = sq_err(user_f, item_f, ub)
-                rmse = jnp.sqrt(jnp.maximum(total, 0.0)
-                                / jnp.maximum(count, 1.0))
+                with scope("als.rmse"):
+                    total, count = sq_err(user_f, item_f, ub)
+                    rmse = jnp.sqrt(jnp.maximum(total, 0.0)
+                                    / jnp.maximum(count, 1.0))
             else:
                 rmse = jnp.zeros((), f32)
             return (user_f, item_f), rmse

@@ -43,8 +43,9 @@ from predictionio_tpu.controller import (
 )
 from predictionio_tpu.data.bimap import BiMap, compress_codes
 from predictionio_tpu.data.store import LEventStore, PEventStore
-from predictionio_tpu.ops.als import ALSConfig, als_train
+from predictionio_tpu.ops.als import ALSConfig, als_train, emit_train_metrics
 from predictionio_tpu.storage.registry import Storage
+from predictionio_tpu.telemetry.spans import span
 
 log = logging.getLogger(__name__)
 
@@ -253,9 +254,11 @@ class ECommAlgorithm(Algorithm):
             checkpoint_dir=ctx.algorithm_checkpoint_dir("als"),
             checkpoint_every=ctx.checkpoint_every_or(1),
         )
+        emit_train_metrics(ctx.metrics, result)
         f = result.item_factors
-        norms = np.linalg.norm(f, axis=1, keepdims=True)
-        unit = np.where(norms > 0, f / np.maximum(norms, 1e-12), 0.0)
+        with span("model.unit_norm"):
+            norms = np.linalg.norm(f, axis=1, keepdims=True)
+            unit = np.where(norms > 0, f / np.maximum(norms, 1e-12), 0.0)
         return ECommModelData(
             user_factors=result.user_factors,
             item_factors=f,

@@ -14,7 +14,6 @@ from __future__ import annotations
 
 import dataclasses
 import logging
-import math
 from typing import Any, Optional
 
 import numpy as np
@@ -34,7 +33,8 @@ from predictionio_tpu.controller import (
 from predictionio_tpu.data.bimap import BiMap, compress_codes
 from predictionio_tpu.data.store import PEventStore
 from predictionio_tpu.models.als_model import ALSModel, SeenItems
-from predictionio_tpu.ops.als import ALSConfig, als_train
+from predictionio_tpu.ops.als import ALSConfig, als_train, emit_train_metrics
+from predictionio_tpu.telemetry.spans import span
 
 log = logging.getLogger(__name__)
 
@@ -222,22 +222,15 @@ class ALSAlgorithm(Algorithm):
             checkpoint_every=ctx.checkpoint_every_or(1),
             bucket_cache_dir=ctx.algorithm_cache_dir("als"),
         )
-        # epoch_times covers only epochs executed this call (a resumed run
-        # skips the first start_epoch epochs); rmse_history covers all
-        for off, t in enumerate(result.epoch_times):
-            step = result.start_epoch + off + 1
-            rec = {"epoch_time_s": t}
-            if result.rmse_history and step <= len(result.rmse_history):
-                rmse = result.rmse_history[step - 1]
-                if not math.isnan(rmse):  # NaN = epoch predates RMSE tracking
-                    rec["rmse"] = rmse
-            ctx.metrics.emit("train/als", step=step, **rec)
+        emit_train_metrics(ctx.metrics, result)
+        with span("model.seen_items"):
+            seen = SeenItems(pd.user_idx, pd.item_idx, len(pd.user_ids))
         return ALSModel(
             user_factors=result.user_factors,
             item_factors=result.item_factors,
             user_ids=pd.user_ids,
             item_ids=pd.item_ids,
-            seen=SeenItems(pd.user_idx, pd.item_idx, len(pd.user_ids)),
+            seen=seen,
             rmse_history=result.rmse_history,
         )
 

@@ -35,7 +35,8 @@ from predictionio_tpu.controller import (
 )
 from predictionio_tpu.data.bimap import BiMap, compress_codes
 from predictionio_tpu.data.store import PEventStore
-from predictionio_tpu.ops.als import ALSConfig, als_train
+from predictionio_tpu.ops.als import ALSConfig, als_train, emit_train_metrics
+from predictionio_tpu.telemetry.spans import span
 
 log = logging.getLogger(__name__)
 
@@ -288,8 +289,9 @@ class ALSAlgorithm(Algorithm):
     @staticmethod
     def _model_from_item_factors(f: np.ndarray,
                                  pd: PreparedData) -> SimilarProductModel:
-        norms = np.linalg.norm(f, axis=1, keepdims=True)
-        unit = np.where(norms > 0, f / np.maximum(norms, 1e-12), 0.0)
+        with span("model.unit_norm"):
+            norms = np.linalg.norm(f, axis=1, keepdims=True)
+            unit = np.where(norms > 0, f / np.maximum(norms, 1e-12), 0.0)
         return SimilarProductModel(
             item_factors_unit=unit.astype(np.float32),
             item_ids=pd.item_ids,
@@ -305,6 +307,7 @@ class ALSAlgorithm(Algorithm):
             checkpoint_dir=ctx.algorithm_checkpoint_dir("als"),
             checkpoint_every=ctx.checkpoint_every_or(1),
         )
+        emit_train_metrics(ctx.metrics, result)
         return self._model_from_item_factors(result.item_factors, pd)
 
     @classmethod

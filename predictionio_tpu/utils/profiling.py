@@ -65,7 +65,13 @@ def metered_jit(fn, label: Optional[str] = None, **jit_kwargs):
     blame) and the device clock's `device_seconds_total` attribution.
     Labels pass through `capped_label` so a caller minting one label per
     runtime value (the old ranking.score_topk_k{k} bug) cannot grow
-    /metrics without bound."""
+    /metrics without bound.
+
+    `wrapper.compile_count()` is `jit_compiles_total{fn=label}` as it
+    stands: a caller that times its own dispatch reads it before and
+    after to leave a compile out of a steady-state figure (`als_train`'s
+    `epoch_times`). A callable, so it stays live on a `functools.wraps`
+    copy of the wrapper."""
     import jax
 
     # the wrapper itself is the metering boundary
@@ -102,6 +108,7 @@ def metered_jit(fn, label: Optional[str] = None, **jit_kwargs):
     # the underlying jitted callable, for callers that need .lower() /
     # .clear_cache() or want to bypass the metering
     wrapper.jitted = jitted
+    wrapper.compile_count = lambda: compiles.value
     return wrapper
 
 
@@ -122,14 +129,6 @@ def maybe_trace(profile_dir: Optional[str]):
     log.info("profiling: tracing to %s", profile_dir)
     with jax.profiler.trace(profile_dir):
         yield profile_dir
-
-
-def annotate(name: str):
-    """Named span that shows up on the trace timeline (use around DASE
-    stages: read/prepare/train/serve)."""
-    import jax
-
-    return jax.profiler.TraceAnnotation(name)
 
 
 def xplane_device_time_s(profile_dir: str) -> float:

@@ -164,6 +164,15 @@ class CoreWorkflow:
                          duration_s=time.perf_counter() - t_wall,
                          error=not ok)
             RECORDER.offer(tl)
+            # the same timeline for an operator whose process ends with
+            # the train (`pio train --metrics-file`): seconds by span
+            # name, a name's ` step_N` suffix left off
+            phases: dict[str, float] = {}
+            for name, _start, seconds, _error, _nested in tl.spans:
+                name = name.partition(" ")[0]
+                phases[name] = phases.get(name, 0.0) + seconds
+            ctx.metrics.emit("train/phases", **phases,
+                             dropped_spans=tl.dropped_spans)
         return instance
 
     @staticmethod

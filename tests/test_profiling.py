@@ -10,7 +10,6 @@ import numpy as np
 from predictionio_tpu.utils.profiling import (
     MetricsLogger,
     NullMetricsLogger,
-    annotate,
     maybe_trace,
     metered_jit,
 )
@@ -53,9 +52,11 @@ class TestTrace:
         import jax
         import jax.numpy as jnp
 
+        from predictionio_tpu.telemetry.spans import span
+
         d = str(tmp_path / "prof")
         with maybe_trace(d):
-            with annotate("test-span"):
+            with span("test-span"):
                 jax.jit(lambda x: x * 2)(jnp.ones(8)).block_until_ready()
         # TensorBoard layout: plugins/profile/<run>/ with at least one file
         prof_root = os.path.join(d, "plugins", "profile")

@@ -1,0 +1,278 @@
+"""The train route's own instrumentation: host spans from `Engine.train`
+down to `als_train`'s phases, the named scopes of the train loop's
+program, the bucket gauges, `train/phases` and the loop-stamp epoch
+times. Stamps are compared with each other, never with a wall clock."""
+
+import dataclasses
+import json
+import re
+
+import numpy as np
+import pytest
+
+from predictionio_tpu.controller import WorkflowContext
+from predictionio_tpu.data.bimap import BiMap
+from predictionio_tpu.ops import als
+from predictionio_tpu.ops.als import (
+    ALSConfig,
+    LoopChunk,
+    als_train,
+    epoch_times_of,
+)
+from predictionio_tpu.telemetry import spans
+from predictionio_tpu.telemetry.registry import REGISTRY
+from predictionio_tpu.utils.profiling import MetricsLogger
+from predictionio_tpu.workflow.core_workflow import CoreWorkflow
+from predictionio_tpu.workflow.workflow_utils import (
+    EngineVariant,
+    extract_engine_params,
+    get_engine,
+)
+from tests.test_ecommerce_template import ingest, variant_dict
+
+N_USERS, N_ITEMS = 12, 9
+SCOPES = ("als.gather_gram", "als.yty", "als.solve", "als.split_merge",
+          "als.scatter", "als.rmse")
+MISS = ["als.train", "als.digest", "als.bucket_cache.load", "als.bucketize",
+        "als.bucketize", "als.bucket_cache.save", "als.put_buckets",
+        "als.init_factors", "als.loop.dispatch", "als.loop.wait",
+        "als.readback"]
+HIT = [n for n in MISS
+       if n not in ("als.bucketize", "als.bucket_cache.save")]
+
+
+def ratings(seed=0):
+    """A tiny COO set in which user 0 rated every item (so a split cap
+    of 4 splits its row)."""
+    rng = np.random.default_rng(seed)
+    u = np.concatenate([np.zeros(N_ITEMS, np.int32),
+                        rng.integers(1, N_USERS, 40).astype(np.int32)])
+    i = np.concatenate([np.arange(N_ITEMS, dtype=np.int32),
+                        rng.integers(0, N_ITEMS, 40).astype(np.int32)])
+    return u, i, rng.uniform(1, 5, len(u)).astype(np.float32)
+
+
+CFG = ALSConfig(rank=4, iterations=2, reg=0.1, seed=3)
+
+
+def under_timeline(fn):
+    """(result of fn(), the finished timeline it ran under)."""
+    tl, token = spans.begin("test", "train", "RUN", "t-1")
+    try:
+        out = fn()
+    finally:
+        spans.finish(tl, token, status=None, duration_s=0.0)
+    return out, tl
+
+
+def als_spans(tl):
+    """The timeline's `als.*` spans as (name, start, end), by start."""
+    return sorted(((n, s, s + d) for n, s, d, _e, _nested in tl.spans
+                   if n.startswith("als.")), key=lambda x: x[1])
+
+
+@pytest.mark.parametrize("warm_cache,expected", [(False, MISS), (True, HIT)],
+                         ids=["cache_miss", "cache_hit"])
+def test_als_train_records_its_phases_in_order_under_als_train(
+        tmp_path, warm_cache, expected):
+    u, i, r = ratings()
+    cache = str(tmp_path / "buckets")
+    if warm_cache:
+        als_train(u, i, r, N_USERS, N_ITEMS, CFG, bucket_cache_dir=cache)
+    _, tl = under_timeline(lambda: als_train(
+        u, i, r, N_USERS, N_ITEMS, CFG, bucket_cache_dir=cache))
+    got = als_spans(tl)
+    assert [n for n, _, _ in got] == expected
+    (_, lo, hi), inner = got[0], got[1:]
+    assert all(lo <= s and e <= hi for _, s, e in inner)
+    # one after the other: no phase starts before the one before it ended
+    assert all(a[2] <= b[1] for a, b in zip(inner, inner[1:]))
+    assert tl.dropped_spans == 0
+
+
+def test_without_a_timeline_nothing_is_recorded_and_the_factors_are_the_same():
+    u, i, r = ratings()
+    traced, tl = under_timeline(
+        lambda: als_train(u, i, r, N_USERS, N_ITEMS, CFG))
+    recorded = len(tl.spans)
+    assert spans.current() is None
+    plain = als_train(u, i, r, N_USERS, N_ITEMS, CFG)
+    assert len(tl.spans) == recorded and spans.current() is None
+    assert np.array_equal(plain.user_factors, traced.user_factors)
+    assert np.array_equal(plain.item_factors, traced.item_factors)
+
+
+@pytest.fixture()
+def loop_text(monkeypatch):
+    """Lowered text, with locations, of the train loop's program as one
+    `als_train` (implicit, a split row, RMSE on) enters it."""
+    entered = {}
+    real = als._get_train_loop
+
+    def spy(*key, **kw):
+        loop = real(*key, **kw)
+
+        def call(*args):
+            entered["text"] = loop.jitted.lower(*args).as_text(
+                debug_info=True)
+            return loop(*args)
+        return call
+
+    monkeypatch.setattr(als, "_get_train_loop", spy)
+    u, i, r = ratings()
+    als_train(u, i, r, N_USERS, N_ITEMS,
+              dataclasses.replace(CFG, implicit=True, alpha=2.0, split_cap=4),
+              compute_rmse=True)
+    return entered["text"]
+
+
+@pytest.mark.parametrize("scope", SCOPES)
+def test_the_train_loops_program_carries_every_scope(loop_text, scope):
+    # a location names its ops `<outer scopes>/<scope>/<primitive>`
+    assert re.search(rf'["/]{re.escape(scope)}/', loop_text)
+    assert "@jit_run" in loop_text  # the benchmark finds the loop by it
+
+
+def test_bucket_gauges_count_entries_and_cells_per_side(tmp_path):
+    u, i, r = ratings()
+    als_train(u, i, r, N_USERS, N_ITEMS, CFG)
+    ub, _, ib, _ = als.bucketize_cached(
+        u, i, r, N_USERS, N_ITEMS, 8, CFG.split_cap, CFG.cap_growth, None)
+    entries = dict(REGISTRY.get("als_bucket_entries").collect())
+    cells = dict(REGISTRY.get("als_bucket_cells").collect())
+    for side, buckets in (("user", ub), ("item", ib)):
+        assert entries[(side,)] == len(r)
+        assert entries[(side,)] == sum(b.mask.sum() for b in buckets)
+        assert cells[(side,)] == sum(b.mask.size for b in buckets)
+        assert cells[(side,)] >= entries[(side,)]
+
+
+@pytest.mark.parametrize("chunks,expected", [
+    # the first chunk compiled: left out, the two warm ones make the mean
+    ([(1, 9.0, 0.5, True), (1, 0.25, 0.75, False), (1, 0.5, 0.5, False)],
+     [1.0, 1.0, 1.0]),
+    # every chunk compiled: the fences alone, over all the steps
+    ([(4, 30.0, 2.0, True)], [0.5] * 4),
+    ([(2, 0.5, 1.5, False), (1, 0.25, 0.75, False)], [1.0] * 3),
+    ([], []),
+], ids=["first_compiled", "all_compiled", "all_warm", "fully_resumed"])
+def test_epoch_times_come_from_the_loop_stamps_of_warm_chunks(
+        chunks, expected):
+    assert epoch_times_of([LoopChunk(*c) for c in chunks]) == expected
+
+
+def test_a_checkpointed_trains_epoch_times_are_built_from_its_loop_spans(
+        tmp_path):
+    als._get_train_loop.cache_clear()  # the first chunk compiles
+    u, i, r = ratings()
+    cfg = dataclasses.replace(CFG, iterations=3)
+    result, tl = under_timeline(lambda: als_train(
+        u, i, r, N_USERS, N_ITEMS, cfg,
+        checkpoint_dir=str(tmp_path / "ckpt"), checkpoint_every=1))
+    chunks = result.loop_chunks
+    assert [c.steps for c in chunks] == [1, 1, 1]
+    assert [c.compiled for c in chunks] == [True, False, False]
+    assert result.epoch_times == epoch_times_of(chunks)
+    assert result.epoch_times == [
+        (chunks[1].dispatch_s + chunks[1].wait_s
+         + chunks[2].dispatch_s + chunks[2].wait_s) / 2] * 3
+    # the stamps are those around the loop's spans: one pair a chunk, each
+    # stamp no shorter than the span inside it; saves lie outside them
+    by_name = {}
+    for n, s, e in als_spans(tl):
+        by_name.setdefault(n, []).append(e - s)
+    for k, name in ((1, "als.loop.dispatch"), (2, "als.loop.wait")):
+        assert len(by_name[name]) == 3
+        assert all(c[k] >= d for c, d in zip(chunks, by_name[name]))
+    saves = [n for n, *_ in tl.spans if n.startswith("checkpoint.save")]
+    assert len(saves) == 3 and len(by_name["als.readback"]) == 3
+
+
+@pytest.fixture()
+def ecommerce(memory_storage):
+    ingest(memory_storage)
+    variant = EngineVariant.from_dict(variant_dict({"numIterations": 2}))
+    engine = get_engine(variant.engine_factory)
+    return engine, extract_engine_params(engine, variant), variant
+
+
+def test_engine_train_records_the_dase_stages(memory_storage, ecommerce):
+    engine, ep, _ = ecommerce
+    ctx = WorkflowContext(storage=memory_storage, seed=1)
+    _, tl = under_timeline(lambda: engine.train(ctx, ep))
+    starts = {n: s for n, s, _d, _e, _nested in tl.spans}
+    assert (starts["dase.read"] < starts["dase.prepare"]
+            < starts["dase.train"] < starts["als.train"]
+            < starts["model.unit_norm"])
+
+
+def test_run_train_writes_one_train_phases_record(memory_storage, ecommerce,
+                                                  tmp_path):
+    engine, ep, variant = ecommerce
+    path = str(tmp_path / "metrics.jsonl")
+    with MetricsLogger(path) as metrics:
+        ctx = WorkflowContext(storage=memory_storage, seed=1,
+                              metrics=metrics)
+        CoreWorkflow.run_train(engine, ep, variant, ctx)
+    records = [json.loads(x) for x in open(path)]
+    phases = [x for x in records if x["stage"] == "train/phases"]
+    assert len(phases) == 1
+    assert {"workflow.train", "dase.read", "dase.prepare", "dase.train",
+            "als.train", "als.bucketize", "als.put_buckets",
+            "als.init_factors", "als.loop.dispatch", "als.loop.wait",
+            "als.readback", "model.unit_norm", "workflow.serialize",
+            "workflow.persist", "dropped_spans"} <= set(phases[0])
+    assert phases[0]["dropped_spans"] == 0
+    assert phases[0]["workflow.train"] >= phases[0]["dase.train"] >= (
+        phases[0]["als.train"])
+    assert [x["step"] for x in records if x["stage"] == "train/als"] == [1, 2]
+
+
+def test_run_train_writes_the_phases_of_a_failed_run(memory_storage,
+                                                     ecommerce, tmp_path,
+                                                     monkeypatch):
+    engine, ep, variant = ecommerce
+    from predictionio_tpu.templates.ecommerce import engine as template
+
+    def boom(*a, **k):
+        raise RuntimeError("no chip")
+    monkeypatch.setattr(template, "als_train", boom)
+    path = str(tmp_path / "metrics.jsonl")
+    with MetricsLogger(path) as metrics:
+        ctx = WorkflowContext(storage=memory_storage, seed=1,
+                              metrics=metrics)
+        with pytest.raises(RuntimeError, match="no chip"):
+            CoreWorkflow.run_train(engine, ep, variant, ctx)
+    phases = [json.loads(x) for x in open(path)]
+    assert [x["stage"] for x in phases] == ["train/phases"]
+    assert "dase.train" in phases[0] and "als.train" not in phases[0]
+
+
+def _prepared(module):
+    u, i, r = ratings()
+    fields = {"user_ids": BiMap.string_int([f"u{k}" for k in range(N_USERS)]),
+              "item_ids": BiMap.string_int([f"i{k}" for k in range(N_ITEMS)]),
+              "user_idx": u, "item_idx": i, "item_categories": {}}
+    value = ({"confidence": r} if "confidence" in
+             {f.name for f in dataclasses.fields(module.PreparedData)}
+             else {"counts": r})
+    return module.PreparedData(**fields, **value)
+
+
+@pytest.mark.parametrize("template,algorithm", [
+    ("ecommerce", "ECommAlgorithm"), ("similarproduct", "ALSAlgorithm")])
+def test_the_implicit_templates_emit_train_als(tmp_path, template, algorithm):
+    import importlib
+
+    module = importlib.import_module(
+        f"predictionio_tpu.templates.{template}.engine")
+    algo_cls = getattr(module, algorithm)
+    algo = algo_cls(algo_cls.params_class(rank=4, numIterations=3, seed=1))
+    path = str(tmp_path / "metrics.jsonl")
+    with MetricsLogger(path) as metrics:
+        _, tl = under_timeline(lambda: algo.train(
+            WorkflowContext(metrics=metrics), _prepared(module)))
+    lines = [json.loads(x) for x in open(path)]
+    assert [x["step"] for x in lines if x["stage"] == "train/als"] == [1, 2, 3]
+    assert all(x["epoch_time_s"] > 0 for x in lines)
+    assert "model.unit_norm" in [s[0] for s in tl.spans]
