@@ -16,7 +16,7 @@ import logging
 import os
 import subprocess
 import threading
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import numpy as np
 
@@ -91,13 +91,16 @@ def get_lib() -> Optional[ctypes.CDLL]:
                                  np.ctypeslib.ndpointer(np.int64),
                                  np.ctypeslib.ndpointer(np.float32))
         f64 = ctypes.c_double
-        lib.pio_plan_buckets.restype = i64
-        lib.pio_plan_buckets.argtypes = [
-            i32p, i64, ctypes.c_int32, i64, i64, i64, f64, i64p, i64p]
-        lib.pio_fill_buckets.restype = i64
-        lib.pio_fill_buckets.argtypes = [
-            i32p, i32p, f32p, i64, ctypes.c_int32, i64, i64, i64, f64, i64,
-            i64p, i64p, i32p, i32p, f32p, f32p]
+        lib.pio_bucket_plan.restype = i64
+        lib.pio_bucket_plan.argtypes = [
+            i32p, i32p, i64, ctypes.c_int32, i64, i64, i64, i64, f64,
+            ctypes.POINTER(ctypes.c_void_p), i64p, i64p, i64p, i64p]
+        lib.pio_bucket_fill.restype = i64
+        lib.pio_bucket_fill.argtypes = [
+            ctypes.c_void_p, i32p, i32p, f32p, i32p, i32p, f32p, f32p,
+            i32p, i32p]
+        lib.pio_bucket_free.restype = None
+        lib.pio_bucket_free.argtypes = [ctypes.c_void_p]
         cstr = ctypes.c_char_p
         cstrp = ctypes.POINTER(ctypes.c_char_p)
         i64_out = ctypes.POINTER(ctypes.c_int64)
@@ -245,46 +248,80 @@ def columnar_scan_native(db_path: str, sql: str, params: list,
         lib.pio_scan_free(handle)
 
 
+class NativeBuckets(NamedTuple):
+    buckets: list  # of ops.als.Bucket, caps ascending
+    split_rows: np.ndarray  # [n_split] int32: the split table
+    path: str  # how the columns were ordered: "native_counting" | "native_comparison"
+
+
+# why pio_bucket_plan / pio_bucket_fill declined (pio_native.cpp's k* codes)
+_BUCKET_DECLINED = {
+    -1: "row ids outside [0, n_rows) or negative column ids",
+    -2: "more than 63 bucket capacities",
+    -3: "more entries or rows than an int32 index holds",
+    -4: "out of memory",
+}
+
+
 def bucket_ragged_native(rows: np.ndarray, cols: np.ndarray,
                          vals: np.ndarray, n_rows: int,
                          row_multiple: int = 8,
                          max_cap: Optional[int] = None,
                          min_cap: int = 8,
-                         cap_growth: float = 1.5):
-    """COO → padded buckets via the C++ loader; output matches
-    ops.als.bucket_ragged bit for bit. Returns None when the native
-    library is unavailable (caller falls back to numpy)."""
+                         cap_growth: float = 1.5,
+                         split_cap: Optional[int] = None,
+                         ) -> Optional[NativeBuckets]:
+    """COO → padded buckets via the C++ loader, rows over `split_cap`
+    split into segments there too; output matches ops.als.bucket_ragged
+    (`max_cap`) and ops.als.bucket_ragged_split (`split_cap`) bit for bit.
+    Returns None when the native library is unavailable or declines the
+    input (caller falls back to numpy, which defines the semantics)."""
     lib = get_lib()
     if lib is None:
+        return None
+    if max_cap is not None and (max_cap < 1 or split_cap is not None):
+        return None  # degenerate cap, or a combination numpy never defined
+    if split_cap is not None and split_cap < 1:
         return None
     rows = np.ascontiguousarray(rows, dtype=np.int32)
     cols = np.ascontiguousarray(cols, dtype=np.int32)
     vals = np.ascontiguousarray(vals, dtype=np.float32)
     n = len(rows)
-    if max_cap is not None and max_cap < 1:
-        return None  # degenerate cap: numpy path defines the semantics
-    mc = 0 if max_cap is None else int(max_cap)
+    if len(cols) != n or len(vals) != n or not 0 <= n_rows < 2 ** 31:
+        return None
     caps = np.zeros(63, dtype=np.int64)
     rpads = np.zeros(63, dtype=np.int64)
-    nb = lib.pio_plan_buckets(rows, n, n_rows, row_multiple, mc, min_cap,
-                              cap_growth, caps, rpads)
+    has_seg = np.zeros(63, dtype=np.int64)
+    info = np.zeros(2, dtype=np.int64)
+    plan = ctypes.c_void_p()
+    nb = lib.pio_bucket_plan(rows, cols, n, n_rows, row_multiple,
+                             max_cap or 0, split_cap or 0, min_cap,
+                             cap_growth, ctypes.byref(plan), caps, rpads,
+                             has_seg, info)
     if nb < 0:
-        # out-of-range row ids: defer to the numpy path so behavior is
-        # identical with and without a toolchain
-        log.warning("native: row ids outside [0, n_rows) — numpy fallback")
+        # defer to the numpy path so behavior is identical with and
+        # without a toolchain
+        log.warning("native: bucketizer declined (%s) — numpy fallback",
+                    _BUCKET_DECLINED.get(nb, nb))
         return None
-    caps, rpads = caps[:nb], rpads[:nb]
-    total_rows = int(rpads.sum())
-    total_elems = int((rpads * caps).sum())
-    rows_out = np.empty(total_rows, dtype=np.int32)
-    cols_out = np.empty(total_elems, dtype=np.int32)
-    vals_out = np.empty(total_elems, dtype=np.float32)
-    mask_out = np.empty(total_elems, dtype=np.float32)
-    rc = lib.pio_fill_buckets(rows, cols, vals, n, n_rows, row_multiple,
-                              mc, min_cap, cap_growth, nb, caps, rpads,
-                              rows_out, cols_out, vals_out, mask_out)
+    try:
+        caps, rpads = caps[:nb], rpads[:nb]
+        total_rows = int(rpads.sum())
+        total_elems = int((rpads * caps).sum())
+        rows_out = np.empty(total_rows, dtype=np.int32)
+        segmap_out = np.empty(total_rows, dtype=np.int32)
+        split_rows = np.empty(int(info[0]), dtype=np.int32)
+        # zeroed here, not there: calloc's untouched pages cost nothing
+        cols_out = np.zeros(total_elems, dtype=np.int32)
+        vals_out = np.zeros(total_elems, dtype=np.float32)
+        mask_out = np.zeros(total_elems, dtype=np.float32)
+        rc = lib.pio_bucket_fill(plan, rows, cols, vals, rows_out, cols_out,
+                                 vals_out, mask_out, segmap_out, split_rows)
+    finally:
+        lib.pio_bucket_free(plan)
     if rc != 0:
-        log.warning("native: fill/plan disagreement (rc=%d) — fallback", rc)
+        log.warning("native: bucketizer fill failed (%s) — numpy fallback",
+                    _BUCKET_DECLINED.get(rc, rc))
         return None
 
     from predictionio_tpu.ops.als import Bucket
@@ -299,10 +336,13 @@ def bucket_ragged_native(rows: np.ndarray, cols: np.ndarray,
             cols=cols_out[eo:eo + rpad * cap].reshape(shape),
             vals=vals_out[eo:eo + rpad * cap].reshape(shape),
             mask=mask_out[eo:eo + rpad * cap].reshape(shape),
+            segmap=segmap_out[ro:ro + rpad] if has_seg[b] else None,
         ))
         ro += rpad
         eo += rpad * cap
-    return buckets
+    return NativeBuckets(
+        buckets, split_rows,
+        "native_counting" if info[1] else "native_comparison")
 
 
 def agg_props_native(db_path: str, sql: str, params: list,

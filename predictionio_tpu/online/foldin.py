@@ -98,7 +98,7 @@ def solve_rows(opposing: np.ndarray,
     cols = np.concatenate([np.asarray(c, np.int32) for c, _ in entries])
     vals = np.concatenate([np.asarray(v, np.float32) for _, v in entries])
     buckets = bucket_ragged(rows, cols, vals, n_rows=n,
-                            cap_growth=cfg.cap_growth)
+                            cap_growth=cfg.cap_growth, side="fold")
     k = opposing.shape[-1]
     # the opposing factor matrix grows a few rows per cold append, and
     # its row count is a traced shape — unpadded, EVERY post-append fold

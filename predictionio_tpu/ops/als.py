@@ -29,12 +29,13 @@ from __future__ import annotations
 import dataclasses
 import functools
 import logging
+import time
 from typing import NamedTuple, Optional, Sequence
 
 import numpy as np
 
 from predictionio_tpu.telemetry.registry import REGISTRY
-from predictionio_tpu.telemetry.spans import span
+from predictionio_tpu.telemetry.spans import record as record_span, span
 from predictionio_tpu.utils import faults
 
 log = logging.getLogger(__name__)
@@ -53,6 +54,14 @@ BUCKET_CELLS = REGISTRY.gauge(
     "Cells (rows x cap, summed over buckets, before chunk padding) of the "
     "side's buckets in the last als_train",
     labelnames=("side",))
+# Which way each bucketizer call went. A train whose sides read `numpy`
+# fell back: no toolchain, PIO_NATIVE=0, or an input the loader declines.
+BUCKETIZE_CALLS = REGISTRY.counter(
+    "als_bucketize_calls_total",
+    "bucket_ragged / bucket_ragged_split calls by side (user | item | "
+    "fold | rows) and the path that built the buckets (native_counting | "
+    "native_comparison | numpy)",
+    labelnames=("side", "path"))
 
 
 def _spanned(name: str):
@@ -105,6 +114,36 @@ def cap_ladder(max_count: int, min_cap: int, growth: float) -> np.ndarray:
     return np.asarray(ladder, dtype=np.int64)
 
 
+def _bucketize(rows, cols, vals, n_rows: int, row_multiple: int,
+               max_cap: Optional[int], split_cap: Optional[int],
+               cap_growth: float, side: str):
+    """(buckets, split_rows) from the native loader, or from the numpy
+    reference where the loader is absent or declines the input. Either
+    way the call says which: the counter for `/metrics`, the seconds
+    under the path's name for the timeline (`train/phases`)."""
+    from predictionio_tpu import native as _native
+
+    t0 = time.monotonic()
+    nb = _native.bucket_ragged_native(rows, cols, vals, n_rows,
+                                      row_multiple, max_cap, MIN_CAP,
+                                      cap_growth, split_cap)
+    if nb is not None:
+        buckets, split_rows, path = nb
+    else:
+        path = "numpy"
+        if split_cap is None:
+            buckets, split_rows = _bucket_ragged_numpy(
+                rows, cols, vals, n_rows, row_multiple, max_cap,
+                cap_growth), np.zeros(0, np.int32)
+        else:
+            buckets, split_rows = _bucket_ragged_split_numpy(
+                rows, cols, vals, n_rows, row_multiple, split_cap,
+                cap_growth)
+    BUCKETIZE_CALLS.labels(side=side, path=path).inc()
+    record_span(f"als.bucketize.{path}", time.monotonic() - t0)
+    return buckets, split_rows
+
+
 def bucket_ragged(
     rows: np.ndarray,
     cols: np.ndarray,
@@ -113,26 +152,30 @@ def bucket_ragged(
     row_multiple: int = 8,
     max_cap: Optional[int] = None,
     cap_growth: float = 1.5,
+    side: str = "rows",
 ) -> list[Bucket]:
     """COO triplets → per-row padded buckets, bucketed by nnz.
 
     Rows with no entries are skipped (their factors stay at init).
     `row_multiple` pads each bucket's row count (use mesh data-axis size ×
     8 so shards stay tile-aligned). `max_cap` truncates pathological rows
-    (keeping the most recent entries is the caller's job; default no cap).
-    `cap_growth` sets the capacity ladder (see `cap_ladder`).
+    to their first `max_cap` entries in the caller's order (keeping the
+    most recent ones is the caller's job; default no cap). `cap_growth`
+    sets the capacity ladder (see `cap_ladder`). `side` only labels the
+    call in `als_bucketize_calls_total`.
 
     The hot path runs in the native C++ loader (native/pio_native.cpp,
-    bit-identical output) when a toolchain is available; PIO_NATIVE=0 or
-    a failed build falls back to this numpy implementation.
+    bit-identical output, linear in the entry count) when a toolchain is
+    available; PIO_NATIVE=0, a failed build or an input it declines falls
+    back to the numpy implementation below, the reference.
     """
-    from predictionio_tpu import native as _native
+    return _bucketize(rows, cols, vals, n_rows, row_multiple, max_cap, None,
+                      cap_growth, side)[0]
 
-    nb = _native.bucket_ragged_native(rows, cols, vals, n_rows,
-                                      row_multiple, max_cap, MIN_CAP,
-                                      cap_growth)
-    if nb is not None:
-        return nb
+
+def _bucket_ragged_numpy(rows, cols, vals, n_rows: int, row_multiple: int,
+                         max_cap: Optional[int],
+                         cap_growth: float) -> list[Bucket]:
     rows = np.asarray(rows, dtype=np.int32)
     cols = np.asarray(cols, dtype=np.int32)
     vals = np.asarray(vals, dtype=np.float32)
@@ -182,6 +225,7 @@ def bucket_ragged_split(
     row_multiple: int = 8,
     split_cap: Optional[int] = None,
     cap_growth: float = 1.5,
+    side: str = "rows",
 ) -> tuple[list[Bucket], np.ndarray]:
     """`bucket_ragged`, but rows with more than `split_cap` entries are
     **split into segments** instead of padding the whole matrix out to the
@@ -196,20 +240,32 @@ def bucket_ragged_split(
     entries) before solving, so results are bit-comparable to the unsplit
     math in f32 accumulation.
 
+    The native loader splits, groups and column-sorts the side in one
+    call (`native.bucket_ragged_native` with `split_cap`); where it is
+    unavailable or declines, the numpy implementation below runs, with
+    the same output bit for bit.
+
     Returns (buckets, split_rows) where split_rows[u] is the original row
     id of split-table slot u (empty array when nothing was split).
     """
-    if split_cap is None or len(rows) == 0:
-        return (bucket_ragged(rows, cols, vals, n_rows, row_multiple,
-                              cap_growth=cap_growth),
+    return _bucketize(rows, cols, vals, n_rows, row_multiple, None, split_cap,
+                      cap_growth, side)
+
+
+def _bucket_ragged_split_numpy(rows, cols, vals, n_rows: int,
+                               row_multiple: int, split_cap: int,
+                               cap_growth: float):
+    def whole_rows():
+        return (_bucket_ragged_numpy(rows, cols, vals, n_rows, row_multiple,
+                                     None, cap_growth),
                 np.zeros(0, np.int32))
+    if len(rows) == 0:
+        return whole_rows()
     rows = np.asarray(rows, dtype=np.int32)
     counts = np.bincount(rows, minlength=n_rows)
     hot = np.nonzero(counts > split_cap)[0].astype(np.int32)
     if hot.size == 0:
-        return (bucket_ragged(rows, cols, vals, n_rows, row_multiple,
-                              cap_growth=cap_growth),
-                np.zeros(0, np.int32))
+        return whole_rows()
 
     cols = np.asarray(cols, dtype=np.int32)
     vals = np.asarray(vals, dtype=np.float32)
@@ -221,9 +277,9 @@ def bucket_ragged_split(
     rank = np.arange(len(rows_s), dtype=np.int64) - starts[rows_s]
     seg = (rank // split_cap).astype(np.int64)
 
-    # pseudo-row numbering: hot row h's segment s → n_rows + base[h] + s.
-    # Work on the hot-entry subset only: full-width [nnz] temporaries cost
-    # ~1 s per op at ML-20M scale on this host.
+    # pseudo-row numbering: hot row h's segment s → n_rows + base[h] + s
+    # (on the hot-entry subset only: a full-width [nnz] temporary is a
+    # pass over memory each)
     nseg = -(-counts[hot] // split_cap)
     base = np.concatenate(([0], np.cumsum(nseg)))[:-1]
     hot_slot = np.full(n_rows, -1, np.int64)
@@ -234,8 +290,8 @@ def bucket_ragged_split(
                       + seg[idx_hot]).astype(np.int32)
     n_rows_eff = int(n_rows + nseg.sum())
 
-    buckets = bucket_ragged(rows2, cols_s, vals_s, n_rows_eff, row_multiple,
-                            cap_growth=cap_growth)
+    buckets = _bucket_ragged_numpy(rows2, cols_s, vals_s, n_rows_eff,
+                                   row_multiple, None, cap_growth)
 
     # map pseudo ids back: real row ids + segmap into the split table
     pseudo_to_slot = np.repeat(hot_slot[hot], nseg).astype(np.int32)
@@ -377,8 +433,6 @@ def _bucket_cache_save(cache_dir: str, key: str,
         if os.path.exists(tmp):
             os.unlink(tmp)
         raise
-    import time
-
     entries = []
     for e in os.scandir(cache_dir):
         try:  # a concurrent rank's GC may unlink between scandir and stat
@@ -485,11 +539,11 @@ def bucketize_cached(
         with span("als.bucketize"):
             user_buckets, u_split = bucket_ragged_split(
                 user_idx, item_idx, ratings, n_users, row_multiple,
-                split_cap, cap_growth=cap_growth)
+                split_cap, cap_growth=cap_growth, side="user")
         with span("als.bucketize"):
             item_buckets, i_split = bucket_ragged_split(
                 item_idx, user_idx, ratings, n_items, row_multiple,
-                split_cap, cap_growth=cap_growth)
+                split_cap, cap_growth=cap_growth, side="item")
         if bucket_cache_dir:
             try:
                 # atomic write: concurrent ranks race safely (same bytes)
@@ -1123,8 +1177,6 @@ def als_train(
                      / np.sqrt(cfg.rank))
         user_factors, item_factors = place_factors(
             jnp.zeros((n_users, cfg.rank), dtype=dtype), item_init)
-
-    import time
 
     checkpoint_every = max(1, checkpoint_every)
     start_iter = 0

@@ -243,6 +243,37 @@ class TestFoldInMath:
             ref = np.linalg.solve(a, yc.T @ vals.astype(np.float64))
             np.testing.assert_allclose(x, ref, rtol=1e-3, atol=1e-4)
 
+    def test_fold_bucketizes_on_the_comparison_path_bitwise_as_numpy(self):
+        # a few histories against a catalog-wide column range: the native
+        # bucketizer must not build a histogram over the catalog for
+        # them, and what it builds is the numpy path's, so the solve is
+        import unittest.mock as mock
+
+        from predictionio_tpu import native
+        from predictionio_tpu.ops.als import BUCKETIZE_CALLS
+
+        if not native.native_available():
+            pytest.skip("no C++ toolchain")
+        rng = np.random.default_rng(5)
+        opposing = rng.standard_normal((200_000, 4)).astype(np.float32)
+        entries = self._entries(rng, n_rows=24, n_opposing=200_000, nnz=9)
+        entries[3] = (np.zeros(0, np.int32), np.zeros(0, np.float32))
+
+        def calls(path):
+            return BUCKETIZE_CALLS.labels(side="fold", path=path).value
+
+        before = {p: calls(p) for p in
+                  ("native_comparison", "native_counting", "numpy")}
+        solved = solve_rows(opposing, entries, self.CFG)
+        assert calls("native_comparison") == before["native_comparison"] + 1
+        assert calls("native_counting") == before["native_counting"]
+        assert calls("numpy") == before["numpy"]
+        with mock.patch.object(native, "bucket_ragged_native",
+                               return_value=None):
+            reference = solve_rows(opposing, entries, self.CFG)
+        assert calls("numpy") == before["numpy"] + 1
+        assert solved.tobytes() == reference.tobytes()
+
     def test_empty_history_rows_solve_to_zeros(self):
         rng = np.random.default_rng(3)
         opposing = rng.standard_normal((8, 4)).astype(np.float32)

@@ -65,10 +65,22 @@ def under_timeline(fn):
     return out, tl
 
 
+PATHS = "als.bucketize."  # + the path a bucketizer call took
+
+
 def als_spans(tl):
-    """The timeline's `als.*` spans as (name, start, end), by start."""
+    """The timeline's `als.*` spans as (name, start, end), by start; the
+    bucketizer's path records (`bucketize_paths`) left out."""
     return sorted(((n, s, s + d) for n, s, d, _e, _nested in tl.spans
-                   if n.startswith("als.")), key=lambda x: x[1])
+                   if n.startswith("als.") and not n.startswith(PATHS)),
+                  key=lambda x: x[1])
+
+
+def bucketize_paths(tl):
+    """(path, start, end) of every bucketizer call's record, by start."""
+    return sorted(((n[len(PATHS):], s, s + d)
+                   for n, s, d, _e, _nested in tl.spans
+                   if n.startswith(PATHS)), key=lambda x: x[1])
 
 
 @pytest.mark.parametrize("warm_cache,expected", [(False, MISS), (True, HIT)],
@@ -88,6 +100,13 @@ def test_als_train_records_its_phases_in_order_under_als_train(
     # one after the other: no phase starts before the one before it ended
     assert all(a[2] <= b[1] for a, b in zip(inner, inner[1:]))
     assert tl.dropped_spans == 0
+    # a side that was built says which way, inside its `als.bucketize`
+    builds = [(s, e) for n, s, e in got if n == "als.bucketize"]
+    paths = bucketize_paths(tl)
+    assert len(paths) == len(builds)
+    assert all(lo <= s and e <= hi and path in (
+        "native_counting", "native_comparison", "numpy")
+        for (path, s, e), (lo, hi) in zip(paths, builds))
 
 
 def test_without_a_timeline_nothing_is_recorded_and_the_factors_are_the_same():
@@ -223,6 +242,7 @@ def test_run_train_writes_one_train_phases_record(memory_storage, ecommerce,
             "als.readback", "model.unit_norm", "workflow.serialize",
             "workflow.persist", "dropped_spans"} <= set(phases[0])
     assert phases[0]["dropped_spans"] == 0
+    assert sum(k.startswith(PATHS) for k in phases[0]) == 1  # both sides' path
     assert phases[0]["workflow.train"] >= phases[0]["dase.train"] >= (
         phases[0]["als.train"])
     assert [x["step"] for x in records if x["stage"] == "train/als"] == [1, 2]
