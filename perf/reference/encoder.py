@@ -1,0 +1,235 @@
+"""Plain reference of the next-item encoder (`models/encoder.py`): the
+forward pass, both losses and, through `jax.grad`, their gradients.
+
+Straightforward `jax.numpy` in float32 under
+`jax.default_matmul_precision("highest")`: no kernel, no packing tricks
+(attention is a dense [L, L] mask from the segment ids), no
+recomputation, no dispatch (an expert runs on every token and its result
+is weighted by the router's weight for that token, zero where the token
+did not pick it). It takes the same parameter tree as the system and is
+given the same share of the model: the experts `first .. first + held - 1`
+of every expert layer and the vocabulary rows the tree holds.
+
+Departures from the published model (DeepSeek-V3's equations under
+JoyAI-LLM-Flash's config.json), each shared with the system:
+
+- The absent experts' part of an expert layer's result is left out, and
+  the partial result goes on to the next layer (a chip's share of a
+  deployment, without its exchange).
+- The vocabulary is the slice held here: logits, softmax and both losses
+  are over it.
+- Tokens are items of packed user histories: attention is causal inside
+  a history's own segment, and a RoPE position counts from the history's
+  start.
+- RoPE turns adjacent pairs (x[2i], x[2i+1]) (`rope_interleave`); the
+  published code permutes them into halves first, which changes no score
+  because queries and keys are permuted alike.
+- The MTP module takes h_t before the final norm (the last block's
+  residual stream); its own expert block has its own router bias.
+- The router's load-balance bias is a buffer: it picks, and is not in
+  the weights or the gradient. `n_group = topk_group = 1`, so the pick
+  is a plain top-k.
+
+One departure is the system's alone: in `ops/moe.py` an expert's output
+rows travel to the combine, and the gradient rows back, in the
+operands' dtype (bfloat16 in the benchmark's configuration) and not as
+the float32 sum, as an exchange between chips would carry them. Here
+everything stays in the dtype of the arrays given: float32, or bfloat16
+throughout where the benchmark's control hands it bfloat16 weights.
+
+`wrap` is applied to every block function, to the attention of a query
+block and to an expert's turn; the default does nothing. A caller that has to fit the
+published widths on one chip passes `jax.checkpoint` and a `q_block`,
+which change where intermediates are kept and not what is computed.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+
+def _identity(fn):
+    return fn
+
+
+def rms(x, w, eps):
+    return x / jnp.sqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * w
+
+
+def rope(x, positions, theta):
+    """x [L, heads, d] as d/2 pairs (x[2i], x[2i+1]), each turned by
+    position * theta^(-2i/d)."""
+    d = x.shape[-1]
+    freq = 1.0 / theta ** (np.arange(0, d, 2, dtype=np.float32) / d)
+    ang = positions.astype(jnp.float32)[:, None, None] * freq
+    cos, sin = jnp.cos(ang).astype(x.dtype), jnp.sin(ang).astype(x.dtype)
+    re, im = x[..., 0::2], x[..., 1::2]
+    return jnp.stack([re * cos - im * sin, re * sin + im * cos],
+                     axis=-1).reshape(x.shape)
+
+
+def swiglu(x, w13, w2):
+    f = w2.shape[0]
+    h = x @ w13
+    return (jax.nn.silu(h[:, :f]) * h[:, f:]) @ w2
+
+
+def attention(q, k, v, seg, scale, q_block, wrap):
+    """q, k [L, H, dk], v [L, H, dv]: softmax over the keys that are not
+    later than the query and lie in its segment. With `q_block`, the
+    queries go a block after another (`lax.map`), each against every
+    key."""
+    l = q.shape[0]
+
+    def rows(block):
+        q_rows, seg_rows, at = block
+        s = jnp.einsum("qhd,khd->hqk", q_rows, k) * scale
+        q_pos = at + jnp.arange(q_rows.shape[0])
+        mask = ((jnp.arange(l)[None, :] <= q_pos[:, None])
+                & (seg_rows[:, None] == seg[None, :]))
+        p = jax.nn.softmax(jnp.where(mask[None], s, -jnp.inf), axis=-1)
+        return jnp.einsum("hqk,khd->qhd", p, v)
+
+    if not q_block or q_block >= l:
+        return wrap(rows)((q, seg, 0))
+    n = l // q_block
+    out = jax.lax.map(wrap(rows), (q.reshape(n, q_block, *q.shape[1:]),
+                                   seg.reshape(n, q_block),
+                                   jnp.arange(n) * q_block))
+    return out.reshape(l, *out.shape[2:])
+
+
+def mla(p, cfg, x, seg, pos, q_block, wrap):
+    l = x.shape[0]
+    h, dn, dr, dv = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
+                     cfg.qk_rope_head_dim, cfg.v_head_dim)
+    q = (rms(x @ p["w_qa"], p["q_norm"], cfg.rms_norm_eps)
+         @ p["w_qb"]).reshape(l, h, dn + dr)
+    kva = x @ p["w_kva"]
+    kv = (rms(kva[:, :cfg.kv_lora_rank], p["kv_norm"], cfg.rms_norm_eps)
+          @ p["w_kvb"]).reshape(l, h, dn + dv)
+    k_rope = rope(kva[:, None, cfg.kv_lora_rank:], pos, cfg.rope_theta)
+    q = jnp.concatenate(
+        [q[..., :dn], rope(q[..., dn:], pos, cfg.rope_theta)], axis=-1)
+    k = jnp.concatenate(
+        [kv[..., :dn], jnp.broadcast_to(k_rope, (l, h, dr))], axis=-1)
+    o = attention(q, k, kv[..., dn:], seg, (dn + dr) ** -0.5, q_block, wrap)
+    return o.reshape(l, h * dv) @ p["w_o"]
+
+
+def expert_layer(p, bias, cfg, x, first=None, held=None, wrap=_identity):
+    """Shared expert + the part of the routed result that the experts
+    `first .. first + held - 1` give (the tree's own by default; the
+    share test passes other shares and an uncut tree), an expert after
+    another. Returns (y, tokens per held expert, each token's picks
+    [L, k])."""
+    first = cfg.expert_first if first is None else first
+    held = p["experts_w13"].shape[0] if held is None else held
+    offset = first - cfg.expert_first  # into the tree's stacked experts
+    s = jax.nn.sigmoid(x @ p["w_g"])
+    _, idx = jax.lax.top_k(s + bias[None, :], cfg.num_experts_per_tok)
+    picked = jnp.take_along_axis(s, idx, axis=-1)
+    w = cfg.routed_scaling_factor * picked / picked.sum(-1, keepdims=True)
+
+    def one(y, expert):
+        e, w13, w2 = expert
+        hit = idx == e
+        return y + (w * hit).sum(-1)[:, None] * swiglu(x, w13, w2), hit.sum()
+
+    y, counts = jax.lax.scan(
+        wrap(one), swiglu(x, p["shared_w13"], p["shared_w2"]),
+        (first + jnp.arange(held), p["experts_w13"][offset:offset + held],
+         p["experts_w2"][offset:offset + held]))
+    return y, counts, idx
+
+
+def block(p, bias, cfg, h, seg, pos, q_block, wrap):
+    h = h + mla(p["attn"], cfg, rms(h, p["norm1"], cfg.rms_norm_eps), seg,
+                pos, q_block, wrap)
+    x = rms(h, p["norm2"], cfg.rms_norm_eps)
+    if bias is None:
+        return h + swiglu(x, p["w13"], p["w2"]), None
+    y, counts, picks = expert_layer(p, bias, cfg, x, wrap=wrap)
+    return h + y, (counts, picks)
+
+
+def forward(params, cfg, tokens, seg, pos, q_block=None, wrap=_identity):
+    """One sequence: tokens, seg, pos [L]. Returns (h [L, D] before the
+    final norm, h_mtp or None, (counts, picks) per expert layer, the MTP
+    block's or None)."""
+    h = params["emb"][tokens]
+    for p in params["dense"]:
+        h, _ = wrap(lambda p, h: block(p, None, cfg, h, seg, pos, q_block,
+                                       wrap))(p, h)
+    routed = []
+    for n in range(cfg.n_moe):
+        p = jax.tree_util.tree_map(lambda a: a[n], params["moe"])
+        h, r = wrap(lambda p, b, h: block(p, b, cfg, h, seg, pos, q_block,
+                                          wrap))(
+            p, params["router_bias"][n], h)
+        routed.append(r)
+    if not cfg.num_nextn_predict_layers:
+        return h, None, routed, None
+    m = params["mtp"]
+    nxt = params["emb"][jnp.roll(tokens, -1)]
+    both = jnp.concatenate([rms(h, m["norm_h"], cfg.rms_norm_eps),
+                            rms(nxt, m["norm_e"], cfg.rms_norm_eps)], -1)
+    h2, r2 = wrap(lambda p, b, x: block(p, b, cfg, x, seg, pos, q_block,
+                                        wrap))(
+        m["block"], params["mtp_router_bias"], both @ m["w_eh"])
+    return h, h2, routed, r2
+
+
+def logits_of(params, cfg, h):
+    return rms(h, params["final_norm"], cfg.rms_norm_eps) @ params["head"]
+
+
+def nll_sums(params, cfg, tokens, seg, pos, q_block=None, wrap=_identity):
+    """One sequence's sums: (sum of CE terms, their count, sum of MTP
+    terms, their count, (counts, picks) per expert layer, the MTP
+    block's)."""
+    l = tokens.shape[0]
+    h, h2, routed, r2 = forward(params, cfg, tokens, seg, pos, q_block, wrap)
+
+    def part(hidden, k):
+        ahead = jnp.roll(seg, -k)
+        ok = (seg != 0) & (ahead == seg) & (jnp.arange(l) < l - k)
+        logits = wrap(lambda p, x: logits_of(p, cfg, x))(params, hidden)
+        logp = jax.nn.log_softmax(logits, -1)
+        nll = -jnp.take_along_axis(
+            logp, jnp.roll(tokens, -k)[:, None], axis=-1)[:, 0]
+        return jnp.sum(jnp.where(ok, nll, 0.0)), jnp.sum(ok)
+
+    s1, n1 = part(h, 1)
+    if h2 is None:
+        return s1, n1, 0.0, 0, routed, None
+    s2, n2 = part(h2, 2)
+    return s1, n1, s2, n2, routed, r2
+
+
+def losses(params, cfg, tokens, seg, pos, q_block=None, wrap=_identity):
+    """A batch [B, L], a sequence at a time: (loss, ce, ce_mtp, counts
+    [n_moe, held] or None, MTP counts or None)."""
+    with jax.default_matmul_precision("highest"):
+        parts = [nll_sums(params, cfg, tokens[b], seg[b], pos[b], q_block,
+                          wrap) for b in range(tokens.shape[0])]
+    s1, n1, s2, n2 = (sum(p[i] for p in parts) for i in range(4))
+    ce = s1 / jnp.maximum(n1, 1)
+    counts = (sum(jnp.stack([c for c, _ in p[4]]) for p in parts)
+              if cfg.n_moe else None)
+    if not cfg.num_nextn_predict_layers:
+        return ce, ce, None, counts, None
+    ce_mtp = s2 / jnp.maximum(n2, 1)
+    return (ce + cfg.mtp_loss_weight * ce_mtp, ce, ce_mtp, counts,
+            sum(p[5][0] for p in parts))
+
+
+def score(params, cfg, history):
+    """Next-item logits of one history (a 1-D array of item rows)."""
+    n = len(history)
+    with jax.default_matmul_precision("highest"):
+        h, _, _, _ = forward(params, cfg, jnp.asarray(history, jnp.int32),
+                             jnp.ones(n, jnp.int32), jnp.arange(n))
+        return logits_of(params, cfg, h[-1:])[0]

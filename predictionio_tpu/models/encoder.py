@@ -1,0 +1,509 @@
+"""The next-item encoder, driven by a configuration: latent attention
+(MLA), a dense or a sparse-expert feed-forward in every block, an
+optional multi-token-prediction (MTP) module, trained on packed
+histories.
+
+One code path runs every size. A configuration file in the published
+model's own key names (`EncoderConfig.from_json`) gives the widths, the
+share of the model held here and the training sizes; the sessionrec
+template's 16-wide default (`templates/sessionrec/encoder-16.json`) is
+one such file.
+
+Equations (the plain reference is `quality/encoder_reference.py`):
+
+    block   h += MLA(RMSNorm(h));  h += FFN(RMSNorm(h))
+    MLA     c_q = RMSNorm(x W_qa); [q_nope | q_rope] = c_q W_qb per head
+            [c_kv | k_rope] = x W_kva; c_kv = RMSNorm(c_kv)
+            [k_nope | v] = c_kv W_kvb per head; interleaved RoPE on q_rope
+            and on the one k_rope all heads share; scores
+            (q_nope.k_nope + q_rope.k_rope) / sqrt(d_nope + d_rope), causal
+            and inside a history's own segment; out = concat(heads) W_o
+    FFN     SwiGLU in the first `first_k_dense_replace` blocks; after them
+            shared SwiGLU + the held experts' part of the routed result
+            (`ops/moe.py`)
+    MTP     h' = W_eh [RMSNorm(h_t) ; RMSNorm(E(x_{t+1}))] -> one expert
+            block -> the shared final norm and head -> predicts x_{t+2}
+    loss    CE + mtp_loss_weight * CE_mtp, both inside segments
+
+Precision: parameters, gradients and Adam moments float32; matrix products
+take `compute_dtype` operands, accumulate in float32 and hand the float32
+sum on; router scores, softmax, norms, RoPE and the loss are float32.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import json
+import math
+
+import jax
+import jax.numpy as jnp
+
+from predictionio_tpu.ops import moe
+from predictionio_tpu.ops.attention import segment_attention
+
+
+@dataclasses.dataclass(frozen=True)
+class EncoderConfig:
+    hidden_size: int
+    intermediate_size: int
+    num_hidden_layers: int
+    num_attention_heads: int
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    first_k_dense_replace: int = 1 << 30  # every block dense
+    moe_intermediate_size: int = 0
+    n_routed_experts: int = 0        # held here
+    experts_total: int = 0           # the router's width
+    expert_first: int = 0            # id of the first held expert
+    n_shared_experts: int = 1
+    num_experts_per_tok: int = 0
+    routed_scaling_factor: float = 1.0
+    num_nextn_predict_layers: int = 0
+    mtp_loss_weight: float = 0.3
+    bias_update_rate: float = 0.001
+    vocab_size: int = 0              # 0: as many items as the data has
+    rope_theta: float = 10000.0
+    rms_norm_eps: float = 1e-6
+    init_std: float = 0.02
+    compute_dtype: str = "float32"   # operands of the matrix products
+    attention_block: int = 512
+    moe_block_rows: int = 256
+    loss_chunk: int = 4096
+    remat: bool = True
+    pack_len: int = 0                # 0: the sequence tier of maxSeqLen
+    seqs_per_step: int = 0           # 0: every sequence in one step
+    adam_b1: float = 0.9
+    adam_b2: float = 0.999
+    adam_eps: float = 1e-8
+    report_blocks: tuple = ()        # ((name, "leaf.path", (index ...)), ...)
+
+    @property
+    def n_dense(self) -> int:
+        return min(self.first_k_dense_replace, self.num_hidden_layers)
+
+    @property
+    def n_moe(self) -> int:
+        return self.num_hidden_layers - self.n_dense
+
+    @classmethod
+    def from_dict(cls, d: dict) -> "EncoderConfig":
+        """From a file in the published config's key names. The keys
+        `share`, `precision` and `train` are groups of this repo's own."""
+        flat = dict(d)
+        for group in ("share", "precision", "train"):
+            flat.update(d.get(group, {}))
+        known = {f.name for f in dataclasses.fields(cls)}
+        kw = {k: v for k, v in flat.items() if k in known and v is not None}
+        kw["report_blocks"] = tuple(
+            (b["name"], b["leaf"],
+             tuple(tuple(i) if isinstance(i, list) else int(i)
+                   for i in b.get("index", ())))
+            for b in flat.get("report_blocks", ()))
+        return cls(**kw)
+
+    @classmethod
+    def from_json(cls, path: str) -> "EncoderConfig":
+        with open(path) as f:
+            return cls.from_dict(json.load(f))
+
+
+def _dt(name: str):
+    return jnp.dtype(name)
+
+
+def rms_norm(x, w, eps):
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True)
+                             + eps) * w
+
+
+def rope(x, positions, theta: float):
+    """Interleaved rotary embedding: pairs (x[2i], x[2i+1]) turn by
+    position * theta^(-2i/d). x [..., L, heads, d]; positions [..., L]."""
+    d = x.shape[-1]
+    inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    ang = positions.astype(jnp.float32)[..., None, None] * inv
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    x = x.astype(jnp.float32)
+    even, odd = x[..., 0::2], x[..., 1::2]
+    return jnp.stack([even * cos - odd * sin, even * sin + odd * cos],
+                     axis=-1).reshape(x.shape)
+
+
+def _mm(cfg: "EncoderConfig", x, w):
+    """x w with `compute_dtype` operands and a float32 sum."""
+    dtype = _dt(cfg.compute_dtype)
+    return jnp.dot(x.astype(dtype), w.astype(dtype),
+                   preferred_element_type=jnp.float32)
+
+
+def swiglu(cfg: "EncoderConfig", x, w13, w2):
+    """SwiGLU with gate and up side by side in w13 [D, 2F]; w2 [F, D]."""
+    f = w2.shape[0]
+    h = _mm(cfg, x, w13)
+    return _mm(cfg, jax.nn.silu(h[..., :f]) * h[..., f:], w2)
+
+
+def _by_rows(cfg: "EncoderConfig", fn, x2d):
+    """fn over x2d [T, D], `loss_chunk` rows at a time where T is a
+    whole number of them, so that a wide hidden activation is only ever
+    held for one chunk (recomputed in the backward pass)."""
+    t, chunk = x2d.shape[0], cfg.loss_chunk
+    if not cfg.remat or t <= chunk or t % chunk:
+        return fn(x2d)
+    out = jax.lax.map(jax.checkpoint(fn),
+                      x2d.reshape(t // chunk, chunk, x2d.shape[1]))
+    return out.reshape(t, out.shape[-1])
+
+
+def mla(p, cfg: EncoderConfig, x, seg, pos, scope: str = "enc.mla"):
+    """Latent attention on x [B, L, D] (already normed)."""
+    cd = _dt(cfg.compute_dtype)
+    b, l, _ = x.shape
+    h, dn, dr, dv = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
+                     cfg.qk_rope_head_dim, cfg.v_head_dim)
+    c_q = rms_norm(_mm(cfg, x, p["w_qa"]), p["q_norm"], cfg.rms_norm_eps)
+    q = _mm(cfg, c_q, p["w_qb"]).reshape(b, l, h, dn + dr)
+    kva = _mm(cfg, x, p["w_kva"])
+    c_kv = rms_norm(kva[..., :cfg.kv_lora_rank], p["kv_norm"],
+                    cfg.rms_norm_eps)
+    kv = _mm(cfg, c_kv, p["w_kvb"]).reshape(b, l, h, dn + dv)
+    q_rope = rope(q[..., dn:], pos, cfg.rope_theta)
+    k_rope = rope(kva[..., None, cfg.kv_lora_rank:], pos, cfg.rope_theta)
+    q = jnp.concatenate([q[..., :dn], q_rope], axis=-1)
+    k = jnp.concatenate(
+        [kv[..., :dn], jnp.broadcast_to(k_rope, (b, l, h, dr))], axis=-1)
+    heads_first = lambda t: t.astype(cd).transpose(0, 2, 1, 3)  # noqa: E731
+    scale = 1.0 / math.sqrt(dn + dr)
+    o = segment_attention(heads_first(q), heads_first(k),
+                          heads_first(kv[..., dn:]), seg, pos,
+                          block=cfg.attention_block, scale=scale,
+                          scope=scope)
+    return _mm(cfg, o.transpose(0, 2, 1, 3).reshape(b, l, h * dv), p["w_o"])
+
+
+def expert_ffn(p, bias, cfg: EncoderConfig, x2d, scope: str = "enc.moe"):
+    """Shared SwiGLU + the held experts' part. Returns (y, routed): the
+    held experts' token counts, every expert's load and each token's
+    picks [T, k]."""
+    cd = _dt(cfg.compute_dtype)
+    with jax.named_scope("moe.router"):
+        idx, weights, load = moe.route(
+            x2d, p["w_g"], bias, cfg.num_experts_per_tok,
+            cfg.routed_scaling_factor)
+    with jax.named_scope("moe.experts"):
+        y, counts = moe.held_experts(
+            x2d, weights, idx, p["experts_w13"], p["experts_w2"],
+            cfg.expert_first, cfg.moe_block_rows, cd, scope)
+    with jax.named_scope("moe.shared"):
+        y = y + swiglu(cfg, x2d, p["shared_w13"], p["shared_w2"])
+    return y, {"counts": counts, "load": load, "picks": idx}
+
+
+def block(p, bias, cfg: EncoderConfig, h, seg, pos, scope: str = ""):
+    """One block on the residual stream h [B, L, D]. `bias` is None in a
+    dense block. Returns (h, routed), `routed` (see `expert_ffn`) None
+    when dense. Its ops are traced under `enc.mla`, `enc.dense_ffn` and
+    `enc.moe`, or all under `scope` where one is given (the MTP module's
+    block)."""
+    b, l, d = h.shape
+    with jax.named_scope(scope or "enc.mla"):
+        h = h + mla(p["attn"], cfg,
+                    rms_norm(h, p["norm1"], cfg.rms_norm_eps), seg, pos,
+                    scope or "enc.mla")
+    x2d = rms_norm(h, p["norm2"], cfg.rms_norm_eps).reshape(b * l, d)
+    if bias is None:
+        with jax.named_scope(scope or "enc.dense_ffn"):
+            y = _by_rows(cfg, lambda x: swiglu(cfg, x, p["w13"], p["w2"]),
+                         x2d)
+        return h + y.reshape(b, l, d), None
+    with jax.named_scope(scope or "enc.moe"):
+        y, routed = expert_ffn(p, bias, cfg, x2d, scope or "enc.moe")
+    return h + y.reshape(b, l, d), routed
+
+
+def _maybe_remat(fn, cfg: EncoderConfig):
+    return jax.checkpoint(fn) if cfg.remat else fn
+
+
+def encode(params, cfg: EncoderConfig, tokens, seg, pos):
+    """tokens, seg, pos [B, L] -> the last block's residual stream
+    [B, L, D] (before the final norm) and what the expert layers routed
+    (`expert_ffn`), stacked over the layers: counts [n_moe, held], load
+    [n_moe, experts_total], picks [n_moe, B * L, k]; None without one."""
+    h = jnp.take(params["emb"], tokens, axis=0)
+    for p in params["dense"]:
+        h = _maybe_remat(
+            lambda p, h: block(p, None, cfg, h, seg, pos)[0], cfg)(p, h)
+    if not cfg.n_moe:
+        return h, None
+
+    def one(h, layer):
+        p, bias = layer
+        return block(p, bias, cfg, h, seg, pos)
+
+    return jax.lax.scan(_maybe_remat(one, cfg), h,
+                        (params["moe"], params["router_bias"]))
+
+
+def mtp_hidden(params, cfg: EncoderConfig, h, tokens, seg, pos):
+    """The MTP module's residual stream for every position t: from h_t
+    and the embedding of token t+1 (rolled in; the last position of a
+    segment is masked by the loss)."""
+    p = params["mtp"]
+
+    def run(p, h):
+        nxt = jnp.take(params["emb"], jnp.roll(tokens, -1, axis=1), axis=0)
+        both = jnp.concatenate(
+            [rms_norm(h, p["norm_h"], cfg.rms_norm_eps),
+             rms_norm(nxt, p["norm_e"], cfg.rms_norm_eps)], axis=-1)
+        h2 = _mm(cfg, both, p["w_eh"])
+        return block(p["block"], params["mtp_router_bias"], cfg, h2, seg,
+                     pos, scope="enc.mtp")
+
+    with jax.named_scope("enc.mtp"):
+        return _maybe_remat(run, cfg)(p, h)
+
+
+def cross_entropy_sum(params, cfg: EncoderConfig, h2d, targets, valid):
+    """Sum over the valid rows of h2d [T, D] of -log softmax(head(
+    RMSNorm(h)))[target], a chunk of rows at a time: [chunk, vocabulary]
+    is the most that is ever held, and the backward pass recomputes it."""
+    t = h2d.shape[0]
+    chunk = cfg.loss_chunk if t % cfg.loss_chunk == 0 else t
+
+    def one(total, xs):
+        h_c, t_c, v_c = xs
+        logits = _mm(cfg, rms_norm(h_c, params["final_norm"],
+                                   cfg.rms_norm_eps), params["head"])
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        tgt = jnp.take_along_axis(logits, t_c[:, None], axis=-1)[:, 0]
+        return total + jnp.sum((lse - tgt) * v_c), None
+
+    split = lambda a: a.reshape((t // chunk, chunk) + a.shape[1:])  # noqa: E731
+    total, _ = jax.lax.scan(
+        _maybe_remat(one, cfg), jnp.float32(0.0),
+        (split(h2d), split(targets), split(valid.astype(jnp.float32))))
+    return total
+
+
+def losses(params, cfg: EncoderConfig, tokens, seg, pos):
+    """(CE + w * CE_mtp, aux). A position counts where its target lies in
+    its own history: t+1 for CE, t+2 for the MTP module."""
+    b, l = tokens.shape
+    h, routed = encode(params, cfg, tokens, seg, pos)
+
+    def shifted(k):
+        ahead = jnp.roll(seg, -k, axis=1)
+        ok = (seg != 0) & (ahead == seg) & (jnp.arange(l) < l - k)[None, :]
+        return jnp.roll(tokens, -k, axis=1).reshape(-1), ok.reshape(-1)
+
+    with jax.named_scope("enc.head_loss"):
+        t1, v1 = shifted(1)
+        ce = (cross_entropy_sum(params, cfg, h.reshape(b * l, -1), t1, v1)
+              / jnp.maximum(jnp.sum(v1), 1))
+    aux = {"ce": ce, **(routed or {})}
+    if not cfg.num_nextn_predict_layers:
+        return ce, aux
+    h2, mtp_routed = mtp_hidden(params, cfg, h, tokens, seg, pos)
+    with jax.named_scope("enc.head_loss"):
+        t2, v2 = shifted(2)
+        ce_mtp = (cross_entropy_sum(params, cfg, h2.reshape(b * l, -1),
+                                    t2, v2) / jnp.maximum(jnp.sum(v2), 1))
+    aux.update(ce_mtp=ce_mtp,
+               **{"mtp_" + k: v for k, v in mtp_routed.items()})
+    return ce + cfg.mtp_loss_weight * ce_mtp, aux
+
+
+# -- parameters ----------------------------------------------------------------
+
+def _attn_shapes(cfg: EncoderConfig) -> dict:
+    d, h = cfg.hidden_size, cfg.num_attention_heads
+    return {
+        "w_qa": (d, cfg.q_lora_rank), "q_norm": (cfg.q_lora_rank,),
+        "w_qb": (cfg.q_lora_rank,
+                 h * (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)),
+        "w_kva": (d, cfg.kv_lora_rank + cfg.qk_rope_head_dim),
+        "kv_norm": (cfg.kv_lora_rank,),
+        "w_kvb": (cfg.kv_lora_rank,
+                  h * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
+        "w_o": (h * cfg.v_head_dim, d),
+    }
+
+
+def _block_shapes(cfg: EncoderConfig, dense: bool) -> dict:
+    d = cfg.hidden_size
+    out = {"attn": _attn_shapes(cfg), "norm1": (d,), "norm2": (d,)}
+    if dense:
+        out.update(w13=(d, 2 * cfg.intermediate_size),
+                   w2=(cfg.intermediate_size, d))
+    else:
+        f, e = cfg.moe_intermediate_size, cfg.n_routed_experts
+        fs = f * cfg.n_shared_experts
+        out.update(w_g=(d, cfg.experts_total),
+                   shared_w13=(d, 2 * fs), shared_w2=(fs, d),
+                   experts_w13=(e, d, 2 * f), experts_w2=(e, f, d))
+    return out
+
+
+def param_shapes(cfg: EncoderConfig, vocab: int) -> dict:
+    """The parameter tree as shapes. Expert blocks are stacked on a
+    leading axis (`moe`), so that one scan runs them."""
+    d = cfg.hidden_size
+    shapes = {"emb": (vocab, d), "head": (d, vocab), "final_norm": (d,),
+              "dense": [_block_shapes(cfg, True)
+                        for _ in range(cfg.n_dense)]}
+    is_shape = lambda s: isinstance(s, tuple)  # noqa: E731
+    if cfg.n_moe:
+        shapes["moe"] = jax.tree_util.tree_map(
+            lambda s: (cfg.n_moe,) + s, _block_shapes(cfg, False),
+            is_leaf=is_shape)
+    if cfg.num_nextn_predict_layers:
+        shapes["mtp"] = {"norm_h": (d,), "norm_e": (d,), "w_eh": (2 * d, d),
+                         "block": _block_shapes(cfg, False)}
+    return shapes
+
+
+def count_parameters(cfg: EncoderConfig, vocab: int) -> int:
+    leaves = jax.tree_util.tree_leaves(
+        param_shapes(cfg, vocab), is_leaf=lambda s: isinstance(s, tuple))
+    return sum(math.prod(s) for s in leaves)
+
+
+def init_params(cfg: EncoderConfig, vocab: int, key):
+    """Weights normal(0, init_std), norms one; made where `key` lives."""
+    shapes = param_shapes(cfg, vocab)
+    leaves, tree = jax.tree_util.tree_flatten_with_path(
+        shapes, is_leaf=lambda s: isinstance(s, tuple))
+    keys = jax.random.split(key, len(leaves))
+    out = []
+    for k, (path, shape) in zip(keys, leaves):
+        name = str(path[-1])
+        if "norm" in name:
+            out.append(jnp.ones(shape, jnp.float32))
+        else:
+            out.append(cfg.init_std
+                       * jax.random.normal(k, shape, jnp.float32))
+    return jax.tree_util.tree_unflatten(tree, out)
+
+
+def init_buffers(cfg: EncoderConfig) -> dict:
+    """What the gradient does not touch: the routers' load-balance bias."""
+    out = {}
+    if cfg.n_moe:
+        out["router_bias"] = jnp.zeros((cfg.n_moe, cfg.experts_total),
+                                       jnp.float32)
+    if cfg.num_nextn_predict_layers:
+        out["mtp_router_bias"] = jnp.zeros(cfg.experts_total, jnp.float32)
+    return out
+
+
+def leaf_of(tree, path: str, index=()):
+    """`tree["a"]["b"][...]` for the path "a.b"; a digit indexes a list.
+    `index` then picks inside the leaf: an int, or (lo, hi) for a slice."""
+    node = tree
+    for part in path.split("."):
+        node = node[int(part)] if part.isdigit() else node[part]
+    if not index:
+        return node
+    return node[tuple(slice(*i) if isinstance(i, tuple) else i
+                      for i in index)]
+
+
+# -- the train step and the scorer -------------------------------------------
+
+def _balance(bias, load, rate):
+    load = load.astype(jnp.float32)
+    return bias + rate * jnp.sign(
+        jnp.mean(load, axis=-1, keepdims=True) - load)
+
+
+def train_step(cfg: EncoderConfig, lr: float):
+    """`step(state, tokens, seg, pos) -> (state, metrics)`: one Adam step
+    on a batch of packed sequences. state = {params, m, v, t, buffers}."""
+
+    def sessionrec_train_step(state, tokens, seg, pos):
+        buffers = state["buffers"]
+
+        def loss_fn(p):
+            return losses({**p, **buffers}, cfg, tokens, seg, pos)
+
+        (loss, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+            state["params"])
+        with jax.named_scope("enc.adam"):
+            t = state["t"] + 1.0
+            b1, b2 = cfg.adam_b1, cfg.adam_b2
+            tree_map = jax.tree_util.tree_map
+            m = tree_map(lambda mm, g: b1 * mm + (1 - b1) * g,
+                         state["m"], grads)
+            v = tree_map(lambda vv, g: b2 * vv + (1 - b2) * g * g,
+                         state["v"], grads)
+            params = tree_map(
+                lambda p, mm, vv: p - lr * (mm / (1.0 - b1 ** t))
+                / (jnp.sqrt(vv / (1.0 - b2 ** t)) + cfg.adam_eps),
+                state["params"], m, v)
+            new_buffers = dict(buffers)
+            if cfg.n_moe:
+                new_buffers["router_bias"] = _balance(
+                    buffers["router_bias"], aux["load"],
+                    cfg.bias_update_rate)
+            if cfg.num_nextn_predict_layers:
+                new_buffers["mtp_router_bias"] = _balance(
+                    buffers["mtp_router_bias"], aux["mtp_load"],
+                    cfg.bias_update_rate)
+        metrics = {"loss": loss, **{k: v for k, v in aux.items()
+                                    if not k.endswith("load")}}
+        return ({"params": params, "m": m, "v": v, "t": t,
+                 "buffers": new_buffers}, metrics)
+
+    return sessionrec_train_step
+
+
+def first_step_report(cfg: EncoderConfig):
+    """`report(state) -> {"grads", "params"}` for the configuration's
+    report blocks, from the state the first step of a train left: the
+    gradient the step used (Adam's first moment started at zero, so it
+    holds (1 - b1) times that gradient) and the parameters it moved."""
+
+    def sessionrec_first_step_report(state):
+        return {"grads": jax.tree_util.tree_map(
+                    lambda m: m / (1.0 - cfg.adam_b1),
+                    report_of(cfg, state["m"])),
+                "params": report_of(cfg, state["params"])}
+
+    return sessionrec_first_step_report
+
+
+def init_state(cfg: EncoderConfig, vocab: int, key):
+    params = init_params(cfg, vocab, key)
+    zeros = lambda: jax.tree_util.tree_map(jnp.zeros_like, params)  # noqa: E731
+    return {"params": params, "m": zeros(), "v": zeros(),
+            "t": jnp.float32(0.0), "buffers": init_buffers(cfg)}
+
+
+def report_of(cfg: EncoderConfig, params) -> dict:
+    """The configuration's report blocks out of `params` (or a tree of
+    its shape), as arrays of their own."""
+    return {name: jnp.array(leaf_of(params, path, ix))
+            for name, path, ix in cfg.report_blocks}
+
+
+def score(params, cfg: EncoderConfig, seq, lengths):
+    """Next-item logits [B, V] from right-padded histories seq [B, L]
+    with `lengths` real tokens each: the last real position's state
+    through the final norm and the head. Pads are a segment of their
+    own, so a real position never sees one."""
+    b, l = seq.shape
+    real = jnp.arange(l)[None, :] < lengths[:, None]
+    seg = real.astype(jnp.int32)
+    pos = jnp.where(real, jnp.arange(l)[None, :],
+                    jnp.arange(l)[None, :] - lengths[:, None])
+    h, _ = encode(params, cfg, jnp.where(real, seq, 0), seg,
+                  pos.astype(jnp.int32))
+    last = h[jnp.arange(b), jnp.clip(lengths - 1, 0, l - 1)]
+    return _mm(cfg, rms_norm(last, params["final_norm"], cfg.rms_norm_eps),
+               params["head"])

@@ -27,7 +27,7 @@ representation.
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, Iterable, List, Tuple
+from typing import Dict, Iterable, List, Optional, Tuple
 
 import numpy as np
 
@@ -61,11 +61,13 @@ class SessionRecModel:
     """Immutable served state for the sessionrec template.
 
     `params` is a plain dict pytree of numpy arrays (pickles with the
-    model store, device_puts cleanly at dispatch):
-
-        emb    [V+1, D]  item embeddings; row V is the sequence pad row
-        pos    [Lmax, D] learned positional embeddings (Lmax = top tier)
-        blocks [{wq, wk, wv, wo, w1, b1, w2, b2}]  attention blocks
+    model store, device_puts cleanly at dispatch): the parameter tree of
+    `models/encoder.py` (`param_shapes`) with the routers' bias buffers
+    beside it. Here only `emb` [V, D], the item embeddings, is read.
+    `encoder` is the encoder's configuration as a plain dict
+    (`dataclasses.asdict(EncoderConfig)`: this module stays free of jax);
+    `train_report` is what the first step of the train reported for the
+    configuration's report blocks, or None.
 
     `user_windows[user]` is the user's canonical recent-item window as
     item-id strings (oldest → newest, ≤ max_seq_len); `session_vecs` is
@@ -80,10 +82,12 @@ class SessionRecModel:
     session_vecs: Dict[str, np.ndarray]
     max_seq_len: int
     n_heads: int
+    encoder: Optional[dict] = None
+    train_report: Optional[dict] = None
 
     @property
     def n_items(self) -> int:
-        return int(self.params["emb"].shape[0]) - 1
+        return len(self.item_ids)
 
     def window_rows(self, items: Iterable[str]) -> List[int]:
         """Embedding rows for the known items of a window, order kept.

@@ -36,15 +36,183 @@ _NEG_INF = -1e30  # large-negative instead of -inf: keeps fully-masked rows
 # (causal ring blocks entirely in the future) NaN-free after softmax
 
 
-def dense_attention(q, k, v, causal: bool = False):
-    """Reference single-device attention. q,k,v: [B, H, S, D]."""
-    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / math.sqrt(q.shape[-1])
+def dense_attention(q, k, v, causal: bool = False, segment_ids=None,
+                    scale: Optional[float] = None):
+    """Reference single-device attention. q,k: [B, H, S, D]; v: [B, H, S,
+    Dv]. `segment_ids` [B, S] keeps a query inside the keys of its own
+    segment (packed histories); `scale` defaults to 1/sqrt(D)."""
+    scale = 1.0 / math.sqrt(q.shape[-1]) if scale is None else scale
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
+                   preferred_element_type=jnp.float32) * scale
+    sq, sk = s.shape[-2], s.shape[-1]
+    mask = None
     if causal:
-        sq, sk = s.shape[-2], s.shape[-1]
-        mask = jnp.tril(jnp.ones((sq, sk), dtype=bool), sk - sq)
+        mask = jnp.tril(jnp.ones((sq, sk), dtype=bool), sk - sq)[None, None]
+    if segment_ids is not None:
+        same = (segment_ids[:, :, None] == segment_ids[:, None, :])[:, None]
+        mask = same if mask is None else mask & same
+    if mask is not None:
         s = jnp.where(mask, s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bhqk,bhkd->bhqd", p, v)
+    return jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v,
+                      preferred_element_type=jnp.float32)
+
+
+# -- blockwise causal attention inside segments ------------------------------
+#
+# One sequence at a time, a query block at a time, over the key blocks
+# that can hold a visible key: from the block where the segment of the
+# query block's first token starts (`kv_lo`) to the query block itself.
+# The score matrix of a block pair is all that is ever held; the backward
+# pass recomputes it from the saved log-sum-exp (the flash-attention
+# recurrence, in plain jax.numpy: no kernel).
+
+def _slab(x, start, size, axis=1):
+    return jax.lax.dynamic_slice_in_dim(x, start, size, axis)
+
+
+def _pair_scores(qi, kj, seg_q, seg_k, i, j, blk, scale):
+    s = jnp.einsum("hqd,hkd->hqk", qi, kj,
+                   preferred_element_type=jnp.float32) * scale
+    q_pos = i * blk + jnp.arange(blk)
+    k_pos = j * blk + jnp.arange(blk)
+    mask = (k_pos[None, :] <= q_pos[:, None]) & (seg_q[:, None]
+                                                 == seg_k[None, :])
+    return s, mask[None]
+
+
+def _segment_fwd_seq(q, k, v, seg, kv_lo, blk, scale):
+    h, l, _ = q.shape
+    dv = v.shape[-1]
+
+    def q_block(i):
+        qi, seg_q = _slab(q, i * blk, blk), _slab(seg, i * blk, blk, 0)
+
+        def fold(j, carry):
+            kj, vj = _slab(k, j * blk, blk), _slab(v, j * blk, blk)
+            s, mask = _pair_scores(qi, kj, seg_q, _slab(seg, j * blk, blk, 0),
+                                   i, j, blk, scale)
+            return _online_fold(*carry, jnp.where(mask, s, _NEG_INF), vj)
+
+        o, m, den = jax.lax.fori_loop(
+            kv_lo[i], i + 1, fold,
+            (jnp.zeros((h, blk, dv), jnp.float32),
+             jnp.full((h, blk), _NEG_INF, jnp.float32),
+             jnp.zeros((h, blk), jnp.float32)))
+        return o / den[..., None], m + jnp.log(den)
+
+    o, lse = jax.lax.map(q_block, jnp.arange(l // blk))
+    return (o.transpose(1, 0, 2, 3).reshape(h, l, dv),
+            lse.transpose(1, 0, 2).reshape(h, l))
+
+
+def _segment_bwd_seq(q, k, v, seg, kv_lo, o, lse, do, blk, scale):
+    h, l, dk_ = q.shape
+    delta = jnp.sum(do * o, axis=-1)  # [H, L]
+
+    def q_block(carry, i):
+        qi, seg_q = _slab(q, i * blk, blk), _slab(seg, i * blk, blk, 0)
+        doi = _slab(do, i * blk, blk).astype(v.dtype)
+        lse_i, delta_i = _slab(lse, i * blk, blk), _slab(delta, i * blk, blk)
+
+        def fold(j, inner):
+            dq_i, dk, dv = inner
+            kj, vj = _slab(k, j * blk, blk), _slab(v, j * blk, blk)
+            s, mask = _pair_scores(qi, kj, seg_q, _slab(seg, j * blk, blk, 0),
+                                   i, j, blk, scale)
+            p = jnp.where(mask, jnp.exp(s - lse_i[..., None]), 0.0)
+            dp = jnp.einsum("hqd,hkd->hqk", doi, vj,
+                            preferred_element_type=jnp.float32)
+            ds = (p * (dp - delta_i[..., None]) * scale).astype(q.dtype)
+            dv_j = jnp.einsum("hqk,hqd->hkd", p.astype(v.dtype), doi,
+                              preferred_element_type=jnp.float32)
+            dk_j = jnp.einsum("hqk,hqd->hkd", ds, qi,
+                              preferred_element_type=jnp.float32)
+            dq_i = dq_i + jnp.einsum("hqk,hkd->hqd", ds, kj,
+                                     preferred_element_type=jnp.float32)
+            dk = jax.lax.dynamic_update_slice_in_dim(
+                dk, _slab(dk, j * blk, blk) + dk_j, j * blk, 1)
+            dv = jax.lax.dynamic_update_slice_in_dim(
+                dv, _slab(dv, j * blk, blk) + dv_j, j * blk, 1)
+            return dq_i, dk, dv
+
+        dq_i, dk, dv = jax.lax.fori_loop(
+            kv_lo[i], i + 1, fold,
+            (jnp.zeros((h, blk, dk_), jnp.float32),) + carry)
+        return (dk, dv), dq_i
+
+    (dk, dv), dq = jax.lax.scan(
+        q_block, (jnp.zeros(k.shape, jnp.float32),
+                  jnp.zeros(v.shape, jnp.float32)), jnp.arange(l // blk))
+    return dq.transpose(1, 0, 2, 3).reshape(q.shape), dk, dv
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _segment_attention(q, k, v, seg, kv_lo, blk, scale, scope):
+    return _segment_attention_fwd(q, k, v, seg, kv_lo, blk, scale, scope)[0]
+
+
+def _segment_attention_fwd(q, k, v, seg, kv_lo, blk, scale, scope):
+    with jax.named_scope(scope):
+        o, lse = jax.lax.map(
+            lambda a: _segment_fwd_seq(*a, blk, scale),
+            (q, k, v, seg, kv_lo))
+    return o, (q, k, v, seg, kv_lo, o, lse)
+
+
+def _segment_attention_bwd(blk, scale, scope, res, do):
+    # a backward pass is traced outside the caller's scopes: it opens the
+    # one it was given, so that a trace can tell whose time it is
+    q, k, v, seg, kv_lo, o, lse = res
+    with jax.named_scope(scope):
+        dq, dk, dv = jax.lax.map(
+            lambda a: _segment_bwd_seq(*a, blk, scale),
+            (q, k, v, seg, kv_lo, o, lse, do))
+    return (dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype),
+            None, None)
+
+
+_segment_attention.defvjp(_segment_attention_fwd, _segment_attention_bwd)
+
+
+def segment_attention(q, k, v, segment_ids, positions, block: int = 512,
+                      scale: Optional[float] = None,
+                      scope: str = "attention.segment"):
+    """Causal attention held inside segments, for packed sequences.
+
+    q, k: [B, H, L, D]; v: [B, H, L, Dv]; `segment_ids` [B, L] (tokens of
+    one history share an id, histories lie one after another);
+    `positions` [B, L] counts each token from its segment's start, which
+    is where the first key block a query block needs is read from. A
+    sequence that one block holds takes `dense_attention`; a longer one
+    goes a block pair at a time and never holds [H, L, L]; its ops,
+    those of the backward pass too, are traced under `scope`. Returns
+    [B, H, L, Dv] in float32."""
+    scale = 1.0 / math.sqrt(q.shape[-1]) if scale is None else scale
+    l = q.shape[2]
+    if l <= block:
+        return dense_attention(q, k, v, causal=True,
+                               segment_ids=segment_ids, scale=scale)
+    if l % block:
+        raise ValueError(f"sequence {l} is not a multiple of block {block}")
+    starts = (jnp.arange(l)[None, :] - positions)[:, ::block]
+    kv_lo = jnp.clip(starts // block, 0, None).astype(jnp.int32)
+    return _segment_attention(q, k, v, segment_ids, kv_lo, block, scale,
+                              scope)
+
+
+def _online_fold(o, m, l, s, v_blk):
+    """Fold one K/V block, given its masked scores s [..., Sq, Skv], into
+    the running (o, m, l) softmax state."""
+    m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+    # rescale old accumulators, then add this block's contribution
+    alpha = jnp.exp(m - m_new)
+    p = jnp.exp(s - m_new[..., None])
+    l_new = l * alpha + jnp.sum(p, axis=-1)
+    o_new = o * alpha[..., None] + jnp.einsum(
+        "...qk,...kd->...qd", p.astype(v_blk.dtype), v_blk,
+        preferred_element_type=o.dtype)
+    return o_new, m_new, l_new
 
 
 def _online_block(o, m, l, q, k_blk, v_blk, q_pos, kv_pos, causal):
@@ -53,14 +221,7 @@ def _online_block(o, m, l, q, k_blk, v_blk, q_pos, kv_pos, causal):
     if causal:
         mask = kv_pos[None, :] <= q_pos[:, None]  # [Sq, Skv]
         s = jnp.where(mask[None, None], s, _NEG_INF)
-    m_blk = jnp.max(s, axis=-1)  # [B, H, Sq]
-    m_new = jnp.maximum(m, m_blk)
-    # rescale old accumulators, then add this block's contribution
-    alpha = jnp.exp(m - m_new)
-    p = jnp.exp(s - m_new[..., None])
-    l_new = l * alpha + jnp.sum(p, axis=-1)
-    o_new = o * alpha[..., None] + jnp.einsum("bhqk,bhkd->bhqd", p, v_blk)
-    return o_new, m_new, l_new
+    return _online_fold(o, m, l, s, v_blk)
 
 
 def ring_attention(q, k, v, mesh: Mesh, axis: str = DATA_AXIS,

@@ -442,6 +442,10 @@ class DeviceClock:
             except Exception:  # noqa: BLE001 — the clock must never die
                 log.debug("device: drain measurement failed", exc_info=True)
             finally:
+                # the item holds the dispatch's outputs: kept across the
+                # wait for the next one, a train step's state (gigabytes
+                # on the device) would outlive its train
+                item = None
                 DEVICE_CLOCK_QUEUE.set(self._queue.qsize())
 
     def _measure(self, out: Any, t0: float, t1: float, fn: str, route: str,

@@ -1,12 +1,16 @@
 """Session-based next-item engine template (DASE components).
 
 The scenario-diversity frontier (ROADMAP item 4): every other served
-template is factor- or frequency-based; this one is a small causal
-self-attention next-item model — item embeddings plus 1–2
-`ops.attention.dense_attention` blocks — trained through the normal
-DataSource → Preparator → Algorithm path over per-user event sequences
-from `data/view.py`'s ordered aggregation, and served through the
-existing MicroBatcher.
+template is factor- or frequency-based; this one is a causal
+self-attention next-item model — the config-driven encoder of
+`models/encoder.py` (latent attention, dense or sparse-expert
+feed-forward, an optional multi-token-prediction module), by default
+one 16-wide dense block — trained through the normal DataSource →
+Preparator → Algorithm path over per-user event sequences from
+`data/view.py`'s ordered aggregation, packed into fixed sequences with
+attention held inside each history, and served through the existing
+MicroBatcher. The algorithm's `encoderConfig` names the encoder's
+configuration file; `engine.json` names `encoder-16.json`.
 
 Serving pads over TWO ragged axes on fixed ladders: the batcher's
 power-of-two bucket ladder bounds the batch dimension, and the
@@ -15,13 +19,17 @@ PIO_SERVING_SEQ_TIERS) bounds the history-length dimension — so the
 jitted scorer's executable space is (batch tiers × sequence tiers),
 each compiled once, instead of one compile per ragged length.
 
-Pad positions are exact no-ops, which is what makes batched-vs-single
-parity bitwise at every tier: histories right-pad, the causal mask
-keeps every real position from attending past itself (a masked score is
-`_NEG_INF`, whose softmax term underflows to exactly 0.0 in f32), the
-readout gathers the LAST REAL position's state, and all other ops are
-per-position or per-row. A history therefore scores identically at any
-tier that fits it and in any batch that carries it.
+Pad positions are exact no-ops: histories right-pad, pads are a segment
+of their own and the causal mask keeps every real position from
+attending past itself (a masked score's softmax term underflows to
+exactly 0.0 in f32), the readout gathers the LAST REAL position's
+state, and all other ops are per-position or per-row. A history
+therefore scores identically at any sequence tier that fits it. Across
+BATCH tiers the scores agree to the last bits and not always bitwise:
+XLA's CPU backend picks a dot kernel by the operands' shapes (a vector
+product, its own small-matrix loop, Eigen) and their sums differ in the
+last bit (`TestTierParity::test_batched_vs_single_bitwise_at_every_tier`
+shows it on the CPU).
 
 Wire shapes:
     query:  {"user": "u1", "num": 4}            — served session window
@@ -34,6 +42,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import logging
+import os
 from datetime import timezone
 from typing import Dict, List, Optional
 
@@ -57,6 +66,8 @@ from predictionio_tpu.models.session_model import (
     SessionRecModel,
     recent_window,
 )
+from predictionio_tpu.telemetry.registry import REGISTRY
+from predictionio_tpu.telemetry.spans import span
 from predictionio_tpu.serving.batcher import (
     pad_to_seq_tier,
     seq_tier_ladder,
@@ -174,89 +185,153 @@ class Preparator(BasePreparator):
         return PreparedData(item_ids=item_ids, user_seqs=user_seqs)
 
 
-# -- jitted forward ----------------------------------------------------------
+# -- packing ----------------------------------------------------------------
 
-def _encode(params, seq, n_heads: int):
-    """[B, L] padded item rows → [B, L, D] contextual states.
+def pack_histories(seqs, pack_len: int):
+    """Pack histories into sequences of `pack_len` tokens, first-fit
+    decreasing: the longest first, each into the first sequence that
+    still has room. A history longer than `pack_len` keeps its newest
+    `pack_len` items. Returns (tokens, segment ids, positions), each
+    int32 [n, pack_len]: a history's tokens share a segment id (1, 2, ...
+    along the sequence; 0 marks padding) and a position counts from the
+    history's start (padding counts from where it starts)."""
+    seqs = [np.asarray(s[-pack_len:], np.int32) for s in seqs]
+    order = sorted(range(len(seqs)), key=lambda n: -len(seqs[n]))
+    room: List[int] = []
+    placed: List[List[int]] = []
+    for n in order:
+        need = len(seqs[n])
+        for b, free in enumerate(room):
+            if need <= free:
+                break
+        else:
+            b = len(room)
+            room.append(pack_len)
+            placed.append([])
+        room[b] -= need
+        placed[b].append(n)
+    tokens = np.zeros((len(room), pack_len), np.int32)
+    seg = np.zeros_like(tokens)
+    pos = np.zeros_like(tokens)
+    for b, members in enumerate(placed):
+        at = 0
+        for k, n in enumerate(members):
+            end = at + len(seqs[n])
+            tokens[b, at:end] = seqs[n]
+            seg[b, at:end] = k + 1
+            pos[b, at:end] = np.arange(end - at)
+            at = end
+        pos[b, at:] = np.arange(pack_len - at)
+    return tokens, seg, pos
 
-    Right-padded rows index the pad embedding (row V); causal
-    dense_attention keeps every real position's state a function of
-    real positions only, so the encoding of a history is invariant to
-    the tier it was padded to (see module docstring)."""
-    import jax
-    import jax.numpy as jnp
-    from predictionio_tpu.ops.attention import dense_attention
 
-    emb = params["emb"]
-    x = emb[seq] + params["pos"][: seq.shape[1]][None, :, :]
-    b, l, d = x.shape
-    for blk in params["blocks"]:
-        q = (x @ blk["wq"]).reshape(b, l, n_heads, -1).transpose(0, 2, 1, 3)
-        k = (x @ blk["wk"]).reshape(b, l, n_heads, -1).transpose(0, 2, 1, 3)
-        v = (x @ blk["wv"]).reshape(b, l, n_heads, -1).transpose(0, 2, 1, 3)
-        a = dense_attention(q, k, v, causal=True)
-        x = x + a.transpose(0, 2, 1, 3).reshape(b, l, d) @ blk["wo"]
-        x = x + (jax.nn.relu(x @ blk["w1"] + blk["b1"]) @ blk["w2"]
-                 + blk["b2"])
-    return x
+# -- the encoder's programs ----------------------------------------------------
+
+def _resolve_config_path(path: str) -> str:
+    """A configuration file as named: absolute, from the working
+    directory, or beside this template's engine.json."""
+    if os.path.isabs(path) or os.path.exists(path):
+        return path
+    return os.path.join(os.path.dirname(os.path.abspath(__file__)), path)
+
+
+DEFAULT_ENCODER = "encoder-16.json"  # beside this template's engine.json
 
 
 @functools.lru_cache(maxsize=8)
-def _scorer(n_heads: int):
+def _encoder_config(path: str, embed_dim: Optional[int] = None,
+                    num_blocks: Optional[int] = None,
+                    num_heads: Optional[int] = None):
+    """The configuration the file `path` gives. With no file, the
+    template's default block (`encoder-16.json`: dense blocks, float32,
+    every sequence in one step) at the sizes given: a block's widths
+    follow from `embed_dim` and `num_heads` in the default's own
+    proportions."""
+    from predictionio_tpu.models.encoder import EncoderConfig
+
+    sizes = (embed_dim, num_blocks, num_heads)
+    cfg = EncoderConfig.from_json(_resolve_config_path(path
+                                                       or DEFAULT_ENCODER))
+    if path:
+        if any(s is not None for s in sizes):
+            raise ValueError(
+                "encoderConfig gives the encoder's sizes: embedDim, "
+                "numBlocks and numHeads size the default block only")
+        return cfg
+    d = int(embed_dim or cfg.hidden_size)
+    h = int(num_heads or cfg.num_attention_heads)
+    n = int(num_blocks or cfg.num_hidden_layers)
+    return dataclasses.replace(
+        cfg, hidden_size=d, intermediate_size=2 * d, num_hidden_layers=n,
+        first_k_dense_replace=n, num_attention_heads=h, q_lora_rank=d,
+        kv_lora_rank=max(d // 2, 4), qk_nope_head_dim=max(d // h, 2),
+        qk_rope_head_dim=max(d // (2 * h) * 2, 2), v_head_dim=max(d // h, 2))
+
+
+def _config_of(model: SessionRecModel):
+    """The encoder's configuration back from the model's plain dict."""
+    from predictionio_tpu.models.encoder import EncoderConfig
+
+    return EncoderConfig(**model.encoder)
+
+
+@functools.lru_cache(maxsize=8)
+def _scorer(cfg):
     """The served next-item scorer, metered so every dispatch lands in
     the jit-cache inventory / device attribution and a ladder miss
     names its changed dimension in /debug/jit.json. Executable space:
     one compile per (batch tier, sequence tier) after warmup — args are
     (params pytree, seq [B, L], lengths [B]), so a sequence-ladder miss
     blames "arg1 dim1: <old>→<new>"."""
-    from predictionio_tpu.utils.profiling import metered_jit
+    from predictionio_tpu.models import encoder
+    from predictionio_tpu.utils import profiling
 
     def score(params, seq, lengths):
-        import jax.numpy as jnp
+        return encoder.score(params, cfg, seq, lengths)
 
-        x = _encode(params, seq, n_heads)
-        b, l, _ = x.shape
-        idx = jnp.clip(lengths - 1, 0, l - 1)
-        h = x[jnp.arange(b), idx]  # last REAL position per row
-        n_items = params["emb"].shape[0] - 1
-        return h @ params["emb"][:n_items].T  # tied output embedding
-
-    return metered_jit(score, label="sessionrec.score")
+    return profiling.metered_jit(score, label="sessionrec.score")
 
 
 @functools.lru_cache(maxsize=8)
-def _train_step(n_heads: int, lr: float):
-    """One full-batch Adam step on masked next-item cross-entropy."""
-    from predictionio_tpu.utils.profiling import metered_jit
+def _train_programs(cfg, vocab: int, lr: float):
+    """(init, step, report) for a configuration: the state made on the
+    device from a key; one Adam step on a batch of packed sequences (the
+    state donated); the configuration's report blocks out of the state
+    the first step left."""
+    from predictionio_tpu.models import encoder
+    from predictionio_tpu.utils import profiling
 
-    def step(params, m, v, t, seq, lengths):
-        import jax
-        import jax.numpy as jnp
+    def init(key):
+        return encoder.init_state(cfg, vocab, key)
 
-        def loss_fn(p):
-            x = _encode(p, seq, n_heads)
-            n_items = p["emb"].shape[0] - 1
-            logits = x[:, :-1] @ p["emb"][:n_items].T  # [B, L-1, V]
-            targets = jnp.minimum(seq[:, 1:], n_items - 1)
-            mask = (jnp.arange(seq.shape[1] - 1)[None, :]
-                    < (lengths - 1)[:, None]).astype(logits.dtype)
-            logp = jax.nn.log_softmax(logits, axis=-1)
-            nll = -jnp.take_along_axis(
-                logp, targets[..., None], axis=-1)[..., 0]
-            return (nll * mask).sum() / jnp.maximum(mask.sum(), 1.0)
+    return (profiling.metered_jit(init, label="sessionrec.init"),
+            profiling.metered_jit(encoder.train_step(cfg, lr),
+                                  label="sessionrec.train_step",
+                                  donate_argnums=(0,)),
+            profiling.metered_jit(encoder.first_step_report(cfg),
+                                  label="sessionrec.first_step_report"))
 
-        loss, grads = jax.value_and_grad(loss_fn)(params)
-        t = t + 1.0
-        tree_map = jax.tree_util.tree_map
-        m = tree_map(lambda mm, g: 0.9 * mm + 0.1 * g, m, grads)
-        v = tree_map(lambda vv, g: 0.999 * vv + 0.001 * g * g, v, grads)
-        params = tree_map(
-            lambda p, mm, vv: p - lr * (mm / (1.0 - 0.9 ** t))
-            / (jnp.sqrt(vv / (1.0 - 0.999 ** t)) + 1e-8),
-            params, m, v)
-        return params, m, v, t, loss
 
-    return metered_jit(step, label="sessionrec.train_step")
+def _run_steps(programs, state, batches, epochs: int, report: bool):
+    """Every optimizer step of a train, dispatched back to back and
+    waited for once: the device goes from one step's program to the
+    next without the host in between. Returns (state, the first step's
+    metrics, the last step's, the first step's report or None)."""
+    import jax
+
+    _, step, first_report = programs
+    first = last = reported = None
+    with span("sessionrec.loop.dispatch"):
+        for _ in range(epochs):
+            for batch in batches:
+                state, last = step(state, *batch)
+                if first is None:
+                    first = last
+                    if report:
+                        reported = first_report(state)
+    with span("sessionrec.loop.wait"):
+        jax.block_until_ready((state, last, reported))
+    return state, first, last, reported
 
 
 def _pad_batch_tier(n: int) -> int:
@@ -271,27 +346,51 @@ def _pad_batch_tier(n: int) -> int:
 
 def _serve_tiers(model: SessionRecModel) -> tuple:
     """Sequence tiers this model can serve: the env ladder clamped to
-    the trained positional table (a tier the table can't cover would
-    index past it)."""
-    l_pos = int(np.asarray(model.params["pos"]).shape[0])
+    the top tier of the model's own window length (positions are
+    rotary, so the clamp bounds the executable space, not a table)."""
+    top = seq_tier_ladder(model.max_seq_len)[-1]
     tiers = tuple(t for t in seq_tiers_from_env(model.max_seq_len)
-                  if t <= l_pos)
+                  if t <= top)
     return tiers or seq_tier_ladder(model.max_seq_len)
+
+
+# Set by every SessionRecAlgorithm.train. tokens / cells is the share of
+# a step's positions that hold an event and not padding.
+PACK_TOKENS = REGISTRY.gauge(
+    "encoder_pack_tokens", "Events placed into the packed sequences of "
+    "the last sessionrec train")
+PACK_CELLS = REGISTRY.gauge(
+    "encoder_pack_cells", "Positions (sequences x length) of the packed "
+    "sequences of the last sessionrec train")
+PACK_FILL = REGISTRY.gauge(
+    "encoder_pack_fill", "encoder_pack_tokens / encoder_pack_cells of "
+    "the last sessionrec train")
+EXPERT_TOKENS = REGISTRY.gauge(
+    "encoder_expert_tokens", "Tokens the last train step sent to each "
+    "held expert, by expert layer (mtp = the MTP module's) and expert id",
+    labelnames=("layer", "expert"))
+TOKENS_TOTAL = REGISTRY.counter(
+    "encoder_tokens_total", "Events the sessionrec train steps have "
+    "trained on (padding not counted)")
 
 
 @dataclasses.dataclass
 class SessionRecParams(Params):
-    embedDim: int = 16
-    numBlocks: int = 1
-    numHeads: int = 2
+    # sizes of the default block; not to be given beside encoderConfig
+    embedDim: Optional[int] = None
+    numBlocks: Optional[int] = None
+    numHeads: Optional[int] = None
     maxSeqLen: int = 32
     epochs: int = 30
     stepSize: float = 0.05
     seed: Optional[int] = None
+    # a configuration file of models/encoder.py (see EncoderConfig);
+    # empty: the default block (encoder-16.json) at the sizes above
+    encoderConfig: str = ""
 
 
 class SessionRecAlgorithm(Algorithm):
-    """Causal self-attention next-item model over session windows."""
+    """Causal latent-attention next-item model over session windows."""
 
     params_class = SessionRecParams
     checkpoint_tags = ("sessionrec",)
@@ -304,59 +403,67 @@ class SessionRecAlgorithm(Algorithm):
         import jax
 
         p = self.params
+        cfg = _encoder_config(p.encoderConfig, p.embedDim, p.numBlocks,
+                              p.numHeads)
         seed = ctx.seed if p.seed is None else p.seed
-        rng = np.random.default_rng(int(seed) if seed is not None else 0)
         n_items = len(pd.item_ids)
-        d = int(p.embedDim)
+        vocab = int(cfg.vocab_size) or n_items
+        if n_items > vocab:
+            raise ValueError(f"{n_items} items do not fit the encoder's "
+                             f"vocabulary of {vocab}")
         cap = int(p.maxSeqLen)
-        # positional table spans the default ladder's top tier for this
-        # window length — independent of the serve-time env so a model
-        # never deploys with fewer positions than its own ladder needs
-        l_pos = seq_tier_ladder(cap)[-1]
+        pack_len = int(cfg.pack_len) or seq_tier_ladder(cap)[-1]
 
-        def init_w(*shape):
-            return (rng.standard_normal(shape) * 0.1).astype(np.float32)
+        with span("sessionrec.pack"):
+            seqs = [s[-cap:] for _, s in sorted(pd.user_seqs.items())
+                    if len(s) >= 2]
+            tokens, seg, pos = pack_histories(seqs, pack_len)
+            # whole steps: sequences of padding alone fill the last one
+            per_step = int(cfg.seqs_per_step) or _pad_batch_tier(len(tokens))
+            short = -len(tokens) % per_step
+            if short:
+                tokens, seg = (np.pad(a, ((0, short), (0, 0)))
+                               for a in (tokens, seg))
+                pos = np.concatenate(
+                    [pos, np.tile(np.arange(pack_len, dtype=np.int32),
+                                  (short, 1))])
+            real = int((seg != 0).sum())
+            PACK_TOKENS.set(real)
+            PACK_CELLS.set(seg.size)
+            PACK_FILL.set(real / seg.size if seg.size else 0.0)
 
-        blocks = []
-        for _ in range(int(p.numBlocks)):
-            blocks.append({
-                "wq": init_w(d, d), "wk": init_w(d, d),
-                "wv": init_w(d, d), "wo": init_w(d, d),
-                "w1": init_w(d, 2 * d),
-                "b1": np.zeros(2 * d, np.float32),
-                "w2": init_w(2 * d, d),
-                "b2": np.zeros(d, np.float32),
-            })
-        params = {
-            # row n_items is the sequence pad row (kept zero at init;
-            # pads never reach the loss or the readout)
-            "emb": np.concatenate(
-                [init_w(n_items, d), np.zeros((1, d), np.float32)]),
-            "pos": init_w(l_pos, d),
-            "blocks": blocks,
-        }
-
-        seqs = [s[-cap:] for _, s in sorted(pd.user_seqs.items())
-                if len(s) >= 2]
-        n = len(seqs)
-        if n:
-            bt = _pad_batch_tier(n)
-            seq = np.full((bt, l_pos), n_items, np.int32)
-            lengths = np.zeros(bt, np.int32)
-            for r, s in enumerate(seqs):
-                seq[r, :len(s)] = s
-                lengths[r] = len(s)
-            step = _train_step(int(p.numHeads), float(p.stepSize))
-            m = jax.tree_util.tree_map(np.zeros_like, params)
-            v = jax.tree_util.tree_map(np.zeros_like, params)
-            t = np.float32(0.0)
-            loss = None
-            for _ in range(int(p.epochs)):
-                params, m, v, t, loss = step(params, m, v, t, seq, lengths)
-            params = jax.tree_util.tree_map(np.asarray, params)
+        programs = _train_programs(cfg, vocab, float(p.stepSize))
+        with span("sessionrec.init"):
+            state = programs[0](jax.random.key(
+                int(seed) % (2 ** 31 - 1) if seed is not None else 0))
+            # a step's batch: [sequences a step, length] of each array
+            n_steps = len(tokens) // per_step if seqs else 0
+            batches = [tuple(a[n * per_step:(n + 1) * per_step]
+                             for a in (tokens, seg, pos))
+                       for n in range(n_steps)]
+            on_device = jax.device_put(batches)
+        state, first, metrics, reported = _run_steps(
+            programs, state, on_device, int(p.epochs),
+            bool(cfg.report_blocks))
+        TOKENS_TOTAL.inc(real * int(p.epochs))
+        with span("sessionrec.readback"):
+            params = jax.device_get({**state["params"], **state["buffers"]})
+            first, metrics, reported = jax.device_get(
+                (first, metrics, reported))
+        train_report = (None if reported is None else
+                        {"batch": batches[0], "metrics": first, **reported})
+        del state
+        if metrics is not None:
+            for name in ("counts", "mtp_counts"):
+                rows = np.atleast_2d(metrics.get(name, np.zeros((0, 0))))
+                for layer, row in enumerate(rows):
+                    for e, count in enumerate(row):
+                        EXPERT_TOKENS.labels(
+                            layer="mtp" if name == "mtp_counts"
+                            else str(layer),
+                            expert=str(cfg.expert_first + e)).set(int(count))
             log.info("SessionRec: trained %d sequences, %d items, final "
-                     "loss %.4f", n, n_items,
-                     float(loss) if loss is not None else float("nan"))
+                     "loss %.4f", len(seqs), n_items, float(metrics["loss"]))
 
         windows = {
             u: tuple(pd.item_ids.from_index(s[-cap:]))
@@ -364,9 +471,14 @@ class SessionRecAlgorithm(Algorithm):
         }
         model = SessionRecModel(
             params=params, item_ids=pd.item_ids, user_windows=windows,
-            session_vecs={}, max_seq_len=cap, n_heads=int(p.numHeads))
-        model.session_vecs.update(
-            {u: model.session_vec_of(w) for u, w in windows.items()})
+            session_vecs={}, max_seq_len=cap,
+            n_heads=int(cfg.num_attention_heads),
+            encoder=dataclasses.asdict(cfg), train_report=train_report)
+        with span("model.session_vecs"):
+            # a window at a time: one gather of every window's rows (a
+            # gigabyte at 131072 events x 2048) was 15x slower on the host
+            model.session_vecs.update(
+                {u: model.session_vec_of(w) for u, w in windows.items()})
         return model
 
     def predict(self, model: SessionRecModel,
@@ -397,12 +509,11 @@ class SessionRecAlgorithm(Algorithm):
             groups.setdefault(tier, []).append((pos, rows, num))
         if not groups:
             return out
-        score = _scorer(model.n_heads)
-        pad_row = model.n_items
+        score = _scorer(_config_of(model))
         for tier, entries in groups.items():
             b = len(entries)
             bt = _pad_batch_tier(b)
-            seq = np.full((bt, tier), pad_row, np.int32)
+            seq = np.zeros((bt, tier), np.int32)
             lengths = np.zeros(bt, np.int32)
             for r, (_, rows, _) in enumerate(entries):
                 seq[r, :len(rows)] = rows
@@ -414,7 +525,7 @@ class SessionRecAlgorithm(Algorithm):
                 lengths[b:] = lengths[b - 1]
             logits = np.asarray(score(model.params, seq, lengths))
             for r, (pos, rows, num) in enumerate(entries):
-                s = logits[r].copy()
+                s = logits[r][:model.n_items].copy()
                 seen = np.unique(np.asarray(rows, np.int32))
                 s[seen] = -np.inf  # never re-recommend the window
                 k = min(num, s.shape[0] - len(seen))

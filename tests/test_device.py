@@ -382,3 +382,33 @@ class TestCoverageJitMeteringRule:
             os.path.dirname(os.path.abspath(__file__)))
         proj = Project(repo_root, subdirs=("predictionio_tpu",))
         assert engine.run_rules(proj, ["coverage-jit-metering"]) == []
+
+
+class TestDeviceClockDropsWhatItMeasured:
+    def test_the_drain_thread_keeps_no_dispatch_output_alive(self):
+        """The output of a train step is the train's whole state on the
+        device. The drain thread waits for its next item with the last
+        one no longer referenced, or that state would outlive its train
+        (it did: 8 GB of an encoder's state held the chip at PR 27)."""
+        import gc
+        import weakref
+
+        import jax  # noqa: F401  (or the drain thread's first import is timed)
+
+        class Output:
+            pass
+
+        clock = device.DeviceClock(maxsize=4)
+        try:
+            out = Output()
+            alive = weakref.ref(out)
+            assert clock.submit(out, 0.0, 0.0, "t.drop", "(test)", "", False)
+            del out
+            assert clock.flush(timeout=5.0)
+            deadline = time.monotonic() + 20.0
+            while alive() is not None and time.monotonic() < deadline:
+                gc.collect()
+                time.sleep(0.01)
+            assert alive() is None
+        finally:
+            clock.stop()

@@ -1,0 +1,326 @@
+"""The config-driven next-item encoder (`models/encoder.py`,
+`ops/attention.py::segment_attention`, `ops/moe.py`) against its plain
+reference (`quality/encoder_reference.py`) at small widths on the CPU:
+hidden 32, 8 experts of which 2 are held, one dense block, two expert
+blocks and the MTP module. Seeded weights, float32 throughout."""
+
+import dataclasses
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from predictionio_tpu.models import encoder as enc
+from predictionio_tpu.ops import moe
+from predictionio_tpu.ops.attention import dense_attention, segment_attention
+from predictionio_tpu.quality import encoder_reference as ref
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+VOCAB = 50
+CFG = enc.EncoderConfig(
+    hidden_size=32, intermediate_size=48, num_hidden_layers=3,
+    num_attention_heads=4, q_lora_rank=24, kv_lora_rank=16,
+    qk_nope_head_dim=8, qk_rope_head_dim=4, v_head_dim=8,
+    first_k_dense_replace=1, moe_intermediate_size=12, n_routed_experts=2,
+    experts_total=8, expert_first=2, num_experts_per_tok=3,
+    routed_scaling_factor=2.5, num_nextn_predict_layers=1, vocab_size=VOCAB,
+    attention_block=16, moe_block_rows=4, loss_chunk=32, remat=True,
+    init_std=0.3)
+LENGTHS = [[10, 30, 20], [40, 5, 15]]  # histories of two packed sequences
+
+
+def packed(lengths=LENGTHS, l=64, seed=0):
+    rng = np.random.default_rng(seed)
+    b = len(lengths)
+    tokens = rng.integers(0, VOCAB, (b, l)).astype(np.int32)
+    seg = np.zeros((b, l), np.int32)
+    pos = np.zeros((b, l), np.int32)
+    for row, lens in enumerate(lengths):
+        at = 0
+        for n, ln in enumerate(lens):
+            seg[row, at:at + ln] = n + 1
+            pos[row, at:at + ln] = np.arange(ln)
+            at += ln
+        pos[row, at:] = np.arange(l - at)
+    return jnp.asarray(tokens), jnp.asarray(seg), jnp.asarray(pos)
+
+
+@pytest.fixture(scope="module")
+def params():
+    state = jax.jit(lambda k: enc.init_state(CFG, VOCAB, k))(
+        jax.random.key(0))
+    rng = np.random.default_rng(1)
+    p = {**state["params"], **state["buffers"]}
+    for name in ("router_bias", "mtp_router_bias"):  # a bias that picks
+        p[name] = jnp.asarray(rng.standard_normal(p[name].shape) * 0.05,
+                              jnp.float32)
+    return p
+
+
+def close(a, b, tol=2e-5):
+    a, b = np.asarray(a), np.asarray(b)
+    assert np.abs(a - b).max() <= tol * max(np.abs(b).max(), 1e-3)
+
+
+# -- attention ---------------------------------------------------------------
+
+@pytest.mark.parametrize("block", [8, 16, 32])
+def test_blockwise_attention_equals_dense_attention(block):
+    rng = np.random.default_rng(block)
+    q, k = (jnp.asarray(rng.standard_normal((2, 3, 64, 12)), jnp.float32)
+            for _ in range(2))
+    v = jnp.asarray(rng.standard_normal((2, 3, 64, 8)), jnp.float32)
+    _, seg, pos = packed()
+
+    def blockwise(q, k, v):
+        return segment_attention(q, k, v, seg, pos, block=block)
+
+    def dense(q, k, v):
+        return dense_attention(q, k, v, causal=True, segment_ids=seg)
+
+    close(blockwise(q, k, v), dense(q, k, v))
+    got = jax.grad(lambda *a: (blockwise(*a) ** 2).sum(), (0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: (dense(*a) ** 2).sum(), (0, 1, 2))(q, k, v)
+    for g, w in zip(got, want):
+        close(g, w)
+
+
+def test_a_sequence_one_block_holds_takes_the_dense_path():
+    rng = np.random.default_rng(3)
+    q = jnp.asarray(rng.standard_normal((2, 2, 64, 6)), jnp.float32)
+    _, seg, pos = packed()
+    close(segment_attention(q, q, q, seg, pos, block=64),
+          segment_attention(q, q, q, seg, pos, block=16))
+    with pytest.raises(ValueError):
+        segment_attention(q, q, q, seg, pos, block=48)
+
+
+# -- the system against the reference, piece by piece --------------------------
+
+def test_mla_equals_the_reference(params):
+    _, seg, pos = packed()
+    x = jnp.asarray(np.random.default_rng(4).standard_normal((2, 64, 32)),
+                    jnp.float32)
+    p = params["dense"][0]["attn"]
+    got = jax.jit(lambda p, x: enc.mla(p, CFG, x, seg, pos))(p, x)
+    one = jax.jit(lambda p, x, seg, pos: ref.mla(p, CFG, x, seg, pos, None,
+                                                 lambda f: f))
+    with jax.default_matmul_precision("highest"):
+        for b in range(2):
+            close(got[b], one(p, x[b], seg[b], pos[b]))
+
+
+def test_the_expert_layer_equals_the_reference(params):
+    x = jnp.asarray(np.random.default_rng(5).standard_normal((96, 32)),
+                    jnp.float32)
+    p = jax.tree_util.tree_map(lambda a: a[1], params["moe"])
+    bias = params["router_bias"][1]
+    got, routed = jax.jit(
+        lambda p, x: enc.expert_ffn(p, bias, CFG, x))(p, x)
+    counts, load = routed["counts"], routed["load"]
+    with jax.default_matmul_precision("highest"):
+        want, want_counts, want_picks = jax.jit(
+            lambda p, x: ref.expert_layer(p, bias, CFG, x))(p, x)
+    close(got, want)
+    assert np.array_equal(counts, want_counts)
+    assert np.array_equal(np.sort(routed["picks"]), np.sort(want_picks))
+    assert int(load.sum()) == 96 * CFG.num_experts_per_tok
+    assert np.array_equal(load[CFG.expert_first:CFG.expert_first + 2], counts)
+
+
+@pytest.fixture(scope="module")
+def both_losses(params):
+    batch = packed()
+    loss, aux = jax.jit(lambda p: enc.losses(p, CFG, *batch))(params)
+    return dict(aux, loss=loss), jax.jit(
+        lambda p: ref.losses(p, CFG, *batch))(params)
+
+
+@pytest.mark.parametrize("what", ["loss", "ce", "ce_mtp", "counts",
+                                  "mtp_counts"])
+def test_the_losses_and_the_loads_equal_the_reference(both_losses, what):
+    got, (r_loss, r_ce, r_mtp, r_counts, r_mtp_counts) = both_losses
+    got = got[what]
+    want = {"loss": r_loss, "ce": r_ce, "ce_mtp": r_mtp, "counts": r_counts,
+            "mtp_counts": r_mtp_counts}[what]
+    if "counts" in what:
+        assert np.array_equal(got, want)
+    else:
+        close(got, want, 1e-6)
+
+
+@pytest.fixture(scope="module")
+def gradients(params):
+    batch = packed()
+    got = jax.jit(jax.grad(lambda p: enc.losses(p, CFG, *batch)[0]))(params)
+    want = jax.jit(jax.grad(lambda p: ref.losses(p, CFG, *batch)[0]))(params)
+    return got, want
+
+
+@pytest.mark.parametrize("leaf", [
+    "emb", "head", "final_norm", "dense.0.w13", "dense.0.attn.w_qb",
+    "dense.0.attn.w_kva", "moe.attn.w_o", "moe.attn.kv_norm", "moe.w_g",
+    "moe.shared_w13", "moe.experts_w13", "moe.experts_w2", "mtp.w_eh",
+    "mtp.norm_e", "mtp.block.experts_w2", "mtp.block.w_g"])
+def test_the_whole_steps_gradients_equal_the_reference(gradients, leaf):
+    got, want = gradients
+    close(enc.leaf_of(got, leaf), enc.leaf_of(want, leaf), 1e-4)
+
+
+def test_the_router_bias_is_outside_the_gradient(gradients):
+    got, _ = gradients
+    assert not np.any(got["router_bias"]) and not np.any(
+        got["mtp_router_bias"])
+
+
+def test_a_packed_batch_equals_its_histories_run_apart(params):
+    tokens, seg, pos = packed()
+    h, _ = jax.jit(lambda p: enc.encode(p, CFG, tokens, seg, pos))(params)
+    apart = jax.jit(lambda p, t, s, q: enc.encode(p, CFG, t, s, q)[0])
+    at = 0
+    for ln in LENGTHS[0]:
+        alone = np.zeros((1, 64), np.int32)
+        alone[0, :ln] = tokens[0, at:at + ln]
+        one_seg = jnp.asarray((np.arange(64) < ln).astype(np.int32))[None]
+        one_pos = jnp.asarray(np.where(np.arange(64) < ln, np.arange(64),
+                                       np.arange(64) - ln).astype(np.int32))[None]
+        h1 = apart(params, jnp.asarray(alone), one_seg, one_pos)
+        close(h[0, at:at + ln], h1[0, :ln], 1e-4)
+        at += ln
+
+
+# -- the share of a deployment ---------------------------------------------------
+
+def test_the_shares_add_up_to_the_uncut_layer(params):
+    """Four chips hold two experts each. The parts their shares give, the
+    shared expert counted once, are the uncut reference layer."""
+    rng = np.random.default_rng(6)
+    x = jnp.asarray(rng.standard_normal((80, 32)), jnp.float32)
+    uncut = dataclasses.replace(CFG, n_routed_experts=8, expert_first=0)
+    p = jax.tree_util.tree_map(lambda a: a[0], jax.jit(
+        lambda k: enc.init_params(uncut, VOCAB, k))(jax.random.key(7))["moe"])
+    bias = jnp.asarray(rng.standard_normal(8) * 0.05, jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        whole, whole_counts, _ = jax.jit(
+            lambda p: ref.expert_layer(p, bias, uncut, x))(p)
+    shared = enc.swiglu(uncut, x, p["shared_w13"], p["shared_w2"])
+    total, seen = shared, []
+    for first in (0, 2, 4, 6):
+        share = dataclasses.replace(CFG, expert_first=first)
+        mine = dict(p, experts_w13=p["experts_w13"][first:first + 2],
+                    experts_w2=p["experts_w2"][first:first + 2])
+        y, routed = jax.jit(
+            lambda m, share=share: enc.expert_ffn(m, bias, share, x))(mine)
+        total = total + (y - shared)  # what every chip computes alike: once
+        seen.append(routed["counts"])
+    close(total, whole)
+    assert np.array_equal(np.concatenate(seen), whole_counts)
+    assert int(whole_counts.sum()) == 80 * uncut.num_experts_per_tok
+
+
+@pytest.mark.parametrize("block", [1, 4, 64])
+def test_the_dispatch_drops_no_token(block):
+    rng = np.random.default_rng(8)
+    x = jnp.asarray(rng.standard_normal((40, 8)), jnp.float32)
+    idx = jnp.asarray(np.stack([rng.permutation(6)[:3] for _ in range(40)]),
+                      jnp.int32)
+    w = jnp.asarray(rng.random((40, 3)), jnp.float32)
+    w13 = jnp.asarray(rng.standard_normal((3, 8, 10)), jnp.float32)
+    w2 = jnp.asarray(rng.standard_normal((3, 5, 8)), jnp.float32)
+    y, counts = jax.jit(lambda x: moe.held_experts(
+        x, w, idx, w13, w2, first=1, block=block))(x)
+    want = 0.0
+    for e in range(3):
+        h = x @ w13[e]
+        want = want + ((w * (idx == 1 + e)).sum(-1)[:, None]
+                       * ((jax.nn.silu(h[:, :5]) * h[:, 5:]) @ w2[e]))
+    close(y, want)
+    assert np.array_equal(counts, [(np.asarray(idx) == 1 + e).sum()
+                                   for e in range(3)])
+
+
+# -- the train step, the scorer, the configuration file -----------------------------
+
+def test_the_step_lowers_the_loss_and_moves_the_bias():
+    cfg = dataclasses.replace(CFG, report_blocks=(
+        ("w_eh", "mtp.w_eh", ()), ("rows", "emb", ((0, 8),))))
+    state = jax.jit(lambda k: enc.init_state(cfg, VOCAB, k))(
+        jax.random.key(2))
+    step = jax.jit(enc.train_step(cfg, 0.01))
+    batch = packed()
+    first = None
+    for _ in range(4):
+        state, metrics = step(state, *batch)
+        first = first if first is not None else metrics
+    assert float(metrics["loss"]) < float(first["loss"])
+    assert first["counts"].shape == (2, 2) and first["mtp_counts"].shape == (2,)
+    assert first["picks"].shape == (2, 128, 3)
+    assert first["mtp_picks"].shape == (128, 3)
+    assert np.any(np.asarray(state["buffers"]["router_bias"]) != 0)
+    assert enc.report_of(cfg, state["params"])["rows"].shape == (8, 32)
+
+
+def test_the_first_steps_report_holds_the_gradient_it_used(params):
+    cfg = dataclasses.replace(CFG, report_blocks=(
+        ("w_eh", "mtp.w_eh", ()), ("rows", "emb", ((0, 8),))))
+    state = jax.jit(lambda k: enc.init_state(cfg, VOCAB, k))(
+        jax.random.key(2))
+    batch = packed()
+    want = jax.jit(jax.grad(lambda p: enc.losses(
+        {**p, **state["buffers"]}, cfg, *batch)[0]))(state["params"])
+    before = jax.device_get(enc.report_of(cfg, state["params"]))
+    after, _ = jax.jit(enc.train_step(cfg, 0.01))(state, *batch)
+    report = jax.jit(enc.first_step_report(cfg))(after)
+    for name, g in enc.report_of(cfg, want).items():
+        close(report["grads"][name], g, 1e-6)
+        moved = np.sign(np.asarray(report["params"][name]) - before[name])
+        big = np.abs(np.asarray(g)) > 1e-6
+        assert np.array_equal(moved[big], -np.sign(np.asarray(g))[big])
+
+
+def test_the_scorer_equals_the_reference_forward(params):
+    tokens, _, _ = packed()
+    seq = np.zeros((2, 16), np.int32)
+    seq[0, :10], seq[1, :5] = tokens[0, :10], tokens[1, :5]
+    got = jax.jit(lambda p, s, n: enc.score(p, CFG, s, n))(
+        params, jnp.asarray(seq), jnp.asarray([10, 5]))
+    one = jax.jit(lambda p, h: ref.score(p, CFG, h))
+    close(got[0], one(params, tokens[0, :10]), 1e-4)
+    close(got[1], one(params, tokens[1, :5]), 1e-4)
+
+
+def test_the_published_configuration_counts_680_million_parameters():
+    with open(os.path.join(ROOT, "perf", "configs",
+                           "joyai_llm_flash_1of16.json")) as f:
+        raw = json.load(f)
+    cfg = enc.EncoderConfig.from_dict(raw)
+    n = enc.count_parameters(cfg, cfg.vocab_size)
+    assert abs(n - 680.4e6) / 680.4e6 < 0.01
+    assert (cfg.n_dense, cfg.n_moe, cfg.experts_total) == (1, 4, 256)
+    assert [b[0] for b in cfg.report_blocks][:3] == [
+        "expert_w13", "expert_w2", "router"]
+    # every width as published
+    for key, value in {"hidden_size": 2048, "intermediate_size": 7168,
+                       "moe_intermediate_size": 768, "q_lora_rank": 1536,
+                       "kv_lora_rank": 512, "qk_nope_head_dim": 128,
+                       "qk_rope_head_dim": 64, "v_head_dim": 128,
+                       "num_attention_heads": 32,
+                       "num_experts_per_tok": 8}.items():
+        assert raw[key] == value == getattr(cfg, key)
+
+
+def test_the_default_block_is_a_configuration_of_the_same_encoder():
+    from predictionio_tpu.templates.sessionrec import engine
+
+    cfg = engine._encoder_config("")
+    assert cfg == engine._encoder_config(engine.DEFAULT_ENCODER)
+    wide = engine._encoder_config("", 32, 2, 4)
+    assert (wide.hidden_size, wide.n_dense, wide.qk_nope_head_dim) == (32, 2, 8)
+    assert (cfg.n_dense, cfg.n_moe, cfg.num_nextn_predict_layers) == (1, 0, 0)
+    assert cfg.compute_dtype == "float32" and not cfg.remat
+    shapes = enc.param_shapes(cfg, 9)
+    assert set(shapes) == {"emb", "head", "final_norm", "dense"}
+    assert enc.init_buffers(cfg) == {}
