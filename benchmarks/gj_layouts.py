@@ -1,6 +1,9 @@
-"""Same-window A/B: packed vs aug GJ layouts, DEVICE time via xplane.
+"""Same-window A/B of the GJ solver's layouts, DEVICE time via xplane.
 Chained solves (b_{i+1} = A^-1 b_i) inside one jit defeat CSE and
-amortize dispatch."""
+amortize dispatch; the time is the whole solve's (the kernel and the XLA
+layout copies around it). The first two shapes are the hot buckets of an
+ML-20M train at rank 64 (item side, user side); run by no benchmark cell:
+`python3 benchmarks/gj_layouts.py` on the chip."""
 import os, sys, time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import numpy as np
@@ -21,7 +24,7 @@ def bench(k, r):
     ref = np.linalg.solve(a, b[..., None])[..., 0]
     ad, bd = jnp.asarray(a), jnp.asarray(b)
     out = {}
-    for layout in ("aug", "packed", "blocked2", "chol"):
+    for layout in ("lanes", "aug", "packed", "blocked2", "chol"):
         if layout == "chol":
             def solve(a_, b_):
                 c = jnp.linalg.cholesky(a_)
@@ -45,8 +48,9 @@ def bench(k, r):
                      "this image, or wrong backend) — A/B needs device time")
         out[layout] = best / N
         print(f"  k={k:3d} r={r} {layout:6s}: {best/N*1e3:7.2f} ms/solve (device)")
-    print(f"  k={k:3d}: blocked2 vs aug {out['aug']/out['blocked2']:.2f}x, "
-          f"vs chol {out['chol']/out['blocked2']:.2f}x")
+    print(f"  k={k:3d} r={r}: lanes vs aug {out['aug']/out['lanes']:.2f}x, "
+          f"blocked2 vs aug {out['aug']/out['blocked2']:.2f}x, "
+          f"lanes vs chol {out['chol']/out['lanes']:.2f}x")
 
-for k, r in [(64, 12664), (128, 12664), (32, 12664)]:
+for k, r in [(64, 31296), (64, 12768), (128, 12664), (32, 12664)]:
     bench(k, r)

@@ -678,6 +678,7 @@ def _solve_buckets_device(
     cdtype = jnp.dtype(cfg.compute_dtype)
     f32 = jnp.float32
     ne_einsum = normal_eq_einsum(cdtype)
+    solve_trace_s = 0.0  # host seconds building this side's solves
 
     def chol_solve(a, b):
         chol = jnp.linalg.cholesky(a)
@@ -766,12 +767,16 @@ def _solve_buckets_device(
 
     def finalize(a, b, n, row_sharded=True):
         """Partial (A, b, n) → solved factors (adds Gram/reg, f32 → dtype)."""
+        nonlocal solve_trace_s
+        t0 = time.monotonic()
         if cfg.implicit:
             a = a + gram[None]
         reg = cfg.reg * (n if cfg.weighted_reg else jnp.ones_like(n))
         a = (a + reg[:, None, None] * jnp.eye(k, dtype=f32)[None])
-        return solve_spd(a.astype(opposing.dtype), b.astype(opposing.dtype),
-                         row_sharded)
+        x = solve_spd(a.astype(opposing.dtype), b.astype(opposing.dtype),
+                      row_sharded)
+        solve_trace_s += time.monotonic() - t0
+        return x
 
     def process(rows_c, cols_c, vals_c, mask_c, segmap_c, new, accs):
         with scope("als.gather_gram"):
@@ -806,6 +811,13 @@ def _solve_buckets_device(
             x_u = finalize(*accs, row_sharded=False)
         with scope("als.scatter"):
             new = new.at[split_rows].set(x_u.astype(new.dtype), mode="drop")
+    if cfg.solver == "gj":
+        # which kernel this side's half-iteration holds, and what a first
+        # train paid to build it: one timeline record a side and trace,
+        # as `als.bucketize.<path>` says which way the bucketizer went
+        from predictionio_tpu.ops import pallas_solve
+
+        record_span(f"als.solve.{pallas_solve.layout_for(k)}", solve_trace_s)
     return new
 
 

@@ -9,15 +9,38 @@ triangular solves). A batched CG solver is worse still (1.5–2.8 s/epoch
 vs 1.07 s): its matvecs re-read the [R, K, K] Gram from HBM every
 iteration.
 
-Three kernel layouts, all Gauss-Jordan reductions driven by
-data-independent steps of elementwise VPU work (pivot selection via
-one-hot iota masks, elimination as one fused FMA+select pass), vectorized
-over the batch so throughput scales with the batch instead of the
-sequential critical path of one factorization. Round-3 device-time A/B
-(docs/performance.md) settled which runs when — "auto" picks per rank:
+Four kernel layouts, all Gauss-Jordan reductions in data-independent
+steps of elementwise VPU work, vectorized over the batch so throughput
+scales with the batch instead of the sequential critical path of one
+factorization. "auto" picks by rank: ``lanes`` below 96, ``schur`` (MXU
+products around the multi-RHS kernel) from 96 on; docs/performance.md has
+the device-time A/Bs that settled it.
 
-- ``aug`` (round 1; the rank-64 winner): ROW-based GJ on the augmented
-  [R_tile, K, K+1→lane-padded] block; b rides as the last column.
+- ``lanes`` (PR 29; rank < 96): ONE SYSTEM A LANE. A block is
+  [K+1, K, 128]: leading index = column (b last), sublanes = row, lanes =
+  128 systems. The pivot column is taken by a dynamic index on the
+  leading dim and a column's pivot-row entry is read from the pivot
+  column by symmetry, so a step is one masked sublane reduce (the pivot)
+  and then multiply, subtract, select over the live columns: no one-hot
+  selection, no cross-lane traffic, no padded lane. On the hot
+  [31296, 64, 64] bucket 4.3 ms against ``aug``'s 40.8 ms and 8.5 ms of
+  XLA copies round it (v5e, chip runs of PR 28); in an ML-20M rank-64
+  iteration the solves fell from 0.249 s to 0.034 s and the kernel's
+  share of its bytes bound rose from 1.6 % to 14.7 % (ledger, PR 28).
+  Around it one XLA copy turns [R, K, K] batch-minor; b and x are
+  batch-minor already as XLA lays them out. A few rows cost a whole
+  block, 19 µs, where ``aug``'s 8-row tile costs 125 µs. This is NOT the
+  lane packing that round 3 refuted: that was ``packed`` below, several
+  systems side by side within one system's columns, which keeps the
+  one-hot selection and adds per-group reductions.
+
+The three older layouts keep one system in a [K, lanes] tile and select
+pivots through one-hot iota masks (elimination as one fused FMA+select
+pass over the block); kept forcible for the A/B:
+
+- ``aug`` (round 1; what "auto" took below rank 96 until PR 29):
+  ROW-based GJ on the augmented [R_tile, K, K+1→lane-padded] block; b
+  rides as the last column.
 
 - ``packed``: COLUMN-based GJ on M = [[A], [bᵀ]] with b carried as an
   extra SUBLANE row. A is symmetric, so reducing A to I by column
@@ -38,9 +61,11 @@ sequential critical path of one factorization. Round-3 device-time A/B
 
 Mosaic lessons baked in (round-1 findings, kept so nobody re-learns them):
 - dynamic slices/stores on the sublane/lane dims miscompile silently
-  (compiled output diverged while interpret mode was exact) — all
-  selection goes through one-hot masks, and the grid walks the outer
-  (batch) dim only;
+  (compiled output diverged while interpret mode was exact) — selection
+  on those dims goes through one-hot masks, and the grid walks the outer
+  (batch) dim only; a dynamic index on a LEADING (untiled) dim is sound
+  (chip check of PR 28: K 10/32/64/88, R 1…5000 within 1e-5 of float64),
+  and is what ``lanes`` rests on;
 - `input_output_aliases` does NOT deliver the input inside the out block
   once the grid pipelines (>1 tile ⇒ NaNs) — the working copy is an
   explicit VMEM scratch instead.
@@ -65,6 +90,18 @@ from __future__ import annotations
 import functools
 import os
 
+from predictionio_tpu.telemetry.registry import REGISTRY
+
+_LAYOUTS = ("lanes", "aug", "packed", "blocked2", "schur")
+# The layout is chosen while a program is traced, so this counts the
+# solves a process built into its programs (one a bucket of a train
+# loop, however many iterations run it), not the solves it ran.
+SOLVE_CALLS = REGISTRY.counter(
+    "als_solve_calls_total",
+    "gj_solve calls traced into a program, by the kernel layout built "
+    "for them (lanes | aug | packed | blocked2 | schur)",
+    labelnames=("layout",))
+
 # VMEM budget for blocks in flight: pipelined input blocks + the scratch
 # working copy + x (≈4 blocks of slack). Sets the batch tile.
 _VMEM_BUDGET = 12 * 1024 * 1024
@@ -72,6 +109,13 @@ _LANES = 128
 _SUBLANES = 8
 _MAX_RANK = 256
 _MAX_GROUPS = 4
+# lanes layout: largest order whose [K+1, K, 128] blocks fit VMEM
+_LANES_MAX_RANK = 128
+# lanes layout: columns eliminated a turn of the inner loop. The loop's
+# body is lowered once for every bucket shape of a train program, in
+# every process: 8 is 5 % faster on the chip (4.32 against 4.53 ms at
+# [31296, 64, 64]) and a third more equations to lower.
+_LANES_GROUP = 4
 
 
 def _lane_pad(n: int) -> int:
@@ -292,6 +336,121 @@ def _build_solver_aug(k: int, r_tile: int, n_tiles: int, interpret: bool):
     )
 
 
+@functools.lru_cache(maxsize=8)
+def _lanes_kernel(k: int):
+    """The kernel body of the lanes layout at order k: column-GJ with ONE
+    SYSTEM A LANE on a block M[c, i, r] of [kr + 1, kr, 128] (order k
+    zero-padded to kr = sub_pad(k), whole sublane tiles): leading index
+    c = column of A (c = kr: b), sublanes i = row, lanes r = 128 systems.
+    Nothing crosses lanes, no lane is padding.
+
+    Step j takes its pivot column by a dynamic index on the leading
+    (untiled) dim, `scr[j]`, and eliminates row j from every column
+    c > j. Column c's row-j entry is read as colj[c]: the trailing block
+    of an SPD matrix stays symmetric under elimination, so only the b
+    column extracts its own. Columns c <= j are dead (x is wanted, not
+    A^-1): the inner loop starts at the pivot's own group of columns,
+    and what it still does to that group's dead columns is never read.
+    A group is a window `scr.at[pl.ds(first, g)]` indexed statically: as
+    `scr[first + t]` the compiler cannot tell the columns apart and
+    orders every load behind the store before it (5.9 against 4.3 ms at
+    [31296, 64, 64]; chip runs of PR 28).
+
+    What a first call pays (PERF.md, PR 29). A train program holds one
+    `pallas_call` a bucket shape, about forty, and Pallas traces the
+    kernel anew for each: on the chip's host that was 2.2 s of a first
+    train for this body and 4.2 s with 8 columns a group. The block does
+    not depend on the batch, so the body is a `jax.jit` that is traced
+    once a process and inlined into each kernel after that. Lowering
+    stays one pass over the body a bucket, so the body is kept short:
+    both loops rolled, `lax` selects and an integer `lax.div` where
+    `jnp.where` and `//` would each leave a nested jit in it.
+    """
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+    from jax.experimental import pallas as pl
+
+    g = _LANES_GROUP
+    kr = _sub_pad(k)
+    tile = (kr, _LANES)
+
+    def rows(v):  # [1, 128] -> every sublane
+        return lax.broadcast_in_dim(v, tile, (0, 1))
+
+    def body(a_ref, b_ref, x_ref, scr, fn_ref):
+        scr[:kr] = a_ref[...]
+        scr[kr] = b_ref[...]
+        sub = lax.broadcasted_iota(jnp.int32, tile, 0)
+        zeros = jnp.zeros(tile, jnp.float32)
+
+        def step(j, _):
+            colj = scr[j]  # [kr, 128]: column j of every system
+            is_j = sub == j
+
+            def entry_j(col):  # [1, 128]
+                return jnp.sum(lax.select(is_j, col, zeros), axis=0,
+                               keepdims=True)
+
+            def eliminated(col, f):
+                return lax.select(is_j, rows(f), col - colj * f)
+
+            d = entry_j(colj)
+            # all-zero (padding) systems: every factor is 0, x stays 0
+            d = lax.select(jnp.abs(d) < 1e-30, jnp.ones_like(d), d)
+            fn_ref[...] = colj / d  # row c = A[j, c] / d by symmetry
+            bcol = scr[kr]
+            scr[kr] = eliminated(bcol, entry_j(bcol) / d)
+
+            def group(i, _):
+                first = pl.multiple_of(i * g, g)
+                f = fn_ref[pl.ds(first, g), :]
+                cols = scr.at[pl.ds(first, g)]
+                for t in range(g):
+                    cols[t] = eliminated(cols[t], f[t:t + 1, :])
+                return 0
+
+            lax.fori_loop(lax.div(j, g), kr // g, group, 0)
+            return 0
+
+        lax.fori_loop(0, k, step, 0)
+        x_ref[...] = scr[kr]
+
+    # inlined into the kernel that is being traced: never dispatched,
+    # never compiled by itself, so there is nothing to meter
+    return jax.jit(body, inline=True)  # pio-lint: disable=coverage-jit-metering
+
+
+def _build_solver_lanes(k: int, r: int, interpret: bool):
+    """`pallas_call` of the lanes kernel on a [kr, kr, R] and b [kr, R],
+    the batch minor: a grid over blocks of 128 systems."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    kr = _sub_pad(k)
+    block = (kr + 1) * kr * _LANES * 4
+    return pl.pallas_call(
+        _lanes_kernel(k),
+        # the batch is the minor dim and is not padded: the last block's
+        # lanes past R hold whatever the buffer held, solve to anything
+        # (lanes never meet) and are not written back
+        grid=(-(-r // _LANES),),
+        in_specs=[pl.BlockSpec((kr, kr, _LANES), lambda t: (0, 0, t)),
+                  pl.BlockSpec((kr, _LANES), lambda t: (0, t))],
+        out_specs=pl.BlockSpec((kr, _LANES), lambda t: (0, t)),
+        out_shape=jax.ShapeDtypeStruct((kr, r), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((kr + 1, kr, _LANES), jnp.float32),
+                        pltpu.VMEM((kr, _LANES), jnp.float32)],
+        # two pipelined input blocks + the working copy + x
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=max(16, 3 * block // 2**20 + 8) * 2**20),
+        name="gj_lanes",
+        interpret=interpret,
+    )
+
+
 @functools.lru_cache(maxsize=32)
 def _build_solver_aug_multi(k: int, kp: int, r_tile: int, n_tiles: int,
                             interpret: bool):
@@ -459,36 +618,67 @@ def _solve_aug(a, b, interpret: bool, blocked: bool = False):
     return x[:r]
 
 
+def _solve_lanes(a, b, interpret: bool):
+    import jax.numpy as jnp
+
+    r, k, _ = a.shape
+    kr = _sub_pad(k)
+    # the batch goes last: [K, K, R] and [K, R] (which of A's two index
+    # orders leads does not matter, A is symmetric). Written as a 2-D
+    # transpose between reshapes so that XLA keeps it one copy AFTER the
+    # caller's regularisation (fused into the Gram's product); as
+    # `transpose(1, 2, 0)` it moved the layout change up to the Gram's
+    # output and the regularisation became a second pass.
+    a_t = a.astype(jnp.float32).reshape(r, k * k).T.reshape(k, k, r)
+    b_t = b.astype(jnp.float32).T
+    if kr != k:  # order padded to whole sublane tiles with zeros
+        a_t = jnp.pad(a_t, ((0, kr - k), (0, kr - k), (0, 0)))
+        b_t = jnp.pad(b_t, ((0, kr - k), (0, 0)))
+    x_t = _build_solver_lanes(k, r, interpret)(a_t, b_t)
+    return x_t[:k].T
+
+
+def layout_for(k: int, layout: str = "") -> str:
+    """The kernel layout `gj_solve` builds at order k: the one asked for,
+    else `PIO_GJ_LAYOUT`, else ("auto") by rank alone: "schur" from 96 up
+    (recursive Schur over MXU matmuls — 1.49× vs the best one-hot layout
+    at rank 128), "lanes" below (one system a lane: 9× "aug"'s kernel at
+    rank 64 — docs/performance.md). Raises where the layout cannot be
+    built: forced layouts exist for honest A/Bs — never silently measure
+    a different kernel than the label claims."""
+    layout = layout or os.environ.get("PIO_GJ_LAYOUT", "auto")
+    if layout == "auto":
+        layout = "schur" if k >= 96 else "lanes"
+    if layout not in _LAYOUTS:
+        raise ValueError(f"unknown gj_solve layout {layout!r} "
+                         f"(want auto/{'/'.join(_LAYOUTS)})")
+    if layout == "blocked2" and k % 2:
+        raise ValueError(f"layout='blocked2' needs even rank, got {k}")
+    if layout == "lanes" and k > _LANES_MAX_RANK:
+        raise ValueError(f"layout='lanes' holds a [K+1, K, 128] block in "
+                         f"VMEM: rank <= {_LANES_MAX_RANK}, got {k}")
+    return layout
+
+
 def gj_solve(a, b, interpret: bool = False, layout: str = ""):
     """Solve x = A⁻¹ b for a batch of SPD systems.
 
     a: [R, K, K] f32 — SPD, hence symmetric (λ-regularized normal
-       equations; the packed layout's column elimination relies on the
-       symmetry); all-zero systems (bucket padding rows) yield x = 0.
+       equations; the lanes and packed layouts' column elimination
+       relies on the symmetry); all-zero systems (bucket padding rows)
+       yield x = 0.
     b: [R, K] f32
-    layout: "auto" (default) picks "schur" for rank ≥ 96 (recursive
-       Schur over MXU matmuls — 1.49× vs the best elementwise layout at
-       rank 128) and "aug" otherwise (lane packing, 2-pivot blocking,
-       and schur all LOST at rank ≤ 64 on device time —
-       docs/performance.md round-3 tables). "aug", "packed", "blocked2",
-       "schur" force a layout; PIO_GJ_LAYOUT overrides when unset.
+    layout: "" or "auto" (default) goes by `layout_for`; "lanes", "aug",
+       "packed", "blocked2", "schur" force a layout. The layout built is
+       counted in `als_solve_calls_total{layout}`.
     returns x: [R, K] f32
     """
-    layout = layout or os.environ.get("PIO_GJ_LAYOUT", "auto")
-    k = a.shape[1]
-    if layout == "auto":
-        layout = "schur" if k >= 96 else "aug"
+    layout = layout_for(a.shape[1], layout)
+    SOLVE_CALLS.labels(layout=layout).inc()
     if layout == "schur":
         return schur_solve(a, b, interpret)
+    if layout == "lanes":
+        return _solve_lanes(a, b, interpret)
     if layout == "packed":
         return _solve_packed(a, b, interpret)
-    if layout == "blocked2":
-        # forced layouts exist for honest A/Bs — never silently measure a
-        # different kernel than the label claims
-        if k % 2:
-            raise ValueError(f"layout='blocked2' needs even rank, got {k}")
-        return _solve_aug(a, b, interpret, blocked=True)
-    if layout != "aug":
-        raise ValueError(f"unknown gj_solve layout {layout!r} "
-                         "(want auto/aug/packed/blocked2/schur)")
-    return _solve_aug(a, b, interpret)
+    return _solve_aug(a, b, interpret, blocked=layout == "blocked2")

@@ -9,9 +9,32 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from predictionio_tpu.ops import pallas_solve
 from predictionio_tpu.ops.als import ALSConfig, als_train
 from predictionio_tpu.ops.pallas_solve import gj_applicable, gj_solve
 from predictionio_tpu.parallel.mesh import make_mesh
+from predictionio_tpu.telemetry import spans
+
+
+def _walk_eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations hold
+    (loop bodies, kernels), each once."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for item in (value if isinstance(value, (list, tuple))
+                         else [value]):
+                inner = getattr(item, "jaxpr", item)
+                if hasattr(inner, "eqns"):
+                    yield from _walk_eqns(inner)
+
+
+def _pallas_calls(jaxpr):
+    return [e for e in _walk_eqns(jaxpr) if e.primitive.name == "pallas_call"]
+
+
+def _built(layout):
+    return pallas_solve.SOLVE_CALLS.labels(layout=layout).value
 
 
 def _spd_batch(rng, r, k, reg=None):
@@ -33,18 +56,117 @@ class TestGJSolve:
         rel = np.abs(x - ref).max() / np.abs(ref).max()
         assert rel < 1e-4, rel
 
-    @pytest.mark.parametrize("layout", ["aug", "packed", "blocked2"])
+    @pytest.mark.parametrize("layout", ["lanes", "aug", "packed",
+                                        "blocked2"])
     @pytest.mark.parametrize("r,k", [(33, 64), (9, 128), (7, 100)])
     def test_every_layout_matches(self, layout, r, k):
-        """All three kernel layouts (docs/performance.md round-3 A/B) stay
-        numerically exact; 'auto' routing is free to change between them."""
+        """Every kernel layout stays numerically exact and selectable by
+        name, and a forced layout is the one that is built and counted;
+        'auto' routing is free to change between them."""
         rng = np.random.default_rng(4)
         a, b = _spd_batch(rng, r, k)
+        before = _built(layout)
         x = np.asarray(gj_solve(jnp.asarray(a), jnp.asarray(b),
                                 interpret=True, layout=layout))
+        assert _built(layout) == before + 1
         ref = np.linalg.solve(a, b[..., None])[..., 0]
         rel = np.abs(x - ref).max() / np.abs(ref).max()
         assert rel < 1e-4, (layout, rel)
+
+    @pytest.mark.parametrize("k", [10, 32, 64, 88])
+    @pytest.mark.parametrize("r", [1, 7, 8, 40, 128, 200, 943])
+    def test_lanes_layout_matches_float64(self, r, k):
+        """One system a lane: batches on both sides of a 128-lane block
+        and orders on and off the 8-sublane tile, against float64;
+        all-zero systems (bucket padding) come out exactly 0."""
+        rng = np.random.default_rng(1000 * k + r)
+        a, b = _spd_batch(rng, r, k)
+        zeros = [2, r - 1] if r > 3 else []
+        a[zeros] = 0.0
+        b[zeros] = 0.0
+        live = np.setdiff1d(np.arange(r), zeros)
+        x = np.asarray(gj_solve(jnp.asarray(a), jnp.asarray(b),
+                                interpret=True, layout="lanes"))
+        ref = np.linalg.solve(a[live].astype(np.float64),
+                              b[live, :, None].astype(np.float64))[..., 0]
+        assert np.abs(x[live] - ref).max() / np.abs(ref).max() < 1e-5
+        np.testing.assert_array_equal(x[zeros], 0.0)
+
+    @pytest.mark.parametrize("k,layout", [(8, "lanes"), (63, "lanes"),
+                                          (96, "schur"), (128, "schur")])
+    def test_auto_routes_by_rank(self, monkeypatch, k, layout):
+        """'auto' goes by the rank alone: one system a lane below 96,
+        schur from 96 up; the counter says which was built."""
+        monkeypatch.delenv("PIO_GJ_LAYOUT", raising=False)
+        a, b = _spd_batch(np.random.default_rng(k), 3, k)
+        before = {name: _built(name) for name in pallas_solve._LAYOUTS}
+        x = np.asarray(gj_solve(jnp.asarray(a), jnp.asarray(b),
+                                interpret=True))
+        after = {name: _built(name) for name in pallas_solve._LAYOUTS}
+        before[layout] += 1
+        assert after == before
+        ref = np.linalg.solve(a, b[..., None])[..., 0]
+        assert np.abs(x - ref).max() / np.abs(ref).max() < 1e-4
+
+    def test_environment_forces_a_layout(self, monkeypatch):
+        monkeypatch.setenv("PIO_GJ_LAYOUT", "aug")
+        a, b = _spd_batch(np.random.default_rng(11), 5, 16)
+        before = _built("aug")
+        gj_solve(jnp.asarray(a), jnp.asarray(b), interpret=True)
+        assert _built("aug") == before + 1
+
+    @pytest.mark.parametrize("layout,k", [("lanes", 136), ("blocked2", 9),
+                                          ("lane", 8)])
+    def test_layouts_that_cannot_be_built_raise(self, layout, k):
+        """A forced layout never silently becomes another one."""
+        a, b = _spd_batch(np.random.default_rng(12), 2, k)
+        with pytest.raises(ValueError, match="layout"):
+            gj_solve(jnp.asarray(a), jnp.asarray(b), interpret=True,
+                     layout=layout)
+
+    def test_lanes_kernel_is_traced_once_for_every_batch(self, monkeypatch):
+        """Guard on what a first call pays (PERF.md, PR 29): Pallas
+        traces a kernel anew in every `pallas_call`, about forty a train
+        program; the lanes body is a jit whose trace every call after
+        the first reuses, whatever the batch."""
+        from jax.experimental import pallas as pl
+
+        traced = []
+        real = pl.multiple_of  # the body calls it once, in its inner loop
+        monkeypatch.setattr(
+            pl, "multiple_of",
+            lambda *a, **kw: traced.append(1) or real(*a, **kw))
+        k = 24  # an order no other test builds: its body is not traced yet
+        pallas_solve._lanes_kernel.cache_clear()
+
+        def solves(*ab):
+            return [gj_solve(ab[i], ab[i + 1], layout="lanes")
+                    for i in range(0, len(ab), 2)]
+
+        shapes = []
+        for r in (5, 128, 300, 1000):
+            shapes += [jax.ShapeDtypeStruct((r, k, k), jnp.float32),
+                       jax.ShapeDtypeStruct((r, k), jnp.float32)]
+        jaxpr = jax.make_jaxpr(solves)(*shapes).jaxpr
+        assert len(_pallas_calls(jaxpr)) == 4
+        assert len(traced) == 1
+
+    @pytest.mark.parametrize("k", [10, 64, 88])
+    def test_lanes_kernel_stays_rolled(self, k):
+        """Guard on what a first call pays (PERF.md, PR 29): a train
+        program holds one kernel a bucket shape, each traced and lowered
+        in every process. Both loops of the kernel stay rolled, so its
+        jaxpr does not grow with the order; a body unrolled over the
+        columns (64 steps x 64 columns at rank 64) cannot come back
+        unseen."""
+        a = jax.ShapeDtypeStruct((300, k, k), jnp.float32)
+        b = jax.ShapeDtypeStruct((300, k), jnp.float32)
+        jaxpr = jax.make_jaxpr(
+            lambda a_, b_: gj_solve(a_, b_, layout="lanes"))(a, b).jaxpr
+        (call,) = _pallas_calls(jaxpr)
+        assert sum(1 for _ in _walk_eqns(call.params["jaxpr"])) <= 100
+        # around it: casts, the change of layout, its padding, and back
+        assert sum(1 for _ in _walk_eqns(jaxpr)) <= 120
 
     @pytest.mark.parametrize("r,k,m", [(9, 16, 5), (33, 32, 33),
                                        (7, 64, 1), (5, 8, 120)])
@@ -165,6 +287,53 @@ class TestALSWithGJ:
                            mesh=mesh, compute_rmse=True)
         np.testing.assert_allclose(res_gj.rmse_history, res_ch.rmse_history,
                                    rtol=2e-3)
+
+    def test_train_loop_builds_one_kernel_a_bucket(self, monkeypatch):
+        """Guard on what a first call pays (PERF.md, PR 29): the scan
+        body is traced once, and each side's half-iteration builds one
+        solve a bucket plus one for its split rows' accumulators, each
+        the lanes kernel at rank < 96."""
+        from predictionio_tpu.ops import als
+
+        monkeypatch.delenv("PIO_GJ_LAYOUT", raising=False)
+        expected = []
+        real = als._solve_buckets_device
+
+        def spy(opposing, out_rows, buckets_dev, cfg, split_rows=None,
+                *args, **kwargs):
+            n_split = 0 if split_rows is None else split_rows.shape[0]
+            expected.append(len(buckets_dev) + (1 if n_split else 0))
+            return real(opposing, out_rows, buckets_dev, cfg, split_rows,
+                        *args, **kwargs)
+
+        monkeypatch.setattr(als, "_solve_buckets_device", spy)
+        als._get_train_loop.cache_clear()
+        ui, ii, r, n_u, n_i = self._data()
+        mesh = make_mesh({"data": 1, "model": 1}, devices=jax.devices()[:1])
+        # split_cap 16: some of the 40 users' rows are split, so the
+        # accumulators' solve is there
+        cfg = ALSConfig(rank=8, iterations=3, reg=0.05, seed=0, solver="gj",
+                        pallas="interpret", split_cap=16)
+        before = {name: _built(name) for name in pallas_solve._LAYOUTS}
+        tl, token = spans.begin("test", "train", "RUN", "t-1")
+        try:
+            res = als_train(ui, ii, r, n_u, n_i, cfg, mesh=mesh)
+        finally:
+            spans.finish(tl, token, status=None, duration_s=0.0)
+        assert np.isfinite(res.user_factors).all()
+        assert len(expected) == 2 and min(expected) >= 2, expected
+        built = {name: _built(name) - before[name]
+                 for name in pallas_solve._LAYOUTS}
+        assert built.pop("lanes") == sum(expected)
+        assert not any(built.values()), built
+        # the timeline says which kernel the loop holds: one record a
+        # side, inside the dispatch that traced it
+        by_name = {}
+        for name, start, dur, _err, _nested in tl.spans:
+            by_name.setdefault(name, []).append((start, start + dur))
+        (lo, hi), = by_name["als.loop.dispatch"]
+        assert len(by_name["als.solve.lanes"]) == 2
+        assert all(lo <= s and e <= hi for s, e in by_name["als.solve.lanes"])
 
     def test_schur_layout_matches_chol_trajectory(self, monkeypatch):
         """Full ALS training through the schur solver path (forced via

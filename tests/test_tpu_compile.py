@@ -1,0 +1,80 @@
+"""The main path's Pallas kernels compiled for a TPU that is described,
+not attached (no chip here): what Mosaic refuses (a slice off the tiling,
+too much VMEM, an index it cannot lower) fails here and costs no chip
+time. Interpret mode cannot show any of that. Nothing runs, so no result
+and no time is checked.
+
+The topology is described inside a fixture, never at import: only the
+worker that is handed this file loads the TPU's library."""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec, SingleDeviceSharding
+
+from predictionio_tpu.ops import pallas_solve
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler on this image
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compiled_text(one_chip, r, k, layout):
+    a = jax.ShapeDtypeStruct((r, k, k), jnp.float32, sharding=one_chip)
+    b = jax.ShapeDtypeStruct((r, k), jnp.float32, sharding=one_chip)
+
+    def solve(a, b):
+        with jax.named_scope("als.solve"):
+            return pallas_solve.gj_solve(a, b, layout=layout)
+
+    return jax.jit(solve).lower(a, b).compile().as_text()
+
+
+# the hot bucket of an ML-20M train (a ragged last block: 31296 = 244.5
+# blocks), a fold's handful of rows under one block, a rank off the
+# sublane tile, the largest blocks `auto` hands the layout (rank 88-95)
+@pytest.mark.parametrize("r,k", [(31296, 64), (8, 64), (40, 10), (1000, 88)])
+def test_lanes_solver_compiles_for_v5e(one_chip, r, k):
+    text = _compiled_text(one_chip, r, k, "")
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    # the benchmark's readers find the kernel by its scope and its call
+    assert "als.solve" in text and "gj_lanes" in text
+
+
+def test_aug_solver_still_compiles_for_v5e(one_chip):
+    """The layout kept for the A/B stays a kernel the chip accepts."""
+    text = _compiled_text(one_chip, 12768, 64, "aug")
+    assert 'custom_call_target="tpu_custom_call"' in text
+    assert "gj_lanes" not in text
+
+
+def test_lanes_solver_compiles_a_device_under_shard_map(topo):
+    """`als_train`'s branch for a mesh: each of four chips solves its own
+    row shard, padded to whole blocks of 128 lanes by itself, with no
+    collective."""
+    mesh = Mesh(np.array(topo.devices), ("data",))
+    rows = NamedSharding(mesh, PartitionSpec("data"))
+    a = jax.ShapeDtypeStruct((4 * 1000, 64, 64), jnp.float32, sharding=rows)
+    b = jax.ShapeDtypeStruct((4 * 1000, 64), jnp.float32, sharding=rows)
+    spec = PartitionSpec("data")
+    solve = jax.shard_map(pallas_solve.gj_solve, mesh=mesh,
+                          in_specs=(spec, spec), out_specs=spec,
+                          check_vma=False)
+    text = jax.jit(solve).lower(a, b).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "gj_lanes" in text and "all-" not in text
