@@ -34,6 +34,7 @@ from typing import NamedTuple, Optional, Sequence
 
 import numpy as np
 
+from predictionio_tpu.ops.solve import solve_spd
 from predictionio_tpu.telemetry.registry import REGISTRY
 from predictionio_tpu.telemetry.spans import record as record_span, span
 from predictionio_tpu.utils import faults
@@ -311,6 +312,9 @@ def _bucket_ragged_split_numpy(rows, cols, vals, n_rows: int,
     return buckets, hot
 
 
+SOLVERS = ("auto", "gj", "chol")
+
+
 @dataclasses.dataclass(frozen=True)
 class ALSConfig:
     """Frozen (hashable) so jitted solvers cache across als_train calls."""
@@ -327,7 +331,7 @@ class ALSConfig:
     # dtype (f32 accumulation via preferred_element_type keeps the normal
     # equations well-conditioned); "float32" for bit-stable results.
     compute_dtype: str = "float32"
-    # normal-equation solver:
+    # normal-equation solver (anything else is a ValueError):
     #   "auto" — "gj" on TPU when the rank fits its VMEM budget, else "chol"
     #   "gj"   — Pallas batched Gauss-Jordan (ops/pallas_solve.py): the
     #            batched Cholesky custom-call dominates rank-64 epochs
@@ -336,13 +340,7 @@ class ALSConfig:
     #            it runs shard_mapped, one kernel per device row shard
     #   "chol" — Cholesky (A is SPD by construction — λ>0 — and two
     #            triangular solves beat LU by ~30% on v5e)
-    #   "lu"   — jnp.linalg.solve
-    #   "cg"   — batched conjugate gradient; measured SLOWER than chol at
-    #            rank 64 (its matvecs re-read the [R,K,K] Gram from HBM
-    #            every iteration: 1.5–2.8 s vs 1.07 s/epoch) — kept for
-    #            ranks too large for gj/chol memory budgets
     solver: str = "auto"
-    cg_iters: int = 0  # 0 = auto: rank//2 clamped to [8, 32]
     # rows with more entries than this are split into segments whose
     # partial normal equations are summed on device before solving
     # (bucket_ragged_split): bounds the dense tile width a hot row can
@@ -362,6 +360,11 @@ class ALSConfig:
     # vector-indexed gather to beat it, and the scalar-loop kernel peaked
     # at 1.1× XLA at rank 128 while failing to compile at rank 64.
     pallas: str = "auto"
+
+    def __post_init__(self):
+        if self.solver not in SOLVERS:
+            raise ValueError(f"unknown ALS solver {self.solver!r} "
+                             f"(want {' / '.join(SOLVERS)})")
 
 
 # HBM budget for one bucket-chunk's [R, C, K] gathered-factor block; buckets
@@ -680,66 +683,6 @@ def _solve_buckets_device(
     ne_einsum = normal_eq_einsum(cdtype)
     solve_trace_s = 0.0  # host seconds building this side's solves
 
-    def chol_solve(a, b):
-        chol = jnp.linalg.cholesky(a)
-        y1 = jax.lax.linalg.triangular_solve(
-            chol, b[..., None], left_side=True, lower=True)
-        return jax.lax.linalg.triangular_solve(
-            chol, y1, left_side=True, lower=True,
-            transpose_a=True)[..., 0]
-
-    def solve_spd(a, b, row_sharded=True):
-        if cfg.solver == "gj":
-            from predictionio_tpu.ops import pallas_solve
-
-            if mesh is not None and mesh.size > 1:
-                if not row_sharded:
-                    # the [U] split-accumulator batch is not a multiple of
-                    # the data axis; U is tiny, so chol is fine here
-                    return chol_solve(a, b)
-                # pallas_call is a single-device program GSPMD can't
-                # partition; shard_map runs one kernel per device on its
-                # local row shard (rows are bucketed to multiples of the
-                # data-axis size, so shards are even)
-                from predictionio_tpu.parallel.mesh import DATA_AXIS
-                from jax.sharding import PartitionSpec as P
-
-                spec = P(DATA_AXIS)  # als_train requires a 'data' axis
-                solve = jax.shard_map(
-                    lambda a_, b_: pallas_solve.gj_solve(
-                        a_, b_, interpret=interpret),
-                    mesh=mesh, in_specs=(spec, spec), out_specs=spec,
-                    # pallas_call out_shape carries no varying-mesh-axes
-                    # info; the kernel is elementwise over rows, so the
-                    # replication check adds nothing here
-                    check_vma=False)
-                return solve(a.astype(f32), b.astype(f32)).astype(a.dtype)
-            return pallas_solve.gj_solve(a.astype(f32), b.astype(f32),
-                                         interpret=interpret).astype(a.dtype)
-        if cfg.solver == "chol":
-            return chol_solve(a, b)
-        if cfg.solver == "cg":
-            iters = cfg.cg_iters or max(8, min(32, k // 2))
-            # Jacobi-preconditioned CG: all matvecs, MXU/VPU-only
-            dinv = 1.0 / jnp.maximum(
-                jnp.diagonal(a, axis1=-2, axis2=-1), 1e-12)
-            x = jnp.zeros_like(b)
-            r = b
-            z = dinv * r
-            p = z
-            rz = jnp.sum(r * z, -1)
-            for _ in range(iters):
-                ap = jnp.einsum("rkl,rl->rk", a, p)
-                alpha = rz / jnp.maximum(jnp.sum(p * ap, -1), 1e-30)
-                x = x + alpha[:, None] * p
-                r = r - alpha[:, None] * ap
-                z = dinv * r
-                rz_new = jnp.sum(r * z, -1)
-                p = z + (rz_new / jnp.maximum(rz, 1e-30))[:, None] * p
-                rz = rz_new
-            return x
-        return jnp.linalg.solve(a, b[..., None])[..., 0]
-
     if cfg.implicit:
         # global Gram over real (non-sentinel-pad) opposing rows (f32: it
         # is summed into per-row partials that may accumulate across
@@ -774,7 +717,8 @@ def _solve_buckets_device(
         reg = cfg.reg * (n if cfg.weighted_reg else jnp.ones_like(n))
         a = (a + reg[:, None, None] * jnp.eye(k, dtype=f32)[None])
         x = solve_spd(a.astype(opposing.dtype), b.astype(opposing.dtype),
-                      row_sharded)
+                      kernel=cfg.solver == "gj", interpret=interpret,
+                      mesh=mesh, row_sharded=row_sharded)
         solve_trace_s += time.monotonic() - t0
         return x
 
@@ -901,7 +845,8 @@ def _get_train_loop(n_users: int, n_items: int, cfg: ALSConfig,
 def resolve_solver(cfg: ALSConfig) -> ALSConfig:
     """Resolve `solver='auto'` to a concrete solver for this backend/rank,
     and downgrade an unusable 'gj' request to 'chol' (with a warning).
-    Shared by `als_train` and the grid evaluator."""
+    Shared by `als_train`, the grid evaluator and fold-in; any other
+    `solver` was refused when `cfg` was made."""
     import jax
 
     if cfg.solver == "auto":
@@ -1057,13 +1002,10 @@ def als_train(
     if _checks.enabled() and not model_sharded:
         # checkify cannot transform pallas_call (KeyError: closed_call), so
         # assert mode pins the pure-XLA solver path
-        if cfg.solver in ("auto", "gj") or cfg.pallas != "off":
+        if cfg.solver != "chol" or cfg.pallas != "off":
             log.info("als_train: --check-asserts forces the XLA solver path "
                      "(checkify cannot transform Pallas kernels)")
-        cfg = dataclasses.replace(
-            cfg,
-            solver="chol" if cfg.solver in ("auto", "gj") else cfg.solver,
-            pallas="off")
+        cfg = dataclasses.replace(cfg, solver="chol", pallas="off")
 
     cfg = resolve_solver(cfg)
 

@@ -51,6 +51,7 @@ from predictionio_tpu.ops.als import (
     normal_eq_einsum,
     resolve_solver,
 )
+from predictionio_tpu.ops.solve import solve_spd
 
 log = logging.getLogger(__name__)
 
@@ -79,8 +80,6 @@ def grid_compatible(cfgs: Sequence[ALSConfig]) -> Optional[str]:
                 return (f"grid point {i} differs from point 0 in "
                         f"{name!r} ({getattr(c, name)!r} != "
                         f"{getattr(base, name)!r})")
-    if base.solver == "cg":
-        return "solver='cg' is not grid-batched"
     return None
 
 
@@ -90,16 +89,12 @@ def grid_groups(cfgs: Sequence[ALSConfig]) -> list[list[int]]:
     Cells agreeing on every static field land in one group — e.g. the
     stock Recommendation eval grid over rank×λ becomes one group per
     rank, each batching its λ cells; iteration counts may differ within
-    a group (traced horizon mask). Non-batchable cells (solver='cg')
-    come back as singletons. Group order preserves first appearance;
-    indices within a group keep caller order."""
+    a group (traced horizon mask). Group order preserves first
+    appearance; indices within a group keep caller order."""
     static = [f.name for f in dataclasses.fields(ALSConfig)
               if f.name not in VARIABLE_FIELDS]
     groups: dict = {}
     for idx, c in enumerate(cfgs):
-        if c.solver == "cg":
-            groups[("cg", idx)] = [idx]
-            continue
         key = tuple(getattr(c, n) for n in static)
         groups.setdefault(key, []).append(idx)
     return list(groups.values())
@@ -136,7 +131,6 @@ def _solve_buckets_grid(
     """One grid half-epoch: per row, solve G normal-equation systems that
     share the row's gathered entries. Mirrors als._solve_buckets_device
     with a batched `g` axis; see module docstring for the layout."""
-    import jax
     import jax.numpy as jnp
 
     v, g, k = opposing.shape
@@ -151,46 +145,6 @@ def _solve_buckets_grid(
     cdtype = jnp.dtype(cfg.compute_dtype)
     f32 = jnp.float32
     ne_einsum = normal_eq_einsum(cdtype)
-
-    def chol_solve(a, b):
-        chol = jnp.linalg.cholesky(a)
-        y1 = jax.lax.linalg.triangular_solve(
-            chol, b[..., None], left_side=True, lower=True)
-        return jax.lax.linalg.triangular_solve(
-            chol, y1, left_side=True, lower=True, transpose_a=True)[..., 0]
-
-    def solve_spd(a, b, row_sharded=True):
-        """[R, G, K, K], [R, G, K] → [R, G, K]: flatten the (row, grid)
-        batch into the row-batched solvers als_train uses."""
-        r = a.shape[0]
-        a2 = a.reshape(r * g, k, k)
-        b2 = b.reshape(r * g, k)
-        if cfg.solver == "gj":
-            from predictionio_tpu.ops import pallas_solve
-
-            if mesh is not None and mesh.size > 1 and row_sharded:
-                from jax.sharding import PartitionSpec as P
-
-                from predictionio_tpu.parallel.mesh import DATA_AXIS
-
-                spec = P(DATA_AXIS)
-                solve = jax.shard_map(
-                    lambda a_, b_: pallas_solve.gj_solve(
-                        a_, b_, interpret=interpret),
-                    mesh=mesh, in_specs=(spec, spec), out_specs=spec,
-                    check_vma=False)
-                x2 = solve(a2.astype(f32), b2.astype(f32)).astype(a.dtype)
-            elif mesh is not None and mesh.size > 1:
-                x2 = chol_solve(a2, b2)  # tiny split-accumulator batch
-            else:
-                x2 = pallas_solve.gj_solve(
-                    a2.astype(f32), b2.astype(f32),
-                    interpret=interpret).astype(a.dtype)
-        elif cfg.solver == "chol":
-            x2 = chol_solve(a2, b2)
-        else:
-            x2 = jnp.linalg.solve(a2, b2[..., None])[..., 0]
-        return x2.reshape(r, g, k)
 
     if cfg.implicit:
         op_c = opposing.astype(cdtype)
@@ -217,8 +171,14 @@ def _solve_buckets_grid(
         reg_rg = regs[None, :] * (n[:, None] if cfg.weighted_reg
                                   else jnp.ones_like(n)[:, None])
         a = a + reg_rg[..., None, None] * jnp.eye(k, dtype=f32)[None, None]
-        return solve_spd(a.astype(opposing.dtype), b.astype(opposing.dtype),
-                         row_sharded)
+        # flatten the (row, grid) batch into the row-batched solve
+        # als_train uses
+        r = a.shape[0]
+        x = solve_spd(a.astype(opposing.dtype).reshape(r * g, k, k),
+                      b.astype(opposing.dtype).reshape(r * g, k),
+                      kernel=cfg.solver == "gj", interpret=interpret,
+                      mesh=mesh, row_sharded=row_sharded)
+        return x.reshape(r, g, k)
 
     def process(rows_c, cols_c, vals_c, mask_c, segmap_c, new, accs):
         n = mask_c.sum(-1)

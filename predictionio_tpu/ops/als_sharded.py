@@ -42,6 +42,7 @@ from predictionio_tpu.ops.als import (
     _walk_bucket_chunks,
     normal_eq_einsum,
 )
+from predictionio_tpu.ops.solve import solve_spd
 from predictionio_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
 
 log = logging.getLogger(__name__)
@@ -107,20 +108,6 @@ def get_train_loop_sharded(
             for has_seg in flags
         ]
 
-    def solve_spd(a, b):
-        """Device-local SPD solve (already inside shard_map)."""
-        if cfg.solver == "gj":
-            from predictionio_tpu.ops import pallas_solve
-
-            return pallas_solve.gj_solve(
-                a.astype(f32), b.astype(f32),
-                interpret=cfg.pallas == "interpret").astype(a.dtype)
-        chol = jnp.linalg.cholesky(a)
-        y1 = lax.linalg.triangular_solve(
-            chol, b[..., None], left_side=True, lower=True)
-        return lax.linalg.triangular_solve(
-            chol, y1, left_side=True, lower=True, transpose_a=True)[..., 0]
-
     def half_step(opposing_local, out_pad: int, buckets, split_rows,
                   n_split: int):
         """Solve every row against the model-sharded opposing table;
@@ -151,7 +138,10 @@ def get_train_loop_sharded(
                 a = a + gram[None]
             reg = cfg.reg * (n if cfg.weighted_reg else jnp.ones_like(n))
             a = a + reg[:, None, None] * jnp.eye(k, dtype=f32)[None]
-            return solve_spd(a.astype(dtype), b.astype(dtype))
+            # device-local: this is already inside `run`'s shard_map
+            return solve_spd(a.astype(dtype), b.astype(dtype),
+                             kernel=cfg.solver == "gj",
+                             interpret=cfg.pallas == "interpret")
 
         def process(sliced, carry):
             rows_c, cols_c, vals_c, mask_c, segmap_c = sliced

@@ -1,63 +1,48 @@
-"""Pallas TPU kernel: batched SPD solve via vectorized Gauss-Jordan.
+"""Pallas TPU kernels: batched SPD solve via vectorized Gauss-Jordan.
 
 The other ALS hot op: after the Gram/RHS einsums, each bucket needs
 x_r = A_r⁻¹ b_r for thousands of small (K×K, K = rank) SPD systems. XLA
 lowers `jnp.linalg.cholesky` to a custom-call whose batched factorization
 dominates rank-64 epochs (v5e profile, round 1: 873 ms of a 1.8 s 10-iter
 loop on the 12 664-row bucket — ~66% of device time including the paired
-triangular solves). A batched CG solver is worse still (1.5–2.8 s/epoch
-vs 1.07 s): its matvecs re-read the [R, K, K] Gram from HBM every
-iteration.
+triangular solves).
 
-Four kernel layouts, all Gauss-Jordan reductions in data-independent
-steps of elementwise VPU work, vectorized over the batch so throughput
-scales with the batch instead of the sequential critical path of one
-factorization. "auto" picks by rank: ``lanes`` below 96, ``schur`` (MXU
-products around the multi-RHS kernel) from 96 on; docs/performance.md has
-the device-time A/Bs that settled it.
+Two layouts, both Gauss-Jordan reductions in data-independent steps of
+elementwise VPU work, vectorized over the batch so throughput scales with
+the batch instead of the sequential critical path of one factorization.
+`layout_for` picks by rank alone; docs/performance.md has the device-time
+A/Bs that settled it, and those of the layouts that lost.
 
-- ``lanes`` (PR 29; rank < 96): ONE SYSTEM A LANE. A block is
-  [K+1, K, 128]: leading index = column (b last), sublanes = row, lanes =
-  128 systems. The pivot column is taken by a dynamic index on the
-  leading dim and a column's pivot-row entry is read from the pivot
-  column by symmetry, so a step is one masked sublane reduce (the pivot)
-  and then multiply, subtract, select over the live columns: no one-hot
-  selection, no cross-lane traffic, no padded lane. On the hot
-  [31296, 64, 64] bucket 4.3 ms against ``aug``'s 40.8 ms and 8.5 ms of
-  XLA copies round it (v5e, chip runs of PR 28); in an ML-20M rank-64
-  iteration the solves fell from 0.249 s to 0.034 s and the kernel's
-  share of its bytes bound rose from 1.6 % to 14.7 % (ledger, PR 28).
-  Around it one XLA copy turns [R, K, K] batch-minor; b and x are
-  batch-minor already as XLA lays them out. A few rows cost a whole
-  block, 19 µs, where ``aug``'s 8-row tile costs 125 µs. This is NOT the
-  lane packing that round 3 refuted: that was ``packed`` below, several
-  systems side by side within one system's columns, which keeps the
-  one-hot selection and adds per-group reductions.
+- ``lanes`` (rank < 96): ONE SYSTEM A LANE. A block is [K+1, K, 128]:
+  leading index = column (b last), sublanes = row, lanes = 128 systems.
+  The pivot column is taken by a dynamic index on the leading dim and a
+  column's pivot-row entry is read from the pivot column by symmetry, so
+  a step is one masked sublane reduce (the pivot) and then multiply,
+  subtract, select over the live columns: no one-hot selection, no
+  cross-lane traffic, no padded lane. Around it one XLA copy turns
+  [R, K, K] batch-minor; b and x are batch-minor already as XLA lays
+  them out. A few rows cost a whole block, 19 µs. In an ML-20M rank-64
+  iteration the solves take 0.034 s and the kernel reaches 14.7 % of its
+  bytes bound (ledger, PR 28).
 
-The three older layouts keep one system in a [K, lanes] tile and select
-pivots through one-hot iota masks (elimination as one fused FMA+select
-pass over the block); kept forcible for the A/B:
+- ``schur`` (rank ≥ 96): recursive Schur complements. The elimination
+  becomes [R, K/2, K/2] batched MXU matmuls round a multi-RHS kernel at
+  order ≤ 32 (`gj_solve_multi`), which keeps one system in a
+  [K, lanes] tile, carries its right-hand sides as extra lanes and
+  selects pivots through one-hot iota masks (elimination as one fused
+  FMA+select pass over the block).
 
-- ``aug`` (round 1; what "auto" took below rank 96 until PR 29):
-  ROW-based GJ on the augmented [R_tile, K, K+1→lane-padded] block; b
-  rides as the last column.
-
-- ``packed``: COLUMN-based GJ on M = [[A], [bᵀ]] with b carried as an
-  extra SUBLANE row. A is symmetric, so reducing A to I by column
-  operations turns the b row into bᵀA⁻¹ = xᵀ. Removing the augmented
-  column from the LANE dim frees it for packing G = ⌊128/K⌋ (≤4) systems
-  per 128-lane block. The ROADMAP r2 #1 hypothesis (rank-64 lane padding
-  = 50% waste → pack 2 systems → 1.3–1.6×) was REFUTED on device time:
-  0.77× at rank 64 — the per-group pivot reductions cost more than the
-  padding they recover. It wins only where the augmented column spills
-  into a whole extra 128-lane tile: rank 128 (256→128 lanes, 1.05×),
-  which "auto" selects.
-
-- ``blocked2``: two pivots per step via an explicit 2×2 pivot-block
-  inverse, testing the latency-bound hypothesis (half the sequential
-  steps, ~8% more elementwise work). Also refuted: 0.89×/0.71× at rank
-  64/128 — the kernel is throughput-bound at what Mosaic achieves, so
-  extra ops cost proportionally and shorter chains buy nothing.
+Hypotheses refuted on device time, so nobody tries them again (the tables
+are in docs/performance.md; the kernels were deleted in PR 30):
+- several systems side by side in one system's lanes: 0.77× the one-hot
+  single-system kernel at rank 64 — the per-group pivot reductions cost
+  more than the lane padding they recover. ``lanes`` is NOT this
+  packing: it has no one-hot selection and no per-group reduce;
+- two pivots a step through a 2×2 pivot-block inverse: 0.89× / 0.71× at
+  rank 64 / 128 — the one-hot kernel is bound by VPU throughput at what
+  Mosaic achieves, not by the length of its chain;
+- a batched CG in place of the factorization: 1.5–2.8 s/epoch vs 1.07 s
+  (its matvecs re-read the [R, K, K] Gram from HBM every iteration).
 
 Mosaic lessons baked in (round-1 findings, kept so nobody re-learns them):
 - dynamic slices/stores on the sublane/lane dims miscompile silently
@@ -72,12 +57,10 @@ Mosaic lessons baked in (round-1 findings, kept so nobody re-learns them):
 
 Gauss-Jordan does ~2·K³ useful FLOPs per system (vs Cholesky's K³/3) but
 they are perfectly batch-parallel VPU FMAs instead of a sequential
-custom-call — measured 3.4× faster than the Cholesky path at rank 64 on
-v5e (110 ms → 32 ms on a [12664, 64, 64] batch, round 1). No
-pivoting: A = YᵀWY + λ(n)I is SPD (hence symmetric) with strictly
-positive diagonal, the same assumption MLlib's dppsv Cholesky makes.
-All-zero systems (bucket padding rows) short-circuit to x = 0 via the
-pivot guard.
+custom-call. No pivoting: A = YᵀWY + λ(n)I is SPD (hence symmetric) with
+strictly positive diagonal, the same assumption MLlib's dppsv Cholesky
+makes. All-zero systems (bucket padding rows) short-circuit to x = 0 via
+the pivot guard.
 
 No reference counterpart: PredictionIO delegates these solves to Spark
 MLlib's JNI BLAS («org.apache.spark.mllib.recommendation.ALS» →
@@ -88,29 +71,27 @@ TPU-native equivalent of that native layer.
 from __future__ import annotations
 
 import functools
-import os
 
 from predictionio_tpu.telemetry.registry import REGISTRY
 
-_LAYOUTS = ("lanes", "aug", "packed", "blocked2", "schur")
+_LAYOUTS = ("lanes", "schur")
 # The layout is chosen while a program is traced, so this counts the
 # solves a process built into its programs (one a bucket of a train
 # loop, however many iterations run it), not the solves it ran.
 SOLVE_CALLS = REGISTRY.counter(
     "als_solve_calls_total",
     "gj_solve calls traced into a program, by the kernel layout built "
-    "for them (lanes | aug | packed | blocked2 | schur)",
+    "for them (lanes | schur)",
     labelnames=("layout",))
 
-# VMEM budget for blocks in flight: pipelined input blocks + the scratch
-# working copy + x (≈4 blocks of slack). Sets the batch tile.
-_VMEM_BUDGET = 12 * 1024 * 1024
 _LANES = 128
 _SUBLANES = 8
 _MAX_RANK = 256
-_MAX_GROUPS = 4
-# lanes layout: largest order whose [K+1, K, 128] blocks fit VMEM
-_LANES_MAX_RANK = 128
+# `layout_for`: "schur" from this order up, "lanes" below. The lanes
+# layout's [K+1, K, 128] blocks fit VMEM up to order 128.
+_SCHUR_FROM_RANK = 96
+# `schur_solve` recurses down to this order, then `gj_solve_multi`
+_SCHUR_BASE = 32
 # lanes layout: columns eliminated a turn of the inner loop. The loop's
 # body is lowered once for every bucket shape of a train program, in
 # every process: 8 is 5 % faster on the chip (4.32 against 4.53 ms at
@@ -126,214 +107,16 @@ def _sub_pad(n: int) -> int:
     return -(-n // _SUBLANES) * _SUBLANES
 
 
-def _groups(k: int) -> int:
-    """Systems per 128-lane block in the packed layout."""
-    return max(1, min(_MAX_GROUPS, _LANES // k))
-
-
-def _row_tile(per_row_bytes: int, budget: int = _VMEM_BUDGET) -> int:
-    """Batch tile (multiple of 8, ≤128) sized so ~4 blocks fit in VMEM."""
+def _row_tile(per_row_bytes: int, budget: int) -> int:
+    """Batch tile (multiple of 8, ≤128) sized so ~4 blocks (pipelined
+    input blocks + the scratch working copy + the output) fit in `budget`
+    bytes of VMEM."""
     t = budget // (4 * per_row_bytes)
     return max(8, min(128, t // 8 * 8))
 
 
 def gj_applicable(rank: int) -> bool:
     return rank <= _MAX_RANK
-
-
-@functools.lru_cache(maxsize=32)
-def _build_solver_packed(k: int, g: int, sp: int, lanes: int, r_tile: int,
-                         n_tiles: int, interpret: bool):
-    """Column-GJ on [R_tile, sp, lanes] blocks holding g systems each.
-
-    Block layout: sublane i < k = row i of A for every packed system;
-    sublane k = bᵀ; lanes [s·k, (s+1)·k) = system s's columns. After k
-    column-elimination steps A → I and the b row holds xᵀ (A symmetric).
-    """
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    def kernel(m_ref, x_ref, scr):
-        scr[:] = m_ref[:]
-        sub = jax.lax.broadcasted_iota(jnp.int32, (1, sp, 1), 1)
-        lane = jax.lax.broadcasted_iota(jnp.int32, (1, 1, lanes), 2)
-        # static per-group lane masks; `low` = lanes of groups < g
-        # (prefix regions for the one-extra-reduce group combine)
-        gmask = [(lane >= s * k) & (lane < (s + 1) * k) for s in range(g)]
-
-        def group_broadcast(vals):
-            """Per-group lane sum of `vals`, broadcast back to every lane
-            of its group (prefix sums: g-1 extra masked reduces)."""
-            if g == 1:
-                return jnp.sum(vals, axis=2, keepdims=True) \
-                    * jnp.ones_like(vals)
-            pref = [jnp.sum(jnp.where(lane < s * k, vals, 0.0), axis=2,
-                            keepdims=True) for s in range(1, g)]
-            pref.append(jnp.sum(vals, axis=2, keepdims=True))
-            out = jnp.zeros_like(vals)
-            prev = 0.0
-            for s in range(g):
-                out = jnp.where(gmask[s], pref[s] - prev, out)
-                prev = pref[s]
-            return out
-
-        def step(j, _):
-            m = scr[:]
-            # one pivot lane per packed system
-            piv = gmask[0] & (lane == j)
-            for s in range(1, g):
-                piv = piv | (gmask[s] & (lane == s * k + j))
-            p = group_broadcast(jnp.where(piv, m, 0.0))
-            # f = row j of M (per lane c: M[j, c]); its pivot-lane entry
-            # is the pivot d = M[j, j] — recovered from f, not from a
-            # second full-block reduce
-            f = jnp.sum(jnp.where(sub == j, m, 0.0), axis=1, keepdims=True)
-            d = group_broadcast(jnp.where(piv, f, 0.0))
-            # all-zero (padding) systems: guard the pivot so they solve
-            # to x = 0 instead of poisoning the tile with inf/NaN
-            d = jnp.where(jnp.abs(d) < 1e-30, 1.0, d)
-            pn = p / d
-            # pivot columns become the normalized column; every other
-            # column eliminates its row-j entry
-            scr[:] = jnp.where(piv, pn, m - pn * f)
-            return 0
-
-        jax.lax.fori_loop(0, k, step, 0, unroll=False)
-        # xᵀ = the b row after elimination, one segment per system
-        is_b = jax.lax.broadcasted_iota(jnp.int32, (1, sp, 1), 1) == k
-        x_ref[:] = jnp.sum(jnp.where(is_b, scr[:], 0.0), axis=1)
-
-    return pl.pallas_call(
-        kernel,
-        grid=(n_tiles,),
-        in_specs=[pl.BlockSpec((r_tile, sp, lanes), lambda t: (t, 0, 0))],
-        out_specs=pl.BlockSpec((r_tile, lanes), lambda t: (t, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_tiles * r_tile, lanes),
-                                       jnp.float32),
-        scratch_shapes=[pltpu.VMEM((r_tile, sp, lanes), jnp.float32)],
-        interpret=interpret,
-    )
-
-
-@functools.lru_cache(maxsize=32)
-def _build_solver_blocked2(k: int, r_tile: int, n_tiles: int,
-                           interpret: bool):
-    """Row-GJ on the augmented layout, TWO pivots per step via an explicit
-    2×2 pivot-block inverse (k must be even).
-
-    Built to TEST the latency-bound hypothesis (K sequential steps of
-    chained masked reductions → halve the chain for ~8% more elementwise
-    work). The hypothesis was REFUTED: 0.89×/0.71× vs single-pivot at
-    rank 64/128 on device time (docs/performance.md round-3 A/B) — the
-    kernel is VPU-throughput-bound. Kept selectable for re-measurement on
-    future hardware/Mosaic generations.
-    """
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    kp = _lane_pad(k + 1)
-
-    def kernel(aug_ref, x_ref, scr):
-        scr[:] = aug_ref[:]
-        sub = jax.lax.broadcasted_iota(jnp.int32, (1, k, 1), 1)
-        lane = jax.lax.broadcasted_iota(jnp.int32, (1, 1, kp), 2)
-
-        def step(s, _):
-            j0 = 2 * s
-            j1 = j0 + 1
-            a = scr[:]  # [R, K, KP]
-            r0m = sub == j0
-            r1m = sub == j1
-            c0m = lane == j0
-            c1m = lane == j1
-            row0 = jnp.sum(jnp.where(r0m, a, 0.0), axis=1, keepdims=True)
-            row1 = jnp.sum(jnp.where(r1m, a, 0.0), axis=1, keepdims=True)
-            # 2×2 pivot block P = [[p00, p01], [p10, p11]]
-            p00 = jnp.sum(jnp.where(c0m, row0, 0.0), axis=2, keepdims=True)
-            p01 = jnp.sum(jnp.where(c1m, row0, 0.0), axis=2, keepdims=True)
-            p10 = jnp.sum(jnp.where(c0m, row1, 0.0), axis=2, keepdims=True)
-            p11 = jnp.sum(jnp.where(c1m, row1, 0.0), axis=2, keepdims=True)
-            det = p00 * p11 - p01 * p10
-            # padding systems arrive all-zero: solve to x = 0. A zero
-            # diagonal pivot with a live off-diagonal cannot happen for
-            # SPD A (leading principal minors are positive).
-            det = jnp.where(jnp.abs(det) < 1e-30, 1.0, det)
-            # normalized pivot rows: P⁻¹ @ [row0; row1]
-            n0 = (p11 * row0 - p01 * row1) / det
-            n1 = (p00 * row1 - p10 * row0) / det
-            col0 = jnp.sum(jnp.where(c0m, a, 0.0), axis=2, keepdims=True)
-            col1 = jnp.sum(jnp.where(c1m, a, 0.0), axis=2, keepdims=True)
-            pivm = r0m | r1m
-            col0 = jnp.where(pivm, 0.0, col0)
-            col1 = jnp.where(pivm, 0.0, col1)
-            upd = a - col0 * n0 - col1 * n1
-            scr[:] = jnp.where(r0m, n0, jnp.where(r1m, n1, upd))
-            return 0
-
-        jax.lax.fori_loop(0, k // 2, step, 0, unroll=False)
-        is_b = lane == k
-        x_ref[:] = jnp.sum(jnp.where(is_b, scr[:], 0.0), axis=2)
-
-    return pl.pallas_call(
-        kernel,
-        grid=(n_tiles,),
-        in_specs=[pl.BlockSpec((r_tile, k, kp), lambda g: (g, 0, 0))],
-        out_specs=pl.BlockSpec((r_tile, k), lambda g: (g, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_tiles * r_tile, k), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((r_tile, k, kp), jnp.float32)],
-        interpret=interpret,
-    )
-
-
-@functools.lru_cache(maxsize=32)
-def _build_solver_aug(k: int, r_tile: int, n_tiles: int, interpret: bool):
-    """Row-GJ on augmented [R_tile, K, lane_pad(K+1)] blocks (round-1
-    layout, kept for on-chip A/B against the packed kernel)."""
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    kp = _lane_pad(k + 1)  # augmented + lane-padded column count
-
-    def kernel(aug_ref, x_ref, scr):
-        scr[:] = aug_ref[:]
-        sub = jax.lax.broadcasted_iota(jnp.int32, (1, k, 1), 1)
-        lane = jax.lax.broadcasted_iota(jnp.int32, (1, 1, kp), 2)
-
-        def step(j, _):
-            a = scr[:]  # [R, K, KP]
-            is_row = sub == j
-            is_col = lane == j
-            row = jnp.sum(jnp.where(is_row, a, 0.0), axis=1,
-                          keepdims=True)  # [R, 1, KP] pivot row
-            d = jnp.sum(jnp.where(is_col, row, 0.0), axis=2,
-                        keepdims=True)  # [R, 1, 1] pivot
-            d = jnp.where(jnp.abs(d) < 1e-30, 1.0, d)
-            row = row / d
-            col = jnp.sum(jnp.where(is_col, a, 0.0), axis=2,
-                          keepdims=True)  # [R, K, 1] pivot column
-            col = jnp.where(is_row, 0.0, col)
-            scr[:] = jnp.where(is_row, row, a - col * row)
-            return 0
-
-        jax.lax.fori_loop(0, k, step, 0, unroll=False)
-        is_b = lane == k
-        x_ref[:] = jnp.sum(jnp.where(is_b, scr[:], 0.0), axis=2)
-
-    return pl.pallas_call(
-        kernel,
-        grid=(n_tiles,),
-        in_specs=[pl.BlockSpec((r_tile, k, kp), lambda g: (g, 0, 0))],
-        out_specs=pl.BlockSpec((r_tile, k), lambda g: (g, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_tiles * r_tile, k), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((r_tile, k, kp), jnp.float32)],
-        interpret=interpret,
-    )
 
 
 @functools.lru_cache(maxsize=8)
@@ -521,10 +304,10 @@ def gj_solve_multi(a, b, interpret: bool = False):
     return out[:r, :, k:k + m]
 
 
-def schur_solve(a, b, interpret: bool = False, base: int = 32):
+def schur_solve(a, b, interpret: bool = False):
     """x = A⁻¹ b via recursive Schur complements: the elimination work
     becomes [R, K/2, K/2] batched MXU matmuls plus multi-RHS GJ kernels
-    at the `base` size.
+    at order ≤ `_SCHUR_BASE`.
 
     Round-3 finding: the elementwise GJ kernel is VPU-throughput-bound
     (docs/performance.md layout A/B), so the only way to move the solve
@@ -541,16 +324,16 @@ def schur_solve(a, b, interpret: bool = False, base: int = 32):
     single = b.ndim == 2
     if single:
         b = b[..., None]
-    x = _schur_rec(a, b, base, interpret)
+    x = _schur_rec(a, b, interpret)
     return x[..., 0] if single else x
 
 
-def _schur_rec(a, b, base: int, interpret: bool):
+def _schur_rec(a, b, interpret: bool):
     import jax
     import jax.numpy as jnp
 
     k = a.shape[1]
-    if k <= base or k % 2:
+    if k <= _SCHUR_BASE or k % 2:
         return gj_solve_multi(a, b, interpret)
     h = k // 2
 
@@ -569,53 +352,12 @@ def _schur_rec(a, b, base: int, interpret: bool):
     b1, b2 = b[:, :h], b[:, h:]
     # one base call solves A11 against [A12 | B1] together (the RHS
     # columns ride in the same lane-padded block)
-    w = _schur_rec(a11, jnp.concatenate([a12, b1], axis=2), base, interpret)
+    w = _schur_rec(a11, jnp.concatenate([a12, b1], axis=2), interpret)
     w12, w1b = w[:, :, :h], w[:, :, h:]
     s = a22 - mm(a21, w12)  # SPD Schur complement
-    y2 = _schur_rec(s, b2 - mm(a21, w1b), base, interpret)
+    y2 = _schur_rec(s, b2 - mm(a21, w1b), interpret)
     y1 = w1b - mm(w12, y2)
     return jnp.concatenate([y1, y2], axis=1)
-
-
-def _solve_packed(a, b, interpret: bool):
-    import jax.numpy as jnp
-
-    r, k, _ = a.shape
-    g = _groups(k)
-    lanes = _lane_pad(g * k)
-    sp = _sub_pad(k + 1)
-    # tighter budget than the aug layout: the taller block (+x out block)
-    # tripped the 16 MB scoped-vmem ceiling at the 12 MB/4-block sizing
-    r_tile = _row_tile(sp * lanes * 4, budget=10 * 1024 * 1024)
-    rg = -(-r // g)  # packed row-groups needed
-    rg_pad = -(-rg // r_tile) * r_tile
-
-    m = jnp.concatenate(
-        [a.astype(jnp.float32), b.astype(jnp.float32)[:, None, :]], axis=1)
-    m = jnp.pad(m, ((0, rg_pad * g - r), (0, sp - (k + 1)), (0, 0)))
-    # [rg, g, sp, k] → [rg, sp, g·k]: consecutive systems share a block
-    m = (m.reshape(rg_pad, g, sp, k).transpose(0, 2, 1, 3)
-         .reshape(rg_pad, sp, g * k))
-    m = jnp.pad(m, ((0, 0), (0, 0), (0, lanes - g * k)))
-    x = _build_solver_packed(k, g, sp, lanes, r_tile, rg_pad // r_tile,
-                             interpret)(m)
-    x = x[:, :g * k].reshape(rg_pad * g, k)
-    return x[:r]
-
-
-def _solve_aug(a, b, interpret: bool, blocked: bool = False):
-    import jax.numpy as jnp
-
-    r, k, _ = a.shape
-    kp = _lane_pad(k + 1)
-    r_tile = _row_tile(k * kp * 4)
-    r_pad = -(-r // r_tile) * r_tile
-    aug = jnp.concatenate(
-        [a.astype(jnp.float32), b.astype(jnp.float32)[..., None]], axis=-1)
-    aug = jnp.pad(aug, ((0, r_pad - r), (0, 0), (0, kp - (k + 1))))
-    build = _build_solver_blocked2 if blocked else _build_solver_aug
-    x = build(k, r_tile, r_pad // r_tile, interpret)(aug)
-    return x[:r]
 
 
 def _solve_lanes(a, b, interpret: bool):
@@ -638,47 +380,26 @@ def _solve_lanes(a, b, interpret: bool):
     return x_t[:k].T
 
 
-def layout_for(k: int, layout: str = "") -> str:
-    """The kernel layout `gj_solve` builds at order k: the one asked for,
-    else `PIO_GJ_LAYOUT`, else ("auto") by rank alone: "schur" from 96 up
-    (recursive Schur over MXU matmuls — 1.49× vs the best one-hot layout
-    at rank 128), "lanes" below (one system a lane: 9× "aug"'s kernel at
-    rank 64 — docs/performance.md). Raises where the layout cannot be
-    built: forced layouts exist for honest A/Bs — never silently measure
-    a different kernel than the label claims."""
-    layout = layout or os.environ.get("PIO_GJ_LAYOUT", "auto")
-    if layout == "auto":
-        layout = "schur" if k >= 96 else "lanes"
-    if layout not in _LAYOUTS:
-        raise ValueError(f"unknown gj_solve layout {layout!r} "
-                         f"(want auto/{'/'.join(_LAYOUTS)})")
-    if layout == "blocked2" and k % 2:
-        raise ValueError(f"layout='blocked2' needs even rank, got {k}")
-    if layout == "lanes" and k > _LANES_MAX_RANK:
-        raise ValueError(f"layout='lanes' holds a [K+1, K, 128] block in "
-                         f"VMEM: rank <= {_LANES_MAX_RANK}, got {k}")
-    return layout
+def layout_for(k: int) -> str:
+    """The kernel layout `gj_solve` builds at order k, by rank alone:
+    "schur" from 96 up (recursive Schur over MXU matmuls — 1.49× the best
+    one-hot layout at rank 128), "lanes" below (one system a lane: 9× the
+    one-hot kernel at rank 64 — docs/performance.md)."""
+    return "schur" if k >= _SCHUR_FROM_RANK else "lanes"
 
 
-def gj_solve(a, b, interpret: bool = False, layout: str = ""):
+def gj_solve(a, b, interpret: bool = False):
     """Solve x = A⁻¹ b for a batch of SPD systems.
 
     a: [R, K, K] f32 — SPD, hence symmetric (λ-regularized normal
-       equations; the lanes and packed layouts' column elimination
-       relies on the symmetry); all-zero systems (bucket padding rows)
-       yield x = 0.
+       equations; the lanes layout's column elimination relies on the
+       symmetry); all-zero systems (bucket padding rows) yield x = 0.
     b: [R, K] f32
-    layout: "" or "auto" (default) goes by `layout_for`; "lanes", "aug",
-       "packed", "blocked2", "schur" force a layout. The layout built is
-       counted in `als_solve_calls_total{layout}`.
-    returns x: [R, K] f32
+    returns x: [R, K] f32. The layout built (`layout_for`) is counted in
+    `als_solve_calls_total{layout}`.
     """
-    layout = layout_for(a.shape[1], layout)
+    layout = layout_for(a.shape[1])
     SOLVE_CALLS.labels(layout=layout).inc()
     if layout == "schur":
         return schur_solve(a, b, interpret)
-    if layout == "lanes":
-        return _solve_lanes(a, b, interpret)
-    if layout == "packed":
-        return _solve_packed(a, b, interpret)
-    return _solve_aug(a, b, interpret, blocked=layout == "blocked2")
+    return _solve_lanes(a, b, interpret)

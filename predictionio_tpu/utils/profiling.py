@@ -184,9 +184,9 @@ def trace_device_time_s(fn) -> float:
 
     Returns 0.0 WITHOUT running `fn` when the TensorFlow xplane protos are
     absent (capture could never be parsed) — callers treat <=0 as
-    "device time unavailable" (bench_north_star emits device_epoch_s=null,
-    benchmarks/gj_layouts.py exits), so skipping the doomed trace saves
-    minutes of profiled reps on a TF-less image."""
+    "device time unavailable" (bench_north_star emits device_epoch_s=null),
+    so skipping the doomed trace saves minutes of profiled reps on a
+    TF-less image."""
     import shutil
     import tempfile
 

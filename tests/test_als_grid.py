@@ -7,6 +7,7 @@ import dataclasses
 import numpy as np
 import pytest
 
+from predictionio_tpu.ops import pallas_solve
 from predictionio_tpu.ops.als import ALSConfig, als_train
 from predictionio_tpu.ops.als_grid import als_train_grid, grid_compatible
 
@@ -48,10 +49,6 @@ class TestGridCompatible:
         reason = grid_compatible(cfgs)
         assert reason is not None and field in reason
 
-    def test_cg_rejected(self):
-        cfgs = [dataclasses.replace(self.BASE, solver="cg")] * 2
-        assert "cg" in grid_compatible(cfgs)
-
     def test_empty_rejected(self):
         assert grid_compatible([]) is not None
 
@@ -60,7 +57,7 @@ class TestGridCompatible:
 
         cfgs = [dataclasses.replace(self.BASE, rank=r, reg=lam)
                 for r in (8, 16) for lam in (0.01, 0.1)]
-        cfgs.append(dataclasses.replace(self.BASE, solver="cg"))
+        cfgs.append(dataclasses.replace(self.BASE, solver="chol"))
         groups = grid_groups(cfgs)
         assert sorted(map(sorted, groups)) == [[0, 1], [2, 3], [4]]
 
@@ -142,6 +139,36 @@ class TestGridMatchesSequential:
         grid_1 = als_train_grid(u, i, v, n_u, n_i, cfgs, mesh=single)
         for gm, g1 in zip(grid_m, grid_1):
             assert rel_err(gm.user_factors, g1.user_factors) < 1e-4
+
+    @pytest.mark.parametrize("n_devices", [1, 8])
+    def test_gj_grid_matches_chol_grid(self, n_devices):
+        """The grid's kernel branch (interpret mode): the (row, grid)
+        batch flattened into `solve_spd`, on one device and under
+        `shard_map` on a data mesh, where the split accumulators' batch
+        goes to Cholesky; against the same grid solved by Cholesky."""
+        import jax
+        from jax.sharding import Mesh
+
+        from predictionio_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
+
+        u, i, v, n_u, n_i = coo(n=6000, n_u=120, n_i=80, seed=2)
+        base = ALSConfig(rank=8, iterations=2, seed=3, split_cap=32)
+        cfgs = [dataclasses.replace(base, reg=r) for r in (0.05, 0.5)]
+        mesh = Mesh(np.array(jax.devices()[:n_devices]).reshape(-1, 1),
+                    (DATA_AXIS, MODEL_AXIS))
+        chol = als_train_grid(
+            u, i, v, n_u, n_i,
+            [dataclasses.replace(c, solver="chol") for c in cfgs], mesh=mesh)
+        built = pallas_solve.SOLVE_CALLS.labels(layout="lanes")
+        before = built.value
+        gj = als_train_grid(
+            u, i, v, n_u, n_i,
+            [dataclasses.replace(c, solver="gj", pallas="interpret")
+             for c in cfgs], mesh=mesh)
+        assert built.value > before  # the kernel, not a fallback
+        for g, c in zip(gj, chol):
+            assert rel_err(g.user_factors, c.user_factors) < 5e-4
+            assert rel_err(g.item_factors, c.item_factors) < 5e-4
 
     def test_model_sharded_mesh_rejected(self):
         import jax

@@ -56,18 +56,19 @@ class TestGJSolve:
         rel = np.abs(x - ref).max() / np.abs(ref).max()
         assert rel < 1e-4, rel
 
-    @pytest.mark.parametrize("layout", ["lanes", "aug", "packed",
-                                        "blocked2"])
+    @pytest.mark.parametrize("layout", pallas_solve._LAYOUTS)
     @pytest.mark.parametrize("r,k", [(33, 64), (9, 128), (7, 100)])
-    def test_every_layout_matches(self, layout, r, k):
-        """Every kernel layout stays numerically exact and selectable by
-        name, and a forced layout is the one that is built and counted;
-        'auto' routing is free to change between them."""
+    def test_every_layout_matches(self, monkeypatch, layout, r, k):
+        """Both layouts stay numerically exact on either side of the
+        rank at which `layout_for` changes over, and the one built is
+        the one counted; the threshold is free to move between them."""
+        monkeypatch.setattr(pallas_solve, "_SCHUR_FROM_RANK",
+                            k if layout == "schur" else k + 1)
         rng = np.random.default_rng(4)
         a, b = _spd_batch(rng, r, k)
         before = _built(layout)
         x = np.asarray(gj_solve(jnp.asarray(a), jnp.asarray(b),
-                                interpret=True, layout=layout))
+                                interpret=True))
         assert _built(layout) == before + 1
         ref = np.linalg.solve(a, b[..., None])[..., 0]
         rel = np.abs(x - ref).max() / np.abs(ref).max()
@@ -86,7 +87,7 @@ class TestGJSolve:
         b[zeros] = 0.0
         live = np.setdiff1d(np.arange(r), zeros)
         x = np.asarray(gj_solve(jnp.asarray(a), jnp.asarray(b),
-                                interpret=True, layout="lanes"))
+                                interpret=True))
         ref = np.linalg.solve(a[live].astype(np.float64),
                               b[live, :, None].astype(np.float64))[..., 0]
         assert np.abs(x[live] - ref).max() / np.abs(ref).max() < 1e-5
@@ -94,10 +95,9 @@ class TestGJSolve:
 
     @pytest.mark.parametrize("k,layout", [(8, "lanes"), (63, "lanes"),
                                           (96, "schur"), (128, "schur")])
-    def test_auto_routes_by_rank(self, monkeypatch, k, layout):
-        """'auto' goes by the rank alone: one system a lane below 96,
-        schur from 96 up; the counter says which was built."""
-        monkeypatch.delenv("PIO_GJ_LAYOUT", raising=False)
+    def test_auto_routes_by_rank(self, k, layout):
+        """The layout goes by the rank alone: one system a lane below
+        96, schur from 96 up; the counter says which was built."""
         a, b = _spd_batch(np.random.default_rng(k), 3, k)
         before = {name: _built(name) for name in pallas_solve._LAYOUTS}
         x = np.asarray(gj_solve(jnp.asarray(a), jnp.asarray(b),
@@ -107,22 +107,6 @@ class TestGJSolve:
         assert after == before
         ref = np.linalg.solve(a, b[..., None])[..., 0]
         assert np.abs(x - ref).max() / np.abs(ref).max() < 1e-4
-
-    def test_environment_forces_a_layout(self, monkeypatch):
-        monkeypatch.setenv("PIO_GJ_LAYOUT", "aug")
-        a, b = _spd_batch(np.random.default_rng(11), 5, 16)
-        before = _built("aug")
-        gj_solve(jnp.asarray(a), jnp.asarray(b), interpret=True)
-        assert _built("aug") == before + 1
-
-    @pytest.mark.parametrize("layout,k", [("lanes", 136), ("blocked2", 9),
-                                          ("lane", 8)])
-    def test_layouts_that_cannot_be_built_raise(self, layout, k):
-        """A forced layout never silently becomes another one."""
-        a, b = _spd_batch(np.random.default_rng(12), 2, k)
-        with pytest.raises(ValueError, match="layout"):
-            gj_solve(jnp.asarray(a), jnp.asarray(b), interpret=True,
-                     layout=layout)
 
     def test_lanes_kernel_is_traced_once_for_every_batch(self, monkeypatch):
         """Guard on what a first call pays (PERF.md, PR 29): Pallas
@@ -140,7 +124,7 @@ class TestGJSolve:
         pallas_solve._lanes_kernel.cache_clear()
 
         def solves(*ab):
-            return [gj_solve(ab[i], ab[i + 1], layout="lanes")
+            return [gj_solve(ab[i], ab[i + 1])
                     for i in range(0, len(ab), 2)]
 
         shapes = []
@@ -161,8 +145,7 @@ class TestGJSolve:
         unseen."""
         a = jax.ShapeDtypeStruct((300, k, k), jnp.float32)
         b = jax.ShapeDtypeStruct((300, k), jnp.float32)
-        jaxpr = jax.make_jaxpr(
-            lambda a_, b_: gj_solve(a_, b_, layout="lanes"))(a, b).jaxpr
+        jaxpr = jax.make_jaxpr(gj_solve)(a, b).jaxpr
         (call,) = _pallas_calls(jaxpr)
         assert sum(1 for _ in _walk_eqns(call.params["jaxpr"])) <= 100
         # around it: casts, the change of layout, its padding, and back
@@ -211,7 +194,7 @@ class TestGJSolve:
         np.testing.assert_array_equal(x[2], np.zeros(64, np.float32))
 
     def test_auto_routes_large_ranks_to_schur(self, monkeypatch):
-        """gj_solve layout='auto' sends rank ≥ 96 through schur_solve."""
+        """gj_solve sends rank ≥ 96 through schur_solve."""
         from predictionio_tpu.ops import pallas_solve
 
         called = []
@@ -226,16 +209,6 @@ class TestGJSolve:
         a, b = _spd_batch(rng, 3, 64)
         gj_solve(jnp.asarray(a), jnp.asarray(b), interpret=True)
         assert not called  # rank 64 stays on the elementwise kernel
-
-    def test_packed_groups_pack_small_ranks(self):
-        """Ranks ≤64 share 128-lane blocks in the packed layout; the
-        unpack must restore original system order."""
-        rng = np.random.default_rng(5)
-        a, b = _spd_batch(rng, 21, 16)
-        x = np.asarray(gj_solve(jnp.asarray(a), jnp.asarray(b),
-                                interpret=True, layout="packed"))
-        ref = np.linalg.solve(a, b[..., None])[..., 0]
-        assert np.abs(x - ref).max() / np.abs(ref).max() < 1e-4
 
     def test_all_zero_system_solves_to_zero(self):
         """Bucket padding rows arrive as A=0, b=0 and must not NaN."""
@@ -295,7 +268,6 @@ class TestALSWithGJ:
         the lanes kernel at rank < 96."""
         from predictionio_tpu.ops import als
 
-        monkeypatch.delenv("PIO_GJ_LAYOUT", raising=False)
         expected = []
         real = als._solve_buckets_device
 
@@ -335,18 +307,64 @@ class TestALSWithGJ:
         assert len(by_name["als.solve.lanes"]) == 2
         assert all(lo <= s and e <= hi for s, e in by_name["als.solve.lanes"])
 
+    def test_rank_96_side_holds_only_schur_kernels(self):
+        """Program-level guard for rank >= 96 (the als128i cell's route):
+        one side's half-iteration holds one schur recursion a bucket and
+        one for its split rows' accumulators, 96 -> 48 -> 24 (two levels,
+        four base kernels each) and no lanes kernel, and the counter
+        says so."""
+        from predictionio_tpu.ops import als
+
+        f32, i32 = jnp.float32, jnp.int32
+        shape = jax.ShapeDtypeStruct
+
+        def bucket(r, c, split):
+            return (shape((r,), i32), shape((r, c), i32),
+                    shape((r, c), f32), shape((r, c), f32),
+                    shape((r,), i32) if split else None)
+
+        cfg = ALSConfig(rank=96, solver="gj", pallas="interpret")
+
+        def side(opposing, buckets, split_rows):
+            return als._solve_buckets_device(opposing, 50, buckets, cfg,
+                                             split_rows)
+
+        before = {name: _built(name) for name in pallas_solve._LAYOUTS}
+        jaxpr = jax.make_jaxpr(side)(
+            shape((41, 96), f32),
+            (bucket(16, 8, False), bucket(8, 32, True)),
+            shape((3,), i32)).jaxpr
+        built = {name: _built(name) - before[name]
+                 for name in pallas_solve._LAYOUTS}
+        assert built == {"lanes": 0, "schur": 3}
+        calls = _pallas_calls(jaxpr)
+        assert len(calls) == 4 * 3
+        assert not any(c.params["name"] == "gj_lanes" for c in calls)
+        # the base kernels see order 24: lanes pad 24 + its right-hand
+        # sides to one tile of 128
+        assert {c.params["out_avals"][0].shape[1:] for c in calls} \
+            == {(24, 128)}
+
     def test_schur_layout_matches_chol_trajectory(self, monkeypatch):
-        """Full ALS training through the schur solver path (forced via
-        PIO_GJ_LAYOUT at a small rank; 'auto' takes it at rank ≥ 96)
-        reproduces the Cholesky trajectory."""
-        monkeypatch.setenv("PIO_GJ_LAYOUT", "schur")
+        """Full ALS training through the schur solver path (its
+        threshold lowered to the test's small rank; it is 96) reproduces
+        the Cholesky trajectory."""
+        from predictionio_tpu.ops import als
+
+        monkeypatch.setattr(pallas_solve, "_SCHUR_FROM_RANK", 8)
+        # the loop of `test_gj_matches_chol_trajectory` holds lanes
+        # kernels for this very config
+        als._get_train_loop.cache_clear()
         ui, ii, r, n_u, n_i = self._data()
         mesh = make_mesh({"data": 1, "model": 1}, devices=jax.devices()[:1])
         base = ALSConfig(rank=8, iterations=5, reg=0.05, seed=0,
                          pallas="interpret")
+        before = _built("schur")
         res_s = als_train(ui, ii, r, n_u, n_i,
                           dataclasses.replace(base, solver="gj"),
                           mesh=mesh, compute_rmse=True)
+        als._get_train_loop.cache_clear()
+        assert _built("schur") > before
         res_c = als_train(ui, ii, r, n_u, n_i,
                           dataclasses.replace(base, solver="chol",
                                               pallas="off"),

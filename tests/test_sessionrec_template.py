@@ -1,12 +1,14 @@
 """Sessionrec template: DASE train end to end, the sequence-tier
 ladder, and the parity contract docs/serving.md points here for —
-a history scores bitwise-identically at every tier that fits it and in
-every batch that carries it, because pads are exact no-ops (masked
-attention, last-real-position readout). Also holds the compile-count
+a history scores bitwise-identically at every tier that fits it,
+because pads are exact no-ops (masked attention, last-real-position
+readout), and to a few ulp in every batch that carries it (the backend
+picks its dot kernel by the batch's shape). Also holds the compile-count
 discipline: after warmup, repeat traffic adds zero compiles and the
 warmed executable space is bounded by (batch tiers × sequence tiers).
 """
 
+import numpy as np
 import pytest
 
 from predictionio_tpu.controller import WorkflowContext
@@ -147,14 +149,21 @@ class TestTrainAndServe:
 
 
 class TestTierParity:
-    """The docs/serving.md promise: bitwise invariance across tiers."""
+    """The docs/serving.md promise: a history's scores do not depend on
+    the sequence tier it pads to (bitwise), nor, to the last bits, on
+    the batch it rides in."""
 
     def _histories(self, model):
         items = [f"i{k}" for k in range(8)]
         # lengths chosen to land on BOTH default tiers (8 and 16)
         return [items[:2], items[:5], items + items[:3]]
 
-    def test_batched_vs_single_bitwise_at_every_tier(self, trained):
+    def test_batched_vs_single_agree_at_every_tier(self, trained):
+        """Across batch shapes the scores agree to a few ulp, not to the
+        bit: XLA's CPU backend picks a dot kernel by the operands'
+        shapes (a vector product, its own small-matrix loop or Eigen)
+        and their sums differ in the last bit. Bit equality holds where
+        the batch shape is the same: the next test."""
         engine, ep, models = trained
         model = models[0]
         queries = [{"items": h, "num": 4} for h in self._histories(model)]
@@ -164,7 +173,10 @@ class TestTierParity:
         singles = [engine.predict(ep, models, q) for q in queries]
         batched = engine.predict_batch(ep, models, queries)
         for s, b in zip(singles, batched):
-            assert _scores(s) == _scores(b)  # float-exact
+            assert [i for i, _ in _scores(s)] == [i for i, _ in _scores(b)]
+            np.testing.assert_array_almost_equal_nulp(
+                np.float32([v for _, v in _scores(s)]),
+                np.float32([v for _, v in _scores(b)]), nulp=16)
 
     def test_same_history_scores_bitwise_on_a_different_ladder(
             self, trained, monkeypatch):

@@ -34,32 +34,40 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _compiled_text(one_chip, r, k, layout):
+def _compiled_text(one_chip, r, k):
     a = jax.ShapeDtypeStruct((r, k, k), jnp.float32, sharding=one_chip)
     b = jax.ShapeDtypeStruct((r, k), jnp.float32, sharding=one_chip)
 
     def solve(a, b):
         with jax.named_scope("als.solve"):
-            return pallas_solve.gj_solve(a, b, layout=layout)
+            return pallas_solve.gj_solve(a, b)
 
     return jax.jit(solve).lower(a, b).compile().as_text()
 
 
 # the hot bucket of an ML-20M train (a ragged last block: 31296 = 244.5
 # blocks), a fold's handful of rows under one block, a rank off the
-# sublane tile, the largest blocks `auto` hands the layout (rank 88-95)
+# sublane tile, the largest blocks `layout_for` hands the layout (rank
+# 88-95)
 @pytest.mark.parametrize("r,k", [(31296, 64), (8, 64), (40, 10), (1000, 88)])
 def test_lanes_solver_compiles_for_v5e(one_chip, r, k):
-    text = _compiled_text(one_chip, r, k, "")
+    text = _compiled_text(one_chip, r, k)
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     # the benchmark's readers find the kernel by its scope and its call
     assert "als.solve" in text and "gj_lanes" in text
 
 
-def test_aug_solver_still_compiles_for_v5e(one_chip):
-    """The layout kept for the A/B stays a kernel the chip accepts."""
-    text = _compiled_text(one_chip, 12768, 64, "aug")
-    assert 'custom_call_target="tpu_custom_call"' in text
+# the hot bucket of the rank-128 cell (als128i.train10) and a fold's rows
+@pytest.mark.parametrize("r", [31232, 8])
+def test_schur_solver_compiles_for_v5e(one_chip, r):
+    """Rank 128 goes 128 -> 64 -> 32: four base kernels (the one-hot
+    multi-RHS kernel) with MXU products between them, all under the
+    scope the benchmark's readers look for."""
+    text = _compiled_text(one_chip, r, 128)
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 4
+    assert all("als.solve" in line for line in calls)
     assert "gj_lanes" not in text
 
 
