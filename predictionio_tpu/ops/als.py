@@ -605,7 +605,9 @@ def normal_eq_einsum(compute_dtype):
     rank 64, λ=0.05 (PERF.md, PR 21): rows with ≤ 2 ratings came out
     ~50 % (median) off the f64 reference, all rows 12 %, against 1e-5
     at HIGHEST — with either solver. So f32 means f32 here, as it does
-    in `pallas_solve._schur_rec`. On CPU the argument changes nothing."""
+    in the solver (the lanes kernel eliminates in f32 on the VPU; the
+    products of `pallas_solve._schur_rec` are pinned to HIGHEST). On CPU
+    the argument changes nothing."""
     import jax
     import jax.numpy as jnp
 

@@ -10,27 +10,27 @@ triangular solves).
 Two layouts, both Gauss-Jordan reductions in data-independent steps of
 elementwise VPU work, vectorized over the batch so throughput scales with
 the batch instead of the sequential critical path of one factorization.
-`layout_for` picks by rank alone; docs/performance.md has the device-time
-A/Bs that settled it, and those of the layouts that lost.
+`layout_for` picks by what the order's block asks of VMEM; the device-time
+A/Bs that settled it, and the layouts that lost: docs/performance.md.
 
-- ``lanes`` (rank < 96): ONE SYSTEM A LANE. A block is [K+1, K, 128]:
-  leading index = column (b last), sublanes = row, lanes = 128 systems.
-  The pivot column is taken by a dynamic index on the leading dim and a
-  column's pivot-row entry is read from the pivot column by symmetry, so
-  a step is one masked sublane reduce (the pivot) and then multiply,
-  subtract, select over the live columns: no one-hot selection, no
-  cross-lane traffic, no padded lane. Around it one XLA copy turns
-  [R, K, K] batch-minor; b and x are batch-minor already as XLA lays
-  them out. A few rows cost a whole block, 19 µs. In an ML-20M rank-64
-  iteration the solves take 0.034 s and the kernel reaches 14.7 % of its
-  bytes bound (ledger, PR 28).
+- ``lanes`` (wherever three blocks fit VMEM: every order `gj_applicable`
+  admits): ONE SYSTEM A LANE. A block is [K+1, K, 128]: leading index =
+  column (b last), sublanes = row, lanes = 128 systems. The pivot column
+  is taken by a dynamic index on the leading dim and a column's pivot-row
+  entry is read from the pivot column by symmetry, so a step is one masked
+  sublane reduce (the pivot) and then multiply, subtract, select over the
+  live columns: no one-hot selection, no cross-lane traffic, no padded
+  lane. Around it one XLA copy turns [R, K, K] batch-minor; b and x are
+  batch-minor already as XLA lays them out. A few rows cost a whole
+  block: 19 µs at K 64, 113 µs at K 128. Of its bytes bound the kernel
+  reaches 14.0 % in an ML-20M rank-64 iteration (ledger, PR 29).
 
-- ``schur`` (rank ≥ 96): recursive Schur complements. The elimination
-  becomes [R, K/2, K/2] batched MXU matmuls round a multi-RHS kernel at
-  order ≤ 32 (`gj_solve_multi`), which keeps one system in a
-  [K, lanes] tile, carries its right-hand sides as extra lanes and
-  selects pivots through one-hot iota masks (elimination as one fused
-  FMA+select pass over the block).
+- ``schur`` (orders whose block does not fit; none up to `_MAX_RANK`):
+  recursive Schur complements. The elimination becomes [R, K/2, K/2]
+  batched MXU matmuls round a multi-RHS kernel at order ≤ 32
+  (`gj_solve_multi`), which keeps one system in a [K, lanes] tile,
+  carries its right-hand sides as extra lanes and selects pivots through
+  one-hot iota masks (one fused FMA+select pass over the block).
 
 Hypotheses refuted on device time, so nobody tries them again (the tables
 are in docs/performance.md; the kernels were deleted in PR 30):
@@ -87,9 +87,9 @@ SOLVE_CALLS = REGISTRY.counter(
 _LANES = 128
 _SUBLANES = 8
 _MAX_RANK = 256
-# `layout_for`: "schur" from this order up, "lanes" below. The lanes
-# layout's [K+1, K, 128] blocks fit VMEM up to order 128.
-_SCHUR_FROM_RANK = 96
+# `layout_for`: "lanes" where `_lanes_vmem_bytes(k)` fits this, the VMEM
+# of one v5e core (orders up to 280), "schur" above.
+_VMEM_BYTES = 128 * 2**20
 # `schur_solve` recurses down to this order, then `gj_solve_multi`
 _SCHUR_BASE = 32
 # lanes layout: columns eliminated a turn of the inner loop. The loop's
@@ -213,7 +213,7 @@ def _build_solver_lanes(k: int, r: int, interpret: bool):
     from jax.experimental.pallas import tpu as pltpu
 
     kr = _sub_pad(k)
-    block = (kr + 1) * kr * _LANES * 4
+
     return pl.pallas_call(
         _lanes_kernel(k),
         # the batch is the minor dim and is not padded: the last block's
@@ -226,9 +226,9 @@ def _build_solver_lanes(k: int, r: int, interpret: bool):
         out_shape=jax.ShapeDtypeStruct((kr, r), jnp.float32),
         scratch_shapes=[pltpu.VMEM((kr + 1, kr, _LANES), jnp.float32),
                         pltpu.VMEM((kr, _LANES), jnp.float32)],
-        # two pipelined input blocks + the working copy + x
+        # what `layout_for` held against the chip's VMEM
         compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=max(16, 3 * block // 2**20 + 8) * 2**20),
+            vmem_limit_bytes=_lanes_vmem_bytes(k)),
         name="gj_lanes",
         interpret=interpret,
     )
@@ -381,11 +381,11 @@ def _solve_lanes(a, b, interpret: bool):
 
 
 def layout_for(k: int) -> str:
-    """The kernel layout `gj_solve` builds at order k, by rank alone:
-    "schur" from 96 up (recursive Schur over MXU matmuls — 1.49× the best
-    one-hot layout at rank 128), "lanes" below (one system a lane: 9× the
-    one-hot kernel at rank 64 — docs/performance.md)."""
-    return "schur" if k >= _SCHUR_FROM_RANK else "lanes"
+    """The kernel layout `gj_solve` builds at order k: "lanes" (one system
+    a lane) wherever the kernel's three blocks fit the chip's VMEM, which
+    is every order `gj_applicable` admits; "schur" (recursive Schur over
+    MXU matmuls) above. `_lanes_vmem_bytes` has the chip's readings."""
+    return "lanes" if _lanes_vmem_bytes(k) <= _VMEM_BYTES else "schur"
 
 
 def gj_solve(a, b, interpret: bool = False):
@@ -403,3 +403,27 @@ def gj_solve(a, b, interpret: bool = False):
     if layout == "schur":
         return schur_solve(a, b, interpret)
     return _solve_lanes(a, b, interpret)
+
+
+def _lanes_vmem_bytes(k: int) -> int:
+    """VMEM the lanes kernel asks for at order k, which `layout_for` holds
+    against the chip's: two pipelined input blocks and the working copy,
+    [K+1, K, 128] f32 each, and 8 MiB for b, x, the factors and the
+    compiler (16 MiB at least, which is K 64's; 32 MiB at K 128, 104 at
+    K 256, 123 at K 280, the last order that fits 128 MiB).
+
+    That is the whole rule because lanes won wherever it fits (my chip
+    runs, PR 31; kernels and the XLA ops round them, the table is in
+    docs/performance.md): 40.1 ms against schur's 135.6 at
+    [31248, 128, 128], every batch of bucket height from order 64 to 256
+    (1.58x there), a fold's 8 rows up to order 128 (0.119 against 0.140
+    ms); schur is ahead only on 8 rows from order 160 up, by 0.01-0.66
+    ms.
+
+    (Down here, below `gj_solve`: a kernel's call sites ride in its
+    Mosaic payload and so in the compile-cache key of every program that
+    holds it. Lines added above `gj_solve` cost every checkout one
+    compile of its train loops.)"""
+    kr = _sub_pad(k)
+    block = (kr + 1) * kr * _LANES * 4
+    return max(16, 3 * block // 2**20 + 8) * 2**20

@@ -5,7 +5,7 @@ Three questions, one home each. *Kernel or Cholesky* is decided once a
 train by `ops.als.resolve_solver` (backend, rank, assert mode) and arrives
 here as `kernel`. *How it is dispatched* (plain, or one kernel a device
 under `shard_map`) is `solve_spd` below. *Which kernel* is
-`pallas_solve.layout_for` (rank alone). Every half-iteration calls
+`pallas_solve.layout_for` (the order alone). Every half-iteration calls
 `solve_spd`: `ops/als.py`, `ops/als_grid.py` (which flattens its grid axis
 into the batch round the call) and `ops/als_sharded.py` (already inside
 its own `shard_map`, so it passes no mesh).

@@ -60,10 +60,10 @@ class TestGJSolve:
     @pytest.mark.parametrize("r,k", [(33, 64), (9, 128), (7, 100)])
     def test_every_layout_matches(self, monkeypatch, layout, r, k):
         """Both layouts stay numerically exact on either side of the
-        rank at which `layout_for` changes over, and the one built is
-        the one counted; the threshold is free to move between them."""
-        monkeypatch.setattr(pallas_solve, "_SCHUR_FROM_RANK",
-                            k if layout == "schur" else k + 1)
+        VMEM size at which `layout_for` changes over, and the one built
+        is the one counted; the size is free to move between them."""
+        monkeypatch.setattr(pallas_solve, "_VMEM_BYTES",
+                            0 if layout == "schur" else 2**40)
         rng = np.random.default_rng(4)
         a, b = _spd_batch(rng, r, k)
         before = _built(layout)
@@ -74,12 +74,13 @@ class TestGJSolve:
         rel = np.abs(x - ref).max() / np.abs(ref).max()
         assert rel < 1e-4, (layout, rel)
 
-    @pytest.mark.parametrize("k", [10, 32, 64, 88])
+    @pytest.mark.parametrize("k", [10, 32, 64, 88, 96, 100, 128])
     @pytest.mark.parametrize("r", [1, 7, 8, 40, 128, 200, 943])
     def test_lanes_layout_matches_float64(self, r, k):
         """One system a lane: batches on both sides of a 128-lane block
-        and orders on and off the 8-sublane tile, against float64;
-        all-zero systems (bucket padding) come out exactly 0."""
+        and orders on and off the 8-sublane tile, up to the rank-128
+        cell's, against float64; all-zero systems (bucket padding) come
+        out exactly 0."""
         rng = np.random.default_rng(1000 * k + r)
         a, b = _spd_batch(rng, r, k)
         zeros = [2, r - 1] if r > 3 else []
@@ -94,10 +95,13 @@ class TestGJSolve:
         np.testing.assert_array_equal(x[zeros], 0.0)
 
     @pytest.mark.parametrize("k,layout", [(8, "lanes"), (63, "lanes"),
-                                          (96, "schur"), (128, "schur")])
+                                          (96, "lanes"), (128, "lanes"),
+                                          (288, "schur")])
     def test_auto_routes_by_rank(self, k, layout):
-        """The layout goes by the rank alone: one system a lane below
-        96, schur from 96 up; the counter says which was built."""
+        """The layout goes by the order alone, through the VMEM its
+        block asks for: one system a lane wherever three blocks fit
+        (up to order 280: every rank `gj_applicable` admits), schur
+        above; the counter says which was built."""
         a, b = _spd_batch(np.random.default_rng(k), 3, k)
         before = {name: _built(name) for name in pallas_solve._LAYOUTS}
         x = np.asarray(gj_solve(jnp.asarray(a), jnp.asarray(b),
@@ -135,7 +139,7 @@ class TestGJSolve:
         assert len(_pallas_calls(jaxpr)) == 4
         assert len(traced) == 1
 
-    @pytest.mark.parametrize("k", [10, 64, 88])
+    @pytest.mark.parametrize("k", [10, 64, 88, 128])
     def test_lanes_kernel_stays_rolled(self, k):
         """Guard on what a first call pays (PERF.md, PR 29): a train
         program holds one kernel a bucket shape, each traced and lowered
@@ -169,9 +173,9 @@ class TestGJSolve:
     @pytest.mark.parametrize("r,k", [(17, 64), (5, 128), (9, 96),
                                      (3, 200), (21, 48)])
     def test_schur_matches_numpy(self, r, k):
-        """Recursive Schur solve (MXU formulation — the rank ≥ 96 'auto'
-        winner, 1.49× at rank 128 on device): exact against numpy, odd
-        split sizes fall back to the base kernel."""
+        """Recursive Schur solve (MXU formulation; behind `gj_solve` for
+        the orders whose lanes block does not fit VMEM): exact against
+        numpy, odd split sizes fall back to the base kernel."""
         from predictionio_tpu.ops.pallas_solve import schur_solve
 
         rng = np.random.default_rng(7)
@@ -194,7 +198,8 @@ class TestGJSolve:
         np.testing.assert_array_equal(x[2], np.zeros(64, np.float32))
 
     def test_auto_routes_large_ranks_to_schur(self, monkeypatch):
-        """gj_solve sends rank ≥ 96 through schur_solve."""
+        """gj_solve sends an order whose lanes block does not fit VMEM
+        through schur_solve, and rank 128 no longer."""
         from predictionio_tpu.ops import pallas_solve
 
         called = []
@@ -202,13 +207,14 @@ class TestGJSolve:
         monkeypatch.setattr(pallas_solve, "schur_solve",
                             lambda *a, **k: called.append(1) or real(*a, **k))
         rng = np.random.default_rng(9)
-        a, b = _spd_batch(rng, 3, 96)
+        a, b = _spd_batch(rng, 3, 288)
         gj_solve(jnp.asarray(a), jnp.asarray(b), interpret=True)
         assert called
         called.clear()
-        a, b = _spd_batch(rng, 3, 64)
-        gj_solve(jnp.asarray(a), jnp.asarray(b), interpret=True)
-        assert not called  # rank 64 stays on the elementwise kernel
+        for k in (64, 128):
+            a, b = _spd_batch(rng, 3, k)
+            gj_solve(jnp.asarray(a), jnp.asarray(b), interpret=True)
+        assert not called  # one system a lane
 
     def test_all_zero_system_solves_to_zero(self):
         """Bucket padding rows arrive as A=0, b=0 and must not NaN."""
@@ -226,6 +232,8 @@ class TestGJSolve:
         assert gj_applicable(64)
         assert gj_applicable(128)
         assert not gj_applicable(512)
+        # and every applicable rank takes one system a lane
+        assert pallas_solve.layout_for(pallas_solve._MAX_RANK) == "lanes"
 
     def test_under_jit(self):
         rng = np.random.default_rng(2)
@@ -265,7 +273,8 @@ class TestALSWithGJ:
         """Guard on what a first call pays (PERF.md, PR 29): the scan
         body is traced once, and each side's half-iteration builds one
         solve a bucket plus one for its split rows' accumulators, each
-        the lanes kernel at rank < 96."""
+        the lanes kernel (at every rank a train can ask the kernel
+        for)."""
         from predictionio_tpu.ops import als
 
         expected = []
@@ -307,12 +316,15 @@ class TestALSWithGJ:
         assert len(by_name["als.solve.lanes"]) == 2
         assert all(lo <= s and e <= hi for s, e in by_name["als.solve.lanes"])
 
-    def test_rank_96_side_holds_only_schur_kernels(self):
-        """Program-level guard for rank >= 96 (the als128i cell's route):
-        one side's half-iteration holds one schur recursion a bucket and
-        one for its split rows' accumulators, 96 -> 48 -> 24 (two levels,
-        four base kernels each) and no lanes kernel, and the counter
-        says so."""
+    @pytest.mark.parametrize("rank,layout", [(128, "lanes"), (288, "schur")])
+    def test_a_side_holds_one_solve_a_bucket(self, rank, layout):
+        """Program-level guard. Rank 128 (the als128i cell's route): one
+        side's half-iteration holds one lanes kernel a bucket and one for
+        its split rows' accumulators, and no schur recursion: 38 kernels
+        in an ML-20M train loop where the recursion held 152. Its twin at
+        an order whose lanes block does not fit VMEM: one schur recursion
+        each, 288 -> 144 -> 72 -> 36 -> 18 (four levels, sixteen base
+        kernels), and no lanes kernel. The counter says which."""
         from predictionio_tpu.ops import als
 
         f32, i32 = jnp.float32, jnp.int32
@@ -323,7 +335,7 @@ class TestALSWithGJ:
                     shape((r, c), f32), shape((r, c), f32),
                     shape((r,), i32) if split else None)
 
-        cfg = ALSConfig(rank=96, solver="gj", pallas="interpret")
+        cfg = ALSConfig(rank=rank, solver="gj", pallas="interpret")
 
         def side(opposing, buckets, split_rows):
             return als._solve_buckets_device(opposing, 50, buckets, cfg,
@@ -331,27 +343,32 @@ class TestALSWithGJ:
 
         before = {name: _built(name) for name in pallas_solve._LAYOUTS}
         jaxpr = jax.make_jaxpr(side)(
-            shape((41, 96), f32),
+            shape((41, rank), f32),
             (bucket(16, 8, False), bucket(8, 32, True)),
             shape((3,), i32)).jaxpr
         built = {name: _built(name) - before[name]
                  for name in pallas_solve._LAYOUTS}
-        assert built == {"lanes": 0, "schur": 3}
+        assert built == {"lanes": 0, "schur": 0, layout: 3}
         calls = _pallas_calls(jaxpr)
-        assert len(calls) == 4 * 3
-        assert not any(c.params["name"] == "gj_lanes" for c in calls)
-        # the base kernels see order 24: lanes pad 24 + its right-hand
-        # sides to one tile of 128
-        assert {c.params["out_avals"][0].shape[1:] for c in calls} \
-            == {(24, 128)}
+        lanes = [c for c in calls if c.params["name"] == "gj_lanes"]
+        if layout == "lanes":
+            assert len(calls) == len(lanes) == 3
+            # x comes back batch-minor, one lane a system: [K, R]
+            assert sorted(c.params["out_avals"][0].shape for c in calls) \
+                == [(128, 3), (128, 8), (128, 16)]
+        else:
+            assert len(calls) == 16 * 3 and not lanes
+            # the base kernels see order 18, its right-hand sides beside
+            # it in whole tiles of 128 lanes
+            assert {c.params["out_avals"][0].shape[1] for c in calls} == {18}
 
     def test_schur_layout_matches_chol_trajectory(self, monkeypatch):
-        """Full ALS training through the schur solver path (its
-        threshold lowered to the test's small rank; it is 96) reproduces
-        the Cholesky trajectory."""
+        """Full ALS training through the schur solver path (no VMEM for
+        a lanes block, so the test's small rank takes it) reproduces the
+        Cholesky trajectory."""
         from predictionio_tpu.ops import als
 
-        monkeypatch.setattr(pallas_solve, "_SCHUR_FROM_RANK", 8)
+        monkeypatch.setattr(pallas_solve, "_VMEM_BYTES", 0)
         # the loop of `test_gj_matches_chol_trajectory` holds lanes
         # kernels for this very config
         als._get_train_loop.cache_clear()

@@ -47,9 +47,11 @@ def _compiled_text(one_chip, r, k):
 
 # the hot bucket of an ML-20M train (a ragged last block: 31296 = 244.5
 # blocks), a fold's handful of rows under one block, a rank off the
-# sublane tile, the largest blocks `layout_for` hands the layout (rank
-# 88-95)
-@pytest.mark.parametrize("r,k", [(31296, 64), (8, 64), (40, 10), (1000, 88)])
+# sublane tile, a rank between the cells', the rank-128 cell's hot bucket
+# (als128i.train10: a block of 8.45 MB, 32 MiB of VMEM asked for) and its
+# fold's rows, the largest block `gj_applicable` admits (104 MiB)
+@pytest.mark.parametrize("r,k", [(31296, 64), (8, 64), (40, 10), (1000, 88),
+                                 (31232, 128), (8, 128), (1000, 256)])
 def test_lanes_solver_compiles_for_v5e(one_chip, r, k):
     text = _compiled_text(one_chip, r, k)
     assert text.count('custom_call_target="tpu_custom_call"') == 1
@@ -57,28 +59,32 @@ def test_lanes_solver_compiles_for_v5e(one_chip, r, k):
     assert "als.solve" in text and "gj_lanes" in text
 
 
-# the hot bucket of the rank-128 cell (als128i.train10) and a fold's rows
-@pytest.mark.parametrize("r", [31232, 8])
+# a bucket's height (what fits the chip beside its temporaries at this
+# order) and a fold's rows
+@pytest.mark.parametrize("r", [1024, 8])
 def test_schur_solver_compiles_for_v5e(one_chip, r):
-    """Rank 128 goes 128 -> 64 -> 32: four base kernels (the one-hot
+    """Order 288, the first whose lanes block does not fit VMEM, goes
+    288 -> 144 -> 72 -> 36 -> 18: sixteen base kernels (the one-hot
     multi-RHS kernel) with MXU products between them, all under the
     scope the benchmark's readers look for."""
-    text = _compiled_text(one_chip, r, 128)
+    assert pallas_solve.layout_for(288) == "schur"
+    text = _compiled_text(one_chip, r, 288)
     calls = [line for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
-    assert len(calls) == 4
+    assert len(calls) == 16
     assert all("als.solve" in line for line in calls)
     assert "gj_lanes" not in text
 
 
-def test_lanes_solver_compiles_a_device_under_shard_map(topo):
+@pytest.mark.parametrize("k", [64, 128])
+def test_lanes_solver_compiles_a_device_under_shard_map(topo, k):
     """`als_train`'s branch for a mesh: each of four chips solves its own
     row shard, padded to whole blocks of 128 lanes by itself, with no
-    collective."""
+    collective; at the rank-128 cell's order too."""
     mesh = Mesh(np.array(topo.devices), ("data",))
     rows = NamedSharding(mesh, PartitionSpec("data"))
-    a = jax.ShapeDtypeStruct((4 * 1000, 64, 64), jnp.float32, sharding=rows)
-    b = jax.ShapeDtypeStruct((4 * 1000, 64), jnp.float32, sharding=rows)
+    a = jax.ShapeDtypeStruct((4 * 1000, k, k), jnp.float32, sharding=rows)
+    b = jax.ShapeDtypeStruct((4 * 1000, k), jnp.float32, sharding=rows)
     spec = PartitionSpec("data")
     solve = jax.shard_map(pallas_solve.gj_solve, mesh=mesh,
                           in_specs=(spec, spec), out_specs=spec,
