@@ -1,0 +1,71 @@
+"""The controls of `kimi_linear.fit8_pack8k`'s `correct`, on the chip at
+the cell's own size, run through `harness.run_cell` like the cell itself:
+
+    python3 perf/tests/control_kimi.py --control bfloat16_reference --seed 7 --seconds 5
+
+The cell runs from a configuration written anew under `.pio_store/` with
+one key changed. `--control bfloat16_reference` and `--control
+no_reset_reference` add `check.control`: the check then prints the
+program's own numbers and returns those of a reference that is wrong on
+purpose against the sound one: computed in bfloat16 throughout (the
+nearest precision below the configuration's), or with the KDA layers'
+state and convolution running on across history boundaries. `--control
+unchanged` trains with a step size of zero: the state is left as it was
+and `update_sign_max_wrong_share` reads 1. Each has to come out as not
+correct. No CPU mode (`perf/tests/test_kimi_cell.py` holds the three at
+the tiny size)."""
+
+import argparse
+import copy
+import json
+import os
+import sys
+import time
+
+T0 = time.perf_counter()
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+sys.path.insert(0, ROOT)
+
+CONTROLS = ("bfloat16_reference", "no_reset_reference", "unchanged")
+
+
+def controlled(config: dict, control: str) -> dict:
+    """The configuration with the control's one key changed."""
+    config = copy.deepcopy(config)
+    if control == "unchanged":
+        config["algorithm_params"]["stepSize"] = 0.0
+    else:
+        config["check"]["control"] = control
+    return config
+
+
+def main() -> int:
+    from perf import harness
+
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--control", required=True, choices=CONTROLS)
+    ap.add_argument("--workload", default="kimi_linear.fit8_pack8k")
+    ap.add_argument("--seed", type=int, required=True)
+    ap.add_argument("--seconds", type=float, default=5.0)
+    args = ap.parse_args()
+    bench = copy.deepcopy(harness.load_json(ROOT, "BENCHMARK.json"))
+    cell = harness.find(bench["workloads"], args.workload, "workload")
+    entry = harness.find(bench["configs"], cell["config"], "config")
+    config = controlled(harness.load_json(ROOT, entry["file"]), args.control)
+    rel = os.path.join(".pio_store", "perf", "control",
+                       f"{entry['name']}.json")
+    config["algorithm_params"]["encoderConfig"] = rel
+    os.makedirs(os.path.dirname(os.path.join(ROOT, rel)), exist_ok=True)
+    with open(os.path.join(ROOT, rel), "w") as f:
+        json.dump(config, f)
+    entry["file"] = rel
+    harness.prepare_environment(ROOT)
+    devices = harness.require_chips(int(cell["chips"]))
+    result = harness.run_cell(ROOT, bench, args.workload, args.seed,
+                              args.seconds, False, T0, devices[:1])
+    print(json.dumps(result), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -1,7 +1,8 @@
-"""The next-item encoder, driven by a configuration: latent attention
-(MLA), a dense or a sparse-expert feed-forward in every block, an
-optional multi-token-prediction (MTP) module, trained on packed
-histories.
+"""The next-item encoder, driven by a configuration: in every block a
+token mixer, latent attention (MLA) or Kimi Delta Attention (KDA, a
+gated delta-rule recurrence, `ops/kda.py`) as the configuration says
+layer by layer, and a dense or a sparse-expert feed-forward; an optional
+multi-token-prediction (MTP) module; trained on packed histories.
 
 One code path runs every size. A configuration file in the published
 model's own key names (`EncoderConfig.from_json`) gives the widths, the
@@ -11,13 +12,24 @@ one such file.
 
 Equations (the plain reference is `quality/encoder_reference.py`):
 
-    block   h += MLA(RMSNorm(h));  h += FFN(RMSNorm(h))
+    block   h += Mixer(RMSNorm(h));  h += FFN(RMSNorm(h))
     MLA     c_q = RMSNorm(x W_qa); [q_nope | q_rope] = c_q W_qb per head
+            (without `q_lora_rank`: [q_nope | q_rope] = x W_q)
             [c_kv | k_rope] = x W_kva; c_kv = RMSNorm(c_kv)
             [k_nope | v] = c_kv W_kvb per head; interleaved RoPE on q_rope
             and on the one k_rope all heads share; scores
             (q_nope.k_nope + q_rope.k_rope) / sqrt(d_nope + d_rope), causal
-            and inside a history's own segment; out = concat(heads) W_o
+            and inside a history's own segment; out = concat(heads) W_o;
+            with `mla_use_nope` nothing is rotated (no positional
+            encoding: the recurrent layers carry the order)
+    KDA     q~, k~, v = SiLU(conv(x W_q)), SiLU(conv(x W_k)), SiLU(conv(
+            x W_v)), conv causal and depthwise, its taps before a
+            history's first token zero; per head q = q~/|q~| d_k^-1/2,
+            k = k~/|k~|; log a = -exp(A_log) softplus(x W_fa W_fb +
+            dt_bias) a channel; b = sigmoid(x W_b) a head; S_t = (I - b_t
+            k_t k_t^T) Diag(a_t) S_{t-1} + b_t k_t v_t^T from zero at a
+            history's first token; o_t = S_t^T q_t; y = (RMSNorm per
+            head(o) * sigmoid(x W_ga W_gb)) W_o
     FFN     SwiGLU in the first `first_k_dense_replace` blocks; after them
             shared SwiGLU + the held experts' part of the routed result
             (`ops/moe.py`)
@@ -39,8 +51,14 @@ import math
 import jax
 import jax.numpy as jnp
 
+from predictionio_tpu.ops import kda as kda_ops
 from predictionio_tpu.ops import moe
 from predictionio_tpu.ops.attention import segment_attention
+
+# the same sizes under other published names (`kimi_linear`'s)
+_ALIASES = {"num_experts": "n_routed_experts",
+            "num_experts_per_token": "num_experts_per_tok",
+            "num_shared_experts": "n_shared_experts"}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -49,11 +67,19 @@ class EncoderConfig:
     intermediate_size: int
     num_hidden_layers: int
     num_attention_heads: int
-    q_lora_rank: int
     kv_lora_rank: int
     qk_nope_head_dim: int
     qk_rope_head_dim: int
     v_head_dim: int
+    q_lora_rank: int = 0             # 0 (null): x W_q, no low-rank query
+    mla_use_nope: bool = False       # MLA without rotation
+    layer_kinds: tuple = ()          # "mla" | "kda" a layer; (): all MLA
+    kda_num_heads: int = 0
+    kda_head_dim: int = 0
+    kda_conv_size: int = 4
+    kda_chunk: int = 64
+    kda_head_block: int = 0          # heads a pass of the mixer; 0: all
+    l2_norm_eps: float = 1e-6
     first_k_dense_replace: int = 1 << 30  # every block dense
     moe_intermediate_size: int = 0
     n_routed_experts: int = 0        # held here
@@ -89,6 +115,17 @@ class EncoderConfig:
     def n_moe(self) -> int:
         return self.num_hidden_layers - self.n_dense
 
+    @property
+    def kinds(self) -> tuple:
+        """The mixer of every layer, the dense layers first."""
+        return self.layer_kinds or ("mla",) * self.num_hidden_layers
+
+    @property
+    def moe_stacked(self) -> bool:
+        """Expert blocks of one kind are stacked on a leading axis and
+        run by one scan; of two kinds they are a list."""
+        return len(set(self.kinds[self.n_dense:])) <= 1
+
     @classmethod
     def from_dict(cls, d: dict) -> "EncoderConfig":
         """From a file in the published config's key names. The keys
@@ -96,6 +133,23 @@ class EncoderConfig:
         flat = dict(d)
         for group in ("share", "precision", "train"):
             flat.update(d.get(group, {}))
+        for theirs, ours in _ALIASES.items():
+            if theirs in flat:
+                flat[ours] = flat[theirs]
+        linear = flat.get("linear_attn_config")
+        if linear:  # layers are numbered from 1 there
+            layers = range(1, int(flat["num_hidden_layers"]) + 1)
+            kda, full = (set(linear[k]) for k in ("kda_layers",
+                                                  "full_attn_layers"))
+            if any((n in kda) == (n in full) for n in layers):
+                raise ValueError("linear_attn_config: every layer is in "
+                                 "one of kda_layers and full_attn_layers")
+            flat.update(
+                layer_kinds=tuple("kda" if n in kda else "mla"
+                                  for n in layers),
+                kda_num_heads=linear["num_heads"],
+                kda_head_dim=linear["head_dim"],
+                kda_conv_size=linear["short_conv_kernel_size"])
         known = {f.name for f in dataclasses.fields(cls)}
         kw = {k: v for k, v in flat.items() if k in known and v is not None}
         kw["report_blocks"] = tuple(
@@ -166,14 +220,21 @@ def mla(p, cfg: EncoderConfig, x, seg, pos, scope: str = "enc.mla"):
     b, l, _ = x.shape
     h, dn, dr, dv = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
                      cfg.qk_rope_head_dim, cfg.v_head_dim)
-    c_q = rms_norm(_mm(cfg, x, p["w_qa"]), p["q_norm"], cfg.rms_norm_eps)
-    q = _mm(cfg, c_q, p["w_qb"]).reshape(b, l, h, dn + dr)
+    if cfg.q_lora_rank:
+        c_q = rms_norm(_mm(cfg, x, p["w_qa"]), p["q_norm"], cfg.rms_norm_eps)
+        q = _mm(cfg, c_q, p["w_qb"]).reshape(b, l, h, dn + dr)
+    else:
+        q = _mm(cfg, x, p["w_q"]).reshape(b, l, h, dn + dr)
     kva = _mm(cfg, x, p["w_kva"])
     c_kv = rms_norm(kva[..., :cfg.kv_lora_rank], p["kv_norm"],
                     cfg.rms_norm_eps)
     kv = _mm(cfg, c_kv, p["w_kvb"]).reshape(b, l, h, dn + dv)
-    q_rope = rope(q[..., dn:], pos, cfg.rope_theta)
-    k_rope = rope(kva[..., None, cfg.kv_lora_rank:], pos, cfg.rope_theta)
+    if cfg.mla_use_nope:
+        q_rope, k_rope = q[..., dn:], kva[..., None, cfg.kv_lora_rank:]
+    else:
+        q_rope = rope(q[..., dn:], pos, cfg.rope_theta)
+        k_rope = rope(kva[..., None, cfg.kv_lora_rank:], pos,
+                      cfg.rope_theta)
     q = jnp.concatenate([q[..., :dn], q_rope], axis=-1)
     k = jnp.concatenate(
         [kv[..., :dn], jnp.broadcast_to(k_rope, (b, l, h, dr))], axis=-1)
@@ -184,6 +245,67 @@ def mla(p, cfg: EncoderConfig, x, seg, pos, scope: str = "enc.mla"):
                           block=cfg.attention_block, scale=scale,
                           scope=scope)
     return _mm(cfg, o.transpose(0, 2, 1, 3).reshape(b, l, h * dv), p["w_o"])
+
+
+def kda(p, cfg: EncoderConfig, x, seg, scope: str = "enc.kda"):
+    """Kimi Delta Attention on x [B, L, D] (already normed). Everything
+    between the input and the output projection is a head's own, so the
+    mixer runs `kda_head_block` heads at a time, each pass recomputed in
+    the backward pass: its projections, convolution, gates, the scan
+    (`ops/kda.py`), the head norm and its rows of W_o. Scopes `proj`,
+    `conv`, `gate`, `scan`, `out` under `scope`."""
+    cd = _dt(cfg.compute_dtype)
+    b, l, d = x.shape
+    h, dh = cfg.kda_num_heads, cfg.kda_head_dim
+    hb = cfg.kda_head_block or h
+    groups = h // hb
+
+    def by_cols(w, dtype=None):  # [.., H dh] -> [groups, .., hb dh]
+        w = w if dtype is None else w.astype(dtype)
+        return jnp.moveaxis(w.reshape(w.shape[:-1] + (groups, hb * dh)),
+                            -2, 0)
+
+    with jax.named_scope(f"{scope}.gate"):
+        f_low = _mm(cfg, x, p["w_fa"])
+        gate_low = _mm(cfg, x, p["w_ga"])
+        beta = jax.nn.sigmoid(_mm(cfg, x, p["w_b"]))            # [B, L, H]
+
+    def heads(ws):
+        (w_q, w_k, w_v, conv_q, conv_k, conv_v, w_fb, dt_bias, a_log,
+         w_gb, w_o, beta_g) = ws
+        with jax.named_scope(f"{scope}.proj"):
+            q, k, v = (_mm(cfg, x, w) for w in (w_q, w_k, w_v))
+        with jax.named_scope(f"{scope}.conv"):
+            q, k, v = (
+                jax.nn.silu(kda_ops.causal_conv(a, w, seg)).reshape(
+                    b, l, hb, dh)
+                for a, w in ((q, conv_q), (k, conv_k), (v, conv_v)))
+            unit = lambda a: a * jax.lax.rsqrt(  # noqa: E731
+                jnp.sum(a * a, axis=-1, keepdims=True) + cfg.l2_norm_eps)
+            q, k = unit(q) * dh ** -0.5, unit(k)
+        with jax.named_scope(f"{scope}.gate"):
+            log_a = (-jnp.exp(a_log)[:, None] * jax.nn.softplus(
+                (_mm(cfg, f_low, w_fb) + dt_bias).reshape(b, l, hb, dh)))
+        o = kda_ops.kda_scan(q, k, v, log_a, beta_g, seg, cfg.kda_chunk, cd,
+                             f"{scope}.scan")
+        with jax.named_scope(f"{scope}.out"):
+            o = (rms_norm(o, p["o_norm"], cfg.rms_norm_eps)
+                 * jax.nn.sigmoid(_mm(cfg, gate_low, w_gb)).reshape(
+                     b, l, hb, dh))
+            return _mm(cfg, o.reshape(b, l, hb * dh), w_o)
+
+    # the sum is outside what is recomputed, so no partial sum is kept
+    y, _ = jax.lax.scan(
+        lambda y, ws: (y + _maybe_remat(heads, cfg)(ws), None),
+        jnp.zeros((b, l, d), jnp.float32),
+        (by_cols(p["w_q"], cd), by_cols(p["w_k"], cd), by_cols(p["w_v"], cd),
+         by_cols(p["conv_q"]), by_cols(p["conv_k"]), by_cols(p["conv_v"]),
+         by_cols(p["w_fb"], cd), by_cols(p["dt_bias"]),
+         p["a_log"].reshape(groups, hb),
+         by_cols(p["w_gb"], cd),
+         p["w_o"].astype(cd).reshape(groups, hb * dh, d),
+         jnp.moveaxis(beta.reshape(b, l, groups, hb), 2, 0)))
+    return y
 
 
 def expert_ffn(p, bias, cfg: EncoderConfig, x2d, scope: str = "enc.moe"):
@@ -204,17 +326,30 @@ def expert_ffn(p, bias, cfg: EncoderConfig, x2d, scope: str = "enc.moe"):
     return y, {"counts": counts, "load": load, "picks": idx}
 
 
+def _mix(p, cfg: EncoderConfig, h, seg, pos, scope: str = ""):
+    """The first half of `block`: h += Mixer(RMSNorm(h)), the mixer the
+    one whose parameters the block holds (`attn`: MLA, `kda`: KDA)."""
+    x = rms_norm(h, p["norm1"], cfg.rms_norm_eps)
+    if "kda" in p:
+        with jax.named_scope(scope or "enc.kda"):
+            return h + kda(p["kda"], cfg, x, seg, scope or "enc.kda")
+    with jax.named_scope(scope or "enc.mla"):
+        return h + mla(p["attn"], cfg, x, seg, pos, scope or "enc.mla")
+
+
 def block(p, bias, cfg: EncoderConfig, h, seg, pos, scope: str = ""):
     """One block on the residual stream h [B, L, D]. `bias` is None in a
     dense block. Returns (h, routed), `routed` (see `expert_ffn`) None
-    when dense. Its ops are traced under `enc.mla`, `enc.dense_ffn` and
-    `enc.moe`, or all under `scope` where one is given (the MTP module's
-    block)."""
+    when dense. Its ops are traced under `enc.mla` or `enc.kda`,
+    `enc.dense_ffn` and `enc.moe`, or all under `scope` where one is
+    given (the MTP module's block)."""
+    return _feed_forward(p, bias, cfg, _mix(p, cfg, h, seg, pos, scope),
+                         scope)
+
+
+def _feed_forward(p, bias, cfg: EncoderConfig, h, scope: str = ""):
+    """The second half of `block`: h += FFN(RMSNorm(h))."""
     b, l, d = h.shape
-    with jax.named_scope(scope or "enc.mla"):
-        h = h + mla(p["attn"], cfg,
-                    rms_norm(h, p["norm1"], cfg.rms_norm_eps), seg, pos,
-                    scope or "enc.mla")
     x2d = rms_norm(h, p["norm2"], cfg.rms_norm_eps).reshape(b * l, d)
     if bias is None:
         with jax.named_scope(scope or "enc.dense_ffn"):
@@ -230,6 +365,17 @@ def _maybe_remat(fn, cfg: EncoderConfig):
     return jax.checkpoint(fn) if cfg.remat else fn
 
 
+def _kda_block(p, bias, cfg: EncoderConfig, h, seg, pos):
+    """`block` for a KDA layer under the configuration's recomputation.
+    The mixer recomputes its own passes of heads (`kda`), so only the
+    feed-forward half is wrapped here: wrapped whole, as a
+    latent-attention block is, every pass would be computed a third
+    time."""
+    return _maybe_remat(
+        lambda p, bias, h: _feed_forward(p, bias, cfg, h), cfg)(
+        p, bias, _mix(p, cfg, h, seg, pos))
+
+
 def encode(params, cfg: EncoderConfig, tokens, seg, pos):
     """tokens, seg, pos [B, L] -> the last block's residual stream
     [B, L, D] (before the final norm) and what the expert layers routed
@@ -237,6 +383,9 @@ def encode(params, cfg: EncoderConfig, tokens, seg, pos):
     [n_moe, experts_total], picks [n_moe, B * L, k]; None without one."""
     h = jnp.take(params["emb"], tokens, axis=0)
     for p in params["dense"]:
+        if "kda" in p:
+            h, _ = _kda_block(p, None, cfg, h, seg, pos)
+            continue
         h = _maybe_remat(
             lambda p, h: block(p, None, cfg, h, seg, pos)[0], cfg)(p, h)
     if not cfg.n_moe:
@@ -246,8 +395,15 @@ def encode(params, cfg: EncoderConfig, tokens, seg, pos):
         p, bias = layer
         return block(p, bias, cfg, h, seg, pos)
 
-    return jax.lax.scan(_maybe_remat(one, cfg), h,
-                        (params["moe"], params["router_bias"]))
+    if cfg.moe_stacked:
+        return jax.lax.scan(_maybe_remat(one, cfg), h,
+                            (params["moe"], params["router_bias"]))
+    routed = []
+    for layer in zip(params["moe"], params["router_bias"]):
+        h, r = (_kda_block(*layer, cfg, h, seg, pos) if "kda" in layer[0]
+                else _maybe_remat(one, cfg)(h, layer))
+        routed.append(r)
+    return h, {k: jnp.stack([r[k] for r in routed]) for k in routed[0]}
 
 
 def mtp_hidden(params, cfg: EncoderConfig, h, tokens, seg, pos):
@@ -323,10 +479,12 @@ def losses(params, cfg: EncoderConfig, tokens, seg, pos):
 
 def _attn_shapes(cfg: EncoderConfig) -> dict:
     d, h = cfg.hidden_size, cfg.num_attention_heads
+    q_out = h * (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
+    query = ({"w_qa": (d, cfg.q_lora_rank), "q_norm": (cfg.q_lora_rank,),
+              "w_qb": (cfg.q_lora_rank, q_out)} if cfg.q_lora_rank
+             else {"w_q": (d, q_out)})
     return {
-        "w_qa": (d, cfg.q_lora_rank), "q_norm": (cfg.q_lora_rank,),
-        "w_qb": (cfg.q_lora_rank,
-                 h * (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)),
+        **query,
         "w_kva": (d, cfg.kv_lora_rank + cfg.qk_rope_head_dim),
         "kv_norm": (cfg.kv_lora_rank,),
         "w_kvb": (cfg.kv_lora_rank,
@@ -335,9 +493,27 @@ def _attn_shapes(cfg: EncoderConfig) -> dict:
     }
 
 
-def _block_shapes(cfg: EncoderConfig, dense: bool) -> dict:
+def _kda_shapes(cfg: EncoderConfig) -> dict:
+    d, dh = cfg.hidden_size, cfg.kda_head_dim
+    wide = cfg.kda_num_heads * dh
+    return {
+        "w_q": (d, wide), "w_k": (d, wide), "w_v": (d, wide),
+        "conv_q": (cfg.kda_conv_size, wide),
+        "conv_k": (cfg.kda_conv_size, wide),
+        "conv_v": (cfg.kda_conv_size, wide),
+        "w_fa": (d, dh), "w_fb": (dh, wide),
+        "a_log": (cfg.kda_num_heads,), "dt_bias": (wide,),
+        "w_b": (d, cfg.kda_num_heads),
+        "w_ga": (d, dh), "w_gb": (dh, wide),
+        "o_norm": (dh,), "w_o": (wide, d),
+    }
+
+
+def _block_shapes(cfg: EncoderConfig, dense: bool, kind: str = "mla") -> dict:
     d = cfg.hidden_size
-    out = {"attn": _attn_shapes(cfg), "norm1": (d,), "norm2": (d,)}
+    mixer = ({"kda": _kda_shapes(cfg)} if kind == "kda"
+             else {"attn": _attn_shapes(cfg)})
+    out = {**mixer, "norm1": (d,), "norm2": (d,)}
     if dense:
         out.update(w13=(d, 2 * cfg.intermediate_size),
                    w2=(cfg.intermediate_size, d))
@@ -351,17 +527,22 @@ def _block_shapes(cfg: EncoderConfig, dense: bool) -> dict:
 
 
 def param_shapes(cfg: EncoderConfig, vocab: int) -> dict:
-    """The parameter tree as shapes. Expert blocks are stacked on a
-    leading axis (`moe`), so that one scan runs them."""
+    """The parameter tree as shapes. Expert blocks of one kind are
+    stacked on a leading axis (`moe`), so that one scan runs them; of
+    two kinds (`EncoderConfig.moe_stacked`) `moe` is a list."""
     d = cfg.hidden_size
+    kinds = cfg.kinds
     shapes = {"emb": (vocab, d), "head": (d, vocab), "final_norm": (d,),
-              "dense": [_block_shapes(cfg, True)
-                        for _ in range(cfg.n_dense)]}
+              "dense": [_block_shapes(cfg, True, kind)
+                        for kind in kinds[:cfg.n_dense]]}
     is_shape = lambda s: isinstance(s, tuple)  # noqa: E731
-    if cfg.n_moe:
+    if cfg.n_moe and cfg.moe_stacked:
         shapes["moe"] = jax.tree_util.tree_map(
-            lambda s: (cfg.n_moe,) + s, _block_shapes(cfg, False),
-            is_leaf=is_shape)
+            lambda s: (cfg.n_moe,) + s,
+            _block_shapes(cfg, False, kinds[cfg.n_dense]), is_leaf=is_shape)
+    elif cfg.n_moe:
+        shapes["moe"] = [_block_shapes(cfg, False, kind)
+                         for kind in kinds[cfg.n_dense:]]
     if cfg.num_nextn_predict_layers:
         shapes["mtp"] = {"norm_h": (d,), "norm_e": (d,), "w_eh": (2 * d, d),
                          "block": _block_shapes(cfg, False)}
@@ -375,7 +556,10 @@ def count_parameters(cfg: EncoderConfig, vocab: int) -> int:
 
 
 def init_params(cfg: EncoderConfig, vocab: int, key):
-    """Weights normal(0, init_std), norms one; made where `key` lives."""
+    """Weights normal(0, init_std), norms one; a KDA layer's decay
+    rates A = exp(a_log) uniform in [1, 16], its `dt_bias` the inverse
+    softplus of a step log-uniform in [0.001, 0.1], its convolution's
+    taps uniform within 1 / sqrt(width). Made where `key` lives."""
     shapes = param_shapes(cfg, vocab)
     leaves, tree = jax.tree_util.tree_flatten_with_path(
         shapes, is_leaf=lambda s: isinstance(s, tuple))
@@ -385,6 +569,17 @@ def init_params(cfg: EncoderConfig, vocab: int, key):
         name = str(path[-1])
         if "norm" in name:
             out.append(jnp.ones(shape, jnp.float32))
+        elif "a_log" in name:
+            out.append(jnp.log(jax.random.uniform(k, shape, jnp.float32,
+                                                  1.0, 16.0)))
+        elif "dt_bias" in name:
+            dt = jnp.exp(jax.random.uniform(k, shape, jnp.float32,
+                                            math.log(1e-3), math.log(1e-1)))
+            out.append(dt + jnp.log(-jnp.expm1(-dt)))
+        elif "conv_" in name:
+            bound = shape[-2] ** -0.5
+            out.append(jax.random.uniform(k, shape, jnp.float32, -bound,
+                                          bound))
         else:
             out.append(cfg.init_std
                        * jax.random.normal(k, shape, jnp.float32))

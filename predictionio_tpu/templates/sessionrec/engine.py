@@ -369,6 +369,17 @@ EXPERT_TOKENS = REGISTRY.gauge(
     "encoder_expert_tokens", "Tokens the last train step sent to each "
     "held expert, by expert layer (mtp = the MTP module's) and expert id",
     labelnames=("layer", "expert"))
+KDA_CHUNKS = REGISTRY.gauge(
+    "encoder_kda_chunks", "Chunks of the KDA scan (ops/kda.py) in the "
+    "sequences of each step of the last train's epoch, a KDA layer",
+    labelnames=("step",))
+KDA_BOUNDARY_CHUNKS = REGISTRY.gauge(
+    "encoder_kda_boundary_chunks", "Of encoder_kda_chunks, those that "
+    "hold a history's first token (the scan's reset masks do work there)",
+    labelnames=("step",))
+KDA_RESETS_TOTAL = REGISTRY.counter(
+    "encoder_kda_resets_total", "Histories the train steps of an encoder "
+    "with KDA layers held: the times a layer's state started from zero")
 TOKENS_TOTAL = REGISTRY.counter(
     "encoder_tokens_total", "Events the sessionrec train steps have "
     "trained on (padding not counted)")
@@ -446,6 +457,16 @@ class SessionRecAlgorithm(Algorithm):
             programs, state, on_device, int(p.epochs),
             bool(cfg.report_blocks))
         TOKENS_TOTAL.inc(real * int(p.epochs))
+        if "kda" in cfg.kinds:
+            from predictionio_tpu.ops.kda import chunk_stats
+
+            resets = 0
+            for n, (_, seg_n, _) in enumerate(batches):
+                chunks, boundary, held = chunk_stats(seg_n, cfg.kda_chunk)
+                KDA_CHUNKS.labels(step=str(n)).set(chunks)
+                KDA_BOUNDARY_CHUNKS.labels(step=str(n)).set(boundary)
+                resets += held
+            KDA_RESETS_TOTAL.inc(resets * int(p.epochs))
         with span("sessionrec.readback"):
             params = jax.device_get({**state["params"], **state["buffers"]})
             first, metrics, reported = jax.device_get(

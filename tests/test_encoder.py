@@ -194,24 +194,41 @@ def test_a_packed_batch_equals_its_histories_run_apart(params):
 
 # -- the share of a deployment ---------------------------------------------------
 
-def test_the_shares_add_up_to_the_uncut_layer(params):
-    """Four chips hold two experts each. The parts their shares give, the
-    shared expert counted once, are the uncut reference layer."""
+# (experts, held a chip, picks a token, scale, hidden, expert width): the
+# JoyAI tiny configuration's, and Kimi Linear's pattern at a small size
+# (8 held a chip, top-8, scaled 2.446, one shared expert of the experts'
+# own width)
+SHARES = {"joyai": (8, 2, 3, 2.5, 32, 12),
+          "kimi_linear": (32, 8, 8, 2.446, 36, 16)}
+
+
+@pytest.mark.parametrize("which", sorted(SHARES))
+def test_the_shares_add_up_to_the_uncut_layer(which):
+    """The chips of a deployment hold `held` experts each. The parts
+    their shares give, the shared expert counted once, are the uncut
+    reference layer."""
+    total_experts, held, picks, scale, d, f = SHARES[which]
     rng = np.random.default_rng(6)
-    x = jnp.asarray(rng.standard_normal((80, 32)), jnp.float32)
-    uncut = dataclasses.replace(CFG, n_routed_experts=8, expert_first=0)
+    x = jnp.asarray(rng.standard_normal((80, d)), jnp.float32)
+    cut = dataclasses.replace(
+        CFG, hidden_size=d, moe_intermediate_size=f,
+        experts_total=total_experts,
+        n_routed_experts=held, num_experts_per_tok=picks,
+        routed_scaling_factor=scale)
+    uncut = dataclasses.replace(cut, n_routed_experts=total_experts,
+                                expert_first=0)
     p = jax.tree_util.tree_map(lambda a: a[0], jax.jit(
         lambda k: enc.init_params(uncut, VOCAB, k))(jax.random.key(7))["moe"])
-    bias = jnp.asarray(rng.standard_normal(8) * 0.05, jnp.float32)
+    bias = jnp.asarray(rng.standard_normal(total_experts) * 0.05, jnp.float32)
     with jax.default_matmul_precision("highest"):
         whole, whole_counts, _ = jax.jit(
             lambda p: ref.expert_layer(p, bias, uncut, x))(p)
     shared = enc.swiglu(uncut, x, p["shared_w13"], p["shared_w2"])
     total, seen = shared, []
-    for first in (0, 2, 4, 6):
-        share = dataclasses.replace(CFG, expert_first=first)
-        mine = dict(p, experts_w13=p["experts_w13"][first:first + 2],
-                    experts_w2=p["experts_w2"][first:first + 2])
+    for first in range(0, total_experts, held):
+        share = dataclasses.replace(cut, expert_first=first)
+        mine = dict(p, experts_w13=p["experts_w13"][first:first + held],
+                    experts_w2=p["experts_w2"][first:first + held])
         y, routed = jax.jit(
             lambda m, share=share: enc.expert_ffn(m, bias, share, x))(mine)
         total = total + (y - shared)  # what every chip computes alike: once

@@ -4,6 +4,7 @@ parameters, and `predict` / `batch_predict` against the reference's full
 forward pass."""
 
 import json
+import os
 
 import numpy as np
 import pytest
@@ -163,3 +164,105 @@ def test_sizes_beside_a_configuration_file_are_refused():
         {"encoderConfig": sessionrec.DEFAULT_ENCODER, "embedDim": 8}))
     with pytest.raises(ValueError, match="size the default block only"):
         algo.train(WorkflowContext(seed=1), _prepared())
+
+
+# -- an encoder with KDA layers, through the same route ---------------------------
+
+@pytest.fixture(scope="module")
+def trained_hybrid(tmp_path_factory):
+    """A model trained from a configuration FILE in Kimi Linear's key
+    names: KDA, KDA, MLA (no rotation, no low-rank query), experts from
+    the second layer, no MTP module."""
+    path = tmp_path_factory.mktemp("enc") / "small-hybrid.json"
+    path.write_text(json.dumps({
+        "hidden_size": 16, "intermediate_size": 24, "num_hidden_layers": 3,
+        "first_k_dense_replace": 1, "num_attention_heads": 2,
+        "q_lora_rank": None, "mla_use_nope": True, "kv_lora_rank": 8,
+        "qk_nope_head_dim": 8, "qk_rope_head_dim": 4, "v_head_dim": 8,
+        "moe_intermediate_size": 8, "num_experts": 2,
+        "num_experts_per_token": 2, "num_shared_experts": 1,
+        "routed_scaling_factor": 2.446, "num_nextn_predict_layers": 0,
+        "rms_norm_eps": 1e-5,
+        "linear_attn_config": {"kda_layers": [1, 2], "full_attn_layers": [3],
+                               "num_heads": 2, "head_dim": 8,
+                               "short_conv_kernel_size": 4},
+        "share": {"experts_total": 4, "expert_first": 1},
+        "train": {"pack_len": 16, "seqs_per_step": 2, "attention_block": 8,
+                  "moe_block_rows": 4, "loss_chunk": 16, "init_std": 0.2,
+                  "kda_chunk": 16, "kda_head_block": 1,
+                  "report_blocks": [
+                      {"name": "a_log", "leaf": "dense.0.kda.a_log"},
+                      {"name": "conv_k", "leaf": "moe.0.kda.conv_k"},
+                      {"name": "w_q", "leaf": "moe.1.attn.w_q"}]}}))
+    algo = SessionRecAlgorithm(params_from_dict(
+        SessionRecAlgorithm.params_class,
+        {"maxSeqLen": 16, "epochs": 2, "stepSize": 0.01,
+         "encoderConfig": str(path)}))
+    from predictionio_tpu.telemetry.registry import REGISTRY
+
+    before = REGISTRY.get("encoder_kda_resets_total").value
+    model = algo.train(WorkflowContext(seed=5), _prepared())
+    return algo, model, REGISTRY.get("encoder_kda_resets_total").value - before
+
+
+class TestTheEncoderWithKdaLayers:
+    def test_train_reports_the_new_blocks_and_builds_the_model(
+            self, trained_hybrid):
+        _, model, _ = trained_hybrid
+        assert tuple(model.encoder["layer_kinds"]) == ("kda", "kda", "mla")
+        report = model.train_report
+        assert report["params"]["a_log"].shape == (2,)
+        assert report["params"]["conv_k"].shape == (4, 16)
+        assert report["params"]["w_q"].shape == (16, 24)
+        assert all(np.abs(g).max() > 0 for g in report["grads"].values())
+        assert report["metrics"]["picks"].shape == (2, 32, 2)
+        assert "ce_mtp" not in report["metrics"]
+        assert set(model.session_vecs) == set(model.user_windows)
+        assert isinstance(model.params["moe"], list)
+        assert np.isfinite(model.params["moe"][0]["kda"]["w_o"]).all()
+
+    def test_the_gauges_and_the_counter_say_what_the_scan_held(
+            self, trained_hybrid):
+        from predictionio_tpu.telemetry.registry import REGISTRY
+
+        _, model, resets = trained_hybrid
+        # 12 users' histories, each in every one of the two epochs
+        assert resets == 2 * 12
+        chunks = dict(REGISTRY.get("encoder_kda_chunks").collect())
+        boundary = dict(REGISTRY.get("encoder_kda_boundary_chunks").collect())
+        assert chunks and set(boundary) == set(chunks)
+        for step in chunks:  # a step: 2 sequences of 16 in chunks of 16
+            assert chunks[step] == 2 and 1 <= boundary[step] <= 2
+
+    @pytest.mark.parametrize("history", [["i3"], ["i3", "i7"],
+                                         ["i1", "i4", "i9", "i2", "i11"],
+                                         [f"i{k}" for k in range(12)]])
+    def test_predict_agrees_with_the_references_forward_on_logits(
+            self, trained_hybrid, history):
+        """`score()` re-encodes a right-padded window: the last real
+        position against the reference's recurrence on the history
+        alone."""
+        from predictionio_tpu.quality import encoder_reference as ref
+
+        algo, model, _ = trained_hybrid
+        single = algo.predict(model, {"items": history, "num": 20})
+        batched = algo.batch_predict(
+            model, [{"items": ["i5", "i6", "i8"], "num": 3},
+                    {"items": history, "num": 20}])[1]
+        assert single == batched
+        want = np.asarray(ref.score(
+            model.params, sessionrec._config_of(model),
+            np.asarray(model.window_rows(history), np.int32)))
+        got = {s["item"]: s["score"] for s in single["itemScores"]}
+        assert len(got) == 20 - len(set(history))
+        for item, value in got.items():
+            assert abs(value - want[model.item_ids.get(item)]) < 2e-4
+
+
+def test_the_benchmarks_reference_is_a_copy_of_the_packages():
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "predictionio_tpu", "quality",
+                           "encoder_reference.py")) as f, \
+            open(os.path.join(root, "perf", "reference",
+                              "kimi_linear.py")) as g:
+        assert f.read() == g.read()

@@ -92,3 +92,27 @@ def test_lanes_solver_compiles_a_device_under_shard_map(topo, k):
     text = jax.jit(solve).lower(a, b).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     assert "gj_lanes" in text and "all-" not in text
+
+
+def test_a_pass_of_the_kda_scan_compiles_for_v5e_at_the_cells_size(one_chip):
+    """`kimi_linear.fit8_pack8k`'s scan, forward and backward, for the
+    heads of one pass of the mixer (2 x 8192 tokens, 4 heads of 128 x
+    128 state, chunks of 64): plain `jax.numpy`, so no kernel to find,
+    but what the TPU's compiler refuses or cannot fit fails here."""
+    from predictionio_tpu.ops import kda
+
+    shape = lambda *s: jax.ShapeDtypeStruct(  # noqa: E731
+        s, jnp.float32, sharding=one_chip)
+    wide, narrow = shape(2, 8192, 4, 128), shape(2, 8192, 4)
+    seg = jax.ShapeDtypeStruct((2, 8192), jnp.int32, sharding=one_chip)
+
+    def loss(q, k, v, log_a, beta, seg):
+        with jax.named_scope("enc.kda.scan"):
+            return jnp.sum(kda.kda_scan(q, k, v, log_a, beta, seg, 64,
+                                        jnp.bfloat16, "enc.kda.scan"))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        wide, wide, wide, wide, narrow, seg).compile()
+    assert "enc.kda.scan" in compiled.as_text()
+    # one pass of four heads, residuals and all, stays under 3 GiB
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 * 2 ** 30
