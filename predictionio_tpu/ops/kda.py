@@ -40,17 +40,28 @@ Precision: log a, its sums and exponentials, b, the pair matrices, the
 triangular inverse and its two products are float32 at the highest
 matmul precision; the four products with the state or the pseudo-values
 (W S_0, K^T U, Q S_0, P U) take `dtype` operands and accumulate in
-float32; the state is float32. Differentiated by autodiff; the caller
-wraps what it wants recomputed in `jax.checkpoint`.
+float32; the state is float32. The caller wraps what it wants recomputed
+in `jax.checkpoint`.
+
+Two paths, one `kda_scan`, which decides from what it can observe: on a
+TPU, with chunks of 64 and head widths that are whole lane tiles, the
+Pallas kernels of `ops/pallas_kda.py` (a chunk's intermediates and the
+state stay in VMEM; a hand-written backward pass); else the plain
+`jax.numpy` below, differentiated by autodiff, which is the CPU's path
+and the kernels' oracle in the tests.
 """
 
 from __future__ import annotations
 
 import functools
+import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from predictionio_tpu.ops import pallas_kda
+from predictionio_tpu.telemetry.spans import record as record_span
 
 _SUB = 16  # sub-block of a chunk inside which pairs are summed by channel
 
@@ -184,10 +195,23 @@ def kda_scan(q, k, v, log_a, beta, seg, chunk: int = 64,
     two). q, k, log_a [B, L, H, dk]; v [B, L, H, dv]; beta [B, L, H];
     seg [B, L]. Returns o [B, L, H, dv], float32; `dtype` is the
     operands' in the products with the state (see the module's
-    docstring). Its ops are traced under `scope`."""
+    docstring). Its ops are traced under `scope`, the backward pass's
+    too. Which path was built is counted in
+    `encoder_kda_scan_calls_total{path}` and left in the timeline as
+    `enc.kda.scan.<path>` (the host seconds spent building it)."""
+    chunk, t0 = int(chunk), time.monotonic()
+    path = ("kernel" if jax.default_backend() == "tpu"
+            and pallas_kda.applicable(chunk, q.shape[-1], v.shape[-1])
+            else "jnp")
+    pallas_kda.SCAN_CALLS.labels(path=path).inc()
     with jax.named_scope(scope):
-        return _kda_scan(q, k, v, log_a, beta, seg, int(chunk),
-                         jnp.dtype(dtype))
+        if path == "kernel":
+            o = pallas_kda.kda_chunks(q, k, v, log_a, beta,
+                                      history_starts(seg), dtype, scope)
+        else:
+            o = _kda_scan(q, k, v, log_a, beta, seg, chunk, jnp.dtype(dtype))
+    record_span(f"enc.kda.scan.{path}", time.monotonic() - t0)
+    return o
 
 
 def _kda_scan(q, k, v, log_a, beta, seg, chunk, dtype):

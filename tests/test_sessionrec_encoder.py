@@ -168,6 +168,9 @@ def test_sizes_beside_a_configuration_file_are_refused():
 
 # -- an encoder with KDA layers, through the same route ---------------------------
 
+_HYBRID_SCANS = {}  # what `trained_hybrid`'s train built and left behind
+
+
 @pytest.fixture(scope="module")
 def trained_hybrid(tmp_path_factory):
     """A model trained from a configuration FILE in Kimi Linear's key
@@ -198,10 +201,21 @@ def trained_hybrid(tmp_path_factory):
         SessionRecAlgorithm.params_class,
         {"maxSeqLen": 16, "epochs": 2, "stepSize": 0.01,
          "encoderConfig": str(path)}))
+    from predictionio_tpu.ops import pallas_kda
+    from predictionio_tpu.telemetry import spans
     from predictionio_tpu.telemetry.registry import REGISTRY
 
-    before = REGISTRY.get("encoder_kda_resets_total").value
-    model = algo.train(WorkflowContext(seed=5), _prepared())
+    scans = lambda: {path: pallas_kda.SCAN_CALLS.labels(path=path).value  # noqa: E731
+                     for path in pallas_kda._PATHS}
+    before, scans_before = REGISTRY.get("encoder_kda_resets_total").value, scans()
+    tl, token = spans.begin("test", "train", "RUN", "t-1")
+    try:
+        model = algo.train(WorkflowContext(seed=5), _prepared())
+    finally:
+        spans.finish(tl, token, status=None, duration_s=0.0)
+    _HYBRID_SCANS.update(
+        built={path: n - scans_before[path] for path, n in scans().items()},
+        spans=[name for name, *_ in tl.spans])
     return algo, model, REGISTRY.get("encoder_kda_resets_total").value - before
 
 
@@ -233,6 +247,17 @@ class TestTheEncoderWithKdaLayers:
         assert chunks and set(boundary) == set(chunks)
         for step in chunks:  # a step: 2 sequences of 16 in chunks of 16
             assert chunks[step] == 2 and 1 <= boundary[step] <= 2
+
+    def test_the_counter_and_the_timeline_say_which_scan_was_built(
+            self, trained_hybrid):
+        """On a CPU `kda_scan` builds the plain `jax.numpy` scan: one a
+        KDA layer at least, none by the kernels, and the timeline holds
+        a record for each under the path's name."""
+        assert trained_hybrid
+        built, names = _HYBRID_SCANS["built"], _HYBRID_SCANS["spans"]
+        assert built["jnp"] >= 2 and built["kernel"] == 0
+        assert names.count("enc.kda.scan.jnp") == built["jnp"]
+        assert "enc.kda.scan.kernel" not in names
 
     @pytest.mark.parametrize("history", [["i3"], ["i3", "i7"],
                                          ["i1", "i4", "i9", "i2", "i11"],

@@ -94,25 +94,52 @@ def test_lanes_solver_compiles_a_device_under_shard_map(topo, k):
     assert "gj_lanes" in text and "all-" not in text
 
 
-def test_a_pass_of_the_kda_scan_compiles_for_v5e_at_the_cells_size(one_chip):
-    """`kimi_linear.fit8_pack8k`'s scan, forward and backward, for the
-    heads of one pass of the mixer (2 x 8192 tokens, 4 heads of 128 x
-    128 state, chunks of 64): plain `jax.numpy`, so no kernel to find,
-    but what the TPU's compiler refuses or cannot fit fails here."""
+def _kda_pass(one_chip, monkeypatch, d):
+    """`kda_scan`, forward and all five gradients, compiled for the
+    described chip at the size of one pass of `kimi_linear.fit8_pack8k`'s
+    mixer (2 x 8192 tokens, 4 heads of a d x d state, chunks of 64,
+    bfloat16 operands). The backend here is the CPU, so the test says
+    "tpu" where `kda_scan` asks (it is the kernel that is compiled for
+    the chip, by Mosaic; nothing runs)."""
     from predictionio_tpu.ops import kda
 
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     shape = lambda *s: jax.ShapeDtypeStruct(  # noqa: E731
         s, jnp.float32, sharding=one_chip)
-    wide, narrow = shape(2, 8192, 4, 128), shape(2, 8192, 4)
+    wide, narrow = shape(2, 8192, 4, d), shape(2, 8192, 4)
     seg = jax.ShapeDtypeStruct((2, 8192), jnp.int32, sharding=one_chip)
 
     def loss(q, k, v, log_a, beta, seg):
-        with jax.named_scope("enc.kda.scan"):
-            return jnp.sum(kda.kda_scan(q, k, v, log_a, beta, seg, 64,
-                                        jnp.bfloat16, "enc.kda.scan"))
+        return jnp.sum(kda.kda_scan(q, k, v, log_a, beta, seg, 64,
+                                    jnp.bfloat16, "enc.kda.scan"))
 
-    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+    return jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
         wide, wide, wide, wide, narrow, seg).compile()
-    assert "enc.kda.scan" in compiled.as_text()
-    # one pass of four heads, residuals and all, stays under 3 GiB
+
+
+def test_a_pass_of_the_kda_scan_compiles_for_v5e_at_the_cells_size(
+        one_chip, monkeypatch):
+    """Two kernels (`ops/pallas_kda.py`): the forward pass that keeps
+    each chunk's incoming state, and the backward pass; both under the
+    scope the benchmark's readers book the scan's time to, the backward
+    one too, which is traced outside the caller's scopes. What Mosaic
+    refuses or the chip cannot fit fails here."""
+    compiled = _kda_pass(one_chip, monkeypatch, 128)
+    calls = [line for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 2
+    assert all("enc.kda.scan" in line for line in calls)
+    assert sum("kda_chunks_fwd" in line for line in calls) == 1
+    assert sum("kda_chunks_bwd" in line for line in calls) == 1
+    # one pass of four heads, residuals and all, stays under the 3 GiB
+    # held since the scan was plain `jax.numpy` (the kernels: the saved
+    # states, 64 MiB, and the flat tensors round them)
     assert compiled.memory_analysis().temp_size_in_bytes < 3 * 2 ** 30
+
+
+def test_a_scan_the_kernels_decline_takes_the_jnp_path(one_chip,
+                                                        monkeypatch):
+    """Heads of 64 x 64 are not whole lane tiles: `kda_scan` builds the
+    plain `jax.numpy` scan, on a TPU too."""
+    text = _kda_pass(one_chip, monkeypatch, 64).as_text()
+    assert "tpu_custom_call" not in text and "enc.kda.scan" in text
