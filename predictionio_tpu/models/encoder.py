@@ -1,8 +1,12 @@
 """The next-item encoder, driven by a configuration: in every block a
-token mixer, latent attention (MLA) or Kimi Delta Attention (KDA, a
-gated delta-rule recurrence, `ops/kda.py`) as the configuration says
-layer by layer, and a dense or a sparse-expert feed-forward; an optional
-multi-token-prediction (MTP) module; trained on packed histories.
+token mixer, latent attention (MLA), Kimi Delta Attention (KDA, a
+gated delta-rule recurrence, `ops/kda.py`) or one of the five of a
+decoder-hybrid-decoder (Mamba's selective scan, `ops/ssm.py`; windowed
+and full differential attention over grouped key and value heads; a
+gated memory unit and a differential cross-attention, which read what
+an earlier layer left) as the configuration says layer by layer, and a
+dense or a sparse-expert feed-forward; an optional multi-token-prediction
+(MTP) module; trained on packed histories.
 
 One code path runs every size. A configuration file in the published
 model's own key names (`EncoderConfig.from_json`) gives the widths, the
@@ -30,6 +34,27 @@ Equations (the plain reference is `quality/encoder_reference.py`):
             k_t k_t^T) Diag(a_t) S_{t-1} + b_t k_t v_t^T from zero at a
             history's first token; o_t = S_t^T q_t; y = (RMSNorm per
             head(o) * sigmoid(x W_ga W_gb)) W_o
+    Mamba   [x | z] = u W_in; x = SiLU(conv(x) + b_c), conv as KDA's;
+            [dl | B | C] = x W_x; dt = softplus(dl W_dt + b_dt);
+            s_t = exp(dt_t (x) A) s_{t-1} + (dt_t x_t) (x) B_t, A =
+            -exp(A_log), s zero entering a history's first token;
+            y_t = s_t C_t + D x_t; out = (y SiLU(z)) W_out. The last
+            such layer's y is the memory m the later layers read.
+    GMU     out = (SiLU(u W_g) m) W_o
+    DiffAttn [Q | K | V] = u W_qkv + b. Query heads (2j, 2j+1) are pair
+            j's q1, q2, key heads (2g, 2g+1) group g's k1, k2, value
+            heads (2g, 2g+1) side by side its V_g; pair j reads group
+            j // (heads / kv heads). o_j = P(q1, k1) V_g - lam P(q2, k2)
+            V_g, P a softmax over the keys s <= t of the history (kind
+            `swa`: with t - s < sliding_window too), scores / sqrt(d);
+            lam = exp(lq1.lk1) - exp(lq2.lk2) + lam0, lam0 = 0.8 - 0.6
+            exp(-0.3 l) at the published layer index l; o_j <- RMSNorm(
+            o_j) (1 - lam0); out = [o_0 ..] W_o + b_o. Kind `full`
+            keeps its K, V; kind `cross` has Q = u W_q + b alone and
+            reads them. No positional encoding.
+    LN      with `layer_norm_eps` every norm is LayerNorm with a bias;
+            with `tie_word_embeddings` the head is the embedding
+            transposed
     FFN     SwiGLU in the first `first_k_dense_replace` blocks; after them
             shared SwiGLU + the held experts' part of the routed result
             (`ops/moe.py`)
@@ -53,6 +78,7 @@ import jax.numpy as jnp
 
 from predictionio_tpu.ops import kda as kda_ops
 from predictionio_tpu.ops import moe
+from predictionio_tpu.ops import ssm
 from predictionio_tpu.ops.attention import segment_attention
 
 # the same sizes under other published names (`kimi_linear`'s)
@@ -67,13 +93,26 @@ class EncoderConfig:
     intermediate_size: int
     num_hidden_layers: int
     num_attention_heads: int
-    kv_lora_rank: int
-    qk_nope_head_dim: int
-    qk_rope_head_dim: int
-    v_head_dim: int
+    kv_lora_rank: int = 0            # the four of MLA; a configuration
+    qk_nope_head_dim: int = 0        # without a latent leaves them out
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     q_lora_rank: int = 0             # 0 (null): x W_q, no low-rank query
     mla_use_nope: bool = False       # MLA without rotation
-    layer_kinds: tuple = ()          # "mla" | "kda" a layer; (): all MLA
+    # "mla" | "kda" | "mamba" | "swa" | "full" | "gmu" | "cross" a layer;
+    # (): all MLA
+    layer_kinds: tuple = ()
+    num_key_value_heads: int = 0     # grouped K/V heads of swa/full/cross
+    sliding_window: int = 0          # of the swa layers
+    layer_first: int = 0             # published index of the first held layer
+    mamba_expand: int = 2            # channels / hidden_size
+    mamba_d_state: int = 16
+    mamba_d_conv: int = 4
+    mamba_dt_rank: int = 0           # 0: hidden_size / 16, rounded up
+    ssm_chunk: int = 64
+    ssm_channels: int = 0            # channels a pass of the scan; 0: all
+    layer_norm_eps: float = 0.0      # > 0: LayerNorm with a bias, not RMSNorm
+    tie_word_embeddings: bool = False
     kda_num_heads: int = 0
     kda_head_dim: int = 0
     kda_conv_size: int = 4
@@ -121,6 +160,18 @@ class EncoderConfig:
         return self.layer_kinds or ("mla",) * self.num_hidden_layers
 
     @property
+    def mamba_channels(self) -> int:
+        return self.mamba_expand * self.hidden_size
+
+    @property
+    def dt_rank(self) -> int:
+        return self.mamba_dt_rank or -(-self.hidden_size // 16)
+
+    @property
+    def norm_eps(self) -> float:
+        return self.layer_norm_eps or self.rms_norm_eps
+
+    @property
     def moe_stacked(self) -> bool:
         """Expert blocks of one kind are stacked on a leading axis and
         run by one scan; of two kinds they are a list."""
@@ -150,6 +201,12 @@ class EncoderConfig:
                 kda_num_heads=linear["num_heads"],
                 kda_head_dim=linear["head_dim"],
                 kda_conv_size=linear["short_conv_kernel_size"])
+        if flat.get("mb_per_layer"):
+            flat["layer_kinds"] = hybrid_decoder_kinds(
+                int(flat.get("layer_first", 0)),
+                int(flat["num_hidden_layers"]),
+                int(flat.get("layers_total", flat["num_hidden_layers"])),
+                int(flat["mb_per_layer"]))
         known = {f.name for f in dataclasses.fields(cls)}
         kw = {k: v for k, v in flat.items() if k in known and v is not None}
         kw["report_blocks"] = tuple(
@@ -165,8 +222,47 @@ class EncoderConfig:
             return cls.from_dict(json.load(f))
 
 
+def hybrid_decoder_kinds(first: int, held: int, total: int,
+                         period: int) -> tuple:
+    """The kinds of the `held` layers from the published index `first`
+    of a decoder-hybrid-decoder of `total` layers: every `period`-th
+    layer (0, period, ..) on the Mamba side, the rest on the attention
+    side. In the first half: Mamba | attention with a window. Layer
+    total/2: Mamba (its scan output is the memory); total/2 + 1: full
+    attention (its K, V are kept). After them: a gated memory unit |
+    cross-attention. A slice that reads a memory or K, V it does not
+    make is refused."""
+    half, kinds = total // 2, []
+    for n in range(first, first + held):
+        if n % period == 0:
+            kinds.append("mamba" if n <= half else "gmu")
+        else:
+            kinds.append("swa" if n < half else
+                         "full" if n == half + 1 else "cross")
+    for reader, maker in (("gmu", "mamba"), ("cross", "full")):
+        if reader in kinds and maker not in kinds[:kinds.index(reader)]:
+            raise ValueError(
+                f"layers {first}..{first + held - 1} of {total} hold a "
+                f"{reader!r} layer and not the {maker!r} layer it reads")
+    return tuple(kinds)
+
+
 def _dt(name: str):
     return jnp.dtype(name)
+
+
+def layer_norm(x, w, bias, eps):
+    x = x.astype(jnp.float32)
+    x = x - jnp.mean(x, axis=-1, keepdims=True)
+    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True)
+                             + eps) * w + bias
+
+
+def _norm(cfg: "EncoderConfig", x, p, name: str):
+    """The configuration's norm of x with p's parameters `name`."""
+    if cfg.layer_norm_eps:
+        return layer_norm(x, p[name], p[name + "_bias"], cfg.layer_norm_eps)
+    return rms_norm(x, p[name], cfg.rms_norm_eps)
 
 
 def rms_norm(x, w, eps):
@@ -308,6 +404,132 @@ def kda(p, cfg: EncoderConfig, x, seg, scope: str = "enc.kda"):
     return y
 
 
+def mamba(p, cfg: EncoderConfig, x, seg, scope: str = "enc.mamba"):
+    """Mamba's mixer on x [B, L, D] (already normed). Returns (out, y):
+    y [B, L, channels] is the scan's output before the gate, the memory
+    a later gated memory unit reads. Scopes `proj`, `conv`, `dt`,
+    `scan` (`ops/ssm.py`), `out` under `scope`."""
+    n, rank = cfg.mamba_d_state, cfg.dt_rank
+    di = cfg.mamba_channels
+    with jax.named_scope(f"{scope}.proj"):
+        # two products: [x | z] [B, L, 2 channels] is never held whole
+        xi, z = _mm(cfg, x, p["w_in"][:, :di]), _mm(cfg, x, p["w_in"][:, di:])
+    with jax.named_scope(f"{scope}.conv"):
+        xi = jax.nn.silu(kda_ops.causal_conv(xi, p["conv_x"], seg,
+                                             p["conv_bias"]))
+    with jax.named_scope(f"{scope}.dt"):
+        low = _mm(cfg, xi, p["w_x"])
+        dt = jax.nn.softplus(_mm(cfg, low[..., :rank], p["w_dt"])
+                             + p["dt_bias"])
+    y = ssm.selective_scan(
+        xi, dt, -jnp.exp(p["a_log"]), low[..., rank:rank + n],
+        low[..., rank + n:], p["d_skip"], seg, cfg.ssm_chunk, jnp.float32,
+        f"{scope}.scan", channels=cfg.ssm_channels)
+    with jax.named_scope(f"{scope}.out"):
+        return _mm(cfg, y * jax.nn.silu(z), p["w_out"]), y
+
+
+def gmu(p, cfg: EncoderConfig, x, m):
+    """The gated memory unit on x [B, L, D] (already normed): the
+    memory m [B, L, channels] gated by x, element by element."""
+    return _mm(cfg, jax.nn.silu(_mm(cfg, x, p["w_g"])) * m, p["w_o"])
+
+
+def lambda_init(layer: int) -> float:
+    """lam0 of differential attention at the published layer index."""
+    return 0.8 - 0.6 * math.exp(-0.3 * layer)
+
+
+def diff_attention(p, cfg: EncoderConfig, x, seg, pos, layer: int,
+                   window=None, kv=None, scope: str = "enc.attn"):
+    """Differential attention on x [B, L, D] (already normed) at the
+    published layer index `layer`. Without `kv` the block makes Q, K, V
+    itself; with it (a cross layer) Q alone, and reads the K [B, L,
+    kv heads, d], V given. Returns (out, (K, V)). The two softmaxes of a
+    pair are two heads of one `segment_attention` over the stacked
+    query heads, each beside its key head and its group's value, so the
+    score tile is a head's. Scopes `proj`, `pairs`, `subln`, `out`."""
+    cd = _dt(cfg.compute_dtype)
+    b, l, d = x.shape
+    h, hk = cfg.num_attention_heads, cfg.num_key_value_heads
+    dh = d // h
+    with jax.named_scope(f"{scope}.proj"):
+        if kv is None:
+            qkv = _mm(cfg, x, p["w_qkv"]) + p["qkv_bias"]
+            q = qkv[..., :h * dh]
+            k = qkv[..., h * dh:(h + hk) * dh].reshape(b, l, hk, dh)
+            v = qkv[..., (h + hk) * dh:].reshape(b, l, hk, dh)
+        else:
+            q = _mm(cfg, x, p["w_q"]) + p["q_bias"]
+            k, v = kv
+        # query head 2j + s reads key head 2g + s and value group g = j //
+        # rep: [groups, rep, 2] is the order of the query heads
+        rep = h // hk
+
+        def beside_queries(t, width):  # [B, L, groups, 1 | 2, width]
+            t = jnp.broadcast_to(t[:, :, :, None],
+                                 (b, l, hk // 2, rep, 2, width))
+            return t.reshape(b, l, h, width).astype(cd).transpose(0, 2, 1, 3)
+
+        stacked = (
+            q.reshape(b, l, h, dh).astype(cd).transpose(0, 2, 1, 3),
+            beside_queries(k.reshape(b, l, hk // 2, 2, dh), dh),
+            beside_queries(v.reshape(b, l, hk // 2, 1, 2 * dh), 2 * dh))
+    o = segment_attention(*stacked, seg, pos, block=cfg.attention_block,
+                          scale=dh ** -0.5, scope=f"{scope}.pairs",
+                          window=window)                # [B, H, L, 2 dh]
+    with jax.named_scope(f"{scope}.subln"):
+        lam0 = lambda_init(layer)
+        lam = (jnp.exp(jnp.sum(p["lambda_q1"] * p["lambda_k1"]))
+               - jnp.exp(jnp.sum(p["lambda_q2"] * p["lambda_k2"])) + lam0)
+        o = rms_norm(o[:, 0::2] - lam * o[:, 1::2], p["sub_norm"],
+                     cfg.norm_eps) * (1.0 - lam0)
+    with jax.named_scope(f"{scope}.out"):
+        out = _mm(cfg, o.transpose(0, 2, 1, 3).reshape(b, l, d),
+                  p["w_o"]) + p["o_bias"]
+    return out, (k, v)
+
+
+def carried_block(p, cfg: EncoderConfig, kind: str, layer: int, h, seg, pos,
+                  carry: dict):
+    """One block of a decoder-hybrid-decoder on the residual stream h
+    [B, L, D], of `kind` at the published index `layer`. `carry` holds
+    what earlier layers left for later ones beside the stream: `m` (the
+    last Mamba layer's scan output) and `kv` (the full-attention
+    layer's K, V). Returns (h, carry). Under the configuration's
+    recomputation a block is one recomputed function: what it leaves is
+    an output of it and what it reads an input, so a maker is not
+    computed again for its readers."""
+    def run(p, h, m, kv):
+        x = _norm(cfg, h, p, "norm1")
+        made = None
+        if kind == "mamba":
+            with jax.named_scope("enc.mamba"):
+                y, made = mamba(p["mamba"], cfg, x, seg)
+        elif kind == "gmu":
+            with jax.named_scope("enc.gmu"):
+                y = gmu(p["gmu"], cfg, x, m)
+        elif kind == "cross":
+            with jax.named_scope("enc.cross"):
+                y, _ = diff_attention(p["cross"], cfg, x, seg, pos, layer,
+                                      kv=kv, scope="enc.cross")
+        else:
+            with jax.named_scope("enc.attn"):
+                y, made = diff_attention(
+                    p["diff"], cfg, x, seg, pos, layer,
+                    window=cfg.sliding_window if kind == "swa" else None)
+        return _feed_forward(p, None, cfg, h + y)[0], made
+
+    h, made = _maybe_remat(run, cfg)(
+        p, h, carry.get("m") if kind == "gmu" else None,
+        carry.get("kv") if kind == "cross" else None)
+    if kind == "mamba":
+        carry = {**carry, "m": made}
+    elif kind == "full":
+        carry = {**carry, "kv": made}
+    return h, carry
+
+
 def expert_ffn(p, bias, cfg: EncoderConfig, x2d, scope: str = "enc.moe"):
     """Shared SwiGLU + the held experts' part. Returns (y, routed): the
     held experts' token counts, every expert's load and each token's
@@ -327,9 +549,9 @@ def expert_ffn(p, bias, cfg: EncoderConfig, x2d, scope: str = "enc.moe"):
 
 
 def _mix(p, cfg: EncoderConfig, h, seg, pos, scope: str = ""):
-    """The first half of `block`: h += Mixer(RMSNorm(h)), the mixer the
+    """The first half of `block`: h += Mixer(norm(h)), the mixer the
     one whose parameters the block holds (`attn`: MLA, `kda`: KDA)."""
-    x = rms_norm(h, p["norm1"], cfg.rms_norm_eps)
+    x = _norm(cfg, h, p, "norm1")
     if "kda" in p:
         with jax.named_scope(scope or "enc.kda"):
             return h + kda(p["kda"], cfg, x, seg, scope or "enc.kda")
@@ -348,9 +570,9 @@ def block(p, bias, cfg: EncoderConfig, h, seg, pos, scope: str = ""):
 
 
 def _feed_forward(p, bias, cfg: EncoderConfig, h, scope: str = ""):
-    """The second half of `block`: h += FFN(RMSNorm(h))."""
+    """The second half of `block`: h += FFN(norm(h))."""
     b, l, d = h.shape
-    x2d = rms_norm(h, p["norm2"], cfg.rms_norm_eps).reshape(b * l, d)
+    x2d = _norm(cfg, h, p, "norm2").reshape(b * l, d)
     if bias is None:
         with jax.named_scope(scope or "enc.dense_ffn"):
             y = _by_rows(cfg, lambda x: swiglu(cfg, x, p["w13"], p["w2"]),
@@ -359,6 +581,9 @@ def _feed_forward(p, bias, cfg: EncoderConfig, h, scope: str = ""):
     with jax.named_scope(scope or "enc.moe"):
         y, routed = expert_ffn(p, bias, cfg, x2d, scope or "enc.moe")
     return h + y.reshape(b, l, d), routed
+
+
+CARRIED_KINDS = ("mamba", "swa", "full", "gmu", "cross")
 
 
 def _maybe_remat(fn, cfg: EncoderConfig):
@@ -381,8 +606,20 @@ def encode(params, cfg: EncoderConfig, tokens, seg, pos):
     [B, L, D] (before the final norm) and what the expert layers routed
     (`expert_ffn`), stacked over the layers: counts [n_moe, held], load
     [n_moe, experts_total], picks [n_moe, B * L, k]; None without one."""
-    h = jnp.take(params["emb"], tokens, axis=0)
-    for p in params["dense"]:
+    return run_blocks(params, cfg, jnp.take(params["emb"], tokens, axis=0),
+                      seg, pos)
+
+
+def run_blocks(params, cfg: EncoderConfig, h, seg, pos):
+    """`encode` from the residual stream h [B, L, D] that enters the
+    first held block (the item embeddings, or what an earlier pipeline
+    stage hands on)."""
+    carry: dict = {}
+    for n, p in enumerate(params["dense"]):
+        if cfg.kinds[n] in CARRIED_KINDS:
+            h, carry = carried_block(p, cfg, cfg.kinds[n],
+                                     cfg.layer_first + n, h, seg, pos, carry)
+            continue
         if "kda" in p:
             h, _ = _kda_block(p, None, cfg, h, seg, pos)
             continue
@@ -425,17 +662,29 @@ def mtp_hidden(params, cfg: EncoderConfig, h, tokens, seg, pos):
         return _maybe_remat(run, cfg)(p, h)
 
 
+def head_logits(params, cfg: EncoderConfig, h):
+    """The final norm and the head on h [.., D]: logits [.., V]. A tied
+    head is the embedding read transposed, by the product itself."""
+    x = _norm(cfg, h, params, "final_norm")
+    if not cfg.tie_word_embeddings:
+        return _mm(cfg, x, params["head"])
+    dtype = _dt(cfg.compute_dtype)
+    return jax.lax.dot_general(
+        x.astype(dtype), params["emb"].astype(dtype),
+        (((x.ndim - 1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+
+
 def cross_entropy_sum(params, cfg: EncoderConfig, h2d, targets, valid):
     """Sum over the valid rows of h2d [T, D] of -log softmax(head(
-    RMSNorm(h)))[target], a chunk of rows at a time: [chunk, vocabulary]
+    norm(h)))[target], a chunk of rows at a time: [chunk, vocabulary]
     is the most that is ever held, and the backward pass recomputes it."""
     t = h2d.shape[0]
     chunk = cfg.loss_chunk if t % cfg.loss_chunk == 0 else t
 
     def one(total, xs):
         h_c, t_c, v_c = xs
-        logits = _mm(cfg, rms_norm(h_c, params["final_norm"],
-                                   cfg.rms_norm_eps), params["head"])
+        logits = head_logits(params, cfg, h_c)
         lse = jax.nn.logsumexp(logits, axis=-1)
         tgt = jnp.take_along_axis(logits, t_c[:, None], axis=-1)[:, 0]
         return total + jnp.sum((lse - tgt) * v_c), None
@@ -509,11 +758,54 @@ def _kda_shapes(cfg: EncoderConfig) -> dict:
     }
 
 
+def _mamba_shapes(cfg: EncoderConfig) -> dict:
+    d, di, n = cfg.hidden_size, cfg.mamba_channels, cfg.mamba_d_state
+    return {"w_in": (d, 2 * di), "conv_x": (cfg.mamba_d_conv, di),
+            "conv_bias": (di,), "w_x": (di, cfg.dt_rank + 2 * n),
+            "w_dt": (cfg.dt_rank, di), "dt_bias": (di,), "a_log": (di, n),
+            "d_skip": (di,), "w_out": (di, d)}
+
+
+def _diff_shapes(cfg: EncoderConfig, cross: bool) -> dict:
+    """Differential attention's; a cross layer projects queries alone."""
+    d = cfg.hidden_size
+    dh = d // cfg.num_attention_heads
+    wide = d + 2 * cfg.num_key_value_heads * dh
+    proj = ({"w_q": (d, d), "q_bias": (d,)} if cross
+            else {"w_qkv": (d, wide), "qkv_bias": (wide,)})
+    return {**proj, "lambda_q1": (dh,), "lambda_k1": (dh,),
+            "lambda_q2": (dh,), "lambda_k2": (dh,), "sub_norm": (2 * dh,),
+            "w_o": (d, d), "o_bias": (d,)}
+
+
+def _mixer_shapes(cfg: EncoderConfig, kind: str) -> dict:
+    """{the key a block holds its mixer under: the mixer's shapes}."""
+    if kind == "kda":
+        return {"kda": _kda_shapes(cfg)}
+    if kind == "mamba":
+        return {"mamba": _mamba_shapes(cfg)}
+    if kind == "gmu":
+        return {"gmu": {"w_g": (cfg.hidden_size, cfg.mamba_channels),
+                        "w_o": (cfg.mamba_channels, cfg.hidden_size)}}
+    if kind in ("swa", "full"):
+        return {"diff": _diff_shapes(cfg, False)}
+    if kind == "cross":
+        return {"cross": _diff_shapes(cfg, True)}
+    return {"attn": _attn_shapes(cfg)}
+
+
+def _norm_shapes(cfg: EncoderConfig, *names: str) -> dict:
+    """A scale for each norm and, for LayerNorm, a bias."""
+    d = cfg.hidden_size
+    out = {name: (d,) for name in names}
+    if cfg.layer_norm_eps:
+        out.update({name + "_bias": (d,) for name in names})
+    return out
+
+
 def _block_shapes(cfg: EncoderConfig, dense: bool, kind: str = "mla") -> dict:
     d = cfg.hidden_size
-    mixer = ({"kda": _kda_shapes(cfg)} if kind == "kda"
-             else {"attn": _attn_shapes(cfg)})
-    out = {**mixer, "norm1": (d,), "norm2": (d,)}
+    out = {**_mixer_shapes(cfg, kind), **_norm_shapes(cfg, "norm1", "norm2")}
     if dense:
         out.update(w13=(d, 2 * cfg.intermediate_size),
                    w2=(cfg.intermediate_size, d))
@@ -532,9 +824,11 @@ def param_shapes(cfg: EncoderConfig, vocab: int) -> dict:
     two kinds (`EncoderConfig.moe_stacked`) `moe` is a list."""
     d = cfg.hidden_size
     kinds = cfg.kinds
-    shapes = {"emb": (vocab, d), "head": (d, vocab), "final_norm": (d,),
+    shapes = {"emb": (vocab, d), **_norm_shapes(cfg, "final_norm"),
               "dense": [_block_shapes(cfg, True, kind)
                         for kind in kinds[:cfg.n_dense]]}
+    if not cfg.tie_word_embeddings:
+        shapes["head"] = (d, vocab)
     is_shape = lambda s: isinstance(s, tuple)  # noqa: E731
     if cfg.n_moe and cfg.moe_stacked:
         shapes["moe"] = jax.tree_util.tree_map(
@@ -556,10 +850,13 @@ def count_parameters(cfg: EncoderConfig, vocab: int) -> int:
 
 
 def init_params(cfg: EncoderConfig, vocab: int, key):
-    """Weights normal(0, init_std), norms one; a KDA layer's decay
-    rates A = exp(a_log) uniform in [1, 16], its `dt_bias` the inverse
-    softplus of a step log-uniform in [0.001, 0.1], its convolution's
-    taps uniform within 1 / sqrt(width). Made where `key` lives."""
+    """Weights normal(0, init_std), norms one, biases zero; a KDA
+    layer's decay rates A = exp(a_log) uniform in [1, 16], a `dt_bias`
+    the inverse softplus of a step log-uniform in [0.001, 0.1], a
+    convolution's taps uniform within 1 / sqrt(width); a Mamba layer's
+    A = 1..states a channel and its skip D one; differential
+    attention's four lambda vectors normal(0, 0.1). Made where `key`
+    lives."""
     shapes = param_shapes(cfg, vocab)
     leaves, tree = jax.tree_util.tree_flatten_with_path(
         shapes, is_leaf=lambda s: isinstance(s, tuple))
@@ -567,8 +864,9 @@ def init_params(cfg: EncoderConfig, vocab: int, key):
     out = []
     for k, (path, shape) in zip(keys, leaves):
         name = str(path[-1])
-        if "norm" in name:
-            out.append(jnp.ones(shape, jnp.float32))
+        if "a_log" in name and any("mamba" in str(part) for part in path):
+            out.append(jnp.broadcast_to(
+                jnp.log(jnp.arange(1.0, shape[-1] + 1.0)), shape))
         elif "a_log" in name:
             out.append(jnp.log(jax.random.uniform(k, shape, jnp.float32,
                                                   1.0, 16.0)))
@@ -576,6 +874,12 @@ def init_params(cfg: EncoderConfig, vocab: int, key):
             dt = jnp.exp(jax.random.uniform(k, shape, jnp.float32,
                                             math.log(1e-3), math.log(1e-1)))
             out.append(dt + jnp.log(-jnp.expm1(-dt)))
+        elif "bias" in name:
+            out.append(jnp.zeros(shape, jnp.float32))
+        elif "norm" in name or "d_skip" in name:
+            out.append(jnp.ones(shape, jnp.float32))
+        elif "lambda_" in name:
+            out.append(0.1 * jax.random.normal(k, shape, jnp.float32))
         elif "conv_" in name:
             bound = shape[-2] ** -0.5
             out.append(jax.random.uniform(k, shape, jnp.float32, -bound,
@@ -700,5 +1004,4 @@ def score(params, cfg: EncoderConfig, seq, lengths):
     h, _ = encode(params, cfg, jnp.where(real, seq, 0), seg,
                   pos.astype(jnp.int32))
     last = h[jnp.arange(b), jnp.clip(lengths - 1, 0, l - 1)]
-    return _mm(cfg, rms_norm(last, params["final_norm"], cfg.rms_norm_eps),
-               params["head"])
+    return head_logits(params, cfg, last)

@@ -37,10 +37,12 @@ _NEG_INF = -1e30  # large-negative instead of -inf: keeps fully-masked rows
 
 
 def dense_attention(q, k, v, causal: bool = False, segment_ids=None,
-                    scale: Optional[float] = None):
+                    scale: Optional[float] = None,
+                    window: Optional[int] = None):
     """Reference single-device attention. q,k: [B, H, S, D]; v: [B, H, S,
     Dv]. `segment_ids` [B, S] keeps a query inside the keys of its own
-    segment (packed histories); `scale` defaults to 1/sqrt(D)."""
+    segment (packed histories); `scale` defaults to 1/sqrt(D); with a
+    `window` a query at t sees the keys s with t - s < window."""
     scale = 1.0 / math.sqrt(q.shape[-1]) if scale is None else scale
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
                    preferred_element_type=jnp.float32) * scale
@@ -51,6 +53,10 @@ def dense_attention(q, k, v, causal: bool = False, segment_ids=None,
     if segment_ids is not None:
         same = (segment_ids[:, :, None] == segment_ids[:, None, :])[:, None]
         mask = same if mask is None else mask & same
+    if window is not None:
+        near = (jnp.arange(sq)[:, None] + (sk - sq) - jnp.arange(sk)[None, :]
+                < window)[None, None]
+        mask = near if mask is None else mask & near
     if mask is not None:
         s = jnp.where(mask, s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
@@ -62,7 +68,9 @@ def dense_attention(q, k, v, causal: bool = False, segment_ids=None,
 #
 # One sequence at a time, a query block at a time, over the key blocks
 # that can hold a visible key: from the block where the segment of the
-# query block's first token starts (`kv_lo`) to the query block itself.
+# query block's first token starts (`kv_lo`) to the query block itself;
+# with a window, from the block of the oldest key the window leaves the
+# query block's first token, where that is the later one.
 # The score matrix of a block pair is all that is ever held; the backward
 # pass recomputes it from the saved log-sum-exp (the flash-attention
 # recurrence, in plain jax.numpy: no kernel).
@@ -71,17 +79,19 @@ def _slab(x, start, size, axis=1):
     return jax.lax.dynamic_slice_in_dim(x, start, size, axis)
 
 
-def _pair_scores(qi, kj, seg_q, seg_k, i, j, blk, scale):
+def _pair_scores(qi, kj, seg_q, seg_k, i, j, blk, scale, window=None):
     s = jnp.einsum("hqd,hkd->hqk", qi, kj,
                    preferred_element_type=jnp.float32) * scale
     q_pos = i * blk + jnp.arange(blk)
     k_pos = j * blk + jnp.arange(blk)
     mask = (k_pos[None, :] <= q_pos[:, None]) & (seg_q[:, None]
                                                  == seg_k[None, :])
+    if window is not None:
+        mask = mask & (q_pos[:, None] - k_pos[None, :] < window)
     return s, mask[None]
 
 
-def _segment_fwd_seq(q, k, v, seg, kv_lo, blk, scale):
+def _segment_fwd_seq(q, k, v, seg, kv_lo, blk, scale, window=None):
     h, l, _ = q.shape
     dv = v.shape[-1]
 
@@ -91,7 +101,7 @@ def _segment_fwd_seq(q, k, v, seg, kv_lo, blk, scale):
         def fold(j, carry):
             kj, vj = _slab(k, j * blk, blk), _slab(v, j * blk, blk)
             s, mask = _pair_scores(qi, kj, seg_q, _slab(seg, j * blk, blk, 0),
-                                   i, j, blk, scale)
+                                   i, j, blk, scale, window)
             return _online_fold(*carry, jnp.where(mask, s, _NEG_INF), vj)
 
         o, m, den = jax.lax.fori_loop(
@@ -106,7 +116,8 @@ def _segment_fwd_seq(q, k, v, seg, kv_lo, blk, scale):
             lse.transpose(1, 0, 2).reshape(h, l))
 
 
-def _segment_bwd_seq(q, k, v, seg, kv_lo, o, lse, do, blk, scale):
+def _segment_bwd_seq(q, k, v, seg, kv_lo, o, lse, do, blk, scale,
+                     window=None):
     h, l, dk_ = q.shape
     delta = jnp.sum(do * o, axis=-1)  # [H, L]
 
@@ -119,7 +130,7 @@ def _segment_bwd_seq(q, k, v, seg, kv_lo, o, lse, do, blk, scale):
             dq_i, dk, dv = inner
             kj, vj = _slab(k, j * blk, blk), _slab(v, j * blk, blk)
             s, mask = _pair_scores(qi, kj, seg_q, _slab(seg, j * blk, blk, 0),
-                                   i, j, blk, scale)
+                                   i, j, blk, scale, window)
             p = jnp.where(mask, jnp.exp(s - lse_i[..., None]), 0.0)
             dp = jnp.einsum("hqd,hkd->hqk", doi, vj,
                             preferred_element_type=jnp.float32)
@@ -147,26 +158,27 @@ def _segment_bwd_seq(q, k, v, seg, kv_lo, o, lse, do, blk, scale):
     return dq.transpose(1, 0, 2, 3).reshape(q.shape), dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
-def _segment_attention(q, k, v, seg, kv_lo, blk, scale, scope):
-    return _segment_attention_fwd(q, k, v, seg, kv_lo, blk, scale, scope)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _segment_attention(q, k, v, seg, kv_lo, blk, scale, scope, window):
+    return _segment_attention_fwd(q, k, v, seg, kv_lo, blk, scale, scope,
+                                  window)[0]
 
 
-def _segment_attention_fwd(q, k, v, seg, kv_lo, blk, scale, scope):
+def _segment_attention_fwd(q, k, v, seg, kv_lo, blk, scale, scope, window):
     with jax.named_scope(scope):
         o, lse = jax.lax.map(
-            lambda a: _segment_fwd_seq(*a, blk, scale),
+            lambda a: _segment_fwd_seq(*a, blk, scale, window),
             (q, k, v, seg, kv_lo))
     return o, (q, k, v, seg, kv_lo, o, lse)
 
 
-def _segment_attention_bwd(blk, scale, scope, res, do):
+def _segment_attention_bwd(blk, scale, scope, window, res, do):
     # a backward pass is traced outside the caller's scopes: it opens the
     # one it was given, so that a trace can tell whose time it is
     q, k, v, seg, kv_lo, o, lse = res
     with jax.named_scope(scope):
         dq, dk, dv = jax.lax.map(
-            lambda a: _segment_bwd_seq(*a, blk, scale),
+            lambda a: _segment_bwd_seq(*a, blk, scale, window),
             (q, k, v, seg, kv_lo, o, lse, do))
     return (dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype),
             None, None)
@@ -175,9 +187,24 @@ def _segment_attention_bwd(blk, scale, scope, res, do):
 _segment_attention.defvjp(_segment_attention_fwd, _segment_attention_bwd)
 
 
+def first_key_blocks(positions, block: int, window: Optional[int] = None,
+                     xp=jnp):
+    """[B, L / block]: for each query block the first key block it
+    visits: where the history of its first token starts and, with a
+    window, no earlier than the block of that token's oldest visible
+    key. `positions` [B, L] counts each token from its history's start."""
+    l = positions.shape[1]
+    starts = (xp.arange(l)[None, :] - positions)[:, ::block]
+    if window is not None:
+        starts = xp.maximum(starts, xp.arange(0, l, block)[None, :]
+                            - (window - 1))
+    return xp.clip(starts // block, 0, None).astype(xp.int32)
+
+
 def segment_attention(q, k, v, segment_ids, positions, block: int = 512,
                       scale: Optional[float] = None,
-                      scope: str = "attention.segment"):
+                      scope: str = "attention.segment",
+                      window: Optional[int] = None):
     """Causal attention held inside segments, for packed sequences.
 
     q, k: [B, H, L, D]; v: [B, H, L, Dv]; `segment_ids` [B, L] (tokens of
@@ -186,19 +213,21 @@ def segment_attention(q, k, v, segment_ids, positions, block: int = 512,
     is where the first key block a query block needs is read from. A
     sequence that one block holds takes `dense_attention`; a longer one
     goes a block pair at a time and never holds [H, L, L]; its ops,
-    those of the backward pass too, are traced under `scope`. Returns
-    [B, H, L, Dv] in float32."""
+    those of the backward pass too, are traced under `scope`. With a
+    `window` a query at t sees the keys s of its history with t - s <
+    window, and a query block skips the key blocks the window leaves
+    out. Returns [B, H, L, Dv] in float32."""
     scale = 1.0 / math.sqrt(q.shape[-1]) if scale is None else scale
     l = q.shape[2]
     if l <= block:
         return dense_attention(q, k, v, causal=True,
-                               segment_ids=segment_ids, scale=scale)
+                               segment_ids=segment_ids, scale=scale,
+                               window=window)
     if l % block:
         raise ValueError(f"sequence {l} is not a multiple of block {block}")
-    starts = (jnp.arange(l)[None, :] - positions)[:, ::block]
-    kv_lo = jnp.clip(starts // block, 0, None).astype(jnp.int32)
+    kv_lo = first_key_blocks(positions, block, window)
     return _segment_attention(q, k, v, segment_ids, kv_lo, block, scale,
-                              scope)
+                              scope, window)
 
 
 def _online_fold(o, m, l, s, v_blk):

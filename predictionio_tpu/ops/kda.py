@@ -87,12 +87,12 @@ def chunk_stats(seg, chunk: int) -> tuple[int, int, int]:
             int((starts & (seg != 0)).sum()))
 
 
-def causal_conv(x, w, seg):
+def causal_conv(x, w, seg, bias=None):
     """Causal depthwise convolution along L of x [B, L, C] with taps w
-    [W, C], the last tap on the token itself. A tap that would read
-    another history reads zero."""
+    [W, C] (and `bias` [C]), the last tap on the token itself. A tap that
+    would read another history reads zero."""
     width = w.shape[0]
-    out = x * w[width - 1]
+    out = x * w[width - 1] if bias is None else x * w[width - 1] + bias
     for back in range(1, width):
         shifted = jnp.pad(x, ((0, 0), (back, 0), (0, 0)))[:, :x.shape[1]]
         same = jnp.pad(seg, ((0, 0), (back, 0)),

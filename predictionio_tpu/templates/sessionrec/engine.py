@@ -380,9 +380,47 @@ KDA_BOUNDARY_CHUNKS = REGISTRY.gauge(
 KDA_RESETS_TOTAL = REGISTRY.counter(
     "encoder_kda_resets_total", "Histories the train steps of an encoder "
     "with KDA layers held: the times a layer's state started from zero")
+SSM_CHUNKS = REGISTRY.gauge(
+    "encoder_ssm_chunks", "Chunks of Mamba's selective scan (ops/ssm.py) "
+    "in the sequences of each step of the last train's epoch, a Mamba layer",
+    labelnames=("step",))
+SSM_BOUNDARY_CHUNKS = REGISTRY.gauge(
+    "encoder_ssm_boundary_chunks", "Of encoder_ssm_chunks, those that "
+    "hold a history's first token (the scan's reset masks do work there)",
+    labelnames=("step",))
+SSM_RESETS_TOTAL = REGISTRY.counter(
+    "encoder_ssm_resets_total", "Histories the train steps of an encoder "
+    "with Mamba layers held: the times a layer's state started from zero")
+ATTN_KEY_BLOCKS = REGISTRY.gauge(
+    "encoder_attn_key_blocks", "Key blocks the query blocks of the last "
+    "train's epoch visit in one blockwise differential-attention layer "
+    "(kind: window | full), summed over its steps: of=visited, from a "
+    "history's first block or the window's; of=causal, what plain causal "
+    "attention would visit", labelnames=("kind", "of"))
 TOKENS_TOTAL = REGISTRY.counter(
     "encoder_tokens_total", "Events the sessionrec train steps have "
     "trained on (padding not counted)")
+
+
+def _count_key_blocks(cfg, batches) -> None:
+    """`encoder_attn_key_blocks` for the blockwise differential
+    attention of the configuration's windowed and full layers, from the
+    positions of an epoch's batches."""
+    from predictionio_tpu.ops.attention import first_key_blocks
+
+    for kind, held, window in (
+            ("window", "swa" in cfg.kinds, cfg.sliding_window),
+            ("full", bool({"full", "cross"} & set(cfg.kinds)), None)):
+        if not held:
+            continue
+        visited = causal = 0
+        for _, _, pos in batches:
+            lo = first_key_blocks(pos, cfg.attention_block, window, np)
+            upto = np.arange(1, lo.shape[1] + 1)
+            visited += int((upto[None, :] - lo).sum())
+            causal += int(lo.shape[0] * upto.sum())
+        ATTN_KEY_BLOCKS.labels(kind=kind, of="visited").set(visited)
+        ATTN_KEY_BLOCKS.labels(kind=kind, of="causal").set(causal)
 
 
 @dataclasses.dataclass
@@ -457,16 +495,24 @@ class SessionRecAlgorithm(Algorithm):
             programs, state, on_device, int(p.epochs),
             bool(cfg.report_blocks))
         TOKENS_TOTAL.inc(real * int(p.epochs))
-        if "kda" in cfg.kinds:
+        for kind, chunk, chunks_of, boundary_of, resets_total in (
+                ("kda", cfg.kda_chunk, KDA_CHUNKS, KDA_BOUNDARY_CHUNKS,
+                 KDA_RESETS_TOTAL),
+                ("mamba", cfg.ssm_chunk, SSM_CHUNKS, SSM_BOUNDARY_CHUNKS,
+                 SSM_RESETS_TOTAL)):
+            if kind not in cfg.kinds:
+                continue
             from predictionio_tpu.ops.kda import chunk_stats
 
             resets = 0
             for n, (_, seg_n, _) in enumerate(batches):
-                chunks, boundary, held = chunk_stats(seg_n, cfg.kda_chunk)
-                KDA_CHUNKS.labels(step=str(n)).set(chunks)
-                KDA_BOUNDARY_CHUNKS.labels(step=str(n)).set(boundary)
+                chunks, boundary, held = chunk_stats(seg_n, chunk)
+                chunks_of.labels(step=str(n)).set(chunks)
+                boundary_of.labels(step=str(n)).set(boundary)
                 resets += held
-            KDA_RESETS_TOTAL.inc(resets * int(p.epochs))
+            resets_total.inc(resets * int(p.epochs))
+        if pack_len > cfg.attention_block:
+            _count_key_blocks(cfg, batches)
         with span("sessionrec.readback"):
             params = jax.device_get({**state["params"], **state["buffers"]})
             first, metrics, reported = jax.device_get(

@@ -98,6 +98,83 @@ def test_a_sequence_one_block_holds_takes_the_dense_path():
         segment_attention(q, q, q, seg, pos, block=48)
 
 
+def windowed_dense(q, k, v, seg, window):
+    """Dense attention with the mask written out: s <= t, the same
+    history, t - s < window."""
+    t = jnp.arange(q.shape[2])
+    mask = ((t[None, :] <= t[:, None])[None]
+            & (seg[:, :, None] == seg[:, None, :]))
+    if window is not None:
+        mask = mask & (t[:, None] - t[None, :] < window)[None]
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(q.shape[-1])
+    p = jax.nn.softmax(jnp.where(mask[:, None], s, -jnp.inf), axis=-1)
+    return jnp.einsum("bhqk,bhkd->bhqd", p, v)
+
+
+# against blocks of 16: no window, smaller than a block, a block, several
+@pytest.mark.parametrize("what", ["forward", "dq", "dk", "dv"])
+@pytest.mark.parametrize("window", [None, 5, 16, 40])
+def test_windowed_blockwise_attention_equals_the_mask_written_out(window,
+                                                                  what):
+    rng = np.random.default_rng(7)
+    q, k = (jnp.asarray(rng.standard_normal((2, 3, 64, 12)), jnp.float32)
+            for _ in range(2))
+    v = jnp.asarray(rng.standard_normal((2, 3, 64, 8)), jnp.float32)
+    _, seg, pos = packed()
+
+    def blockwise(q, k, v):
+        return segment_attention(q, k, v, seg, pos, block=16, window=window)
+
+    def dense(q, k, v):
+        return windowed_dense(q, k, v, seg, window)
+
+    if what == "forward":
+        close(blockwise(q, k, v), dense(q, k, v))
+        # one block holds the sequence: `dense_attention`'s own window
+        return close(segment_attention(q, k, v, seg, pos, block=64,
+                                       window=window), dense(q, k, v))
+    at = ("dq", "dk", "dv").index(what)
+    weight = jnp.asarray(rng.standard_normal((2, 3, 64, 8)), jnp.float32)
+    close(jax.grad(lambda *a: (blockwise(*a) * weight).sum(), at)(q, k, v),
+          jax.grad(lambda *a: (dense(*a) * weight).sum(), at)(q, k, v))
+
+
+def test_a_window_skips_the_key_blocks_it_leaves_out():
+    from predictionio_tpu.ops.attention import first_key_blocks
+
+    pos = np.arange(128)[None, :]                  # one history, 8 blocks
+    assert first_key_blocks(pos, 16, None, np).tolist() == [[0] * 8]
+    assert first_key_blocks(pos, 16, 16, np).tolist() == [
+        [0, 0, 1, 2, 3, 4, 5, 6]]
+    assert first_key_blocks(pos, 16, 1, np).tolist() == [list(range(8))]
+    assert first_key_blocks(pos, 16, 40, np).tolist() == [
+        [0, 0, 0, 0, 1, 2, 3, 4]]
+    # a history that starts at token 70 holds the window's block back
+    pos = np.concatenate([np.arange(70), np.arange(58)])[None, :]
+    assert first_key_blocks(pos, 16, 40, np).tolist() == [
+        [0, 0, 0, 0, 1, 4, 4, 4]]
+
+
+def test_without_a_window_the_traced_program_is_what_it_was():
+    """The jaxpr of forward and backward at `window=None`, as the parent
+    of the PR that brought the window traced it (sha256 of its text;
+    jax 0.9.0): the two accepted encoder cells run this function."""
+    import hashlib
+
+    q = jax.ShapeDtypeStruct((2, 3, 64, 8), jnp.float32)
+    v = jax.ShapeDtypeStruct((2, 3, 64, 4), jnp.float32)
+    s = jax.ShapeDtypeStruct((2, 64), jnp.int32)
+
+    def loss(q, k, v, seg, pos):
+        return jnp.sum(segment_attention(q, k, v, seg, pos, block=16,
+                                         scope="x") ** 2)
+
+    text = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(
+        q, q, v, s, s))
+    assert hashlib.sha256(text.encode()).hexdigest() == (
+        "98165cdb154b310e6c595de8ac29747726d32e67242424248b74b448629af488")
+
+
 # -- the system against the reference, piece by piece --------------------------
 
 def test_mla_equals_the_reference(params):

@@ -212,6 +212,23 @@ def test_the_convolution_reads_zero_before_a_historys_first_token(what):
             at += ln
 
 
+def test_the_convolution_adds_a_bias_where_one_is_given():
+    """Mamba's: a bias a channel on every token, a history's first too;
+    without one (the KDA layers) the traced program is what it was."""
+    rng = np.random.default_rng(1)
+    x = jnp.asarray(rng.standard_normal((2, 40, 5)), jnp.float32)
+    w = jnp.asarray(rng.standard_normal((4, 5)), jnp.float32)
+    bias = jnp.asarray(rng.standard_normal(5), jnp.float32)
+    seg = segments(40, [[1, 2, 3, 20, 14], [40]])
+    plain = kda.causal_conv(x, w, seg)
+    close(kda.causal_conv(x, w, seg, bias), plain + bias, 1e-6)
+    g = jax.grad(lambda b: jnp.sum(kda.causal_conv(x, w, seg, b)))(bias)
+    close(g, np.full(5, 80.0), 1e-6)
+    assert str(jax.make_jaxpr(lambda x, w: kda.causal_conv(x, w, seg))(
+        x, w)) == str(jax.make_jaxpr(
+            lambda x, w: kda.causal_conv(x, w, seg, None))(x, w))
+
+
 def test_the_chunk_statistics():
     seg = np.asarray(segments(150, [HISTORIES, [100]]))
     # row 0: chunks of 64 at 0, 64, 128: starts in the first two; row 1:

@@ -284,10 +284,113 @@ class TestTheEncoderWithKdaLayers:
             assert abs(value - want[model.item_ids.get(item)]) < 2e-4
 
 
+@pytest.fixture(scope="module")
+def trained_sambay(tmp_path_factory):
+    """A model trained from a configuration FILE in Phi-4-mini-flash's
+    key names: layers 3..7 of 8 (windowed attention, Mamba, full
+    attention, a gated memory unit, cross-attention), LayerNorm, a tied
+    head; packed sequences of two attention blocks."""
+    path = tmp_path_factory.mktemp("enc") / "small-sambay.json"
+    path.write_text(json.dumps({
+        "model_type": "phi4flash", "hidden_size": 16,
+        "intermediate_size": 24, "num_hidden_layers": 5,
+        "num_attention_heads": 4, "num_key_value_heads": 2,
+        "mb_per_layer": 2, "sliding_window": 6, "layer_norm_eps": 1e-5,
+        "tie_word_embeddings": True, "mamba_d_state": 4,
+        "share": {"layer_first": 3, "layers_total": 8},
+        "train": {"pack_len": 16, "seqs_per_step": 2, "attention_block": 8,
+                  "loss_chunk": 16, "init_std": 0.2, "ssm_chunk": 8,
+                  "ssm_channels": 16,
+                  "report_blocks": [
+                      {"name": "a_log", "leaf": "dense.1.mamba.a_log"},
+                      {"name": "lambda_q1", "leaf": "dense.4.cross.lambda_q1"},
+                      {"name": "emb", "leaf": "emb"}]}}))
+    algo = SessionRecAlgorithm(params_from_dict(
+        SessionRecAlgorithm.params_class,
+        {"maxSeqLen": 16, "epochs": 2, "stepSize": 0.01,
+         "encoderConfig": str(path)}))
+    from predictionio_tpu.telemetry import spans
+    from predictionio_tpu.telemetry.registry import REGISTRY
+
+    before = REGISTRY.get("encoder_ssm_resets_total").value
+    tl, token = spans.begin("test", "train", "RUN", "t-2")
+    try:
+        model = algo.train(WorkflowContext(seed=5), _prepared())
+    finally:
+        spans.finish(tl, token, status=None, duration_s=0.0)
+    return (algo, model,
+            REGISTRY.get("encoder_ssm_resets_total").value - before,
+            [name for name, *_ in tl.spans])
+
+
+class TestTheEncoderAsADecoderHybridDecoder:
+    def test_train_reports_the_new_blocks_and_builds_the_model(
+            self, trained_sambay):
+        _, model, _, _ = trained_sambay
+        assert tuple(model.encoder["layer_kinds"]) == (
+            "swa", "mamba", "full", "gmu", "cross")
+        assert "head" not in model.params
+        report = model.train_report
+        assert report["params"]["a_log"].shape == (32, 4)
+        assert report["params"]["lambda_q1"].shape == (4,)
+        assert all(np.abs(g).max() > 0 for g in report["grads"].values())
+        assert set(model.session_vecs) == set(model.user_windows)
+        assert np.isfinite(model.params["dense"][1]["mamba"]["w_out"]).all()
+
+    def test_the_gauges_and_the_counters_say_what_the_step_held(
+            self, trained_sambay):
+        from predictionio_tpu.telemetry.registry import REGISTRY
+
+        _, _, resets, names = trained_sambay
+        assert resets == 2 * 12  # 12 users' histories in each of two epochs
+        chunks = dict(REGISTRY.get("encoder_ssm_chunks").collect())
+        boundary = dict(REGISTRY.get("encoder_ssm_boundary_chunks").collect())
+        assert chunks and set(boundary) == set(chunks)
+        for step in chunks:  # a step: 2 sequences of 16 in chunks of 8
+            assert chunks[step] == 4 and 1 <= boundary[step] <= 4
+        blocks = dict(REGISTRY.get("encoder_attn_key_blocks").collect())
+        assert {kind for kind, _ in blocks} == {"window", "full"}
+        causal = {v for (_, of), v in blocks.items() if of == "causal"}
+        # sequences of two blocks: 3 visits each under a causal mask alone
+        assert len(causal) == 1 and causal.pop() % 3 == 0
+        assert all(v <= max(blocks.values()) for v in blocks.values())
+        assert "enc.ssm.scan.jnp" in names
+
+    @pytest.mark.parametrize("history", [["i3"], ["i3", "i7"],
+                                         ["i1", "i4", "i2", "i9", "i5"]])
+    def test_queries_equal_the_reference_scorer(self, trained_sambay,
+                                                history):
+        from predictionio_tpu.quality import encoder_reference as ref
+
+        algo, model, _, _ = trained_sambay
+        single = algo.predict(model, {"items": history, "num": 20})
+        want = np.asarray(ref.score(
+            model.params, sessionrec._config_of(model),
+            np.asarray(model.window_rows(history), np.int32)))
+        got = {s["item"]: s["score"] for s in single["itemScores"]}
+        assert len(got) == 20 - len(set(history))
+        for item, value in got.items():
+            assert abs(value - want[model.item_ids.get(item)]) < 2e-4
+
+
 def test_the_benchmarks_reference_is_a_copy_of_the_packages():
+    """The newest copy (`perf/reference/phi4_flash.py`) is held equal in
+    `tests/test_encoder_sambay.py`. The Kimi cell's copy is the
+    package's reference as PR 32 left it, and the benchmark's file: the
+    package's still defines every function it has, with the arguments
+    it has, in their order."""
+    import ast
+
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "predictionio_tpu", "quality",
-                           "encoder_reference.py")) as f, \
-            open(os.path.join(root, "perf", "reference",
-                              "kimi_linear.py")) as g:
-        assert f.read() == g.read()
+
+    def signatures(*parts):
+        with open(os.path.join(root, *parts)) as f:
+            return {node.name: [a.arg for a in node.args.args]
+                    for node in ast.parse(f.read()).body
+                    if isinstance(node, ast.FunctionDef)}
+
+    package = signatures("predictionio_tpu", "quality",
+                         "encoder_reference.py")
+    for name, args in signatures("perf", "reference",
+                                 "kimi_linear.py").items():
+        assert package[name][:len(args)] == args, name

@@ -143,3 +143,33 @@ def test_a_scan_the_kernels_decline_takes_the_jnp_path(one_chip,
     plain `jax.numpy` scan, on a TPU too."""
     text = _kda_pass(one_chip, monkeypatch, 64).as_text()
     assert "tpu_custom_call" not in text and "enc.kda.scan" in text
+
+
+def test_the_decoder_hybrid_decoders_step_compiles_for_v5e_at_the_cells_size(
+        one_chip):
+    """The whole train step of `phi4flash.fit8_pack8k` (2 x 8192 tokens,
+    the published widths, 577 M parameters with their gradients and
+    Adam moments in float32): the chip's compiler refuses a program
+    that does not fit its 15.75 GiB, and every scope the benchmark's
+    readers look for is in what it built."""
+    from predictionio_tpu.models import encoder as enc
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    cfg = enc.EncoderConfig.from_json(os.path.join(
+        root, "perf", "configs", "phi4_mini_flash_1of8.json"))
+    on_chip = lambda s: jax.ShapeDtypeStruct(  # noqa: E731
+        s.shape, s.dtype, sharding=one_chip)
+    state = jax.tree_util.tree_map(on_chip, jax.eval_shape(
+        lambda k: enc.init_state(cfg, cfg.vocab_size, k), jax.random.key(0)))
+    batch = jax.ShapeDtypeStruct((cfg.seqs_per_step, cfg.pack_len),
+                                 jnp.int32, sharding=one_chip)
+    compiled = jax.jit(enc.train_step(cfg, 1e-5), donate_argnums=(0,)).lower(
+        state, batch, batch, batch).compile()
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes > 12 * 577_199_232
+    assert memory.peak_memory_in_bytes < 15.75 * 2 ** 30
+    text = compiled.as_text()
+    for scope in ("enc.mamba.scan", "enc.mamba.conv", "enc.mamba.dt",
+                  "enc.attn.pairs", "enc.attn.subln", "enc.cross.pairs",
+                  "enc.gmu", "enc.dense_ffn", "enc.head_loss", "enc.adam"):
+        assert scope in text, scope
