@@ -24,13 +24,16 @@ from __future__ import annotations
 
 import functools
 import math
+import time
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from predictionio_tpu.ops import pallas_attention
 from predictionio_tpu.parallel.mesh import DATA_AXIS
+from predictionio_tpu.telemetry.spans import record as record_span
 
 _NEG_INF = -1e30  # large-negative instead of -inf: keeps fully-masked rows
 # (causal ring blocks entirely in the future) NaN-free after softmax
@@ -73,7 +76,11 @@ def dense_attention(q, k, v, causal: bool = False, segment_ids=None,
 # query block's first token, where that is the later one.
 # The score matrix of a block pair is all that is ever held; the backward
 # pass recomputes it from the saved log-sum-exp (the flash-attention
-# recurrence, in plain jax.numpy: no kernel).
+# recurrence). Two paths, one `segment_attention`, which decides from what
+# it can observe: on a TPU, at shapes `pallas_attention.applicable` admits,
+# the kernels of `ops/pallas_attention.py` (a pair's scores and the running
+# accumulators stay in VMEM, at the kernel's own tile); else the plain
+# jax.numpy below, which is the CPU's path and the kernels' oracle.
 
 def _slab(x, start, size, axis=1):
     return jax.lax.dynamic_slice_in_dim(x, start, size, axis)
@@ -216,7 +223,12 @@ def segment_attention(q, k, v, segment_ids, positions, block: int = 512,
     those of the backward pass too, are traced under `scope`. With a
     `window` a query at t sees the keys s of its history with t - s <
     window, and a query block skips the key blocks the window leaves
-    out. Returns [B, H, L, Dv] in float32."""
+    out. Returns [B, H, L, Dv] in float32. The kernels tell a history
+    by `positions` alone (its keys are those from t - positions[t] on,
+    what `first_key_blocks` rests on too). Which path was built above
+    one block is counted in `encoder_segment_attention_calls_total{path}`
+    and left in the timeline as `enc.attention.<path>` (the host seconds
+    spent building it)."""
     scale = 1.0 / math.sqrt(q.shape[-1]) if scale is None else scale
     l = q.shape[2]
     if l <= block:
@@ -225,9 +237,22 @@ def segment_attention(q, k, v, segment_ids, positions, block: int = 512,
                                window=window)
     if l % block:
         raise ValueError(f"sequence {l} is not a multiple of block {block}")
-    kv_lo = first_key_blocks(positions, block, window)
-    return _segment_attention(q, k, v, segment_ids, kv_lo, block, scale,
-                              scope, window)
+    t0 = time.monotonic()
+    path = ("kernel" if jax.default_backend() == "tpu"
+            and pallas_attention.applicable(l, q.shape[-1], v.shape[-1],
+                                            q.dtype.itemsize)
+            else "jnp")
+    pallas_attention.SEGMENT_CALLS.labels(path=path).inc()
+    if path == "kernel":
+        with jax.named_scope(scope):
+            o = pallas_attention.segment_pairs(q, k, v, positions, scale,
+                                               scope, window)
+    else:
+        kv_lo = first_key_blocks(positions, block, window)
+        o = _segment_attention(q, k, v, segment_ids, kv_lo, block, scale,
+                               scope, window)
+    record_span(f"enc.attention.{path}", time.monotonic() - t0)
+    return o
 
 
 def _online_fold(o, m, l, s, v_blk):

@@ -145,6 +145,44 @@ def test_a_scan_the_kernels_decline_takes_the_jnp_path(one_chip,
     assert "tpu_custom_call" not in text and "enc.kda.scan" in text
 
 
+# the three encoder cells' attentions: joyai.fit8_pack8k's and
+# kimi_linear.fit8_pack8k's MLA heads, phi4flash.fit8_pack8k's stacked
+# differential heads with its window and without
+@pytest.mark.parametrize("h,dk,window", [(32, 192, None), (40, 64, 512),
+                                         (40, 64, None)])
+def test_segment_attention_compiles_for_v5e_at_the_cells_sizes(
+        one_chip, monkeypatch, h, dk, window):
+    """Two kernels (`ops/pallas_attention.py`), forward and backward, at
+    2 x 8192 tokens with bfloat16 operands: a head's keys, values and
+    float32 gradient accumulators whole in VMEM. Both stand under the
+    scope the benchmark's readers book attention to, the backward one
+    too, which is traced outside the caller's scopes. The backend here
+    is the CPU, so the test says "tpu" where `segment_attention` asks."""
+    from predictionio_tpu.ops import attention
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    shape = lambda d, dt=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        (2, h, 8192, d), dt, sharding=one_chip)
+    ids = jax.ShapeDtypeStruct((2, 8192), jnp.int32, sharding=one_chip)
+
+    def loss(q, k, v, seg, pos):
+        return jnp.sum(attention.segment_attention(
+            q, k, v, seg, pos, block=512, scale=dk ** -0.5,
+            scope="enc.attn.pairs", window=window))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        shape(dk), shape(dk), shape(128), ids, ids).compile()
+    calls = [line for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 2
+    assert all("enc.attn.pairs" in line for line in calls)
+    assert sum("segment_attention_fwd" in line for line in calls) == 1
+    assert sum("segment_attention_bwd" in line for line in calls) == 1
+    # the output, its cotangent in the operands' dtype, log-sum-exp and
+    # delta: no [H, L, L] and no score tile goes through HBM
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.25 * 2 ** 30
+
+
 def test_the_decoder_hybrid_decoders_step_compiles_for_v5e_at_the_cells_size(
         one_chip):
     """The whole train step of `phi4flash.fit8_pack8k` (2 x 8192 tokens,
