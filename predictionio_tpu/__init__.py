@@ -23,6 +23,10 @@ Heavy deps (jax) are imported lazily by the modules that need them, so the
 storage/event layers remain usable in processes that never touch a device.
 """
 
+import time as _time
+
+_T_IMPORT = _time.perf_counter()
+
 __version__ = "0.1.0"
 
 import os as _os
@@ -36,3 +40,7 @@ if _os.environ.get("PIO_LOCKSAN"):
 from predictionio_tpu.data.events import Event  # noqa: F401
 from predictionio_tpu.data.datamap import DataMap, PropertyMap  # noqa: F401
 from predictionio_tpu.data.bimap import BiMap  # noqa: F401
+
+# first line to last on `time.perf_counter()`: the compile log's first
+# `process.import` record, once telemetry/device.py is imported
+IMPORT_SPAN = (_T_IMPORT, _time.perf_counter())

@@ -11,7 +11,10 @@ from __future__ import annotations
 
 import contextlib
 import logging
+import sys
 from typing import TYPE_CHECKING, Any, Optional
+
+from predictionio_tpu.telemetry import device as device_telemetry
 
 if TYPE_CHECKING:
     import jax
@@ -48,6 +51,11 @@ class WorkflowContext:
         metrics: a `utils.profiling.MetricsLogger` for per-epoch metric
             emission (default: log-only).
         """
+        # where the caller brought jax in itself (a library's, the
+        # benchmark's), programs are built from here on: the compile log
+        # listens before the first
+        if "jax" in sys.modules:
+            device_telemetry.listen()
         self.mesh_shape = mesh_shape
         self.seed = seed
         self.batch = batch

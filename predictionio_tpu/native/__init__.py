@@ -16,6 +16,7 @@ import logging
 import os
 import subprocess
 import threading
+import time
 from typing import NamedTuple, Optional
 
 import numpy as np
@@ -68,6 +69,12 @@ def _compile() -> Optional[str]:
     return so_path
 
 
+def _log_load(t0: float, what: str) -> None:
+    from predictionio_tpu.telemetry.device import COMPILE_LOG
+
+    COMPILE_LOG.add("native.load", what, t0, time.perf_counter())
+
+
 def get_lib() -> Optional[ctypes.CDLL]:
     """The loaded native library, or None (disabled / no toolchain)."""
     global _lib, _lib_failed
@@ -76,16 +83,22 @@ def get_lib() -> Optional[ctypes.CDLL]:
     with _lock:
         if _lib is not None or _lib_failed:
             return _lib
+        # once a process: the sources hashed, the library found or built,
+        # then loaded; `native.load` among the process's first seconds
+        t0 = time.perf_counter()
         so_path = _compile()
         if so_path is None:
             _lib_failed = True
+            _log_load(t0, "unavailable")
             return None
         try:
             lib = ctypes.CDLL(so_path)
         except OSError as e:
             log.warning("native: cannot load %s: %s", so_path, e)
             _lib_failed = True
+            _log_load(t0, "unavailable")
             return None
+        _log_load(t0, os.path.basename(so_path))
         i64, i32p, i64p, f32p = (ctypes.c_int64,
                                  np.ctypeslib.ndpointer(np.int32),
                                  np.ctypeslib.ndpointer(np.int64),

@@ -27,6 +27,11 @@ every dispatch:
   `device_mem_*` high-water gauges plus the headroom burn-rate alert in
   `telemetry/alerts.py`.
 
+Beside them, the compile log (`COMPILE_LOG`): what a program's first call
+pays before it runs, phase by phase, from JAX's own monitoring events
+(`listen()`), and the process's first seconds (imports, the native library,
+the backend's start) as spans of the same list.
+
 Lazy-import discipline: this module never imports jax at module level
 and only touches it when ``"jax" in sys.modules`` — event servers and
 gate drills stay jax-free.
@@ -35,13 +40,15 @@ gate drills stay jax-free.
 from __future__ import annotations
 
 import collections
+import contextlib
 import logging
 import os
 import queue
 import sys
 import threading
 import time
-from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
+from typing import (Any, Deque, Dict, Iterable, List, NamedTuple, Optional,
+                    Sequence, Tuple)
 
 from predictionio_tpu.telemetry import tenant
 from predictionio_tpu.telemetry.registry import REGISTRY, capped_label
@@ -88,6 +95,21 @@ JIT_RETRACES = REGISTRY.counter(
     "/debug/jit.json naming the argument/dimension that changed",
     labelnames=("fn",))
 
+JIT_PHASE_SECONDS = REGISTRY.counter(
+    "jit_phase_seconds_total",
+    "Host seconds spent building jitted programs, by phase (trace, lower, "
+    "backend_compile, cache_load; from JAX's monitoring events). Nested "
+    "events of one phase count once; cache_load lies inside "
+    "backend_compile",
+    labelnames=("fn", "phase"))
+JIT_CACHE_REQUESTS = REGISTRY.counter(
+    "jit_cache_requests_total",
+    "Programs the backend was asked for, by what JAX's persistent "
+    "compilation cache did: hit (an executable was loaded from it) or "
+    "miss (the backend compiled: not held, too small to be kept, or no "
+    "cache)",
+    labelnames=("result",))
+
 DEVICE_MEM_LIVE = REGISTRY.gauge(
     "device_mem_live_bytes",
     "Live jax buffer bytes per device (device-memory sampler)",
@@ -116,6 +138,9 @@ def _env_flag(name: str, default: str = "1") -> bool:
 # -- dispatch-site attribution -------------------------------------------------
 
 _TLS = threading.local()
+# `.fn`: the `metered_jit` label whose call is in flight on this thread
+# (the wrapper stores it round the call; the compile log reads it)
+IN_FLIGHT = _TLS
 
 
 class Attribution:
@@ -153,6 +178,206 @@ def attribution(route: str, tier: str = "") -> Attribution:
 
 def current_attribution() -> Optional[Attribution]:
     return getattr(_TLS, "att", None)
+
+
+# -- the compile log -----------------------------------------------------------
+
+JIT_PHASES = ("trace", "lower", "backend_compile", "cache_load")
+# The log's cap: one big program is a few thousand records (every inner
+# jit traced under it is one); past the cap the oldest go, counted.
+MAX_COMPILE_RECORDS = 16384
+# A record counts as lying inside a later one when it starts no earlier
+# than this before it: both starts are `arrival - duration`, and an
+# event's arrival trails its end by the listeners' own microseconds.
+_NEST_SLACK_S = 1e-4
+
+_DURATION_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend_compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_load",
+}
+
+
+class CompileRecord(NamedTuple):
+    """One interval of the compile log on `time.perf_counter()`'s clock.
+    `depth` counts the records of the same thread it lies inside;
+    `cache` is set on a `backend_compile` record: `hit` where the
+    persistent cache gave the executable (a `cache_load` record lies
+    inside), `miss` where the backend compiled it (JAX keeps its own miss
+    count for compiles it goes on to store only, so that is not read);
+    `name` is JAX's own name for the function of a compile event (under
+    a label in flight, the jit or kernel body that was traced)."""
+    phase: str
+    fn: str
+    start: float
+    end: float
+    depth: int
+    cache: Optional[str]
+    name: Optional[str]
+
+
+class CompileLog:
+    """What building programs cost this process, as intervals: JAX's
+    compile events (`phase` one of `JIT_PHASES`, `fn` the `metered_jit`
+    label in flight, else JAX's name for the function) and the process's
+    first seconds (`phase` = the span's name: `process.import`,
+    `native.load`, `runtime.backend_init`).
+
+    Events nest: an outer trace holds the traces of every jit under it,
+    a `backend_compile` its `cache_load`, and each arrives as it *ends*.
+    So a sum over records is wrong and every total is a union of
+    intervals (`phase_seconds`). Bounded as `Timeline.MAX_SPANS` is, but
+    from the other end: past `cap` the oldest record goes and is counted,
+    so a long-lived server still holds its latest retrace. In memory
+    only; read by `/debug/jit.json`'s inventory, the registry, the train
+    timeline and the benchmark's reader."""
+
+    def __init__(self, cap: int = MAX_COMPILE_RECORDS):
+        self.cap = cap
+        self.dropped = 0
+        self._lock = threading.Lock()
+        # a CompileRecord's fields and the thread, in order of `end`
+        # within a thread
+        self._records: Deque[list] = collections.deque()
+
+    def add(self, phase: str, fn: str, start: float, end: float,
+            cache: Optional[str] = None,
+            name: Optional[str] = None) -> float:
+        """Keep one record. Returns the seconds of it that no record of
+        the same phase still held covers: what a per-phase counter may
+        add without counting a nested event twice."""
+        thread = threading.get_ident()
+        own = end - start
+        with self._lock:
+            uncovered_to = end
+            for rec in reversed(self._records):
+                if rec[7] != thread:
+                    continue
+                if rec[2] < start - _NEST_SLACK_S:
+                    break   # it, and all of this thread before it, is past
+                rec[4] += 1
+                if rec[0] == phase and rec[3] <= uncovered_to:
+                    own -= rec[3] - rec[2]
+                    uncovered_to = rec[2]
+            self._records.append(
+                [phase, fn, start, end, 0, cache, name, thread])
+            while len(self._records) > self.cap:
+                self._records.popleft()
+                self.dropped += 1
+        return max(0.0, own)
+
+    def records(self, t_lo: float = float("-inf"),
+                t_hi: float = float("inf"),
+                thread: Optional[int] = None) -> List[CompileRecord]:
+        """The records that lie inside [t_lo, t_hi], of one thread or all:
+        what was built in a window, by name and phase."""
+        with self._lock:
+            return [CompileRecord(*rec[:7]) for rec in self._records
+                    if rec[2] >= t_lo and rec[3] <= t_hi
+                    and (thread is None or rec[7] == thread)]
+
+    def clear(self) -> None:
+        with self._lock:
+            self._records.clear()
+            self.dropped = 0
+
+
+def phase_seconds(records: Iterable[CompileRecord]) -> Dict[str, float]:
+    """Seconds by phase: the union of each phase's intervals."""
+    by_phase: Dict[str, List[Tuple[float, float]]] = {}
+    for r in records:
+        by_phase.setdefault(r.phase, []).append((r.start, r.end))
+    out = {}
+    for phase, spans_ in by_phase.items():
+        total, reach = 0.0, float("-inf")
+        for lo, hi in sorted(spans_):
+            if hi > reach:
+                total += hi - max(lo, reach)
+                reach = hi
+        out[phase] = total
+    return out
+
+
+COMPILE_LOG = CompileLog()
+# the package's own import, stamped by its `__init__`'s first and last line
+COMPILE_LOG.add("process.import", "predictionio_tpu",
+                *sys.modules["predictionio_tpu"].IMPORT_SPAN)
+
+
+@contextlib.contextmanager
+def first_seconds(phase: str, what: str):
+    """A span of the process's first seconds in the compile log."""
+    t0 = time.perf_counter()
+    try:
+        yield
+    finally:
+        COMPILE_LOG.add(phase, what, t0, time.perf_counter())
+
+
+_listening = False
+_listen_lock = threading.Lock()
+
+
+def listen() -> None:
+    """Hear JAX's compile and compilation-cache events from here on; once
+    a process, before its first program is built. Called where the
+    program brings jax in (`import_jax`, `pio train`'s entry), where a
+    workflow context is made in a process that holds jax already (a
+    library's caller imported it) and by `metered_jit` as it wraps a
+    function; never from inside a module's import, and the lock is held
+    for the flag alone."""
+    global _listening
+    if _listening:
+        return
+    import jax
+
+    with _listen_lock:
+        if not _listening:
+            jax.monitoring.register_event_duration_secs_listener(
+                _on_duration)
+            _listening = True
+
+
+def import_jax():
+    """`import jax` for a process entry: the import's seconds go into the
+    compile log as `process.import` where this is the process's first,
+    and the listeners are on before anything is built."""
+    if "jax" not in sys.modules:
+        with first_seconds("process.import", "jax"):
+            import jax  # noqa: F401
+    listen()
+    return sys.modules["jax"]
+
+
+def _on_duration(event: str, duration: float, fun_name: Optional[str] = None,
+                 **_) -> None:
+    phase = _DURATION_EVENTS.get(event)
+    if phase is None:
+        return
+    end = time.perf_counter()
+    tls = _TLS
+    if fun_name is None:   # the cache's events name no function
+        fun_name = getattr(tls, "jax_fn", None)
+    else:
+        tls.jax_fn = fun_name
+    if fun_name and fun_name.startswith("jit(") and fun_name.endswith(")"):
+        fun_name = fun_name[4:-1]   # a module's name; a trace gives the bare
+    fn = getattr(tls, "fn", None) or fun_name or "(unnamed)"
+    cache = None
+    if phase == "cache_load":
+        tls.cache_loaded = True   # for the backend_compile event round it
+    elif phase == "backend_compile":
+        cache = "hit" if getattr(tls, "cache_loaded", False) else "miss"
+        tls.cache_loaded = False
+        JIT_CACHE_REQUESTS.labels(result=cache).inc()
+    own = COMPILE_LOG.add(phase, fn, end - float(duration), end, cache,
+                          fun_name)
+    # a label in flight is `metered_jit`'s, capped already; JAX's names
+    # (every eager op has one) get a group of their own, so they cannot
+    # use up the labels' room
+    label = getattr(tls, "fn", None) or capped_label("jit_eager_fn", fn)
+    JIT_PHASE_SECONDS.labels(fn=label, phase=phase).inc(own)
 
 
 # -- abstract signatures -------------------------------------------------------
@@ -241,14 +466,30 @@ def diff_signatures(old: Tuple[str, ...],
 # -- jit-cache inventory -------------------------------------------------------
 
 
+# The compile log's part of an inventory entry: seconds by phase (unions,
+# `cache_load` inside `backend_compile`) and the persistent cache's answers.
+_BUILD_FIELDS = tuple(f"{phase}_seconds" for phase in JIT_PHASES) + (
+    "cache_hits", "cache_misses")
+
+
+def _build_fields(built: Sequence[CompileRecord]) -> Dict[str, float]:
+    seconds = phase_seconds(built)
+    out = {f"{phase}_seconds": seconds.get(phase, 0.0)
+           for phase in JIT_PHASES}
+    out["cache_hits"] = sum(r.cache == "hit" for r in built)
+    out["cache_misses"] = sum(r.cache == "miss" for r in built)
+    return out
+
+
 class _FnInventory:
     __slots__ = ("compiles", "dispatches", "compile_seconds", "retraces",
-                 "evicted", "signatures", "blames")
+                 "evicted", "signatures", "blames", "build")
 
     def __init__(self):
         self.compiles = 0
         self.dispatches = 0
         self.compile_seconds = 0.0
+        self.build = dict.fromkeys(_BUILD_FIELDS, 0)
         self.retraces = 0
         self.evicted = 0
         # sig tuple -> {"compiles","dispatches","compile_seconds",
@@ -280,7 +521,9 @@ def _nearest_signature(entry: _FnInventory,
 
 
 def _record_inventory(fn: str, sig: Tuple[str, ...], compiled: bool,
-                      compile_s: float, now: float) -> None:
+                      compile_s: float, now: float,
+                      built: Sequence[CompileRecord] = ()) -> None:
+    build = _build_fields(built) if compiled else None
     with _inventory_lock:
         entry = _INVENTORY.get(fn)
         if entry is None:
@@ -290,6 +533,8 @@ def _record_inventory(fn: str, sig: Tuple[str, ...], compiled: bool,
         if compiled:
             entry.compiles += 1
             entry.compile_seconds += compile_s
+            for key, value in build.items():
+                entry.build[key] += value
             if sig not in entry.signatures and entry.signatures:
                 # Warm function recompiled: a retrace. Name the culprit.
                 entry.retraces += 1
@@ -300,13 +545,13 @@ def _record_inventory(fn: str, sig: Tuple[str, ...], compiled: bool,
                     "against": list(nearest) if nearest else None,
                     "changed": (diff_signatures(nearest, sig)
                                 if nearest else []),
-                    "compile_seconds": round(compile_s, 6),
                 }
                 entry.blames.append(blame)
         rec = entry.signatures.pop(sig, None)
         if rec is None:
             rec = {"compiles": 0, "dispatches": 0, "compile_seconds": 0.0,
-                   "first_seen": now, "last_used": now}
+                   "first_seen": now, "last_used": now,
+                   **dict.fromkeys(_BUILD_FIELDS, 0)}
             while len(entry.signatures) >= MAX_SIGNATURES_PER_FN:
                 entry.signatures.popitem(last=False)
                 entry.evicted += 1
@@ -315,6 +560,8 @@ def _record_inventory(fn: str, sig: Tuple[str, ...], compiled: bool,
         if compiled:
             rec["compiles"] += 1
             rec["compile_seconds"] += compile_s
+            for key, value in build.items():
+                rec[key] += value
         entry.signatures[sig] = rec    # (re-)insert at MRU end
     if blame is not None:
         JIT_RETRACES.labels(fn=fn).inc()
@@ -505,14 +752,17 @@ def record_dispatch(fn: str, args: Sequence[Any] = (),
                     kwargs: Optional[Dict[str, Any]] = None,
                     out: Any = None, t0: float = 0.0,
                     t1: Optional[float] = None, compiled: bool = False,
-                    compile_s: float = 0.0) -> None:
+                    compile_s: float = 0.0,
+                    built: Sequence[CompileRecord] = ()) -> None:
     """The single entry point `utils/profiling.metered_jit` calls per
     dispatch: updates the jit-cache inventory, books route/tier
-    attribution, and hands the output to the device clock."""
+    attribution, and hands the output to the device clock. `compile_s`
+    is the whole wall of a call that compiled, `built` the compile log's
+    records of that call: the phases the wall is made of."""
     t1 = time.perf_counter() if t1 is None else t1
     now = time.time()
     _record_inventory(fn, signature_of(args, kwargs), compiled, compile_s,
-                      now)
+                      now, built)
     att = current_attribution()
     if att is not None:
         route, tier = att.route, att.tier
@@ -549,6 +799,7 @@ def jit_payload() -> Tuple[int, Dict]:
                  "compiles": rec["compiles"],
                  "dispatches": rec["dispatches"],
                  "compile_seconds": round(rec["compile_seconds"], 6),
+                 **{key: round(rec[key], 6) for key in _BUILD_FIELDS},
                  "first_seen": rec["first_seen"],
                  "last_used": rec["last_used"]}
                 for sig, rec in entry.signatures.items()]
@@ -557,6 +808,8 @@ def jit_payload() -> Tuple[int, Dict]:
                 "compiles_total": entry.compiles,
                 "dispatches_total": entry.dispatches,
                 "compile_seconds_total": round(entry.compile_seconds, 6),
+                **{key: round(entry.build[key], 6)
+                   for key in _BUILD_FIELDS},
                 "retraces_total": entry.retraces,
                 "evicted_signatures": entry.evicted,
                 "signatures": sigs,
@@ -847,12 +1100,17 @@ def reset_state() -> None:
 
 def _reinit_after_fork() -> None:
     global _inventory_lock, _attr_lock, _sampler_lock, _backend_name
+    global _listen_lock
     _inventory_lock = threading.Lock()
     _attr_lock = threading.Lock()
     _sampler_lock = threading.Lock()
+    _listen_lock = threading.Lock()
     _backend_name = None
     _INVENTORY.clear()
     _ATTR_TOTALS.clear()
+    # the parent's first seconds and compiles are not the child's
+    COMPILE_LOG._lock = threading.Lock()
+    COMPILE_LOG.clear()
     clock_was_running = CLOCK._running
     CLOCK._lock = threading.Lock()
     CLOCK._queue = queue.Queue(maxsize=CLOCK._queue.maxsize)

@@ -23,6 +23,7 @@ import functools
 import json
 import logging
 import os
+import threading
 import time
 from typing import Any, Optional, TextIO
 
@@ -37,6 +38,9 @@ JIT_COMPILES = REGISTRY.counter(
     "XLA compiles observed per jitted function (a climbing counter at "
     "steady state is a recompile storm — look for unstable shapes)",
     labelnames=("fn",))
+# compile-log phase -> the timeline's span inside `jit.compile.<fn>`
+_PHASE_SPANS = {"trace": "jit.trace", "lower": "jit.lower",
+                "backend_compile": "jit.backend"}
 JIT_COMPILE_SECONDS = REGISTRY.histogram(
     "jit_compile_seconds",
     "Wall time of calls that included a trace+compile, per jitted function",
@@ -59,6 +63,14 @@ def metered_jit(fn, label: Optional[str] = None, **jit_kwargs):
     flight recorder names its cause instead of looking like a slow
     dispatch.
 
+    What that wall is made of comes from JAX's own events
+    (telemetry/device.py::COMPILE_LOG, listening from the first wrap at
+    the latest): the wrapper names the call in flight on its thread, so
+    every event under it is booked to this label, and a compiled call's
+    outermost phases land inside
+    `jit.compile.<label>` as `jit.trace.<label>`, `jit.lower.<label>`
+    and `jit.backend.<label>` (a cache load or a compile).
+
     Every dispatch also feeds the device plane
     (telemetry/device.py): the jit-cache inventory behind
     /debug/jit.json (per-signature compile/dispatch counts, retrace
@@ -74,6 +86,7 @@ def metered_jit(fn, label: Optional[str] = None, **jit_kwargs):
     copy of the wrapper."""
     import jax
 
+    device_telemetry.listen()
     # the wrapper itself is the metering boundary
     jitted = jax.jit(fn, **jit_kwargs)  # pio-lint: disable=coverage-jit-metering
     name = capped_label("jit_fn", label or getattr(fn, "__name__", "jit"))
@@ -81,25 +94,47 @@ def metered_jit(fn, label: Optional[str] = None, **jit_kwargs):
     seconds = JIT_COMPILE_SECONDS.labels(fn=name)
     cache_size = jitted._cache_size
     span_name = f"jit.compile.{name}"
+    in_flight = device_telemetry.IN_FLIGHT
 
     @functools.wraps(fn)
     def wrapper(*args, **kwargs):
         before = cache_size()
+        # the label the compile log books this thread's events to; an
+        # enclosing call's label (this one traced under it) comes back
+        outer = getattr(in_flight, "fn", None)
+        in_flight.fn = name
         t0 = time.perf_counter()
-        out = jitted(*args, **kwargs)
-        t1 = time.perf_counter()
+        try:
+            out = jitted(*args, **kwargs)
+        finally:
+            t1 = time.perf_counter()
+            in_flight.fn = outer
         compiled = cache_size() > before
         elapsed = t1 - t0
+        built = ()
         if compiled:
             compiles.inc()
             seconds.observe(elapsed)
             spans.record(span_name, elapsed)
+            built = device_telemetry.COMPILE_LOG.records(
+                t0, t1, thread=threading.get_ident())
+            tl = spans.current()
+            if tl is not None:
+                # the log's stamps are perf_counter's, the timeline's
+                # offsets monotonic's from its t0
+                shift = time.monotonic() - tl.t0 - time.perf_counter()
+                for r in built:
+                    if r.depth == 0 and r.phase in _PHASE_SPANS:
+                        tl.record(f"{_PHASE_SPANS[r.phase]}.{name}",
+                                  r.start + shift, r.end - r.start,
+                                  nested=True)
             log.info("profiling: %s compiled (cache %d -> %d, %.3fs)",
                      name, before, cache_size(), elapsed)
         try:
             device_telemetry.record_dispatch(
                 name, args, kwargs, out=out, t0=t0, t1=t1,
-                compiled=compiled, compile_s=elapsed if compiled else 0.0)
+                compiled=compiled, compile_s=elapsed if compiled else 0.0,
+                built=built)
         except Exception:  # noqa: BLE001 — telemetry must not fail dispatch
             log.debug("profiling: device record failed for %s", name,
                       exc_info=True)

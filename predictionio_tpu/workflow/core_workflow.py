@@ -88,12 +88,17 @@ class CoreWorkflow:
         The persisting rank is `PIO_PERSIST_RANK` (default 0), which may
         differ from the coordinator (always process 0 in jax) — see
         parallel/distributed.py::persist_rank."""
-        import jax
+        jax = device_telemetry.import_jax()
 
         from predictionio_tpu.parallel.distributed import persist_rank
 
-        p_rank = persist_rank() if jax.process_count() > 1 else 0
-        if jax.process_count() > 1 and jax.process_index() != p_rank:
+        # the train route's first question to the runtime: the backend
+        # starts here, and the compile log keeps the seconds
+        with device_telemetry.first_seconds("runtime.backend_init",
+                                            "workflow.train"):
+            n_processes = jax.process_count()
+        p_rank = persist_rank() if n_processes > 1 else 0
+        if n_processes > 1 and jax.process_index() != p_rank:
             with device_telemetry.attribution("workflow.train",
                                               tier="train"):
                 models = engine.train(ctx, engine_params,
@@ -171,7 +176,11 @@ class CoreWorkflow:
             for name, _start, seconds, _error, _nested in tl.spans:
                 name = name.partition(" ")[0]
                 phases[name] = phases.get(name, 0.0) + seconds
-            ctx.metrics.emit("train/phases", **phases,
+            # and what the process paid before the timeline opened
+            first_seconds = device_telemetry.phase_seconds(
+                r for r in device_telemetry.COMPILE_LOG.records()
+                if r.phase not in device_telemetry.JIT_PHASES)
+            ctx.metrics.emit("train/phases", **phases, **first_seconds,
                              dropped_spans=tl.dropped_spans)
         return instance
 

@@ -13,6 +13,7 @@ import logging
 from typing import Optional
 
 from predictionio_tpu.controller.context import WorkflowContext
+from predictionio_tpu.telemetry import device as device_telemetry
 from predictionio_tpu.workflow.core_workflow import CoreWorkflow
 from predictionio_tpu.workflow.workflow_utils import (
     EngineVariant,
@@ -53,6 +54,9 @@ def run_train(
     debug_nans: bool = False,
     check_asserts: bool = False,
 ):
+    # the train route brings jax in here: timed, and the compile log
+    # listening before the first program
+    device_telemetry.import_jax()
     from predictionio_tpu.parallel.distributed import initialize_from_env
     from predictionio_tpu.utils.profiling import (
         MetricsLogger,
@@ -63,7 +67,9 @@ def run_train(
     initialize_from_env()  # multi-host bootstrap when PIO_COORDINATOR_* set
     set_debug_flags(nan_check=debug_nans, check_asserts=check_asserts)
     variant = read_engine_json(engine_json)
-    engine = get_engine(variant.engine_factory)
+    with device_telemetry.first_seconds("process.import",
+                                        variant.engine_factory):
+        engine = get_engine(variant.engine_factory)
     engine_params = extract_engine_params(engine, variant)
     with MetricsLogger(metrics_file, run=batch or variant.id) as metrics:
         ctx = WorkflowContext(

@@ -248,6 +248,38 @@ def test_run_train_writes_one_train_phases_record(memory_storage, ecommerce,
     assert [x["step"] for x in records if x["stage"] == "train/als"] == [1, 2]
 
 
+def test_run_train_writes_the_process_first_seconds_beside_the_phases(
+        memory_storage, ecommerce, tmp_path):
+    """From the compile log: what the process paid before the timeline
+    opened, and the phases of a first call inside its `jit.compile`."""
+    from predictionio_tpu.ops import als
+    from predictionio_tpu.telemetry import device
+
+    als._get_train_loop.cache_clear()   # the loop is built in this train
+    device.COMPILE_LOG.clear()   # a long test process may have filled it
+    device.COMPILE_LOG.add("process.import", "an.import", 1.0, 1.25)
+    engine, ep, variant = ecommerce
+    path = str(tmp_path / "metrics.jsonl")
+    with MetricsLogger(path) as metrics:
+        ctx = WorkflowContext(storage=memory_storage, seed=1,
+                              metrics=metrics)
+        CoreWorkflow.run_train(engine, ep, variant, ctx)
+    (phases,) = [x for x in map(json.loads, open(path))
+                 if x["stage"] == "train/phases"]
+    assert "runtime.backend_init" in phases
+    assert phases["process.import"] >= 0.25
+    (init,) = [r for r in device.COMPILE_LOG.records()
+               if r.phase == "runtime.backend_init"][-1:]
+    assert init.fn == "workflow.train"
+    loop = "als.train_steps"
+    assert {f"jit.compile.{loop}", f"jit.trace.{loop}", f"jit.lower.{loop}",
+            f"jit.backend.{loop}"} <= set(phases)
+    assert phases[f"jit.compile.{loop}"] >= (
+        phases[f"jit.trace.{loop}"] + phases[f"jit.lower.{loop}"]
+        + phases[f"jit.backend.{loop}"]) - 1e-3
+    assert phases["als.loop.dispatch"] >= phases[f"jit.compile.{loop}"] - 1e-3
+
+
 def test_run_train_writes_the_phases_of_a_failed_run(memory_storage,
                                                      ecommerce, tmp_path,
                                                      monkeypatch):
