@@ -319,6 +319,208 @@ class TestHotRowSplitting:
                                    rtol=2e-4, atol=2e-5)
 
 
+BUDGET = 1 << 30
+
+
+def _largest_chunk_rule(r, c, k, row_multiple, budget=BUDGET):
+    """The rule before PR 40: the largest chunk the budget admits."""
+    per_row = c * k * 4
+    if r * per_row <= budget:
+        return r
+    return min(r, max(1, budget // (per_row * row_multiple)) * row_multiple)
+
+
+def _placed_buckets(monkeypatch):
+    """Spy on `als_train`'s loop: the (user, item) bucket lists each call
+    enters it with land in the returned list."""
+    from predictionio_tpu.ops import als as als_mod
+
+    placed = []
+    real = als_mod._get_train_loop
+
+    def spy(*key, **kw):
+        loop = real(*key, **kw)
+
+        def call(*args):
+            placed.append((args[2], args[3]))
+            return loop(*args)
+        return call
+
+    monkeypatch.setattr(als_mod, "_get_train_loop", spy)
+    return placed
+
+
+class TestChunkRule:
+    """`_bucket_chunk_rows`: the fewest trips the budget admits, the rows
+    spread evenly over them (PR 40)."""
+
+    # ML-20M's chunked buckets (`perf/data.py` at split_cap 32768, the
+    # benchmark's two ALS cells): (rank, rows, cap) -> chunk, trips, padded
+    ML20M = [
+        (64, 176, 12784, 176, 1, 176),
+        (64, 160, 19176, 160, 1, 160),
+        (64, 152, 28768, 80, 2, 160),
+        (64, 208, 43152, 72, 3, 216),
+        (128, 176, 12784, 88, 2, 176),
+        (128, 160, 19176, 80, 2, 160),
+        (128, 152, 28768, 56, 3, 168),
+        (128, 208, 43152, 48, 5, 240),
+        (128, 17048, 144, 8528, 2, 17056),
+        (128, 12680, 216, 6344, 2, 12688),
+        (128, 8992, 328, 4496, 2, 8992),
+        (128, 5648, 496, 2824, 2, 5648),
+        (128, 3272, 744, 1640, 2, 3280),
+    ]
+
+    @pytest.mark.parametrize("rank,rows,cap,chunk,trips,padded", ML20M)
+    def test_ml20m_buckets(self, rank, rows, cap, chunk, trips, padded):
+        from predictionio_tpu.ops.als import (
+            _CHUNK_BUDGET_BYTES,
+            _bucket_chunk_rows,
+        )
+
+        assert _CHUNK_BUDGET_BYTES == BUDGET
+        got = _bucket_chunk_rows(rows, cap, rank, 8)
+        assert got == chunk
+        assert -(-rows // got) == trips
+        assert rows + (-rows) % got == padded
+        # what the walk recomputes from the padded height
+        assert _bucket_chunk_rows(padded, cap, rank, 8) == chunk
+
+    @pytest.mark.parametrize("row_multiple", [1, 8, 16, 24, 32, 64])
+    @pytest.mark.parametrize("k", [4, 64, 128, 1024])
+    def test_properties_over_a_sweep(self, row_multiple, k):
+        from predictionio_tpu.ops.als import _bucket_chunk_rows
+
+        rng = np.random.default_rng(row_multiple * 1000 + k)
+        caps = [8, 144, 744, 12784, 43152, 1 << 20, 1 << 26]
+        units_of = [1, 2, 3, 5, 19, 26, 409, 2131] + list(
+            rng.integers(1, 5000, 24))
+        chunked = 0
+        for c in caps:
+            per_row = c * k * 4
+            for units in units_of:
+                r = int(units) * row_multiple
+                chunk = _bucket_chunk_rows(r, c, k, row_multiple)
+                if r * per_row <= BUDGET:
+                    assert chunk == r  # whole where it fits
+                    continue
+                chunked += 1
+                assert chunk % row_multiple == 0 and 0 < chunk <= r
+                if per_row * row_multiple <= BUDGET:  # one unit fits
+                    assert chunk * per_row <= BUDGET
+                else:
+                    assert chunk == row_multiple
+                trips = -(-r // chunk)
+                old = _largest_chunk_rule(r, c, k, row_multiple)
+                assert trips <= -(-r // old) and chunk <= old
+                padded = trips * chunk
+                assert padded - r < trips * row_multiple
+                assert padded - r <= (-r) % old  # never more than before
+                assert _bucket_chunk_rows(padded, c, k, row_multiple) == chunk
+        assert chunked
+
+    def test_uneven_chunked_train_matches_unchunked(self, monkeypatch):
+        """A bucket of 40 rows where the budget admits 32: two trips of
+        24 (48 placed, not 64), every row as the unchunked train has it."""
+        from predictionio_tpu.ops import als as als_mod
+
+        rng = np.random.default_rng(11)
+        n_users, n_items, per_user = 40, 25, 8
+        ui = np.repeat(np.arange(n_users, dtype=np.int32), per_user)
+        ii = np.concatenate([rng.choice(n_items, per_user, replace=False)
+                             for _ in range(n_users)]).astype(np.int32)
+        r = rng.uniform(1, 5, len(ui)).astype(np.float32)
+        cfg = ALSConfig(rank=4, iterations=3, reg=0.05, seed=2)
+        whole = als_train(ui, ii, r, n_users, n_items, cfg, compute_rmse=True)
+
+        monkeypatch.setattr(als_mod, "_CHUNK_BUDGET_BYTES", 1 << 12)
+        loops = als_mod._get_train_loop
+        loops.cache_clear()
+        placed = _placed_buckets(monkeypatch)
+        chunked = als_train(ui, ii, r, n_users, n_items, cfg,
+                            compute_rmse=True)
+        loops.cache_clear()
+        (ub_dev, _ib_dev), = placed
+        assert [b[1].shape for b in ub_dev] == [(48, 8)]
+        assert als_mod._bucket_chunk_rows(48, 8, 4, 8) == 24
+        np.testing.assert_allclose(chunked.user_factors, whole.user_factors,
+                                   rtol=2e-4, atol=2e-5)
+        np.testing.assert_allclose(chunked.item_factors, whole.item_factors,
+                                   rtol=2e-4, atol=2e-5)
+        np.testing.assert_allclose(chunked.rmse_history, whole.rmse_history,
+                                   rtol=1e-4)
+
+    def test_grid_padded_heights_are_walked_exactly(self, monkeypatch):
+        """`als_grid.put_buckets` pads at rank n_grid x rank; the walk
+        recomputes the same chunk from the padded height."""
+        from predictionio_tpu.ops import als as als_mod, als_grid
+
+        ui, ii, r, _ = synth_ratings(n_users=40, n_items=25, seed=4,
+                                     density=0.4)
+        cfgs = [ALSConfig(rank=4, iterations=2, reg=reg, seed=1)
+                for reg in (0.05, 0.2)]
+        whole = als_grid.als_train_grid(ui, ii, r, 40, 25, cfgs)
+
+        monkeypatch.setattr(als_mod, "_CHUNK_BUDGET_BYTES", 1 << 12)
+        als_grid._get_grid_train_loop.cache_clear()
+        walks = self._spy_walks(monkeypatch, als_grid)
+        chunked = als_grid.als_train_grid(ui, ii, r, 40, 25, cfgs)
+        als_grid._get_grid_train_loop.cache_clear()
+        self._assert_exact(walks)
+        for got, want in zip(chunked, whole):
+            np.testing.assert_allclose(got.user_factors, want.user_factors,
+                                       rtol=2e-4, atol=2e-5)
+
+    def test_foldin_padded_tier_is_walked_exactly(self, monkeypatch):
+        """A fold of 9 rows rides the 32-row tier; where the tier is
+        chunked its height stays a function of the tier alone."""
+        from predictionio_tpu.online import foldin
+        from predictionio_tpu.ops import als as als_mod
+
+        rng = np.random.default_rng(5)
+        opposing = rng.normal(size=(30, 4)).astype(np.float32)
+        entries = [(rng.choice(30, n, replace=False).astype(np.int32),
+                    rng.uniform(1, 5, n).astype(np.float32))
+                   for n in (3, 5, 8, 2, 7, 6, 4, 8, 1)]
+        cfg = ALSConfig(rank=4, reg=0.05, solver="chol")
+        whole = foldin.solve_rows(opposing, entries, cfg)
+
+        # a row of the 8-wide tier is 128 bytes: 24 rows a trip at most
+        monkeypatch.setattr(als_mod, "_CHUNK_BUDGET_BYTES", 3 << 10)
+        foldin._fold_solver.cache_clear()
+        walks = self._spy_walks(monkeypatch, als_mod)
+        chunked = foldin.solve_rows(opposing, entries, cfg)
+        foldin._fold_solver.cache_clear()
+        self._assert_exact(walks)
+        assert [(r, chunk) for r, _cap, chunk in walks] == [(32, 16)]
+        np.testing.assert_allclose(chunked, whole, rtol=2e-4, atol=2e-5)
+
+    @staticmethod
+    def _spy_walks(monkeypatch, mod):
+        """(rows, cap, chunk) of every `_walk_bucket_chunks` that `mod`
+        traces."""
+        from predictionio_tpu.ops import als as als_mod
+
+        walks = []
+        real = als_mod._walk_bucket_chunks
+
+        def spy(arrays, cap, k, row_multiple, fn, carry):
+            rows = arrays[0].shape[0]
+            walks.append((rows, cap, als_mod._bucket_chunk_rows(
+                rows, cap, k, row_multiple)))
+            return real(arrays, cap, k, row_multiple, fn, carry)
+
+        monkeypatch.setattr(mod, "_walk_bucket_chunks", spy)
+        return walks
+
+    @staticmethod
+    def _assert_exact(walks):
+        assert any(chunk < rows for rows, _cap, chunk in walks)
+        for rows, _cap, chunk in walks:
+            assert rows % chunk == 0
+
+
 class TestShardedGJSolver:
     def test_gj_under_8_device_mesh_matches_chol(self, caplog):
         """solver='gj' under a multi-device mesh runs one Pallas kernel per

@@ -5,6 +5,7 @@ times. Stamps are compared with each other, never with a wall clock."""
 
 import dataclasses
 import json
+import os
 import re
 
 import numpy as np
@@ -28,6 +29,7 @@ from predictionio_tpu.workflow.workflow_utils import (
     extract_engine_params,
     get_engine,
 )
+from tests.test_als import _placed_buckets
 from tests.test_ecommerce_template import ingest, variant_dict
 
 N_USERS, N_ITEMS = 12, 9
@@ -164,6 +166,57 @@ def test_bucket_gauges_count_entries_and_cells_per_side(tmp_path):
         assert entries[(side,)] == sum(b.mask.sum() for b in buckets)
         assert cells[(side,)] == sum(b.mask.size for b in buckets)
         assert cells[(side,)] >= entries[(side,)]
+
+
+@pytest.mark.parametrize("budget,chunked", [(1 << 30, False),
+                                            (3 << 10, True)])
+def test_walk_cells_gauge_counts_the_rows_placed(monkeypatch, budget,
+                                                 chunked):
+    """`als_bucket_walk_cells` is what the loop is entered with, chunk
+    padding included; `als_bucket_cells` stops before that padding."""
+    loops = als._get_train_loop
+    monkeypatch.setattr(als, "_CHUNK_BUDGET_BYTES", budget)
+    placed = _placed_buckets(monkeypatch)
+    loops.cache_clear()
+    rng = np.random.default_rng(1)
+    u = np.repeat(np.arange(40, dtype=np.int32), 3)
+    i = rng.integers(0, N_ITEMS, len(u)).astype(np.int32)
+    als_train(u, i, rng.uniform(1, 5, len(u)).astype(np.float32), 40,
+              N_ITEMS, CFG)
+    loops.cache_clear()
+    cells = dict(REGISTRY.get("als_bucket_cells").collect())
+    walk = dict(REGISTRY.get("als_bucket_walk_cells").collect())
+    for side, buckets in zip(("user", "item"), placed[0]):
+        assert walk[(side,)] == sum(b[1].shape[0] * b[1].shape[1]
+                                    for b in buckets)
+        assert walk[(side,)] >= cells[(side,)]
+    # 40 rows of cap 8 at rank 4, 128 bytes a row, 24 rows in 3 KiB: two
+    # trips of 24
+    assert cells[("user",)] == 40 * 8
+    assert walk[("user",)] == (48 * 8 if chunked else 40 * 8)
+    if not chunked:
+        assert walk == cells
+
+
+def test_the_benchmarks_fill_metrics_name_gauges_the_program_has():
+    """`train.bucket_fill` and `train.walk_fill` are data files of the
+    benchmark that name the program's gauges: a rename here would make
+    them fall silent there."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        per_layer = {m["name"]: m for m in json.load(f)["per_layer"]}
+    for name, cells in (("train.bucket_fill", "als_bucket_cells"),
+                        ("train.walk_fill", "als_bucket_walk_cells")):
+        with open(os.path.join(root, "perf", "layers", name + ".json")) as f:
+            spec = json.load(f)
+        assert (spec["reader"], spec["numerator"], spec["denominator"]) == (
+            "gauge_ratio", "als_bucket_entries", cells)
+        assert REGISTRY.get(spec["numerator"]) is not None
+        assert REGISTRY.get(spec["denominator"]) is not None
+        entry = per_layer[name]
+        assert (entry["unit"], entry["moves"], entry["layer"]) == (
+            spec["unit"], spec["moves"], spec["layer"])
+        assert entry["workloads"] == ["als64.train10", "als128i.train10"]
 
 
 @pytest.mark.parametrize("chunks,expected", [
