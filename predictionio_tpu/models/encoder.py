@@ -1,12 +1,15 @@
 """The next-item encoder, driven by a configuration: in every block a
 token mixer, latent attention (MLA), Kimi Delta Attention (KDA, a
-gated delta-rule recurrence, `ops/kda.py`) or one of the five of a
+gated delta-rule recurrence, `ops/kda.py`), one of the five of a
 decoder-hybrid-decoder (Mamba's selective scan, `ops/ssm.py`; windowed
 and full differential attention over grouped key and value heads; a
 gated memory unit and a differential cross-attention, which read what
-an earlier layer left) as the configuration says layer by layer, and a
-dense or a sparse-expert feed-forward; an optional multi-token-prediction
-(MTP) module; trained on packed histories.
+an earlier layer left) or one of the two of a Mamba-2 hybrid (the
+scalar-decay state-space layer in its chunked matrix form, `ops/ssd.py`;
+plain softmax attention over grouped heads without positions) as the
+configuration says layer by layer, and a dense or a sparse-expert
+feed-forward; an optional multi-token-prediction (MTP) module; trained
+on packed histories.
 
 One code path runs every size. A configuration file in the published
 model's own key names (`EncoderConfig.from_json`) gives the widths, the
@@ -52,6 +55,25 @@ Equations (the plain reference is `quality/encoder_reference.py`):
             o_j) (1 - lam0); out = [o_0 ..] W_o + b_o. Kind `full`
             keeps its K, V; kind `cross` has Q = u W_q + b alone and
             reads them. No positional encoding.
+    Mamba-2 [z | xBC | dt'] = u W_in, one product; xBC = SiLU(conv(xBC)
+            + b_c), conv as KDA's over x, B and C together; [x | B | C]
+            = xBC, x as `mamba_n_heads` heads of `mamba_d_head`
+            channels, B and C [N] one group for all heads; dt =
+            softplus(dt' + dt_bias), A = -exp(A_log), one scalar a head;
+            S_t = exp(dt_t A) S_{t-1} + dt_t x_t (x) B_t, S [P, N] a
+            head, zero entering a history's first token; y_t = S_t C_t +
+            D x_t; out = (RMSNorm(y SiLU(z)) w) W_out: gate first, then
+            one norm over all channels
+    GQA     q, k, v = u W_q, u W_k, u W_v; query head j reads key and
+            value head j // (heads / kv heads); o = softmax over the
+            keys s <= t of the history of (q_t . k_s attention_multiplier)
+            v_s, the multiplier the scale itself (1 / sqrt(d) where none
+            is stated); out = concat(o) W_o. No positional encoding.
+    x4      the four multipliers of such a hybrid, each 1 where a
+            configuration states none: h0 = embedding_multiplier
+            E[token]; h += residual_multiplier Mixer(..) and h +=
+            residual_multiplier FFN(..) in its blocks; the attention
+            scale above; logits = head(..) / logits_scaling
     LN      with `layer_norm_eps` every norm is LayerNorm with a bias;
             with `tie_word_embeddings` the head is the embedding
             transposed
@@ -78,6 +100,7 @@ import jax.numpy as jnp
 
 from predictionio_tpu.ops import kda as kda_ops
 from predictionio_tpu.ops import moe
+from predictionio_tpu.ops import ssd as ssd_ops
 from predictionio_tpu.ops import ssm
 from predictionio_tpu.ops.attention import segment_attention
 
@@ -85,6 +108,9 @@ from predictionio_tpu.ops.attention import segment_attention
 _ALIASES = {"num_experts": "n_routed_experts",
             "num_experts_per_token": "num_experts_per_tok",
             "num_shared_experts": "n_shared_experts"}
+# a published `layer_types` entry -> the mixer kind here (Mamba-2's:
+# `mamba` there names the scalar-decay layer)
+_LAYER_TYPES = {"mamba": "ssd", "attention": "gqa"}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -99,10 +125,10 @@ class EncoderConfig:
     v_head_dim: int = 0
     q_lora_rank: int = 0             # 0 (null): x W_q, no low-rank query
     mla_use_nope: bool = False       # MLA without rotation
-    # "mla" | "kda" | "mamba" | "swa" | "full" | "gmu" | "cross" a layer;
-    # (): all MLA
+    # "mla" | "kda" | "mamba" | "swa" | "full" | "gmu" | "cross" | "ssd" |
+    # "gqa" a layer; (): all MLA
     layer_kinds: tuple = ()
-    num_key_value_heads: int = 0     # grouped K/V heads of swa/full/cross
+    num_key_value_heads: int = 0     # grouped K/V heads of swa/full/cross/gqa
     sliding_window: int = 0          # of the swa layers
     layer_first: int = 0             # published index of the first held layer
     mamba_expand: int = 2            # channels / hidden_size
@@ -111,6 +137,14 @@ class EncoderConfig:
     mamba_dt_rank: int = 0           # 0: hidden_size / 16, rounded up
     ssm_chunk: int = 64
     ssm_channels: int = 0            # channels a pass of the scan; 0: all
+    mamba_n_heads: int = 0           # Mamba-2 (ssd): heads of mamba_d_head
+    mamba_d_head: int = 0            # channels, one scalar decay a head
+    mamba_conv_bias: bool = True
+    mamba_chunk_size: int = 256      # tokens a chunk of the ssd scan
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0  # of the carried kinds' blocks
+    attention_multiplier: float = 0.0  # gqa's scale; 0: 1 / sqrt(head width)
+    logits_scaling: float = 1.0      # the head's logits are divided by it
     layer_norm_eps: float = 0.0      # > 0: LayerNorm with a bias, not RMSNorm
     tie_word_embeddings: bool = False
     kda_num_heads: int = 0
@@ -207,6 +241,8 @@ class EncoderConfig:
                 int(flat["num_hidden_layers"]),
                 int(flat.get("layers_total", flat["num_hidden_layers"])),
                 int(flat["mb_per_layer"]))
+        if flat.get("layer_types"):
+            flat["layer_kinds"] = _held_layer_types(flat)
         known = {f.name for f in dataclasses.fields(cls)}
         kw = {k: v for k, v in flat.items() if k in known and v is not None}
         kw["report_blocks"] = tuple(
@@ -220,6 +256,33 @@ class EncoderConfig:
     def from_json(cls, path: str) -> "EncoderConfig":
         with open(path) as f:
             return cls.from_dict(json.load(f))
+
+
+def _held_layer_types(flat: dict) -> tuple:
+    """The kinds of the held layers from a published `layer_types` list
+    (the whole model's, or the held slice alone): `num_hidden_layers`
+    entries from `layer_first` on. Such a model's feed-forward is its
+    shared one where it has no experts. What the two kinds here cannot
+    express is refused: an entry of another type, several B/C groups, a
+    bias on the state-space layer's projections, rotated attention."""
+    types, held = flat["layer_types"], int(flat["num_hidden_layers"])
+    first = int(flat.get("layer_first", 0)) if len(types) > held else 0
+    mine = types[first:first + held]
+    unknown = sorted(set(mine) - set(_LAYER_TYPES))
+    if unknown or len(mine) != held:
+        raise ValueError(
+            f"layer_types[{first}:{first + held}] = {mine}: {held} entries "
+            f"of {sorted(_LAYER_TYPES)} wanted, {unknown} not known")
+    for key, only in (("mamba_n_groups", 1), ("mamba_proj_bias", False),
+                      ("attention_bias", False),
+                      ("position_embedding_type", "nope"),
+                      ("num_local_experts", 0)):
+        if flat.get(key, only) != only:
+            raise ValueError(f"{key} = {flat[key]!r}: a layer_types model "
+                             f"runs with {only!r} alone")
+    if flat.get("shared_intermediate_size"):
+        flat["intermediate_size"] = flat["shared_intermediate_size"]
+    return tuple(_LAYER_TYPES[t] for t in mine)
 
 
 def hybrid_decoder_kinds(first: int, held: int, total: int,
@@ -429,6 +492,56 @@ def mamba(p, cfg: EncoderConfig, x, seg, scope: str = "enc.mamba"):
         return _mm(cfg, y * jax.nn.silu(z), p["w_out"]), y
 
 
+def ssd(p, cfg: EncoderConfig, x, seg, scope: str = "enc.ssd"):
+    """Mamba-2's mixer on x [B, L, D] (already normed): one input
+    projection [z | xBC | dt'], one convolution over x, B and C
+    together, the chunked scan (`ops/ssd.py`), the gate and then one
+    norm over all channels. Scopes `proj`, `conv`, `dt`, `scan`, `norm`,
+    `out` under `scope`."""
+    b, l, _ = x.shape
+    h, dh, n = cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state
+    di = h * dh
+    with jax.named_scope(f"{scope}.proj"):
+        zxbcdt = _mm(cfg, x, p["w_in"])
+    with jax.named_scope(f"{scope}.conv"):
+        xbc = jax.nn.silu(kda_ops.causal_conv(
+            zxbcdt[..., di:2 * di + 2 * n], p["conv_w"], seg,
+            p.get("conv_bias")))
+    with jax.named_scope(f"{scope}.dt"):
+        dt = jax.nn.softplus(zxbcdt[..., 2 * di + 2 * n:] + p["dt_bias"])
+    y = ssd_ops.ssd_scan(
+        xbc[..., :di].reshape(b, l, h, dh), dt, -jnp.exp(p["a_log"]),
+        xbc[..., di:di + n], xbc[..., di + n:], p["d_skip"], seg,
+        cfg.mamba_chunk_size, _dt(cfg.compute_dtype),
+        f"{scope}.scan")
+    with jax.named_scope(f"{scope}.norm"):
+        y = rms_norm(y.reshape(b, l, di) * jax.nn.silu(zxbcdt[..., :di]),
+                     p["norm"], cfg.rms_norm_eps)
+    with jax.named_scope(f"{scope}.out"):
+        return _mm(cfg, y, p["w_out"])
+
+
+def gqa(p, cfg: EncoderConfig, x, seg, pos, scope: str = "enc.gqa"):
+    """Plain softmax attention over grouped heads on x [B, L, D]
+    (already normed), nothing rotated: query head j reads key and value
+    head j // (heads / kv heads). Scopes `proj`, `pairs`, `out`."""
+    cd = _dt(cfg.compute_dtype)
+    b, l, _ = x.shape
+    h, hk = cfg.num_attention_heads, cfg.num_key_value_heads
+    dh = p["w_q"].shape[1] // h
+    with jax.named_scope(f"{scope}.proj"):
+        q = _mm(cfg, x, p["w_q"]).reshape(b, l, h, dh)
+        k, v = (jnp.repeat(_mm(cfg, x, p[w]).reshape(b, l, hk, dh), h // hk,
+                           axis=2) for w in ("w_k", "w_v"))
+        q, k, v = (t.astype(cd).transpose(0, 2, 1, 3) for t in (q, k, v))
+    o = segment_attention(q, k, v, seg, pos, block=cfg.attention_block,
+                          scale=cfg.attention_multiplier or dh ** -0.5,
+                          scope=f"{scope}.pairs")
+    with jax.named_scope(f"{scope}.out"):
+        return _mm(cfg, o.transpose(0, 2, 1, 3).reshape(b, l, h * dh),
+                   p["w_o"])
+
+
 def gmu(p, cfg: EncoderConfig, x, m):
     """The gated memory unit on x [B, L, D] (already normed): the
     memory m [B, L, channels] gated by x, element by element."""
@@ -506,6 +619,12 @@ def carried_block(p, cfg: EncoderConfig, kind: str, layer: int, h, seg, pos,
         if kind == "mamba":
             with jax.named_scope("enc.mamba"):
                 y, made = mamba(p["mamba"], cfg, x, seg)
+        elif kind == "ssd":
+            with jax.named_scope("enc.ssd"):
+                y = ssd(p["ssd"], cfg, x, seg)
+        elif kind == "gqa":
+            with jax.named_scope("enc.gqa"):
+                y = gqa(p["gqa"], cfg, x, seg, pos)
         elif kind == "gmu":
             with jax.named_scope("enc.gmu"):
                 y = gmu(p["gmu"], cfg, x, m)
@@ -518,7 +637,10 @@ def carried_block(p, cfg: EncoderConfig, kind: str, layer: int, h, seg, pos,
                 y, made = diff_attention(
                     p["diff"], cfg, x, seg, pos, layer,
                     window=cfg.sliding_window if kind == "swa" else None)
-        return _feed_forward(p, None, cfg, h + y)[0], made
+        if cfg.residual_multiplier != 1.0:
+            y = cfg.residual_multiplier * y
+        return _feed_forward(p, None, cfg, h + y,
+                             scale=cfg.residual_multiplier)[0], made
 
     h, made = _maybe_remat(run, cfg)(
         p, h, carry.get("m") if kind == "gmu" else None,
@@ -569,21 +691,25 @@ def block(p, bias, cfg: EncoderConfig, h, seg, pos, scope: str = ""):
                          scope)
 
 
-def _feed_forward(p, bias, cfg: EncoderConfig, h, scope: str = ""):
-    """The second half of `block`: h += FFN(norm(h))."""
+def _feed_forward(p, bias, cfg: EncoderConfig, h, scope: str = "",
+                  scale: float = 1.0):
+    """The second half of `block`: h += FFN(norm(h)), a dense one times
+    `scale` (a carried block's residual multiplier)."""
     b, l, d = h.shape
     x2d = _norm(cfg, h, p, "norm2").reshape(b * l, d)
     if bias is None:
         with jax.named_scope(scope or "enc.dense_ffn"):
             y = _by_rows(cfg, lambda x: swiglu(cfg, x, p["w13"], p["w2"]),
                          x2d)
+            if scale != 1.0:
+                y = scale * y
         return h + y.reshape(b, l, d), None
     with jax.named_scope(scope or "enc.moe"):
         y, routed = expert_ffn(p, bias, cfg, x2d, scope or "enc.moe")
     return h + y.reshape(b, l, d), routed
 
 
-CARRIED_KINDS = ("mamba", "swa", "full", "gmu", "cross")
+CARRIED_KINDS = ("mamba", "swa", "full", "gmu", "cross", "ssd", "gqa")
 
 
 def _maybe_remat(fn, cfg: EncoderConfig):
@@ -606,8 +732,10 @@ def encode(params, cfg: EncoderConfig, tokens, seg, pos):
     [B, L, D] (before the final norm) and what the expert layers routed
     (`expert_ffn`), stacked over the layers: counts [n_moe, held], load
     [n_moe, experts_total], picks [n_moe, B * L, k]; None without one."""
-    return run_blocks(params, cfg, jnp.take(params["emb"], tokens, axis=0),
-                      seg, pos)
+    h = jnp.take(params["emb"], tokens, axis=0)
+    if cfg.embedding_multiplier != 1.0:
+        h = cfg.embedding_multiplier * h
+    return run_blocks(params, cfg, h, seg, pos)
 
 
 def run_blocks(params, cfg: EncoderConfig, h, seg, pos):
@@ -667,12 +795,16 @@ def head_logits(params, cfg: EncoderConfig, h):
     head is the embedding read transposed, by the product itself."""
     x = _norm(cfg, h, params, "final_norm")
     if not cfg.tie_word_embeddings:
-        return _mm(cfg, x, params["head"])
-    dtype = _dt(cfg.compute_dtype)
-    return jax.lax.dot_general(
-        x.astype(dtype), params["emb"].astype(dtype),
-        (((x.ndim - 1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        logits = _mm(cfg, x, params["head"])
+    else:
+        dtype = _dt(cfg.compute_dtype)
+        logits = jax.lax.dot_general(
+            x.astype(dtype), params["emb"].astype(dtype),
+            (((x.ndim - 1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
+    return logits
 
 
 def cross_entropy_sum(params, cfg: EncoderConfig, h2d, targets, valid):
@@ -766,6 +898,25 @@ def _mamba_shapes(cfg: EncoderConfig) -> dict:
             "d_skip": (di,), "w_out": (di, d)}
 
 
+def _ssd_shapes(cfg: EncoderConfig) -> dict:
+    """Mamba-2's: w_in's columns are [z | x | B | C | dt'], the
+    convolution runs over [x | B | C]."""
+    d, h, n = cfg.hidden_size, cfg.mamba_n_heads, cfg.mamba_d_state
+    di = h * cfg.mamba_d_head
+    bias = {"conv_bias": (di + 2 * n,)} if cfg.mamba_conv_bias else {}
+    return {"w_in": (d, 2 * di + 2 * n + h),
+            "conv_w": (cfg.mamba_d_conv, di + 2 * n), **bias,
+            "dt_bias": (h,), "a_log": (h,), "d_skip": (h,), "norm": (di,),
+            "w_out": (di, d)}
+
+
+def _gqa_shapes(cfg: EncoderConfig) -> dict:
+    d = cfg.hidden_size
+    dh = d // cfg.num_attention_heads
+    return {"w_q": (d, d), "w_k": (d, cfg.num_key_value_heads * dh),
+            "w_v": (d, cfg.num_key_value_heads * dh), "w_o": (d, d)}
+
+
 def _diff_shapes(cfg: EncoderConfig, cross: bool) -> dict:
     """Differential attention's; a cross layer projects queries alone."""
     d = cfg.hidden_size
@@ -791,7 +942,13 @@ def _mixer_shapes(cfg: EncoderConfig, kind: str) -> dict:
         return {"diff": _diff_shapes(cfg, False)}
     if kind == "cross":
         return {"cross": _diff_shapes(cfg, True)}
-    return {"attn": _attn_shapes(cfg)}
+    if kind == "ssd":
+        return {"ssd": _ssd_shapes(cfg)}
+    if kind == "gqa":
+        return {"gqa": _gqa_shapes(cfg)}
+    if kind == "mla":
+        return {"attn": _attn_shapes(cfg)}
+    raise ValueError(f"no mixer of kind {kind!r}")
 
 
 def _norm_shapes(cfg: EncoderConfig, *names: str) -> dict:
@@ -854,7 +1011,8 @@ def init_params(cfg: EncoderConfig, vocab: int, key):
     layer's decay rates A = exp(a_log) uniform in [1, 16], a `dt_bias`
     the inverse softplus of a step log-uniform in [0.001, 0.1], a
     convolution's taps uniform within 1 / sqrt(width); a Mamba layer's
-    A = 1..states a channel and its skip D one; differential
+    A = 1..states a channel (Mamba-2's: 1..heads, one a head) and its
+    skip D one; differential
     attention's four lambda vectors normal(0, 0.1). Made where `key`
     lives."""
     shapes = param_shapes(cfg, vocab)
@@ -864,7 +1022,8 @@ def init_params(cfg: EncoderConfig, vocab: int, key):
     out = []
     for k, (path, shape) in zip(keys, leaves):
         name = str(path[-1])
-        if "a_log" in name and any("mamba" in str(part) for part in path):
+        if "a_log" in name and any(kind in str(path)
+                                   for kind in ("mamba", "ssd")):
             out.append(jnp.broadcast_to(
                 jnp.log(jnp.arange(1.0, shape[-1] + 1.0)), shape))
         elif "a_log" in name:

@@ -223,7 +223,8 @@ def segment_attention(q, k, v, segment_ids, positions, block: int = 512,
     those of the backward pass too, are traced under `scope`. With a
     `window` a query at t sees the keys s of its history with t - s <
     window, and a query block skips the key blocks the window leaves
-    out. Returns [B, H, L, Dv] in float32. The kernels tell a history
+    out. Returns [B, H, L, Dv] in float32. Values narrower than a lane
+    tile go through the kernels padded to one. The kernels tell a history
     by `positions` alone (its keys are those from t - positions[t] on,
     what `first_key_blocks` rests on too). Which path was built above
     one block is counted in `encoder_segment_attention_calls_total{path}`
@@ -238,15 +239,23 @@ def segment_attention(q, k, v, segment_ids, positions, block: int = 512,
     if l % block:
         raise ValueError(f"sequence {l} is not a multiple of block {block}")
     t0 = time.monotonic()
+    # the kernels' values are whole lane tiles: narrower ones (64) ride
+    # beside columns of zeros, which the result is cut back from
+    dv = v.shape[-1]
+    lanes = -(-dv // 128) * 128
     path = ("kernel" if jax.default_backend() == "tpu"
-            and pallas_attention.applicable(l, q.shape[-1], v.shape[-1],
+            and pallas_attention.applicable(l, q.shape[-1], lanes,
                                             q.dtype.itemsize)
             else "jnp")
     pallas_attention.SEGMENT_CALLS.labels(path=path).inc()
     if path == "kernel":
         with jax.named_scope(scope):
+            if lanes != dv:
+                v = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, lanes - dv)))
             o = pallas_attention.segment_pairs(q, k, v, positions, scale,
                                                scope, window)
+            if lanes != dv:
+                o = o[..., :dv]
     else:
         kv_lo = first_key_blocks(positions, block, window)
         o = _segment_attention(q, k, v, segment_ids, kv_lo, block, scale,

@@ -3,8 +3,9 @@
 The scenario-diversity frontier (ROADMAP item 4): every other served
 template is factor- or frequency-based; this one is a causal
 self-attention next-item model — the config-driven encoder of
-`models/encoder.py` (latent attention, dense or sparse-expert
-feed-forward, an optional multi-token-prediction module), by default
+`models/encoder.py` (a mixer a layer as its configuration says, dense
+or sparse-expert feed-forward, an optional multi-token-prediction
+module), by default
 one 16-wide dense block — trained through the normal DataSource →
 Preparator → Algorithm path over per-user event sequences from
 `data/view.py`'s ordered aggregation, packed into fixed sequences with
@@ -391,6 +392,17 @@ SSM_BOUNDARY_CHUNKS = REGISTRY.gauge(
 SSM_RESETS_TOTAL = REGISTRY.counter(
     "encoder_ssm_resets_total", "Histories the train steps of an encoder "
     "with Mamba layers held: the times a layer's state started from zero")
+SSD_CHUNKS = REGISTRY.gauge(
+    "encoder_ssd_chunks", "Chunks of Mamba-2's scan (ops/ssd.py) in the "
+    "sequences of each step of the last train's epoch, a Mamba-2 layer",
+    labelnames=("step",))
+SSD_BOUNDARY_CHUNKS = REGISTRY.gauge(
+    "encoder_ssd_boundary_chunks", "Of encoder_ssd_chunks, those that "
+    "hold a history's first token (the scan's reset masks do work there)",
+    labelnames=("step",))
+SSD_RESETS_TOTAL = REGISTRY.counter(
+    "encoder_ssd_resets_total", "Histories the train steps of an encoder "
+    "with Mamba-2 layers held: the times a layer's state started from zero")
 ATTN_KEY_BLOCKS = REGISTRY.gauge(
     "encoder_attn_key_blocks", "Key blocks the query blocks of the last "
     "train's epoch visit in one blockwise differential-attention layer "
@@ -499,7 +511,9 @@ class SessionRecAlgorithm(Algorithm):
                 ("kda", cfg.kda_chunk, KDA_CHUNKS, KDA_BOUNDARY_CHUNKS,
                  KDA_RESETS_TOTAL),
                 ("mamba", cfg.ssm_chunk, SSM_CHUNKS, SSM_BOUNDARY_CHUNKS,
-                 SSM_RESETS_TOTAL)):
+                 SSM_RESETS_TOTAL),
+                ("ssd", cfg.mamba_chunk_size, SSD_CHUNKS,
+                 SSD_BOUNDARY_CHUNKS, SSD_RESETS_TOTAL)):
             if kind not in cfg.kinds:
                 continue
             from predictionio_tpu.ops.kda import chunk_stats

@@ -373,12 +373,13 @@ class TestTheEncoderAsADecoderHybridDecoder:
             assert abs(value - want[model.item_ids.get(item)]) < 2e-4
 
 
-def test_the_benchmarks_reference_is_a_copy_of_the_packages():
-    """The newest copy (`perf/reference/phi4_flash.py`) is held equal in
-    `tests/test_encoder_sambay.py`. The Kimi cell's copy is the
-    package's reference as PR 32 left it, and the benchmark's file: the
-    package's still defines every function it has, with the arguments
-    it has, in their order."""
+@pytest.mark.parametrize("copy", ["kimi_linear.py", "phi4_flash.py"])
+def test_the_benchmarks_reference_is_a_copy_of_the_packages(copy):
+    """The newest copy (`perf/reference/granite_hybrid.py`) is held equal
+    in `tests/test_encoder_granite.py`. The Kimi and Phi-4-mini-flash
+    cells' copies are the package's reference as PRs 32 and 37 left it,
+    and the benchmark's files: the package's still defines every
+    function they have, with the arguments they have, in their order."""
     import ast
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -391,6 +392,5 @@ def test_the_benchmarks_reference_is_a_copy_of_the_packages():
 
     package = signatures("predictionio_tpu", "quality",
                          "encoder_reference.py")
-    for name, args in signatures("perf", "reference",
-                                 "kimi_linear.py").items():
+    for name, args in signatures("perf", "reference", copy).items():
         assert package[name][:len(args)] == args, name

@@ -381,3 +381,64 @@ def test_the_implicit_templates_emit_train_als(tmp_path, template, algorithm):
     assert [x["step"] for x in lines if x["stage"] == "train/als"] == [1, 2, 3]
     assert all(x["epoch_time_s"] > 0 for x in lines)
     assert "model.unit_norm" in [s[0] for s in tl.spans]
+
+
+# -- the encoder as a Mamba-2 hybrid: what the benchmark's seven read -----------
+
+SSD_METRICS = ("fit.ssd_s", "fit.ssd_scan_s", "fit.ssd_scan_roofline",
+               "fit.gqa_attn_s", "fit.ffn_s", "fit.ssd_step_mfu",
+               "fit.ssd_boundary_chunk_share")
+
+
+@pytest.fixture(scope="module")
+def hybrid_step_text():
+    """Lowered text, with locations, of one train step of the tiny
+    Mamba-2 hybrid the benchmark's tests run."""
+    import jax
+    import jax.numpy as jnp
+
+    from predictionio_tpu.models import encoder as enc
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    cfg = enc.EncoderConfig.from_json(os.path.join(
+        root, "perf", "tests", "tiny", "granite_4_0_h_micro_1of8.json"))
+    state = jax.eval_shape(
+        lambda k: enc.init_state(cfg, cfg.vocab_size, k), jax.random.key(0))
+    batch = jax.ShapeDtypeStruct((cfg.seqs_per_step, cfg.pack_len), jnp.int32)
+    return jax.jit(enc.train_step(cfg, 1e-3)).lower(
+        state, batch, batch, batch).as_text(debug_info=True)
+
+
+@pytest.mark.parametrize("name", SSD_METRICS)
+def test_the_benchmarks_ssd_metrics_read_what_the_program_has(
+        hybrid_step_text, name):
+    """Each of the seven is a data file of the benchmark that names the
+    program's scopes, its step's module or its gauges: a rename here
+    would make it fall silent there. Every `known` list names all the
+    scopes the step opens, so that no op falls to an enclosing scope by
+    omission."""
+    from predictionio_tpu.templates.sessionrec import engine  # noqa: F401
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        entry = {m["name"]: m for m in json.load(f)["per_layer"]}[name]
+    with open(os.path.join(root, "perf", "layers", name + ".json")) as f:
+        spec = json.load(f)
+    assert (entry["unit"], entry["moves"], entry["layer"]) == (
+        spec["unit"], spec["moves"], spec["layer"])
+    assert entry["workloads"] == ["granite4h.fit16_pack8k"]
+    if spec["reader"] == "gauge_ratio":
+        for gauge in (spec["numerator"], spec["denominator"]):
+            assert REGISTRY.get(gauge) is not None, gauge
+        assert REGISTRY.get("encoder_ssd_resets_total") is not None
+        return
+    assert "@jit_sessionrec_train_step" in hybrid_step_text
+    # a location names its ops `<outer scopes>/<scope>/<primitive>`, or
+    # `jvp(<scope>)` / `transpose(jvp(<scope>))` round an outermost one
+    opened = set(re.findall(r'["/(](enc\.[a-z_.]+)[/)]', hybrid_step_text))
+    if "known" in spec:
+        assert set(spec["known"]) == opened
+        assert set(spec["scopes"]) <= opened
+    else:  # the whole step's share of the peak: its operations' file
+        assert os.path.exists(os.path.join(root, "perf", "ops",
+                                           spec["ops"] + ".py"))
