@@ -15,23 +15,53 @@ after batch_predict).
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import Optional
 
 import numpy as np
 
+from predictionio_tpu import native
 from predictionio_tpu.data.bimap import BiMap
 from predictionio_tpu.ops import ranking
+from predictionio_tpu.telemetry.registry import REGISTRY
+from predictionio_tpu.telemetry.spans import record as record_span
+
+# Which way each seen-items map was built. A train whose build reads
+# `numpy` fell back: no toolchain, PIO_NATIVE=0, or a user row outside
+# [0, n_users), which the loader declines.
+SEEN_ITEMS_BUILDS = REGISTRY.counter(
+    "model_seen_items_builds_total",
+    "SeenItems maps built, by the path that grouped the entries by user "
+    "row (native | numpy)",
+    labelnames=("path",))
 
 
 class SeenItems:
     """CSR map of user row → seen item rows, with the dict-ish `.get`
-    surface `recommend_products` uses. Built from the training COO in two
-    numpy ops (argsort + searchsorted) — the per-event Python dict loop it
-    replaces dominated model-build time at 2M+ events (VERDICT r1 #4).
+    surface `recommend_products` uses. Built from the training COO by
+    grouping the entries by user row in their given order: one counting
+    pass in the native loader (`native.group_rows_native`: histogram,
+    prefix sum, scatter), or where that is absent or declines, the stable
+    argsort + searchsorted of `_group_rows_numpy`, which defines the
+    result (the native path's arrays equal it bit for bit) — the per-event
+    Python dict loop both replace dominated model-build time at 2M+ events
+    (VERDICT r1 #4), the argsort a third of a 20M-event train call.
     Pickles as two arrays, so blob-store persistence stays cheap."""
 
     def __init__(self, user_idx: np.ndarray, item_idx: np.ndarray,
                  n_users: int):
+        t0 = time.monotonic()
+        grouped = native.group_rows_native(user_idx, item_idx, n_users)
+        if grouped is not None:
+            path = "native"
+            self._items, self._indptr = grouped
+        else:
+            path = "numpy"
+            self._group_rows_numpy(user_idx, item_idx, n_users)
+        SEEN_ITEMS_BUILDS.labels(path=path).inc()
+        record_span(f"model.seen_items.{path}", time.monotonic() - t0)
+
+    def _group_rows_numpy(self, user_idx, item_idx, n_users: int) -> None:
         order = np.argsort(user_idx, kind="stable")
         self._items = np.ascontiguousarray(
             np.asarray(item_idx)[order], dtype=np.int32)

@@ -114,6 +114,8 @@ def get_lib() -> Optional[ctypes.CDLL]:
             i32p, i32p]
         lib.pio_bucket_free.restype = None
         lib.pio_bucket_free.argtypes = [ctypes.c_void_p]
+        lib.pio_group_rows.restype = i64
+        lib.pio_group_rows.argtypes = [i32p, i32p, i64, i64, i64p, i32p]
         cstr = ctypes.c_char_p
         cstrp = ctypes.POINTER(ctypes.c_char_p)
         i64_out = ctypes.POINTER(ctypes.c_int64)
@@ -356,6 +358,37 @@ def bucket_ragged_native(rows: np.ndarray, cols: np.ndarray,
     return NativeBuckets(
         buckets, split_rows,
         "native_counting" if info[1] else "native_comparison")
+
+
+def group_rows_native(rows: np.ndarray, cols: np.ndarray,
+                      n_rows: int) -> Optional[tuple]:
+    """COO → CSR by row via the C++ loader: `(items, indptr)`, int32
+    `[n]` and int64 `[n_rows + 1]`, row r's columns in the caller's order
+    at `items[indptr[r]:indptr[r + 1]]` — a stable sort by row without the
+    sort. Returns None when the native library is unavailable or declines
+    the input (a row id outside `[0, n_rows)`, or one that is no integer);
+    the caller falls back to numpy, which defines the semantics."""
+    lib = get_lib()
+    if lib is None:
+        return None
+    rows, cols = np.asarray(rows), np.asarray(cols)
+    n = len(rows)
+    if rows.ndim != 1 or cols.shape != rows.shape or n_rows < 0:
+        return None
+    # a wider id must not wrap into range on its way to int32
+    if n and rows.dtype != np.int32 and (
+            rows.dtype.kind not in "iu" or rows.min() < 0
+            or rows.max() >= n_rows):
+        return None
+    rows = np.ascontiguousarray(rows, dtype=np.int32)
+    cols = np.ascontiguousarray(cols, dtype=np.int32)
+    indptr = np.empty(n_rows + 1, dtype=np.int64)
+    items = np.empty(n, dtype=np.int32)
+    if lib.pio_group_rows(rows, cols, n, n_rows, indptr, items) != 0:
+        log.warning("native: group_rows declined (a row id outside "
+                    "[0, %d)) — numpy fallback", n_rows)
+        return None
+    return items, indptr
 
 
 def agg_props_native(db_path: str, sql: str, params: list,

@@ -2,16 +2,18 @@
 //
 // The reference delegates its hot host paths to the JVM/Spark (RDD
 // shuffles, HBase scans — SURVEY.md §2.5: its only native code lives in
-// dependencies like netlib/netty). The TPU rebuild's equivalent hot host
-// path is the ragged-COO → padded-dense-bucket transform that feeds the
-// device (ops/als.py::bucket_ragged / bucket_ragged_split): one side of a
-// train's ratings in, that side's buckets out, in passes linear in the
-// entry count. This file implements it behind a C ABI bound via ctypes
-// (predictionio_tpu/native/__init__.py): pio_bucket_plan counts the rows
-// once and lays the buckets out, the caller allocates zeroed numpy
+// dependencies like netlib/netty). The TPU rebuild's equivalents are two
+// transforms of a train's COO, both in passes linear in the entry count,
+// behind a C ABI bound via ctypes (predictionio_tpu/native/__init__.py).
+// For each the numpy implementation is the fallback and the reference,
+// and the output is bit-identical to it.
+//
+// 1. Ragged COO → padded dense buckets, what feeds the device
+// (ops/als.py::bucket_ragged / bucket_ragged_split): one side of a
+// train's ratings in, that side's buckets out. pio_bucket_plan counts the
+// rows once and lays the buckets out, the caller allocates zeroed numpy
 // buffers of the planned sizes, pio_bucket_fill places every entry,
-// pio_bucket_free drops the plan. The numpy implementation is the
-// fallback and the reference. Output is bit-identical to it:
+// pio_bucket_free drops the plan:
 //   - a row with more than split_cap entries becomes ceil(count /
 //     split_cap) segment rows (pseudo-row ids n_rows, n_rows + 1, ... in
 //     ascending order of the row, then of the segment); an entry's
@@ -34,6 +36,12 @@
 // entries are few against a wide range (the online fold: a few hundred
 // entries, a catalog of columns), which a comparison sort of (column,
 // index) keys orders sooner. build_plan picks from the two sizes.
+//
+// 2. COO → CSR by row, the seen-items map a served model excludes by
+// (models/als_model.py::SeenItems): pio_group_rows counts the entries a
+// row, takes the prefix sum and writes each entry's column to its row's
+// next free slot in the caller's order — the result of a stable sort by
+// row, duplicates kept, without the sort.
 //
 // Build: g++ -O3 -shared -fPIC (see native/__init__.py; no deps).
 
@@ -332,5 +340,27 @@ int64_t pio_bucket_fill(void* plan_ptr, const int32_t* rows,
 }
 
 void pio_bucket_free(void* plan_ptr) { delete static_cast<Plan*>(plan_ptr); }
+
+// COO → CSR by row. indptr: [n_rows + 1] int64, items: [n] int32, both the
+// caller's. Row r's columns end up in items[indptr[r] .. indptr[r + 1]) in
+// the caller's order. Returns 0, or kBadIds (a row id outside [0, n_rows))
+// with the outputs undefined.
+int64_t pio_group_rows(const int32_t* rows, const int32_t* cols, int64_t n,
+                       int64_t n_rows, int64_t* indptr, int32_t* items) {
+    if (n_rows < 0) return kBadIds;
+    std::fill(indptr, indptr + n_rows + 1, 0);
+    for (int64_t k = 0; k < n; ++k) {
+        const int32_t r = rows[k];
+        if (r < 0 || r >= n_rows) return kBadIds;
+        indptr[r + 1] += 1;
+    }
+    for (int64_t r = 0; r < n_rows; ++r) indptr[r + 1] += indptr[r];
+    // indptr[r] is row r's next free slot, and its end once every entry is
+    // placed: the starts, one slot down
+    for (int64_t k = 0; k < n; ++k) items[indptr[rows[k]]++] = cols[k];
+    std::copy_backward(indptr, indptr + n_rows, indptr + n_rows + 1);
+    indptr[0] = 0;
+    return 0;
+}
 
 }  // extern "C"

@@ -52,8 +52,11 @@ class TestCompileCacheDir:
 
 
 def _run_smoke(*args, cwd=REPO, script=SMOKE):
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    # a cache directory of the smoke's own: its retrain phase counts the
+    # directory's entries, and the suite's other workers persist theirs
+    # into the checkout's while it runs
+    env = dict(os.environ, JAX_PLATFORMS="cpu", JAX_COMPILATION_CACHE_DIR=str(
+        REPO / ".pio_store" / "chip_smoke_jax_cache"))
     env.pop("XLA_FLAGS", None)  # conftest's 8 virtual devices: one will do
     return subprocess.run([sys.executable, str(script), *args], cwd=cwd,
                           env=env, capture_output=True, text=True,
