@@ -8,8 +8,9 @@ an earlier layer left) or one of the two of a Mamba-2 hybrid (the
 scalar-decay state-space layer in its chunked matrix form, `ops/ssd.py`;
 plain softmax attention over grouped heads without positions) as the
 configuration says layer by layer, and a dense or a sparse-expert
-feed-forward; an optional multi-token-prediction (MTP) module; trained
-on packed histories.
+feed-forward, the router on the normed stream after the mixer or on the
+block's own input; an optional multi-token-prediction (MTP) module;
+trained on packed histories.
 
 One code path runs every size. A configuration file in the published
 model's own key names (`EncoderConfig.from_json`) gives the widths, the
@@ -68,7 +69,17 @@ Equations (the plain reference is `quality/encoder_reference.py`):
             value head j // (heads / kv heads); o = softmax over the
             keys s <= t of the history of (q_t . k_s attention_multiplier)
             v_s, the multiplier the scale itself (1 / sqrt(d) where none
-            is stated); out = concat(o) W_o. No positional encoding.
+            is stated); out = concat(o) W_o. No positional encoding;
+            layer by layer, where the configuration's layouts say so
+            (`rope_layout`, `sliding_window_layout`), RoPE on q and k
+            (`rope_interleave` false: pairs (x[i], x[i + d/2])) and a
+            window, t - s < sliding_window
+    RxB     the block of a model that routes before it mixes (`w_g` on
+            the block's input h, not normed): r = h W_r in float32; idx
+            = top-k of r; w = softmax(r[idx]) over the picked; a = h +
+            GQA(RMSNorm(h)); out = a + sum over the held e in idx of w_e
+            W_down,e (relu(W_gate,e x) * (W_up,e x)), x = RMSNorm(a). No
+            bias on the router, no shared expert, no dense layer before
     x4      the four multipliers of such a hybrid, each 1 where a
             configuration states none: h0 = embedding_multiplier
             E[token]; h += residual_multiplier Mixer(..) and h +=
@@ -78,8 +89,9 @@ Equations (the plain reference is `quality/encoder_reference.py`):
             with `tie_word_embeddings` the head is the embedding
             transposed
     FFN     SwiGLU in the first `first_k_dense_replace` blocks; after them
-            shared SwiGLU + the held experts' part of the routed result
-            (`ops/moe.py`)
+            shared SwiGLU (where the model has a shared expert) + the
+            held experts' part of the routed result (`ops/moe.py`), the
+            experts' gate SiLU or, under `moe_gate`, ReLU
     MTP     h' = W_eh [RMSNorm(h_t) ; RMSNorm(E(x_{t+1}))] -> one expert
             block -> the shared final norm and head -> predicts x_{t+2}
     loss    CE + mtp_loss_weight * CE_mtp, both inside segments
@@ -92,6 +104,7 @@ sum on; router scores, softmax, norms, RoPE and the loss are float32.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import json
 import math
 
@@ -130,6 +143,12 @@ class EncoderConfig:
     layer_kinds: tuple = ()
     num_key_value_heads: int = 0     # grouped K/V heads of swa/full/cross/gqa
     sliding_window: int = 0          # of the swa layers
+    head_dim: int = 0                # gqa's head width; 0: hidden / heads
+    # of a model whose grouped-query layers differ, a flag a held layer:
+    # a window of `sliding_window`; RoPE on q and k. (): neither anywhere
+    layer_windowed: tuple = ()
+    layer_rotated: tuple = ()
+    rope_interleave: bool = True     # pairs (2i, 2i+1); False: (i, i + d/2)
     layer_first: int = 0             # published index of the first held layer
     mamba_expand: int = 2            # channels / hidden_size
     mamba_d_state: int = 16
@@ -160,6 +179,9 @@ class EncoderConfig:
     expert_first: int = 0            # id of the first held expert
     n_shared_experts: int = 1
     num_experts_per_tok: int = 0
+    router_scoring: str = "sigmoid"  # | "softmax" (`ops/moe.py::route`)
+    router_on_block_input: bool = False  # w_g reads h before the mixer
+    moe_gate: str = "silu"           # | "relu": the experts' gate
     routed_scaling_factor: float = 1.0
     num_nextn_predict_layers: int = 0
     mtp_loss_weight: float = 0.3
@@ -206,10 +228,17 @@ class EncoderConfig:
         return self.layer_norm_eps or self.rms_norm_eps
 
     @property
+    def router_biased(self) -> bool:
+        """A sigmoid router picks with a load-balance bias (a buffer)."""
+        return self.router_scoring == "sigmoid"
+
+    @property
     def moe_stacked(self) -> bool:
         """Expert blocks of one kind are stacked on a leading axis and
-        run by one scan; of two kinds they are a list."""
-        return len(set(self.kinds[self.n_dense:])) <= 1
+        run by one scan; of two kinds, or with a window or rotation
+        that differs layer by layer, they are a list."""
+        return (len(set(self.kinds[self.n_dense:])) <= 1
+                and not (self.layer_windowed or self.layer_rotated))
 
     @classmethod
     def from_dict(cls, d: dict) -> "EncoderConfig":
@@ -243,6 +272,8 @@ class EncoderConfig:
                 int(flat["mb_per_layer"]))
         if flat.get("layer_types"):
             flat["layer_kinds"] = _held_layer_types(flat)
+        if "moe_num_primary_experts" in flat:
+            _routed_before_mixing(flat)
         known = {f.name for f in dataclasses.fields(cls)}
         kw = {k: v for k, v in flat.items() if k in known and v is not None}
         kw["report_blocks"] = tuple(
@@ -283,6 +314,43 @@ def _held_layer_types(flat: dict) -> tuple:
     if flat.get("shared_intermediate_size"):
         flat["intermediate_size"] = flat["shared_intermediate_size"]
     return tuple(_LAYER_TYPES[t] for t in mine)
+
+
+def _routed_before_mixing(flat: dict) -> None:
+    """The sizes here from SmallThinker's key names: every layer
+    grouped-query attention, windowed and rotated as the two published
+    layouts say (whole, sliced from `layer_first`, or the held slice
+    alone), beside routed experts with the router on the block's input,
+    a softmax over the picked logits and a ReLU gate; no shared expert,
+    no dense layer. A layout entry, router rule or gate the code does
+    not know is refused."""
+    held = int(flat["num_hidden_layers"])
+    flags = {}
+    for key in ("sliding_window_layout", "rope_layout"):
+        layout = list(flat[key])
+        first = int(flat.get("layer_first", 0)) if len(layout) > held else 0
+        mine = layout[first:first + held]
+        if len(mine) != held or set(mine) - {0, 1}:
+            raise ValueError(f"{key}[{first}:{first + held}] = {mine}: "
+                             f"{held} entries of 0 and 1 wanted")
+        flags[key] = tuple(bool(v) for v in mine)
+    if flat["moe_primary_router_apply_softmax"] is not True:
+        raise ValueError("moe_primary_router_apply_softmax = "
+                         f"{flat['moe_primary_router_apply_softmax']!r}: "
+                         "the router's rule is a softmax over the picked")
+    if flat.setdefault("moe_gate", "relu") not in moe.GATES:
+        raise ValueError(f"moe_gate = {flat['moe_gate']!r}: one of "
+                         f"{sorted(moe.GATES)}")
+    flat.update(
+        layer_kinds=("gqa",) * held,
+        layer_windowed=flags["sliding_window_layout"],
+        layer_rotated=flags["rope_layout"],
+        sliding_window=flat["sliding_window_size"], rope_interleave=False,
+        intermediate_size=0, first_k_dense_replace=0, n_shared_experts=0,
+        moe_intermediate_size=flat["moe_ffn_hidden_size"],
+        n_routed_experts=flat["moe_num_primary_experts"],
+        num_experts_per_tok=flat["moe_num_active_primary_experts"],
+        router_scoring="softmax", router_on_block_input=True)
 
 
 def hybrid_decoder_kinds(first: int, held: int, total: int,
@@ -334,14 +402,19 @@ def rms_norm(x, w, eps):
                              + eps) * w
 
 
-def rope(x, positions, theta: float):
-    """Interleaved rotary embedding: pairs (x[2i], x[2i+1]) turn by
-    position * theta^(-2i/d). x [..., L, heads, d]; positions [..., L]."""
+def rope(x, positions, theta: float, interleave: bool = True):
+    """Rotary embedding: pair i of x turns by position * theta^(-2i/d),
+    the pairs (x[2i], x[2i+1]) or, without `interleave`, (x[i],
+    x[i + d/2]) (half rotation). x [..., L, heads, d]; positions [..., L]."""
     d = x.shape[-1]
     inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
     ang = positions.astype(jnp.float32)[..., None, None] * inv
     cos, sin = jnp.cos(ang), jnp.sin(ang)
     x = x.astype(jnp.float32)
+    if not interleave:
+        lo, hi = x[..., :d // 2], x[..., d // 2:]
+        return jnp.concatenate([lo * cos - hi * sin, lo * sin + hi * cos],
+                               axis=-1)
     even, odd = x[..., 0::2], x[..., 1::2]
     return jnp.stack([even * cos - odd * sin, even * sin + odd * cos],
                      axis=-1).reshape(x.shape)
@@ -521,22 +594,27 @@ def ssd(p, cfg: EncoderConfig, x, seg, scope: str = "enc.ssd"):
         return _mm(cfg, y, p["w_out"])
 
 
-def gqa(p, cfg: EncoderConfig, x, seg, pos, scope: str = "enc.gqa"):
+def gqa(p, cfg: EncoderConfig, x, seg, pos, scope: str = "enc.gqa",
+        window=None, rotate: bool = False):
     """Plain softmax attention over grouped heads on x [B, L, D]
-    (already normed), nothing rotated: query head j reads key and value
-    head j // (heads / kv heads). Scopes `proj`, `pairs`, `out`."""
+    (already normed): query head j reads key and value head j // (heads
+    / kv heads). With `rotate` RoPE on q and k, with a `window` the keys
+    t - s < window alone. Scopes `proj`, `pairs`, `out`."""
     cd = _dt(cfg.compute_dtype)
     b, l, _ = x.shape
     h, hk = cfg.num_attention_heads, cfg.num_key_value_heads
     dh = p["w_q"].shape[1] // h
+    turn = ((lambda t: rope(t, pos, cfg.rope_theta, cfg.rope_interleave))
+            if rotate else (lambda t: t))
     with jax.named_scope(f"{scope}.proj"):
-        q = _mm(cfg, x, p["w_q"]).reshape(b, l, h, dh)
-        k, v = (jnp.repeat(_mm(cfg, x, p[w]).reshape(b, l, hk, dh), h // hk,
-                           axis=2) for w in ("w_k", "w_v"))
+        q = turn(_mm(cfg, x, p["w_q"]).reshape(b, l, h, dh))
+        k, v = (jnp.repeat(f(_mm(cfg, x, p[w]).reshape(b, l, hk, dh)),
+                           h // hk, axis=2)
+                for w, f in (("w_k", turn), ("w_v", lambda t: t)))
         q, k, v = (t.astype(cd).transpose(0, 2, 1, 3) for t in (q, k, v))
     o = segment_attention(q, k, v, seg, pos, block=cfg.attention_block,
                           scale=cfg.attention_multiplier or dh ** -0.5,
-                          scope=f"{scope}.pairs")
+                          scope=f"{scope}.pairs", window=window)
     with jax.named_scope(f"{scope}.out"):
         return _mm(cfg, o.transpose(0, 2, 1, 3).reshape(b, l, h * dh),
                    p["w_o"])
@@ -652,52 +730,83 @@ def carried_block(p, cfg: EncoderConfig, kind: str, layer: int, h, seg, pos,
     return h, carry
 
 
-def expert_ffn(p, bias, cfg: EncoderConfig, x2d, scope: str = "enc.moe"):
-    """Shared SwiGLU + the held experts' part. Returns (y, routed): the
-    held experts' token counts, every expert's load and each token's
-    picks [T, k]."""
+def _route(p, bias, cfg: EncoderConfig, x2d):
+    return moe.route(x2d, p["w_g"], bias, cfg.num_experts_per_tok,
+                     cfg.routed_scaling_factor, cfg.router_scoring)
+
+
+def expert_ffn(p, bias, cfg: EncoderConfig, x2d, scope: str = "enc.moe",
+               routing=None):
+    """Shared SwiGLU (where the block holds one) + the held experts'
+    part, routed here on x2d or as `routing` says (`_route`'s result on
+    the block's input). Returns (y, routed): the held experts' token
+    counts, every expert's load and each token's picks [T, k]."""
     cd = _dt(cfg.compute_dtype)
-    with jax.named_scope("moe.router"):
-        idx, weights, load = moe.route(
-            x2d, p["w_g"], bias, cfg.num_experts_per_tok,
-            cfg.routed_scaling_factor)
+    if routing is None:
+        with jax.named_scope("moe.router"):
+            routing = _route(p, bias, cfg, x2d)
+    idx, weights, load = routing
     with jax.named_scope("moe.experts"):
         y, counts = moe.held_experts(
             x2d, weights, idx, p["experts_w13"], p["experts_w2"],
-            cfg.expert_first, cfg.moe_block_rows, cd, scope)
-    with jax.named_scope("moe.shared"):
-        y = y + swiglu(cfg, x2d, p["shared_w13"], p["shared_w2"])
+            cfg.expert_first, cfg.moe_block_rows, cd, scope, cfg.moe_gate)
+    if "shared_w13" in p:
+        with jax.named_scope("moe.shared"):
+            y = y + swiglu(cfg, x2d, p["shared_w13"], p["shared_w2"])
     return y, {"counts": counts, "load": load, "picks": idx}
 
 
-def _mix(p, cfg: EncoderConfig, h, seg, pos, scope: str = ""):
+def _mix(p, cfg: EncoderConfig, h, seg, pos, scope: str = "", n: int = 0):
     """The first half of `block`: h += Mixer(norm(h)), the mixer the
-    one whose parameters the block holds (`attn`: MLA, `kda`: KDA)."""
+    one whose parameters the block holds (`attn`: MLA, `kda`: KDA, `gqa`:
+    grouped-query attention, windowed and rotated as the configuration
+    says of the held layer `n`, traced under `enc.gqa_swa` with a window
+    and `enc.gqa_full` without)."""
     x = _norm(cfg, h, p, "norm1")
     if "kda" in p:
         with jax.named_scope(scope or "enc.kda"):
             return h + kda(p["kda"], cfg, x, seg, scope or "enc.kda")
+    if "gqa" in p:
+        windowed = bool(cfg.layer_windowed) and cfg.layer_windowed[n]
+        scope = scope or ("enc.gqa_swa" if windowed else "enc.gqa_full")
+        with jax.named_scope(scope):
+            return h + gqa(
+                p["gqa"], cfg, x, seg, pos, scope,
+                window=cfg.sliding_window if windowed else None,
+                rotate=bool(cfg.layer_rotated) and cfg.layer_rotated[n])
     with jax.named_scope(scope or "enc.mla"):
         return h + mla(p["attn"], cfg, x, seg, pos, scope or "enc.mla")
 
 
-def block(p, bias, cfg: EncoderConfig, h, seg, pos, scope: str = ""):
-    """One block on the residual stream h [B, L, D]. `bias` is None in a
-    dense block. Returns (h, routed), `routed` (see `expert_ffn`) None
-    when dense. Its ops are traced under `enc.mla` or `enc.kda`,
-    `enc.dense_ffn` and `enc.moe`, or all under `scope` where one is
-    given (the MTP module's block)."""
-    return _feed_forward(p, bias, cfg, _mix(p, cfg, h, seg, pos, scope),
-                         scope)
+def block(p, bias, cfg: EncoderConfig, h, seg, pos, scope: str = "",
+          n: int = 0):
+    """One block on the residual stream h [B, L, D], the held layer `n`
+    where layers differ; a dense block holds no router (`w_g`), and
+    `bias` is None there and for a router without one. Returns (h,
+    routed), `routed` (see `expert_ffn`) None when dense. Its ops are
+    traced under `enc.mla`, `enc.kda` or `enc.gqa_*`, `enc.dense_ffn`
+    and `enc.moe`, or all under `scope` where one is given (the MTP
+    module's block). With `router_on_block_input` the router reads h as
+    it enters, before the mixer (`enc.router`), and the experts run
+    under `enc.experts`."""
+    routing = None
+    if cfg.router_on_block_input and "w_g" in p:
+        with jax.named_scope(scope or "enc.router"):
+            routing = _route(p, bias, cfg, h.reshape(-1, h.shape[-1]))
+    h = _mix(p, cfg, h, seg, pos, scope, n)
+    return _feed_forward(
+        p, bias, cfg, h,
+        scope or ("enc.experts" if routing is not None else ""),
+        routing=routing)
 
 
 def _feed_forward(p, bias, cfg: EncoderConfig, h, scope: str = "",
-                  scale: float = 1.0):
+                  scale: float = 1.0, routing=None):
     """The second half of `block`: h += FFN(norm(h)), a dense one times
     `scale` (a carried block's residual multiplier)."""
     b, l, d = h.shape
     x2d = _norm(cfg, h, p, "norm2").reshape(b * l, d)
-    if bias is None:
+    if "w_g" not in p:
         with jax.named_scope(scope or "enc.dense_ffn"):
             y = _by_rows(cfg, lambda x: swiglu(cfg, x, p["w13"], p["w2"]),
                          x2d)
@@ -705,7 +814,8 @@ def _feed_forward(p, bias, cfg: EncoderConfig, h, scope: str = "",
                 y = scale * y
         return h + y.reshape(b, l, d), None
     with jax.named_scope(scope or "enc.moe"):
-        y, routed = expert_ffn(p, bias, cfg, x2d, scope or "enc.moe")
+        y, routed = expert_ffn(p, bias, cfg, x2d, scope or "enc.moe",
+                               routing)
     return h + y.reshape(b, l, d), routed
 
 
@@ -756,17 +866,18 @@ def run_blocks(params, cfg: EncoderConfig, h, seg, pos):
     if not cfg.n_moe:
         return h, None
 
-    def one(h, layer):
+    def one(h, layer, n=0):
         p, bias = layer
-        return block(p, bias, cfg, h, seg, pos)
+        return block(p, bias, cfg, h, seg, pos, n=n)
 
     if cfg.moe_stacked:
         return jax.lax.scan(_maybe_remat(one, cfg), h,
                             (params["moe"], params["router_bias"]))
     routed = []
-    for layer in zip(params["moe"], params["router_bias"]):
+    biases = params.get("router_bias", (None,) * cfg.n_moe)
+    for n, layer in enumerate(zip(params["moe"], biases)):
         h, r = (_kda_block(*layer, cfg, h, seg, pos) if "kda" in layer[0]
-                else _maybe_remat(one, cfg)(h, layer))
+                else _maybe_remat(functools.partial(one, n=n), cfg)(h, layer))
         routed.append(r)
     return h, {k: jnp.stack([r[k] for r in routed]) for k in routed[0]}
 
@@ -807,10 +918,13 @@ def head_logits(params, cfg: EncoderConfig, h):
     return logits
 
 
-def cross_entropy_sum(params, cfg: EncoderConfig, h2d, targets, valid):
+def cross_entropy_sum(params, cfg: EncoderConfig, h2d, targets, valid,
+                      rows: bool = False):
     """Sum over the valid rows of h2d [T, D] of -log softmax(head(
     norm(h)))[target], a chunk of rows at a time: [chunk, vocabulary]
-    is the most that is ever held, and the backward pass recomputes it."""
+    is the most that is ever held, and the backward pass recomputes it.
+    Returns (the sum, with `rows` every row's term [T], zero where not
+    valid, else None)."""
     t = h2d.shape[0]
     chunk = cfg.loss_chunk if t % cfg.loss_chunk == 0 else t
 
@@ -819,18 +933,22 @@ def cross_entropy_sum(params, cfg: EncoderConfig, h2d, targets, valid):
         logits = head_logits(params, cfg, h_c)
         lse = jax.nn.logsumexp(logits, axis=-1)
         tgt = jnp.take_along_axis(logits, t_c[:, None], axis=-1)[:, 0]
-        return total + jnp.sum((lse - tgt) * v_c), None
+        nll = (lse - tgt) * v_c
+        return total + jnp.sum(nll), nll if rows else None
 
     split = lambda a: a.reshape((t // chunk, chunk) + a.shape[1:])  # noqa: E731
-    total, _ = jax.lax.scan(
+    total, nll = jax.lax.scan(
         _maybe_remat(one, cfg), jnp.float32(0.0),
         (split(h2d), split(targets), split(valid.astype(jnp.float32))))
-    return total
+    return total, nll.reshape(t) if rows else None
 
 
 def losses(params, cfg: EncoderConfig, tokens, seg, pos):
     """(CE + w * CE_mtp, aux). A position counts where its target lies in
-    its own history: t+1 for CE, t+2 for the MTP module."""
+    its own history: t+1 for CE, t+2 for the MTP module. A model with
+    a window layout reports `nll_rows` too, every position's CE term
+    [B, L] (zero where none counts): a window cuts keys from a few rows
+    of a batch, too few for the mean to feel them."""
     b, l = tokens.shape
     h, routed = encode(params, cfg, tokens, seg, pos)
 
@@ -841,16 +959,19 @@ def losses(params, cfg: EncoderConfig, tokens, seg, pos):
 
     with jax.named_scope("enc.head_loss"):
         t1, v1 = shifted(1)
-        ce = (cross_entropy_sum(params, cfg, h.reshape(b * l, -1), t1, v1)
-              / jnp.maximum(jnp.sum(v1), 1))
+        total, nll = cross_entropy_sum(params, cfg, h.reshape(b * l, -1), t1,
+                                       v1, rows=any(cfg.layer_windowed))
+        ce = total / jnp.maximum(jnp.sum(v1), 1)
     aux = {"ce": ce, **(routed or {})}
+    if nll is not None:
+        aux["nll_rows"] = nll.reshape(b, l)
     if not cfg.num_nextn_predict_layers:
         return ce, aux
     h2, mtp_routed = mtp_hidden(params, cfg, h, tokens, seg, pos)
     with jax.named_scope("enc.head_loss"):
         t2, v2 = shifted(2)
         ce_mtp = (cross_entropy_sum(params, cfg, h2.reshape(b * l, -1),
-                                    t2, v2) / jnp.maximum(jnp.sum(v2), 1))
+                                    t2, v2)[0] / jnp.maximum(jnp.sum(v2), 1))
     aux.update(ce_mtp=ce_mtp,
                **{"mtp_" + k: v for k, v in mtp_routed.items()})
     return ce + cfg.mtp_loss_weight * ce_mtp, aux
@@ -911,10 +1032,10 @@ def _ssd_shapes(cfg: EncoderConfig) -> dict:
 
 
 def _gqa_shapes(cfg: EncoderConfig) -> dict:
-    d = cfg.hidden_size
-    dh = d // cfg.num_attention_heads
-    return {"w_q": (d, d), "w_k": (d, cfg.num_key_value_heads * dh),
-            "w_v": (d, cfg.num_key_value_heads * dh), "w_o": (d, d)}
+    d, h = cfg.hidden_size, cfg.num_attention_heads
+    dh = cfg.head_dim or d // h
+    return {"w_q": (d, h * dh), "w_k": (d, cfg.num_key_value_heads * dh),
+            "w_v": (d, cfg.num_key_value_heads * dh), "w_o": (h * dh, d)}
 
 
 def _diff_shapes(cfg: EncoderConfig, cross: bool) -> dict:
@@ -970,8 +1091,9 @@ def _block_shapes(cfg: EncoderConfig, dense: bool, kind: str = "mla") -> dict:
         f, e = cfg.moe_intermediate_size, cfg.n_routed_experts
         fs = f * cfg.n_shared_experts
         out.update(w_g=(d, cfg.experts_total),
-                   shared_w13=(d, 2 * fs), shared_w2=(fs, d),
                    experts_w13=(e, d, 2 * f), experts_w2=(e, f, d))
+        if fs:  # a model without a shared expert holds no such arrays
+            out.update(shared_w13=(d, 2 * fs), shared_w2=(fs, d))
     return out
 
 
@@ -1052,6 +1174,8 @@ def init_params(cfg: EncoderConfig, vocab: int, key):
 def init_buffers(cfg: EncoderConfig) -> dict:
     """What the gradient does not touch: the routers' load-balance bias."""
     out = {}
+    if not cfg.router_biased:
+        return out
     if cfg.n_moe:
         out["router_bias"] = jnp.zeros((cfg.n_moe, cfg.experts_total),
                                        jnp.float32)
@@ -1105,11 +1229,11 @@ def train_step(cfg: EncoderConfig, lr: float):
                 / (jnp.sqrt(vv / (1.0 - b2 ** t)) + cfg.adam_eps),
                 state["params"], m, v)
             new_buffers = dict(buffers)
-            if cfg.n_moe:
+            if "router_bias" in buffers:
                 new_buffers["router_bias"] = _balance(
                     buffers["router_bias"], aux["load"],
                     cfg.bias_update_rate)
-            if cfg.num_nextn_predict_layers:
+            if "mtp_router_bias" in buffers:
                 new_buffers["mtp_router_bias"] = _balance(
                     buffers["mtp_router_bias"], aux["mtp_load"],
                     cfg.bias_update_rate)

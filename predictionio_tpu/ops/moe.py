@@ -3,9 +3,10 @@ layer that is told which of them it holds.
 
 A deployment divides the experts of a layer over chips. Every chip routes
 every token over all the experts (`route`: sigmoid scores, a load-balance
-bias that only picks, weights normalised over the picked and scaled) and
-computes the part of the result that its own experts give
-(`held_experts`). What the absent experts would have added is left out;
+bias that only picks, weights normalised over the picked and scaled; or
+the top-k of the logits and a softmax over the picked) and computes the
+part of the result that its own experts give (`held_experts`: gated
+experts, the gate's activation SiLU or ReLU). What the absent experts would have added is left out;
 on one chip the layer runs without its exchange, and nothing here stands
 in for the chips that are not there.
 
@@ -36,19 +37,33 @@ import jax
 import jax.numpy as jnp
 
 
-def route(x, w_g, bias, top_k: int, scale: float):
+def route(x, w_g, bias, top_k: int, scale: float,
+          scoring: str = "sigmoid"):
     """Scores of x [T, D] over all the experts of w_g [D, E].
 
     Returns (idx [T, k] int32, weights [T, k] float32, load [E] int32):
-    the top-k of `sigmoid(x w_g) + bias`, the picked scores normalised to
-    sum to one and scaled, and how many tokens picked each expert. The
-    bias decides the pick only (`noaux_tc`). The scores are float32 at
-    the highest matmul precision: a pick turns on their last digits."""
-    s = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32), w_g,
-                               precision="highest"))
-    _, idx = jax.lax.top_k(s + jax.lax.stop_gradient(bias)[None, :], top_k)
-    picked = jnp.take_along_axis(s, idx, axis=-1)
-    weights = scale * picked / jnp.sum(picked, axis=-1, keepdims=True)
+    the picks, their weights, and how many tokens picked each expert.
+    `scoring` "sigmoid": the top-k of `sigmoid(x w_g) + bias`, the
+    picked scores normalised to sum to one and scaled; the bias decides
+    the pick only (`noaux_tc`). "softmax": the top-k of the logits
+    x w_g and a softmax over the picked (a softmax over all, picked and
+    renormalised, is the same); no bias, no scale. The scores are
+    float32 at the highest matmul precision: a pick turns on their last
+    digits."""
+    logits = jnp.dot(x.astype(jnp.float32), w_g, precision="highest")
+    if scoring == "softmax":
+        if bias is not None or scale != 1.0:
+            raise ValueError("a softmax router takes no bias and no scale")
+        picked, idx = jax.lax.top_k(logits, top_k)
+        weights = jax.nn.softmax(picked, axis=-1)
+    elif scoring == "sigmoid":
+        s = jax.nn.sigmoid(logits)
+        _, idx = jax.lax.top_k(s + jax.lax.stop_gradient(bias)[None, :],
+                               top_k)
+        picked = jnp.take_along_axis(s, idx, axis=-1)
+        weights = scale * picked / jnp.sum(picked, axis=-1, keepdims=True)
+    else:
+        raise ValueError(f"no router scoring {scoring!r}")
     load = jnp.zeros(w_g.shape[1], jnp.int32).at[idx.reshape(-1)].add(1)
     return idx.astype(jnp.int32), weights, load
 
@@ -95,16 +110,33 @@ def _gather_picks(buf, pos, weights):
     return out
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
-def _held_experts(x, weights, idx, w13, w2, first, block, dtype, scope):
-    return _held_fwd(x, weights, idx, w13, w2, first, block, dtype,
-                     scope)[0]
+def _silu_gate(g):
+    sg = jax.nn.sigmoid(g)
+    return g * sg, lambda: sg * (1.0 + g * (1.0 - sg))
 
 
-def _held_fwd(x, weights, idx, w13, w2, first, block, dtype, scope):
+def _relu_gate(g):
+    on = g > 0
+    return jnp.where(on, g, 0.0), lambda: on.astype(g.dtype)
+
+
+# the gate's activation: act(g) for the forward pass; for the hand-written
+# backward pass act(g) and, when it is called for, d act / d g
+GATES = {"silu": (jax.nn.silu, _silu_gate),
+          "relu": (jax.nn.relu, _relu_gate)}
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
+def _held_experts(x, weights, idx, w13, w2, first, block, dtype, scope,
+                  gate):
+    return _held_fwd(x, weights, idx, w13, w2, first, block, dtype, scope,
+                     gate)[0]
+
+
+def _held_fwd(x, weights, idx, w13, w2, first, block, dtype, scope, gate):
     with jax.named_scope(scope):
         return _held_fwd_scoped(x, weights, idx, w13, w2, first, block,
-                                dtype)
+                                dtype, scope, gate)
 
 
 def _in_dtype(w13, w2, dtype):
@@ -115,11 +147,14 @@ def _in_dtype(w13, w2, dtype):
     return w13.astype(dtype), w2.astype(dtype)
 
 
-def _held_fwd_scoped(x, weights, idx, w13, w2, first, block, dtype):
+def _held_fwd_scoped(x, weights, idx, w13, w2, first, block, dtype, scope,
+                     gate):
     held, d = w13.shape[0], x.shape[1]
     f = w2.shape[1]
-    pos, tok_of_row, group_start, n_blocks, counts = _plan(
-        idx, first, held, block)
+    act = GATES[gate][0]
+    with jax.named_scope(f"{scope}.plan"):
+        pos, tok_of_row, group_start, n_blocks, counts = _plan(
+            idx, first, held, block)
     x_pad = jnp.concatenate([x.astype(dtype), jnp.zeros((1, d), dtype)])
 
     def expert(ys, e):
@@ -129,7 +164,7 @@ def _held_fwd_scoped(x, weights, idx, w13, w2, first, block, dtype):
             at = start + b * block
             xb = jnp.take(x_pad, _rows(tok_of_row, at, block), axis=0)
             h = jnp.dot(xb, w13_e, preferred_element_type=jnp.float32)
-            a = (jax.nn.silu(h[:, :f]) * h[:, f:]).astype(dtype)
+            a = (act(h[:, :f]) * h[:, f:]).astype(dtype)
             yb = jnp.dot(a, w2_e, preferred_element_type=jnp.float32)
             return jax.lax.dynamic_update_slice_in_dim(
                 ys, yb.astype(dtype), at, 0)
@@ -143,18 +178,21 @@ def _held_fwd_scoped(x, weights, idx, w13, w2, first, block, dtype):
     return (y, counts), (x, weights, idx, w13, w2)
 
 
-def _held_bwd(first, block, dtype, scope, res, cot):
+def _held_bwd(first, block, dtype, scope, gate, res, cot):
     # traced outside the caller's scopes: it opens the one it was given
     with jax.named_scope(scope):
-        return _held_bwd_scoped(first, block, dtype, res, cot)
+        return _held_bwd_scoped(first, block, dtype, scope, gate, res, cot)
 
 
-def _held_bwd_scoped(first, block, dtype, res, cot):
+def _held_bwd_scoped(first, block, dtype, scope, gate, res, cot):
     x, weights, idx, w13, w2 = res
     dy = cot[0]
     held, d = w13.shape[0], x.shape[1]
     f = w2.shape[1]
-    pos, tok_of_row, group_start, n_blocks, _ = _plan(idx, first, held, block)
+    act_and_slope = GATES[gate][1]
+    with jax.named_scope(f"{scope}.plan"):
+        pos, tok_of_row, group_start, n_blocks, _ = _plan(idx, first, held,
+                                                          block)
     m = tok_of_row.shape[0]
     x_pad = jnp.concatenate([x.astype(dtype), jnp.zeros((1, d), dtype)])
     dy_pad = jnp.concatenate([dy.astype(dtype), jnp.zeros((1, d), dtype)])
@@ -172,8 +210,7 @@ def _held_bwd_scoped(first, block, dtype, res, cot):
                                                                axis=0)
             h = jnp.dot(xb, w13_e, preferred_element_type=jnp.float32)
             g, u = h[:, :f], h[:, f:]
-            sg = jax.nn.sigmoid(g)
-            act = g * sg
+            act, slope = act_and_slope(g)
             a = (act * u).astype(dtype)
             yb = jnp.dot(a, w2_e, preferred_element_type=jnp.float32)
             dwr = jnp.sum(yb * dyb.astype(jnp.float32), axis=-1)
@@ -182,7 +219,7 @@ def _held_bwd_scoped(first, block, dtype, res, cot):
             dw2_e = dw2_e + jnp.dot(a.T, dyw,
                                     preferred_element_type=jnp.float32)
             da = jnp.dot(dyw, w2_e.T, preferred_element_type=jnp.float32)
-            dg = da * u * (sg * (1.0 + g * (1.0 - sg)))
+            dg = da * u * slope()
             dh = jnp.concatenate([dg, da * act], axis=1).astype(dtype)
             dw13_e = dw13_e + jnp.dot(xb.T, dh,
                                       preferred_element_type=jnp.float32)
@@ -213,17 +250,22 @@ _held_experts.defvjp(_held_fwd, _held_bwd)
 
 
 def held_experts(x, weights, idx, w13, w2, first: int, block: int,
-                 dtype=jnp.float32, scope: str = "moe.held_experts"):
+                 dtype=jnp.float32, scope: str = "moe.held_experts",
+                 gate: str = "silu"):
     """The held experts' part of an expert layer's result.
 
     x [T, D]; idx, weights [T, k] from `route`; w13 [held, D, 2F] and w2
     [held, F, D] are the experts `first .. first + held - 1` of the
     layer. Returns (y [T, D] float32: sum over a token's picks that are
-    held here of weight * SwiGLU_expert(x); counts [held]: tokens each
+    held here of weight * W_down (act(W_gate x) * (W_up x)), act the
+    `gate` named ("silu": SwiGLU; "relu"); counts [held]: tokens each
     held expert was sent). Products take `dtype` operands and accumulate
     in float32; the rows travel to the combine in `dtype` (see the
     module's docstring); the ops, those of the backward pass too, are traced
-    under `scope`."""
+    under `scope`, the dispatch plan's under `<scope>.plan`."""
+    if gate not in GATES:
+        raise ValueError(f"no gate activation {gate!r}: one of "
+                         f"{sorted(GATES)}")
     y, counts = _held_experts(x, weights, idx, w13, w2, int(first),
-                              int(block), jnp.dtype(dtype), scope)
+                              int(block), jnp.dtype(dtype), scope, gate)
     return y, jax.lax.stop_gradient(counts)

@@ -370,6 +370,11 @@ EXPERT_TOKENS = REGISTRY.gauge(
     "encoder_expert_tokens", "Tokens the last train step sent to each "
     "held expert, by expert layer (mtp = the MTP module's) and expert id",
     labelnames=("layer", "expert"))
+EXPERT_BLOCK_ROWS = REGISTRY.gauge(
+    "encoder_expert_block_rows", "Rows the grouped products of the last "
+    "train step walked for each held expert: its tokens rounded up to whole "
+    "row blocks (ops/moe.py); encoder_expert_tokens over it is the blocks' "
+    "fill", labelnames=("layer", "expert"))
 KDA_CHUNKS = REGISTRY.gauge(
     "encoder_kda_chunks", "Chunks of the KDA scan (ops/kda.py) in the "
     "sequences of each step of the last train's epoch, a KDA layer",
@@ -539,10 +544,13 @@ class SessionRecAlgorithm(Algorithm):
                 rows = np.atleast_2d(metrics.get(name, np.zeros((0, 0))))
                 for layer, row in enumerate(rows):
                     for e, count in enumerate(row):
-                        EXPERT_TOKENS.labels(
-                            layer="mtp" if name == "mtp_counts"
-                            else str(layer),
-                            expert=str(cfg.expert_first + e)).set(int(count))
+                        labels = {"layer": "mtp" if name == "mtp_counts"
+                                  else str(layer),
+                                  "expert": str(cfg.expert_first + e)}
+                        EXPERT_TOKENS.labels(**labels).set(int(count))
+                        EXPERT_BLOCK_ROWS.labels(**labels).set(
+                            -(-int(count) // cfg.moe_block_rows)
+                            * cfg.moe_block_rows)
             log.info("SessionRec: trained %d sequences, %d items, final "
                      "loss %.4f", len(seqs), n_items, float(metrics["loss"]))
 

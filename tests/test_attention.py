@@ -95,3 +95,84 @@ class TestDispatch:
         q, k, v = qkv()
         with pytest.raises(ValueError, match="Unknown method"):
             sequence_sharded_attention(q, k, v, mesh8, method="flash")
+
+
+# -- rotation and a window on grouped-query attention (`models/encoder.py`) -------
+
+class TestRotatedWindowedGroupedQuery:
+    """`rope` in its two pairings and `gqa` with a window and rotation,
+    each against the arithmetic written out in numpy."""
+
+    @staticmethod
+    def _rotate_half(x, positions, theta):
+        d = x.shape[-1]
+        inv = theta ** (-np.arange(0, d, 2, dtype=np.float64) / d)
+        ang = positions[..., None, None] * inv
+        lo, hi = x[..., :d // 2], x[..., d // 2:]
+        return np.concatenate([lo * np.cos(ang) - hi * np.sin(ang),
+                               lo * np.sin(ang) + hi * np.cos(ang)], -1)
+
+    @pytest.mark.parametrize("what", ["pairs", "interleaved", "relative"])
+    def test_half_rotation_rope(self, what):
+        from predictionio_tpu.models import encoder as enc
+
+        rng = np.random.default_rng(0)
+        x = rng.standard_normal((2, 12, 3, 8))
+        pos = np.tile(np.arange(12), (2, 1))
+        got = np.asarray(enc.rope(jnp.asarray(x, jnp.float32),
+                                  jnp.asarray(pos), 1.5e6, interleave=False))
+        if what == "pairs":  # pair i is (x[i], x[i + d/2])
+            np.testing.assert_allclose(
+                got, self._rotate_half(x, pos, 1.5e6), atol=1e-5)
+            np.testing.assert_allclose(got[:, 0], x[:, 0], atol=1e-6)
+        elif what == "interleaved":
+            # the other pairing on the channels permuted into pairs
+            perm = np.arange(8).reshape(2, 4).T.reshape(-1)
+            other = np.asarray(enc.rope(
+                jnp.asarray(x[..., perm], jnp.float32), jnp.asarray(pos),
+                1.5e6))
+            np.testing.assert_allclose(other, got[..., perm], atol=1e-5)
+        else:  # a score reads the distance between two positions alone
+            far = np.asarray(enc.rope(
+                jnp.asarray(x, jnp.float32), jnp.asarray(pos + 100), 1.5e6,
+                interleave=False))
+            np.testing.assert_allclose(
+                np.einsum("bqhd,bkhd->bhqk", got, got),
+                np.einsum("bqhd,bkhd->bhqk", far, far), atol=2e-4)
+
+    @pytest.mark.parametrize("window,rotate", [(None, False), (5, False),
+                                               (None, True), (5, True)])
+    def test_a_window_and_rotation_on_gqa(self, window, rotate):
+        from predictionio_tpu.models import encoder as enc
+
+        cfg = enc.EncoderConfig(
+            hidden_size=16, intermediate_size=0, num_hidden_layers=1,
+            num_attention_heads=4, num_key_value_heads=2, head_dim=6,
+            rope_theta=100.0, rope_interleave=False, attention_block=8)
+        rng = np.random.default_rng(1)
+        p = {name: jnp.asarray(0.3 * rng.standard_normal(shape), jnp.float32)
+             for name, shape in enc._gqa_shapes(cfg).items()}
+        assert p["w_q"].shape == (16, 24) and p["w_o"].shape == (24, 16)
+        x = rng.standard_normal((1, 32, 16))
+        seg = np.array([[1] * 20 + [2] * 12])
+        pos = np.array([list(range(20)) + list(range(12))])
+        got = enc.gqa(p, cfg, jnp.asarray(x, jnp.float32), jnp.asarray(seg),
+                      jnp.asarray(pos), window=window, rotate=rotate)
+        w = {k: np.asarray(v, np.float64) for k, v in p.items()}
+        q = (x[0] @ w["w_q"]).reshape(32, 4, 6)
+        k = (x[0] @ w["w_k"]).reshape(32, 2, 6)
+        v = (x[0] @ w["w_v"]).reshape(32, 2, 6)
+        if rotate:
+            q, k = (self._rotate_half(a, pos[0], 100.0) for a in (q, k))
+        t = np.arange(32)
+        mask = (t[None, :] <= t[:, None]) & (seg[0][:, None] == seg[0][None])
+        if window:
+            mask &= t[:, None] - t[None, :] < window
+        out = np.zeros((32, 4, 6))
+        for head in range(4):
+            s = q[:, head] @ k[:, head // 2].T / np.sqrt(6)
+            s = np.where(mask, s, -np.inf)
+            prob = np.exp(s - s.max(-1, keepdims=True))
+            out[:, head] = prob / prob.sum(-1, keepdims=True) @ v[:, head // 2]
+        np.testing.assert_allclose(np.asarray(got[0]),
+                                   out.reshape(32, 24) @ w["w_o"], atol=2e-5)

@@ -5,6 +5,7 @@ hidden 32, 8 experts of which 2 are held, one dense block, two expert
 blocks and the MTP module. Seeded weights, float32 throughout."""
 
 import dataclasses
+import functools
 import json
 import os
 
@@ -334,6 +335,102 @@ def test_the_dispatch_drops_no_token(block):
     close(y, want)
     assert np.array_equal(counts, [(np.asarray(idx) == 1 + e).sum()
                                    for e in range(3)])
+
+
+# -- the second router rule and the second gate (`ops/moe.py`) ----------------------
+
+@pytest.mark.parametrize("what", ["picks", "weights", "load", "gradient",
+                                  "refused"])
+def test_the_softmax_router_picks_by_the_logits_and_weighs_the_picked(what):
+    """`route(..., scoring="softmax")`: the top-k of the logits, a
+    softmax over the picked (the same as a softmax over all, picked and
+    renormalised); no bias, no scale."""
+    rng = np.random.default_rng(11)
+    x = jnp.asarray(rng.standard_normal((40, 8)), jnp.float32)
+    w_g = jnp.asarray(rng.standard_normal((8, 6)), jnp.float32)
+    if what == "refused":
+        for bias, scale, scoring in ((jnp.zeros(6), 1.0, "softmax"),
+                                     (None, 2.5, "softmax"),
+                                     (None, 1.0, "tanh")):
+            with pytest.raises(ValueError):
+                moe.route(x, w_g, bias, 3, scale, scoring)
+        return
+    idx, weights, load = moe.route(x, w_g, None, 3, 1.0, "softmax")
+    logits = np.asarray(x, np.float64) @ np.asarray(w_g, np.float64)
+    order = np.argsort(-logits, axis=-1)[:, :3]
+    if what == "picks":
+        assert np.array_equal(idx, order) and idx.dtype == jnp.int32
+    elif what == "weights":
+        over_all = np.exp(logits) / np.exp(logits).sum(-1, keepdims=True)
+        picked = np.take_along_axis(over_all, order, axis=-1)
+        close(weights, picked / picked.sum(-1, keepdims=True))
+        close(weights.sum(-1), np.ones(40))
+    elif what == "load":
+        assert np.array_equal(load, np.bincount(order.reshape(-1),
+                                                minlength=6))
+    else:  # the weights carry the router's gradient, the picks none
+        target = jnp.asarray(rng.standard_normal((40, 3)), jnp.float32)
+        got = jax.grad(lambda w: (moe.route(x, w, None, 3, 1.0, "softmax")[1]
+                                  * target).sum())(w_g)
+        want = jax.grad(lambda w: (jax.nn.softmax(jnp.take_along_axis(
+            x @ w, jnp.asarray(order), axis=-1), axis=-1) * target).sum())(
+            w_g)
+        close(got, want)
+
+
+def plain_experts(x, w, idx, w13, w2, first, act):
+    """`held_experts` in its plain form: every held expert on every
+    token, weighted by the token's weight for it (zero where it did not
+    pick the expert), under autodiff."""
+    f = w2.shape[1]
+    out = 0.0
+    for e in range(w13.shape[0]):
+        h = x @ w13[e]
+        out = out + ((w * (idx == first + e)).sum(-1)[:, None]
+                     * ((act(h[:, :f]) * h[:, f:]) @ w2[e]))
+    return out
+
+
+@functools.lru_cache(maxsize=None)
+def both_gradients(gate):
+    """(value, every argument's gradient) of `held_experts` under
+    `gate`, and of the plain form under autodiff."""
+    rng = np.random.default_rng(12)
+    args = {"x": jnp.asarray(rng.standard_normal((40, 8)), jnp.float32),
+            "weights": jnp.asarray(rng.random((40, 3)), jnp.float32),
+            "w13": jnp.asarray(rng.standard_normal((3, 8, 10)), jnp.float32),
+            "w2": jnp.asarray(rng.standard_normal((3, 5, 8)), jnp.float32)}
+    idx = jnp.asarray(np.stack([rng.permutation(6)[:3] for _ in range(40)]),
+                      jnp.int32)
+    target = jnp.asarray(rng.standard_normal((40, 8)), jnp.float32)
+    act = {"silu": jax.nn.silu, "relu": jax.nn.relu}[gate]
+
+    def held(a):
+        return (moe.held_experts(a["x"], a["weights"], idx, a["w13"],
+                                 a["w2"], first=1, block=4, gate=gate)[0]
+                * target).sum()
+
+    def plain(a):
+        return (plain_experts(a["x"], a["weights"], idx, a["w13"], a["w2"],
+                              1, act) * target).sum()
+
+    return (jax.jit(jax.value_and_grad(held))(args),
+            jax.jit(jax.value_and_grad(plain))(args))
+
+
+@pytest.mark.parametrize("leaf", ["x", "weights", "w13", "w2"])
+@pytest.mark.parametrize("gate", ["silu", "relu"])
+def test_a_gates_backward_pass_equals_autodiff_of_the_plain_form(gate, leaf):
+    """The hand-written backward pass with the activation as its
+    parameter: SiLU as it was, ReLU with dg = da * u * (g > 0)."""
+    (value, grads), (want_value, want_grads) = both_gradients(gate)
+    close(value, want_value)
+    close(grads[leaf], want_grads[leaf])
+    x = jnp.zeros((4, 8), jnp.float32)
+    with pytest.raises(ValueError, match="gelu"):
+        moe.held_experts(x, jnp.ones((4, 1)), jnp.zeros((4, 1), jnp.int32),
+                         jnp.zeros((1, 8, 4)), jnp.zeros((1, 2, 8)), first=0,
+                         block=4, gate="gelu")
 
 
 # -- the train step, the scorer, the configuration file -----------------------------

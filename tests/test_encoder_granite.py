@@ -368,14 +368,6 @@ def test_the_step_lowers_the_loss():
                for leaf in jax.tree_util.tree_leaves(state["params"]))
 
 
-def test_the_benchmarks_reference_is_a_copy_of_the_packages():
-    with open(os.path.join(ROOT, "predictionio_tpu", "quality",
-                           "encoder_reference.py")) as f, \
-            open(os.path.join(ROOT, "perf", "reference",
-                              "granite_hybrid.py")) as g:
-        assert f.read() == g.read()
-
-
 # -- through the template's train ------------------------------------------------
 
 @pytest.fixture(scope="module")

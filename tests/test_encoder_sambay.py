@@ -221,7 +221,7 @@ def test_the_tied_embedding_gets_the_heads_gradient_too(params, gradients):
         return enc.cross_entropy_sum(
             frozen, CFG, h.reshape(b * l, -1),
             jnp.roll(tokens, -1, axis=1).reshape(-1),
-            ok.reshape(-1)) / jnp.sum(ok)
+            ok.reshape(-1))[0] / jnp.sum(ok)
 
     lookup = jax.jit(jax.grad(lookup_only))(params["emb"])
     unseen = np.setdiff1d(np.arange(VOCAB), np.asarray(tokens))

@@ -373,13 +373,15 @@ class TestTheEncoderAsADecoderHybridDecoder:
             assert abs(value - want[model.item_ids.get(item)]) < 2e-4
 
 
-@pytest.mark.parametrize("copy", ["kimi_linear.py", "phi4_flash.py"])
+@pytest.mark.parametrize("copy", ["kimi_linear.py", "phi4_flash.py",
+                                  "granite_hybrid.py"])
 def test_the_benchmarks_reference_is_a_copy_of_the_packages(copy):
-    """The newest copy (`perf/reference/granite_hybrid.py`) is held equal
-    in `tests/test_encoder_granite.py`. The Kimi and Phi-4-mini-flash
-    cells' copies are the package's reference as PRs 32 and 37 left it,
-    and the benchmark's files: the package's still defines every
-    function they have, with the arguments they have, in their order."""
+    """The newest copy (`perf/reference/smallthinker.py`) is held equal
+    in `tests/test_encoder_smallthinker.py`. The Kimi, Phi-4-mini-flash
+    and Granite cells' copies are the package's reference as PRs 32, 37
+    and 41 left it, and the benchmark's files: the package's still
+    defines every function they have, with the arguments they have, in
+    their order."""
     import ast
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))

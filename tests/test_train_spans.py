@@ -442,3 +442,66 @@ def test_the_benchmarks_ssd_metrics_read_what_the_program_has(
     else:  # the whole step's share of the peak: its operations' file
         assert os.path.exists(os.path.join(root, "perf", "ops",
                                            spec["ops"] + ".py"))
+
+
+# -- the encoder that routes before it mixes: what the benchmark's nine read --------
+
+ST_METRICS = ("fit.st_router_s", "fit.st_experts_s", "fit.st_attn_swa_s",
+              "fit.st_attn_full_s", "fit.st_head_loss_s", "fit.st_adam_s",
+              "fit.st_step_mfu", "fit.st_expert_load_max_over_mean",
+              "fit.st_moe_block_fill")
+
+
+@pytest.fixture(scope="module")
+def routed_step_text():
+    """Lowered text, with locations, of one train step of the tiny
+    configuration in SmallThinker's key names the benchmark's tests run."""
+    import jax
+    import jax.numpy as jnp
+
+    from predictionio_tpu.models import encoder as enc
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    cfg = enc.EncoderConfig.from_json(os.path.join(
+        root, "perf", "tests", "tiny", "smallthinker_21b_1of4.json"))
+    state = jax.eval_shape(
+        lambda k: enc.init_state(cfg, cfg.vocab_size, k), jax.random.key(0))
+    batch = jax.ShapeDtypeStruct((cfg.seqs_per_step, cfg.pack_len), jnp.int32)
+    return jax.jit(enc.train_step(cfg, 1e-3)).lower(
+        state, batch, batch, batch).as_text(debug_info=True)
+
+
+@pytest.mark.parametrize("name", ST_METRICS)
+def test_the_benchmarks_st_metrics_read_what_the_program_has(
+        routed_step_text, name):
+    """Each of the nine is a data file of the benchmark that names the
+    program's scopes, its step's module or its gauges: a rename here
+    would make it fall silent there. Every `known` list names all the
+    scopes the step opens (the router's, the plan's below the experts',
+    the windowed and the full attention's apart), so that no op falls to
+    an enclosing scope by omission."""
+    from predictionio_tpu.templates.sessionrec import engine  # noqa: F401
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        entry = {m["name"]: m for m in json.load(f)["per_layer"]}[name]
+    with open(os.path.join(root, "perf", "layers", name + ".json")) as f:
+        spec = json.load(f)
+    assert (entry["unit"], entry["moves"], entry["layer"]) == (
+        spec["unit"], spec["moves"], spec["layer"])
+    assert entry["workloads"] == ["smallthinker.fit8_pack8k"]
+    if spec["reader"] in ("gauge_ratio", "gauge_max_over_mean"):
+        for key in ("gauge", "numerator", "denominator"):
+            assert key not in spec or REGISTRY.get(spec[key]) is not None
+        return
+    assert "@jit_sessionrec_train_step" in routed_step_text
+    # the plan's scope stands right below the experts': a lookahead, so
+    # that one slash can end a scope and begin the next
+    opened = set(re.findall(r'["/(](enc\.[a-z_.]+)(?=[/)])',
+                            routed_step_text))
+    if "known" in spec:
+        assert set(spec["known"]) == opened
+        assert set(spec["scopes"]) <= opened
+    else:  # the whole step's share of the peak: its operations' file
+        assert os.path.exists(os.path.join(root, "perf", "ops",
+                                           spec["ops"] + ".py"))
