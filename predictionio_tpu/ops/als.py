@@ -36,7 +36,11 @@ import numpy as np
 
 from predictionio_tpu.ops.solve import solve_spd
 from predictionio_tpu.telemetry.registry import REGISTRY
-from predictionio_tpu.telemetry.spans import record as record_span, span
+from predictionio_tpu.telemetry.spans import (
+    Worker,
+    record as record_span,
+    span,
+)
 from predictionio_tpu.utils import faults
 
 log = logging.getLogger(__name__)
@@ -507,6 +511,65 @@ def _bucket_cache_load(cache_dir: str, key: str):
         return None
 
 
+def _save_or_warn(cache_dir: str, key: str, *buckets) -> None:
+    """`_bucket_cache_save`, with the failures a disk can cause logged
+    and not raised; any other error is the caller's."""
+    try:
+        # atomic write: concurrent ranks race safely (same bytes)
+        _bucket_cache_save(cache_dir, key, *buckets)
+        log.info("als_train: bucket cache miss — saved %s", key)
+    except OSError as e:
+        # the cache is a pure optimization: a full/read-only disk
+        # must not fail a train that already bucketized
+        log.warning("als_train: bucket cache save failed (%s) — "
+                    "continuing uncached", e)
+
+
+class BucketCacheSave:
+    """One train's bucket-cache save, written on a thread of its own
+    behind the device loop: nothing in the call reads what it writes,
+    and the calling thread spends the loop parked on the device.
+    `bucketize_cached` stages the entry on a miss, the train starts the
+    thread when its transfers are done (started beside them it slowed
+    `put_buckets` from 0.22 to 0.35 s: PERF.md, PR 44), and whoever made
+    the object joins it, as a context manager on every way out: when a
+    train returns or raises the entry is on disk (or the warning logged)
+    and no thread is left. `als.bucket_cache.join` is the wait: about
+    nothing where the loop outlasted the save, the save's remainder
+    where it did not."""
+
+    def __init__(self):
+        self._entry: Optional[tuple] = None
+        self._worker: Optional[Worker] = None
+
+    def stage(self, cache_dir: str, key: str, *buckets) -> None:
+        """What to write, kept until `start`."""
+        self._entry = (cache_dir, key, *buckets)
+
+    def start(self) -> None:
+        """Start writing what was staged, once: called when the transfers
+        are done and the calling thread is about to park on the loop."""
+        entry, self._entry = self._entry, None
+        if entry is not None:
+            self._worker = Worker("als-bucket-cache-save", _save_or_warn,
+                                  *entry)
+
+    def join(self) -> None:
+        self.start()  # a train that never reached its loop still saves
+        worker, self._worker = self._worker, None
+        if worker is None:  # a hit, or no cache directory: nothing to wait for
+            return
+        with span("als.bucket_cache.join"):
+            worker.wait()
+        worker.join()
+
+    def __enter__(self) -> "BucketCacheSave":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.join()
+
+
 def bucketize_cached(
     user_idx: np.ndarray,
     item_idx: np.ndarray,
@@ -518,6 +581,7 @@ def bucketize_cached(
     cap_growth: float,
     bucket_cache_dir: Optional[str],
     data_digest=None,
+    cache_save: Optional[BucketCacheSave] = None,
 ):
     """Both sides' `bucket_ragged_split`, behind the on-disk fingerprint
     cache when `bucket_cache_dir` is set. Shared by `als_train` and the
@@ -525,6 +589,12 @@ def bucketize_cached(
     bucketizer input and NOT the solver hyperparams, which is exactly why
     an eval grid over (λ, α) can reuse the single train's cache entry.
     `data_digest`: optional zero-arg memoized digest of the COO arrays.
+
+    On a miss the two sides are built at once, a thread a side (they read
+    the same three arrays and share nothing else; the native loader
+    holds no GIL), and both are joined before a bucket is read. The new
+    entry is staged on `cache_save`, which the caller starts and joins
+    before its train returns; without one it is saved before this returns.
 
     Returns (user_buckets, u_split, item_buckets, i_split)."""
     if data_digest is None:
@@ -544,31 +614,30 @@ def bucketize_cached(
             digest_size=16).hexdigest()
         cached = _bucket_cache_load(bucket_cache_dir, bucket_key)
     if cached is not None:
-        user_buckets, u_split, item_buckets, i_split = cached
         log.info("als_train: bucket cache hit %s (host bucketize skipped)",
                  bucket_key)
-    else:
+        return cached
+
+    def build(side: str, rows, cols, n_rows: int):
         with span("als.bucketize"):
-            user_buckets, u_split = bucket_ragged_split(
-                user_idx, item_idx, ratings, n_users, row_multiple,
-                split_cap, cap_growth=cap_growth, side="user")
-        with span("als.bucketize"):
-            item_buckets, i_split = bucket_ragged_split(
-                item_idx, user_idx, ratings, n_items, row_multiple,
-                split_cap, cap_growth=cap_growth, side="item")
-        if bucket_cache_dir:
-            try:
-                # atomic write: concurrent ranks race safely (same bytes)
-                _bucket_cache_save(bucket_cache_dir, bucket_key,
-                                   user_buckets, u_split, item_buckets,
-                                   i_split)
-                log.info("als_train: bucket cache miss — saved %s",
-                         bucket_key)
-            except OSError as e:
-                # the cache is a pure optimization: a full/read-only disk
-                # must not fail a train that already bucketized
-                log.warning("als_train: bucket cache save failed (%s) — "
-                            "continuing uncached", e)
+            return bucket_ragged_split(rows, cols, ratings, n_rows,
+                                       row_multiple, split_cap,
+                                       cap_growth=cap_growth, side=side)
+
+    sides = [Worker(f"als-bucketize-{side}", build, side, rows, cols, n_rows)
+             for side, rows, cols, n_rows in (
+                 ("user", user_idx, item_idx, n_users),
+                 ("item", item_idx, user_idx, n_items))]
+    for worker in sides:  # both ended before either's error is raised
+        worker.wait()
+    (user_buckets, u_split), (item_buckets, i_split) = (
+        worker.join() for worker in sides)
+    if bucket_cache_dir:
+        save = cache_save if cache_save is not None else BucketCacheSave()
+        save.stage(bucket_cache_dir, bucket_key, user_buckets, u_split,
+                   item_buckets, i_split)
+        if save is not cache_save:
+            save.join()
     return user_buckets, u_split, item_buckets, i_split
 
 
@@ -986,10 +1055,28 @@ def als_train(
 
     bucket_cache_dir: when set, the host bucketize result is cached on
     disk under a fingerprint of the training data + every bucketizer
-    input (VERDICT r2 #5 — bucketize is ~14 s of a 20M `pio train` and
-    identical across re-trains on unchanged events); new events or a
-    changed mesh/splitCap/cap_growth miss and rebucketize.
+    input (run in series a miss is 2.9 s of a 7.1 s train call at
+    ML-20M's size, rank 64, one v5e: ledger, PR 43, `als64.train10`; the
+    result is identical across re-trains on unchanged events); new events
+    or a changed mesh/splitCap/cap_growth miss and rebucketize. On a miss
+    the two sides are bucketized at once, a thread a side, and the new
+    entry is saved on a third behind the device loop, joined before this
+    function returns or raises (`BucketCacheSave`).
     """
+    # the save of a new cache entry runs behind the loop and is joined
+    # here on every way out: the entry stands when a train returns
+    with BucketCacheSave() as cache_save:
+        return _als_train(user_idx, item_idx, ratings, n_users, n_items, cfg,
+                          mesh, compute_rmse, checkpoint_dir,
+                          checkpoint_every, resume, bucket_cache_dir,
+                          cache_save)
+
+
+def _als_train(user_idx, item_idx, ratings, n_users: int, n_items: int,
+               cfg: ALSConfig, mesh, compute_rmse: bool,
+               checkpoint_dir: Optional[str], checkpoint_every: int,
+               resume: bool, bucket_cache_dir: Optional[str],
+               cache_save: BucketCacheSave) -> ALSResult:
     import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -1044,7 +1131,7 @@ def als_train(
 
     user_buckets, u_split, item_buckets, i_split = bucketize_cached(
         user_idx, item_idx, ratings, n_users, n_items, row_multiple,
-        split_cap, cfg.cap_growth, bucket_cache_dir, data_digest)
+        split_cap, cfg.cap_growth, bucket_cache_dir, data_digest, cache_save)
     log.info(
         "als_train: %d ratings, %d users (%d buckets, caps %s, %d split), "
         "%d items (%d buckets, caps %s, %d split), rank %d, mesh %s",
@@ -1254,6 +1341,7 @@ def als_train(
         # (a debug path) is not metered and counts as warm
         compile_count = getattr(train, "compile_count", lambda: 0)
         compiles_before = compile_count()
+        cache_save.start()
         t0 = time.monotonic()
         with span("als.loop.dispatch"):
             user_factors, item_factors, rmses = train(

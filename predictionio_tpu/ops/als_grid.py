@@ -45,6 +45,7 @@ import numpy as np
 from predictionio_tpu.ops.als import (
     ALSConfig,
     ALSResult,
+    BucketCacheSave,
     _bucket_chunk_rows,
     _walk_bucket_chunks,
     bucketize_cached,
@@ -330,6 +331,18 @@ def als_train_grid(
     current chip not measured). Device results must not be
     pickled/persisted.
     """
+    # as in `als_train`: a new cache entry is saved behind the device
+    # program and joined here on every way out
+    with BucketCacheSave() as cache_save:
+        return _als_train_grid(user_idx, item_idx, ratings, n_users, n_items,
+                               cfgs, mesh, compute_rmse, bucket_cache_dir,
+                               host_factors, cache_save)
+
+
+def _als_train_grid(user_idx, item_idx, ratings, n_users: int, n_items: int,
+                    cfgs: Sequence[ALSConfig], mesh, compute_rmse: bool,
+                    bucket_cache_dir: Optional[str], host_factors: bool,
+                    cache_save: BucketCacheSave) -> list[ALSResult]:
     import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -359,7 +372,7 @@ def als_train_grid(
     split_cap = cfg.split_cap if cfg.split_cap > 0 else None
     user_buckets, u_split, item_buckets, i_split = bucketize_cached(
         user_idx, item_idx, ratings, n_users, n_items, row_multiple,
-        split_cap, cfg.cap_growth, bucket_cache_dir)
+        split_cap, cfg.cap_growth, bucket_cache_dir, cache_save=cache_save)
     log.info(
         "als_train_grid: %d grid points × (%d ratings, %d users, %d items, "
         "rank %d, %s iters), mesh %s — one device program",
@@ -416,6 +429,7 @@ def als_train_grid(
     train = _get_grid_train_loop(n_users, n_items, cfg, n_grid,
                                  compute_rmse, n_steps, row_multiple,
                                  mesh if mesh.size > 1 else None)
+    cache_save.start()
     user_factors, item_factors, rmses = train(
         keys, regs, alphas, iters, ub_dev, ib_dev, u_split_dev, i_split_dev)
     float(item_factors[0, 0, 0])  # execution fence (see als_train)

@@ -9,7 +9,7 @@ one per request; `spans.span("serving.admission")` blocks record into it
 from anywhere downstream; the flight recorder (telemetry/recorder.py)
 tail-samples the finished product.
 
-Two recording paths, because two threads touch a request:
+Three recording paths, because more than one thread touches a request:
 
 - `span(name)` — a context manager for work on the *request's own thread*
   (admission, validation, storage calls). When jax is loaded it also
@@ -21,6 +21,13 @@ Two recording paths, because two threads touch a request:
   the stamps into its own timeline after being woken. Contextvars don't
   cross threads, and handing the timeline itself to the dispatcher would
   make one slow request's bookkeeping a shared-state problem.
+- `Worker(name, fn, *args)` — for work the request's thread *hands to a
+  thread of its own and joins* (the two sides of a bucketize, the
+  bucket-cache save behind the train loop). The worker records into a
+  timeline of its own, so `span` and `record` inside `fn` behave as on
+  the request's thread, annotations included, and `depth` keeps its one
+  writer; `join()` copies the finished records into the timeline the
+  worker was started under, on the same axis.
 
 Clock discipline: all offsets are `time.monotonic()` relative to the
 timeline's `t0`, the same clock the serving/ingest planes already stamp
@@ -96,7 +103,10 @@ class Timeline:
     def record(self, name: str, start_s: float, duration_s: float,
                error: bool = False, nested: bool = False) -> None:
         if len(self.spans) >= MAX_SPANS:
-            self.dropped_spans += 1
+            # one writer a timeline: a `Worker`'s thread records into a
+            # timeline of its own, which its starter copies over at the
+            # join, on the starter's thread
+            self.dropped_spans += 1  # pio-lint: disable=race-shared-state
             return
         self.spans.append((name, start_s, duration_s, error, nested))
 
@@ -236,6 +246,61 @@ def record_between(name: str, start_monotonic: float,
     tl.record(name, start_monotonic - tl.t0,
               max(0.0, end_monotonic - start_monotonic),
               nested=tl.depth > 0 if nested is None else nested)
+
+
+class Worker:
+    """`fn(*args)` on a thread of its own, started here; `join()` waits
+    for it and returns what `fn` returned, or raises what it raised.
+
+    Under an active timeline the thread records into one of its own
+    (same route and trace id, so the stack sampler bills its frames to
+    the same request) and `join()` hands the records to the starter's
+    timeline with their true intervals; a record is nested where the
+    worker nested it or where the join stands inside a live span. The
+    thread does not outlive a `join()`, and a `Worker` is joined by the
+    thread that made it."""
+
+    __slots__ = ("_thread", "_outer", "_own", "_result", "_error")
+
+    def __init__(self, name: str, fn, *args):
+        self._outer = _active.get()
+        self._own: Optional[Timeline] = None
+        self._result = None
+        self._error: Optional[BaseException] = None
+        self._thread = threading.Thread(target=self._run, args=(fn, args),
+                                        name=name)
+        self._thread.start()
+
+    def _run(self, fn, args) -> None:
+        outer, token = self._outer, None
+        if outer is not None:
+            self._own, token = begin(outer.server, outer.route, outer.method,
+                                     outer.trace_id)
+        try:
+            self._result = fn(*args)
+        except BaseException as e:  # re-raised by join(), in the starter
+            self._error = e
+        finally:
+            if token is not None:
+                finish(self._own, token, status=None, duration_s=0.0)
+
+    def wait(self) -> None:
+        """Until the thread has ended; nothing is copied or raised."""
+        self._thread.join()
+
+    def join(self):
+        self._thread.join()
+        outer, own, self._own = self._outer, self._own, None
+        if own is not None:
+            shift = own.t0 - outer.t0
+            inside = outer.depth > 0
+            for name, start_s, duration_s, error, nested in own.spans:
+                outer.record(name, start_s + shift, duration_s, error,
+                             nested=nested or inside)
+            outer.dropped_spans += own.dropped_spans
+        if self._error is not None:
+            raise self._error
+        return self._result
 
 
 class span:

@@ -3,10 +3,12 @@ down to `als_train`'s phases, the named scopes of the train loop's
 program, the bucket gauges, `train/phases` and the loop-stamp epoch
 times. Stamps are compared with each other, never with a wall clock."""
 
+import contextlib
 import dataclasses
 import json
 import os
 import re
+import threading
 
 import numpy as np
 import pytest
@@ -38,9 +40,11 @@ SCOPES = ("als.gather_gram", "als.yty", "als.solve", "als.split_merge",
 MISS = ["als.train", "als.digest", "als.bucket_cache.load", "als.bucketize",
         "als.bucketize", "als.bucket_cache.save", "als.put_buckets",
         "als.init_factors", "als.loop.dispatch", "als.loop.wait",
-        "als.readback"]
-HIT = [n for n in MISS
-       if n not in ("als.bucketize", "als.bucket_cache.save")]
+        "als.readback", "als.bucket_cache.join"]
+# what a miss runs on threads of its own: the two sides beside each
+# other, then the save behind the rest of the train
+BESIDE = ("als.bucketize", "als.bucket_cache.save")
+HIT = [n for n in MISS if n not in BESIDE + ("als.bucket_cache.join",)]
 
 
 def ratings(seed=0):
@@ -96,19 +100,42 @@ def test_als_train_records_its_phases_in_order_under_als_train(
     _, tl = under_timeline(lambda: als_train(
         u, i, r, N_USERS, N_ITEMS, CFG, bucket_cache_dir=cache))
     got = als_spans(tl)
-    assert [n for n, _, _ in got] == expected
-    (_, lo, hi), inner = got[0], got[1:]
+    assert sorted(n for n, _, _ in got) == sorted(expected)
+    (first, lo, hi), inner = got[0], got[1:]
+    assert first == "als.train"
     assert all(lo <= s and e <= hi for _, s, e in inner)
-    # one after the other: no phase starts before the one before it ended
-    assert all(a[2] <= b[1] for a, b in zip(inner, inner[1:]))
+    # the calling thread's phases, one after the other: none starts
+    # before the one before it ended
+    serial = [x for x in inner if x[0] not in BESIDE]
+    assert [n for n, _, _ in serial] == [n for n in expected[1:]
+                                         if n not in BESIDE]
+    assert all(a[2] <= b[1] for a, b in zip(serial, serial[1:]))
     assert tl.dropped_spans == 0
-    # a side that was built says which way, inside its `als.bucketize`
+    # recorded from another thread or not, a phase is nested in `als.train`
+    nested = {n: flag for n, _s, _d, _e, flag in tl.spans
+              if n.startswith("als.")}
+    assert nested.pop("als.train") is False and all(nested.values())
     builds = [(s, e) for n, s, e in got if n == "als.bucketize"]
+    saves = [(s, e) for n, s, e in got if n == "als.bucket_cache.save"]
+    when = {n: (s, e) for n, s, e in serial}
+    if not warm_cache:
+        # the two sides may overlap each other and nothing else: both lie
+        # between the look-up that missed and the first transfer
+        assert all(when["als.bucket_cache.load"][1] <= s
+                   and e <= when["als.put_buckets"][0] for s, e in builds)
+        # the save starts when both stand and the transfers are done,
+        # and is over when the join is
+        (save,) = saves
+        assert max(e for _, e in builds) <= save[0]
+        assert when["als.init_factors"][1] <= save[0]
+        assert save[1] <= when["als.bucket_cache.join"][1] <= hi
+        assert when["als.readback"][1] <= when["als.bucket_cache.join"][0]
+    # a side that was built says which way, inside its `als.bucketize`
     paths = bucketize_paths(tl)
     assert len(paths) == len(builds)
-    assert all(lo <= s and e <= hi and path in (
-        "native_counting", "native_comparison", "numpy")
-        for (path, s, e), (lo, hi) in zip(paths, builds))
+    assert all(path in ("native_counting", "native_comparison", "numpy")
+               and sum(lo <= s and e <= hi for lo, hi in builds) >= 1
+               for path, s, e in paths)
 
 
 def test_without_a_timeline_nothing_is_recorded_and_the_factors_are_the_same():
@@ -217,6 +244,116 @@ def test_the_benchmarks_fill_metrics_name_gauges_the_program_has():
         assert (entry["unit"], entry["moves"], entry["layer"]) == (
             spec["unit"], spec["moves"], spec["layer"])
         assert entry["workloads"] == ["als64.train10", "als128i.train10"]
+
+
+def test_the_benchmarks_join_metric_names_a_span_the_program_opens(tmp_path):
+    """`train.save_join_s` is a data file of the benchmark that names the
+    span round `als_train`'s wait for its cache save: a rename here would
+    make it fall silent there."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        entry = {m["name"]: m for m in json.load(f)["per_layer"]}[
+            "train.save_join_s"]
+    with open(os.path.join(root, "perf", "layers",
+                           "train.save_join_s.json")) as f:
+        spec = json.load(f)
+    assert (spec["reader"], spec["spans"]) == (
+        "program_span", ["als.bucket_cache.join"])
+    assert (entry["unit"], entry["moves"], entry["layer"]) == (
+        spec["unit"], spec["moves"], spec["layer"]) == (
+        "s", "train_call_s", "training read, host")
+    assert entry["workloads"] == ["als64.train10", "als128i.train10"]
+    u, i, r = ratings()
+    _, tl = under_timeline(lambda: als_train(
+        u, i, r, N_USERS, N_ITEMS, CFG,
+        bucket_cache_dir=str(tmp_path / "buckets")))
+    assert [n for n, *_ in tl.spans].count(spec["spans"][0]) == 1
+
+
+def _stage(name, inner=None):
+    with spans.span(name):
+        if inner:
+            spans.record(inner, 0.0)
+        return threading.get_ident()
+
+
+@pytest.mark.parametrize("inside", [False, True],
+                         ids=["joined_at_depth_0", "joined_inside_a_span"])
+def test_a_workers_spans_reach_the_starters_timeline_at_the_join(inside):
+    """A `spans.Worker` records on a timeline of its own and `join`
+    copies the records over: true intervals, nested where the worker
+    nested them or where the join stands inside a live span."""
+    before = set(threading.enumerate())
+
+    def run():
+        with (spans.span("outer") if inside else contextlib.nullcontext()):
+            workers = [spans.Worker(f"w{k}", _stage, f"stage{k}", "leaf")
+                       for k in range(2)]
+            idents = [w.join() for w in workers]
+            assert spans.current().depth == int(inside)
+            return idents
+    idents, tl = under_timeline(run)
+    assert threading.get_ident() not in idents  # (a thread's may be reused)
+    assert set(threading.enumerate()) == before
+    got = {n: (s, s + d, nested) for n, s, d, _e, nested in tl.spans}
+    assert sorted(n for n, *_ in tl.spans) == sorted(
+        ["leaf", "leaf", "stage0", "stage1"] + ["outer"] * inside)
+    assert got["leaf"][2] is True
+    assert got["stage0"][2] is got["stage1"][2] is inside
+    if inside:
+        lo, hi, _ = got["outer"]
+        assert all(lo <= s and e <= hi for s, e, _ in got.values())
+    assert all(0.0 <= s <= e for s, e, _ in got.values())
+    assert tl.dropped_spans == 0 and tl.depth == 0
+
+
+def test_many_workers_at_once_lose_no_record_and_leave_the_depth_alone():
+    """More workers than cores under a short switch interval: each has
+    its own timeline, so no `depth` update or record can be lost."""
+    import sys
+
+    n = 4 * (os.cpu_count() or 2)
+    per = max(1, (spans.MAX_SPANS - 8) // (2 * n))
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        def stages(k):
+            for j in range(per):
+                _stage(f"stage{k}.{j}", "leaf")
+
+        def run():
+            with spans.span("outer"):
+                workers = [spans.Worker(f"w{k}", stages, k) for k in range(n)]
+                for w in workers:
+                    w._thread.join(timeout=60)
+                    assert not w._thread.is_alive()
+                    w.join()
+                assert spans.current().depth == 1
+        _, tl = under_timeline(run)
+    finally:
+        sys.setswitchinterval(interval)
+    names = [s[0] for s in tl.spans]
+    assert names.count("leaf") == n * per and tl.dropped_spans == 0
+    assert {x for x in names if x.startswith("stage")} == {
+        f"stage{k}.{j}" for k in range(n) for j in range(per)}
+    assert tl.depth == 0 and all(s[4] for s in tl.spans if s[0] != "outer")
+
+
+def test_a_worker_raises_in_the_starter_and_records_nothing_without_a_timeline():
+    def boom():
+        with spans.span("stage"):
+            raise KeyError("lost")
+    worker = spans.Worker("w", boom)
+    worker.wait()
+    with pytest.raises(KeyError, match="lost"):
+        worker.join()
+    assert not worker._thread.is_alive() and spans.current() is None
+    # under a timeline the failed stage is on it, marked
+    def run():
+        with pytest.raises(KeyError):
+            spans.Worker("w", boom).join()
+    _, tl = under_timeline(run)
+    assert [(n, e) for n, _s, _d, e, _n in tl.spans] == [("stage", True)]
 
 
 @pytest.mark.parametrize("chunks,expected", [
