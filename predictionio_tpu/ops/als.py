@@ -76,6 +76,14 @@ BUCKETIZE_CALLS = REGISTRY.counter(
     "fold | rows) and the path that built the buckets (native_counting | "
     "native_comparison | numpy)",
     labelnames=("side", "path"))
+# Which way each data digest went: leaves spread over threads, or hashed
+# by the caller (one leaf or fewer, or one usable core). The value is the
+# same either way.
+DIGEST_CALLS = REGISTRY.counter(
+    "als_digest_calls_total",
+    "_arrays_digest calls by the path that hashed the leaves (parallel | "
+    "inline)",
+    labelnames=("path",))
 
 
 def _spanned(name: str):
@@ -387,7 +395,16 @@ class ALSConfig:
 _CHUNK_BUDGET_BYTES = 1 << 30
 
 
-_BUCKET_CACHE_VERSION = 1
+# 2: PR 45's tree digest (`_arrays_digest`) names every entry anew; what an
+# older build saved is an orphan the keep-newest GC removes.
+_BUCKET_CACHE_VERSION = 2
+
+# The data digest's leaf: each array's bytes are cut every this many and
+# each piece hashed on its own, so that the pieces can be hashed side by
+# side. Part of the digest's value (a cache key, a checkpoint
+# fingerprint), so a constant and not a setting.
+_DIGEST_LEAF_BYTES = 8 << 20
+_DIGEST_MAX_WORKERS = 8
 
 
 def _persist_rank() -> int:
@@ -409,15 +426,84 @@ def _bucket_cache_keep() -> int:
     return max(1, int(os.environ.get("PIO_BUCKET_CACHE_KEEP", "4")))
 
 
-@_spanned("als.digest")
-def _arrays_digest(*arrays, extra: str = "") -> str:
+def _usable_cores() -> int:
+    """Cores this process may run on (its affinity mask where the
+    platform has one: a container's share, not the machine's count)."""
+    import os
+
+    try:
+        return len(os.sched_getaffinity(0))
+    except AttributeError:
+        return os.cpu_count() or 1
+
+
+def _leaf_digest(leaf) -> bytes:
+    """One leaf's own blake2b. Fed through `update`, which drops the GIL
+    over a buffer above 2047 bytes; the constructor's `data` keeps it."""
     import hashlib
 
     h = hashlib.blake2b(digest_size=16)
+    h.update(leaf)
+    return h.digest()
+
+
+@_spanned("als.digest")
+def _arrays_digest(*arrays, extra: str = "") -> str:
+    """A tree hash of the arrays: each one's bytes, read in place, are cut
+    into leaves of `_DIGEST_LEAF_BYTES`, every leaf is hashed alone, and
+    one root blake2b takes, array by array, the dtype, the shape and the
+    leaves' digests in order, then `extra`. The value depends on the
+    data, the dtypes, the shapes, `extra` and the leaf size, and never on
+    who hashed the leaves: an input of more than one leaf's bytes, on a
+    host with more than one usable core, has them spread over
+    `telemetry.spans.Worker` threads (a fixed stride each, at most
+    `_DIGEST_MAX_WORKERS`), started and joined here, inside the caller's
+    one `als.digest` span; anything smaller is hashed inline and starts
+    no thread. A leaf's error is raised here after every worker has
+    ended. The call says which way it went: the counter for `/metrics`,
+    the seconds under the path's name for the timeline (`train/phases`)."""
+    import hashlib
+
+    t0 = time.monotonic()
+    heads, leaves = [], []
     for a in arrays:
-        h.update(np.ascontiguousarray(a).tobytes())
-    h.update(extra.encode())
-    return h.hexdigest()
+        a = np.asarray(a)
+        head = repr((a.dtype.str, a.shape)).encode()
+        # a view of the array's own memory; a copy only where it is not
+        # contiguous
+        flat = np.ascontiguousarray(a).reshape(-1).view(np.uint8)
+        cuts = range(0, flat.size, _DIGEST_LEAF_BYTES)
+        heads.append((head, len(cuts)))
+        leaves.extend(flat[lo:lo + _DIGEST_LEAF_BYTES] for lo in cuts)
+
+    def hashed(mine) -> list:
+        return [_leaf_digest(leaf) for leaf in mine]
+
+    n_workers = min(len(leaves), _usable_cores(), _DIGEST_MAX_WORKERS)
+    if (n_workers > 1
+            and sum(leaf.size for leaf in leaves) > _DIGEST_LEAF_BYTES):
+        path = "parallel"
+        workers = [Worker(f"als-digest-{k}", hashed, leaves[k::n_workers])
+                   for k in range(n_workers)]
+        for worker in workers:  # all ended before any leaf's error is raised
+            worker.wait()
+        digests = [b""] * len(leaves)
+        for k, worker in enumerate(workers):
+            digests[k::n_workers] = worker.join()
+    else:
+        path = "inline"
+        digests = hashed(leaves)
+    root = hashlib.blake2b(digest_size=16)
+    root.update(repr((len(arrays), _DIGEST_LEAF_BYTES)).encode())
+    done = 0
+    for head, n in heads:
+        root.update(head)
+        root.update(b"".join(digests[done:done + n]))
+        done += n
+    root.update(extra.encode())
+    DIGEST_CALLS.labels(path=path).inc()
+    record_span(f"als.digest.{path}", time.monotonic() - t0)
+    return root.hexdigest()
 
 
 @_spanned("als.bucket_cache.save")
@@ -1299,8 +1385,9 @@ def _als_train(user_idx, item_idx, ratings, n_users: int, n_items: int,
                     else:
                         log.warning(
                             "als_train: checkpoint at %s is from different "
-                            "data/config (or a foreign tree) — training "
-                            "from scratch", checkpoint_dir)
+                            "data/config (or a foreign tree, or an older "
+                            "build whose data digest read otherwise) — "
+                            "training from scratch", checkpoint_dir)
         if not compute_rmse:
             rmse_history = []
         elif len(rmse_history) < start_iter:

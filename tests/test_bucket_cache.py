@@ -109,6 +109,56 @@ class TestBucketCache:
         assert np.isfinite(out.user_factors).all()
 
 
+# -- PR 45's tree digest renamed every entry (`_BUCKET_CACHE_VERSION` 2):
+# -- what an older build saved is never loaded, and the GC removes it
+
+
+def _digest_before_pr45(*arrays):
+    """`_arrays_digest` as it stood under `_BUCKET_CACHE_VERSION` 1."""
+    import hashlib
+
+    h = hashlib.blake2b(digest_size=16)
+    for a in arrays:
+        h.update(np.ascontiguousarray(a).tobytes())
+    return h.hexdigest()
+
+
+@pytest.mark.parametrize("digest,version", [
+    (_digest_before_pr45, 1), (als._arrays_digest, 1),
+    (_digest_before_pr45, als._BUCKET_CACHE_VERSION),
+], ids=["an_older_builds_key", "todays_digest_under_version_1",
+        "the_old_digest_under_todays_version"])
+def test_an_entry_under_an_older_builds_key_is_not_loaded_and_is_pruned(
+        tmp_path, monkeypatch, caplog, digest, version):
+    import hashlib
+    import os
+
+    monkeypatch.setenv("PIO_BUCKET_CACHE_KEEP", "1")
+    ui, ii, r, n_u, n_i = _data()
+    cache = tmp_path / "cache"
+    assert als._BUCKET_CACHE_VERSION == 2
+    old_key = hashlib.blake2b(
+        (digest(ui, ii, r) + repr((n_u, n_i, 8, CFG.split_cap,
+                                   CFG.cap_growth, version))).encode(),
+        digest_size=16).hexdigest()
+    # the orphan holds another train's buckets: loaded, it would show
+    other = _data(seed=5)
+    als._bucket_cache_save(str(cache), old_key, *als.bucketize_cached(
+        *other, 8, CFG.split_cap, CFG.cap_growth, None))
+    orphan = cache / f"{old_key}.npz"
+    os.utime(orphan, (1.0e9, 1.0e9))
+    ref = als_train(ui, ii, r, n_u, n_i, CFG)
+    with caplog.at_level(logging.INFO, "predictionio_tpu.ops.als"):
+        out = als_train(ui, ii, r, n_u, n_i, CFG,
+                        bucket_cache_dir=str(cache))
+    assert any("bucket cache miss" in m for m in caplog.messages)
+    assert not any("bucket cache hit" in m for m in caplog.messages)
+    np.testing.assert_array_equal(out.user_factors, ref.user_factors)
+    np.testing.assert_array_equal(out.item_factors, ref.item_factors)
+    (kept,) = cache.iterdir()  # keep-newest: today's entry, the orphan gone
+    assert kept.name != orphan.name and kept.suffix == ".npz"
+
+
 # -- a miss runs its pieces beside each other: the two sides on a thread
 # -- each, the save behind the rest of the train, joined before it returns
 

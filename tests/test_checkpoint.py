@@ -9,6 +9,8 @@ import pytest
 from predictionio_tpu.ops.als import ALSConfig, als_train
 from predictionio_tpu.workflow.checkpoint import CheckpointManager
 from tests.test_als import synth_ratings
+from tests.test_als_digest import cores
+from tests.test_bucket_cache import _digest_before_pr45
 
 
 class TestCheckpointManager:
@@ -112,6 +114,64 @@ class TestALSCheckpointResume:
                                    rtol=1e-4, atol=1e-5)
         assert not np.allclose(fresh.user_factors, stale.user_factors)
         assert len(fresh.epoch_times) == 2
+
+    def test_a_checkpoint_fingerprinted_by_an_older_builds_digest_is_not_resumed(
+            self, tmp_path, caplog):
+        """PR 45's tree digest changed every fingerprint: a checkpoint an
+        older build wrote reads as another run's and the train starts from
+        iteration 0, the safe side, and says why."""
+        import hashlib
+        import json
+        import logging
+
+        ui, ii, r, _ = synth_ratings(n_users=30, n_items=20, seed=9)
+        cfg = ALSConfig(rank=4, iterations=2, reg=0.05, seed=4)
+        first = als_train(ui, ii, r, 30, 20, cfg, checkpoint_dir=str(tmp_path))
+        meta_path = tmp_path / "step_2" / "meta.json"
+        meta = json.loads(meta_path.read_text())
+        todays = meta["metadata"]["fingerprint"]
+        meta["metadata"]["fingerprint"] = hashlib.blake2b(
+            (_digest_before_pr45(ui, ii, r)
+             + repr((30, 20, cfg.rank, cfg.reg, cfg.weighted_reg,
+                     cfg.implicit, cfg.alpha, cfg.seed, cfg.dtype))).encode(),
+            digest_size=8).hexdigest()
+        assert meta["metadata"]["fingerprint"] != todays
+        meta_path.write_text(json.dumps(meta))
+        with caplog.at_level(logging.WARNING, "predictionio_tpu.ops.als"):
+            again = als_train(ui, ii, r, 30, 20, cfg,
+                              checkpoint_dir=str(tmp_path))
+        assert any("older build" in m and "training from scratch" in m
+                   for m in caplog.messages)
+        assert len(again.epoch_times) == 2  # both iterations run anew
+        np.testing.assert_allclose(first.user_factors, again.user_factors,
+                                   rtol=1e-4, atol=1e-5)
+        # and the checkpoint it then wrote is today's again
+        assert json.loads(meta_path.read_text())["metadata"][
+            "fingerprint"] == todays
+
+    @pytest.mark.parametrize("wrote_on,resumed_on", [(1, 8), (8, 1), (2, 13)])
+    def test_a_checkpoint_resumes_on_a_host_of_another_core_count(
+            self, tmp_path, monkeypatch, wrote_on, resumed_on):
+        """The fingerprint holds the data digest, whose value no thread
+        or core count enters: leaves of 64 bytes here, so that the hosts
+        with cores to spare do hash them on threads."""
+        from predictionio_tpu.ops import als
+
+        monkeypatch.setattr(als, "_DIGEST_LEAF_BYTES", 64)
+        counted = als.DIGEST_CALLS.labels(path="parallel")
+        ui, ii, r, _ = synth_ratings(n_users=30, n_items=20, seed=4)
+        paths = []
+        for n_cores, iterations in ((wrote_on, 3), (resumed_on, 5)):
+            cores(monkeypatch, n_cores)
+            before = counted.value
+            out = als_train(ui, ii, r, 30, 20,
+                            ALSConfig(rank=4, iterations=iterations,
+                                      reg=0.05, seed=9),
+                            checkpoint_dir=str(tmp_path), checkpoint_every=1)
+            paths.append(counted.value - before)
+        assert paths == [float(wrote_on > 1), float(resumed_on > 1)]
+        assert len(out.epoch_times) == 2  # resumed at step 3
+        assert CheckpointManager(str(tmp_path)).latest_step() == 5
 
     def test_fully_resumed_run_returns_model_without_training(self, tmp_path):
         ui, ii, r, _ = synth_ratings(n_users=30, n_items=20, seed=9)

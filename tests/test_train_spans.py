@@ -32,6 +32,7 @@ from predictionio_tpu.workflow.workflow_utils import (
     get_engine,
 )
 from tests.test_als import _placed_buckets
+from tests.test_als_digest import cores
 from tests.test_ecommerce_template import ingest, variant_dict
 
 N_USERS, N_ITEMS = 12, 9
@@ -72,27 +73,36 @@ def under_timeline(fn):
 
 
 PATHS = "als.bucketize."  # + the path a bucketizer call took
+DIGEST_PATHS = "als.digest."  # + the path the data digest took
 
 
 def als_spans(tl):
     """The timeline's `als.*` spans as (name, start, end), by start; the
-    bucketizer's path records (`bucketize_paths`) left out."""
+    path records of the bucketizer and the digest (`paths`) left out."""
     return sorted(((n, s, s + d) for n, s, d, _e, _nested in tl.spans
-                   if n.startswith("als.") and not n.startswith(PATHS)),
+                   if n.startswith("als.")
+                   and not n.startswith((PATHS, DIGEST_PATHS))),
                   key=lambda x: x[1])
 
 
-def bucketize_paths(tl):
-    """(path, start, end) of every bucketizer call's record, by start."""
-    return sorted(((n[len(PATHS):], s, s + d)
+def paths(tl, prefix):
+    """(path, start, end) of every record under the prefix, by start."""
+    return sorted(((n[len(prefix):], s, s + d)
                    for n, s, d, _e, _nested in tl.spans
-                   if n.startswith(PATHS)), key=lambda x: x[1])
+                   if n.startswith(prefix)), key=lambda x: x[1])
 
 
+@pytest.mark.parametrize("leaf_bytes,digest_path", [(8 << 20, "inline"),
+                                                    (64, "parallel")])
 @pytest.mark.parametrize("warm_cache,expected", [(False, MISS), (True, HIT)],
                          ids=["cache_miss", "cache_hit"])
 def test_als_train_records_its_phases_in_order_under_als_train(
-        tmp_path, warm_cache, expected):
+        tmp_path, monkeypatch, warm_cache, expected, leaf_bytes,
+        digest_path):
+    # ratings under one leaf are hashed by the caller; cut into leaves of
+    # 64 bytes they go to three workers, and the spans read the same
+    monkeypatch.setattr(als, "_DIGEST_LEAF_BYTES", leaf_bytes)
+    cores(monkeypatch, 3)
     u, i, r = ratings()
     cache = str(tmp_path / "buckets")
     if warm_cache:
@@ -131,11 +141,17 @@ def test_als_train_records_its_phases_in_order_under_als_train(
         assert save[1] <= when["als.bucket_cache.join"][1] <= hi
         assert when["als.readback"][1] <= when["als.bucket_cache.join"][0]
     # a side that was built says which way, inside its `als.bucketize`
-    paths = bucketize_paths(tl)
-    assert len(paths) == len(builds)
+    built = paths(tl, PATHS)
+    assert len(built) == len(builds)
     assert all(path in ("native_counting", "native_comparison", "numpy")
                and sum(lo <= s and e <= hi for lo, hi in builds) >= 1
-               for path, s, e in paths)
+               for path, s, e in built)
+    # so does the one digest, hit or miss, inside its `als.digest`: a wall
+    # on the calling thread, over before the look-up starts
+    ((path, s, e),) = paths(tl, DIGEST_PATHS)
+    assert path == digest_path
+    assert when["als.digest"][0] <= s and e <= when["als.digest"][1]
+    assert when["als.digest"][1] <= when["als.bucket_cache.load"][0]
 
 
 def test_without_a_timeline_nothing_is_recorded_and_the_factors_are_the_same():
@@ -436,6 +452,35 @@ def test_run_train_writes_one_train_phases_record(memory_storage, ecommerce,
     assert phases[0]["workflow.train"] >= phases[0]["dase.train"] >= (
         phases[0]["als.train"])
     assert [x["step"] for x in records if x["stage"] == "train/als"] == [1, 2]
+
+
+@pytest.mark.parametrize("leaf_bytes,path", [(8 << 20, "inline"),
+                                             (64, "parallel")])
+def test_run_train_with_a_bucket_cache_writes_the_digest_as_a_wall(
+        memory_storage, ecommerce, tmp_path, monkeypatch, leaf_bytes, path):
+    """`train/phases` carries `als.digest` once a train, the calling
+    thread's one span whatever hashed the leaves, and the path's record
+    inside it; the workers leave no name of their own."""
+    monkeypatch.setattr(WorkflowContext, "algorithm_cache_dir",
+                        lambda self, name: str(tmp_path / "cache" / name))
+    monkeypatch.setattr(als, "_DIGEST_LEAF_BYTES", leaf_bytes)
+    cores(monkeypatch, 3)
+    engine, ep, variant = ecommerce
+    metrics_file = str(tmp_path / "metrics.jsonl")
+    before = set(threading.enumerate())
+    with MetricsLogger(metrics_file) as metrics:
+        ctx = WorkflowContext(storage=memory_storage, seed=1,
+                              metrics=metrics)
+        CoreWorkflow.run_train(engine, ep, variant, ctx)
+    assert set(threading.enumerate()) == before
+    (phases,) = [x for x in map(json.loads, open(metrics_file))
+                 if x["stage"] == "train/phases"]
+    assert sorted(k for k in phases if k.startswith("als.digest")) == [
+        "als.digest", f"als.digest.{path}"]
+    assert phases["als.digest"] >= phases[f"als.digest.{path}"] > 0
+    assert phases["als.train"] >= phases["als.digest"]
+    assert phases["dropped_spans"] == 0
+    assert not any(k.startswith("als-digest") for k in phases)
 
 
 def test_run_train_writes_the_process_first_seconds_beside_the_phases(
