@@ -156,7 +156,7 @@ def solve_rows(opposing: np.ndarray,
     while target < n:
         target *= 4
     # then to a chunk multiple so _solve_buckets_device's chunk walk
-    # covers the bucket exactly (same arithmetic as put_buckets)
+    # covers the bucket exactly (same arithmetic as place_buckets)
     chunk = _bucket_chunk_rows(target, tcap, k, 8)
     pad = (target - n) + ((-target) % chunk)
     if pad:

@@ -59,14 +59,15 @@ BUCKET_CELLS = REGISTRY.gauge(
     "Cells (rows x cap, summed over buckets, before chunk padding) of the "
     "side's buckets in the last als_train",
     labelnames=("side",))
-# What the train loop walks: `put_buckets` pads a bucket too large for one
+# What the train loop walks: `place_buckets` pads a bucket too large for one
 # gather to a multiple of its chunk (`_bucket_chunk_rows`), and a trip of
 # padding rows costs what a trip of real ones does. entries / walk cells is
 # the share of the loop's gather + Gram + solve rows that is not padding.
 BUCKET_WALK_CELLS = REGISTRY.gauge(
     "als_bucket_walk_cells",
     "Cells (rows x cap, summed over buckets, after chunk padding) of the "
-    "side's buckets as the last als_train placed them on the device",
+    "side's buckets as the last als_train or als_train_grid placed them on "
+    "the device",
     labelnames=("side",))
 # Which way each bucketizer call went. A train whose sides read `numpy`
 # fell back: no toolchain, PIO_NATIVE=0, or an input the loader declines.
@@ -617,7 +618,7 @@ class BucketCacheSave:
     and the calling thread spends the loop parked on the device.
     `bucketize_cached` stages the entry on a miss, the train starts the
     thread when its transfers are done (started beside them it slowed
-    `put_buckets` from 0.22 to 0.35 s: PERF.md, PR 44), and whoever made
+    `als.put_buckets` from 0.22 to 0.35 s: PERF.md, PR 44), and whoever made
     the object joins it, as a context manager on every way out: when a
     train returns or raises the entry is on disk (or the warning logged)
     and no thread is left. `als.bucket_cache.join` is the wait: about
@@ -748,8 +749,49 @@ def _bucket_chunk_rows(r: int, c: int, k: int, row_multiple: int) -> int:
     return min(r, -(-units // trips) * row_multiple)
 
 
+def place_buckets(side: str, buckets: Sequence[Bucket], n_rows: int,
+                  split_rows: np.ndarray, chunk_rows, row_shard, rep):
+    """One side's buckets and split-row ids onto the device, as the loops
+    walk them: `(placed, split_rows_dev)`, each placed bucket the tuple
+    (rows, cols, vals, mask, segmap-or-None) of `row_shard`ed arrays.
+
+    A bucket's rows are padded to a multiple of `chunk_rows(rows, cap)`,
+    the chunk its walk will recompute from the padded height (a train's
+    `_bucket_chunk_rows` at its rank; a grid's at n_grid x rank; in
+    model-sharded mode the data shards' local rule, n_data of them), so
+    the fori_loop of `_walk_bucket_chunks` covers the bucket exactly.
+    Padding rows carry the sentinel ids the scatters drop (`n_rows`, and
+    `len(split_rows)` for a segment), no columns and a zero mask. Sets the
+    side's `BUCKET_WALK_CELLS` to what it placed. The caller opens the
+    span (`als.put_buckets`, one a train)."""
+    import jax
+
+    placed = []
+    for b in buckets:
+        r_total, cap = b.cols.shape
+        pad = (-r_total) % chunk_rows(r_total, cap)
+        arrs = [b.rows, b.cols, b.vals, b.mask, b.segmap]
+        if pad:
+            arrs[0] = np.concatenate([b.rows, np.full(pad, n_rows, np.int32)])
+            for i in (1, 2, 3):
+                arrs[i] = np.concatenate(
+                    [arrs[i], np.zeros((pad, cap), arrs[i].dtype)])
+            if b.segmap is not None:
+                arrs[4] = np.concatenate(
+                    [b.segmap, np.full(pad, len(split_rows), np.int32)])
+        placed.append(tuple(
+            None if a is None else jax.device_put(a, row_shard)
+            for a in arrs))
+    BUCKET_WALK_CELLS.labels(side=side).set(
+        sum(b[1].shape[0] * b[1].shape[1] for b in placed))
+    return placed, jax.device_put(split_rows, rep)
+
+
 def _gather_rows(table, cols, mesh=None):
-    """[R, C] row-id gather from [V, K] → [R, C, K].
+    """[R, C] row-id gather from [V, K] → [R, C, K], or from a grid's
+    [V, G, K] → [R, C, G, K]: whatever follows the row axis is one flat
+    row to the take (G·K wide for a grid, which costs the gather nothing:
+    it is bound by the count of rows, not their width).
 
     Single device: flat `jnp.take` + reshape — XLA lowers it ~10× faster
     than the direct [R, C] indexed gather on TPU (and the bucketizer sorts
@@ -763,8 +805,8 @@ def _gather_rows(table, cols, mesh=None):
     r, c = cols.shape
     # mode="clip" matches the indexed gather's clamp semantics (the
     # default "fill" would turn an out-of-range id into NaN factors)
-    return jnp.take(table, cols.reshape(-1), axis=0, mode="clip").reshape(
-        r, c, table.shape[-1])
+    return jnp.take(table.reshape(table.shape[0], -1), cols.reshape(-1),
+                    axis=0, mode="clip").reshape(r, c, *table.shape[1:])
 
 
 def normal_eq_einsum(compute_dtype):
@@ -799,7 +841,7 @@ def _walk_bucket_chunks(arrays, cap: int, k: int, row_multiple: int, fn, carry):
     `_bucket_chunk_rows`) are walked in row chunks under a fori_loop so the
     [R, C, K] gathers inside `fn` never materialize past the budget.
     `arrays` are per-row device arrays (None entries pass through as None);
-    put_buckets pads row counts to a chunk multiple with the SAME
+    place_buckets pads row counts to a chunk multiple with the SAME
     (cap, k, row_multiple) arithmetic, which keeps the walk exact."""
     import jax
 
@@ -818,17 +860,79 @@ def _walk_bucket_chunks(arrays, cap: int, k: int, row_multiple: int, fn, carry):
     return jax.lax.fori_loop(0, r_total // chunk, body, carry)
 
 
+def _partial_normal_eqs(y, vals, mask, cfg: ALSConfig, alpha):
+    """Raw per-row partial normal equations (A, b) of a chunk's gathered
+    factors `y` [R, C, K] (a grid's [R, C, G, K], with `alpha` its [G]):
+    no global Gram, no regulariser, f32, associative over any split of a
+    row's entries. The one place the Gram/RHS sums are written: the train,
+    the fold, the grid and the model-sharded loop all form them here."""
+    import jax.numpy as jnp
+
+    grid = y.ndim == 4
+    g = "g" if grid else ""
+    cdtype = jnp.dtype(cfg.compute_dtype)
+    ne_einsum = normal_eq_einsum(cdtype)
+    # ym on BOTH einsum sides: the mask is 0/1 so m² == m, and keeping
+    # the raw `y` alive as a second operand forces XLA to materialize
+    # the gather for it (measured 15× slower at the hot-bucket shape)
+    ym = (y * mask[(...,) + (None,) * (y.ndim - 2)]).astype(cdtype)
+    if not cfg.implicit:
+        a = ne_einsum(f"rc{g}k,rc{g}l->r{g}kl", ym, ym)
+        b = ne_einsum(f"rc{g}k,rc->r{g}k", ym, vals.astype(cdtype))
+        return a, b
+    # C - I, zero at padding; one confidence a grid point
+    conf = alpha[None, None, :] * vals[:, :, None] if grid else alpha * vals
+    a = ne_einsum(f"rc{g}k,rc{g},rc{g}l->r{g}kl", ym, conf.astype(cdtype), ym)
+    b = ne_einsum(f"rc{g}k,rc{g}->r{g}k", ym, (1.0 + conf).astype(cdtype))
+    return a, b
+
+
+def _regularise_and_solve(a, b, n, gram, cfg: ALSConfig, reg, dtype,
+                          mesh=None, row_sharded=True):
+    """Partial (A, b) and entry counts `n` [R] → solved factors in `dtype`:
+    adds the implicit mode's YᵀY (`gram`, None in explicit mode) and the
+    regulariser (λ·n_r with ALS-WR, else λ), then `solve_spd`. A grid's
+    [R, G, K, K] with `reg` its [G] is flattened to the (R·G)-row batch
+    the solvers take, so the solver never knows a grid is running. The
+    other place the four loops share."""
+    import jax.numpy as jnp
+
+    k = a.shape[-1]
+    if gram is not None:
+        a = a + gram[None]
+    grid = a.ndim == 4
+    lam = reg[None, :] if grid else reg  # λ a grid point: [1, G]
+    weight = n if cfg.weighted_reg else jnp.ones_like(n)  # ALS-WR: λ·n_r
+    lam = lam * (weight[:, None] if grid else weight)
+    a = a + (lam[..., None, None]
+             * jnp.eye(k, dtype=jnp.float32)[(None,) * (a.ndim - 2)])
+    x = solve_spd(a.astype(dtype).reshape(-1, k, k),
+                  b.astype(dtype).reshape(-1, k),
+                  kernel=cfg.solver == "gj",
+                  interpret=cfg.pallas == "interpret",
+                  mesh=mesh, row_sharded=row_sharded)
+    return x.reshape(b.shape)
+
+
 def _solve_buckets_device(
-    opposing,  # [n_cols(+1 pad row), K] — gathered from
+    opposing,  # [n_cols(+1 pad row), K] — gathered from; a grid's [V, G, K]
     out_rows: int,  # static: rows in the solved-for factor matrix
     buckets_dev: Sequence[tuple],  # per bucket: (rows, cols, vals, mask, segmap)
     cfg: ALSConfig,
     split_rows=None,  # [U] int32 — row ids needing cross-segment combine
     row_multiple: int = 8,
     mesh=None,  # enables the sharded Pallas solve when size > 1
+    reg=None,  # λ and α: a grid's traced [G] arrays; None for a train and
+    alpha=None,  # a fold, which take cfg's floats
 ):
     """One half-epoch: solve every row's normal equations, scatter into a
     fresh [out_rows, K] matrix. Pure jittable function of device arrays.
+
+    The half-iteration of a train, a fold and a grid (`ops/als_grid.py`),
+    which it tells by what it is handed: a grid's `opposing` is [V, G, K]
+    and every row solves G systems that share its gathered entries, so
+    the accumulators and the output grow a middle G axis and the chunk
+    rule sees rows G·K wide; without one every shape is a train's.
 
     Rows split into segments (bucket_ragged_split) have their partial
     (A, b, n) scatter-added into a [U, ...] accumulator keyed by segmap and
@@ -844,65 +948,43 @@ def _solve_buckets_device(
     # ops' metadata and survive a refactor, which compiler names such as
     # `%fusion.1067` do not. Metadata only: no op or fusion changes.
     scope = jax.named_scope
-    k = opposing.shape[-1]
+    *grid, k = opposing.shape[1:]  # [] or [G]
+    g = "g" if grid else ""
+    if reg is None:
+        reg, alpha = cfg.reg, cfg.alpha
     with scope("als.scatter"):
-        new = jnp.zeros((out_rows, k), dtype=opposing.dtype)
+        new = jnp.zeros((out_rows, *grid, k), dtype=opposing.dtype)
     n_split = 0 if split_rows is None else split_rows.shape[0]
     if n_split:
         with scope("als.split_merge"):
-            acc_a = jnp.zeros((n_split, k, k), dtype=jnp.float32)
-            acc_b = jnp.zeros((n_split, k), dtype=jnp.float32)
+            acc_a = jnp.zeros((n_split, *grid, k, k), dtype=jnp.float32)
+            acc_b = jnp.zeros((n_split, *grid, k), dtype=jnp.float32)
             acc_n = jnp.zeros((n_split,), dtype=jnp.float32)
 
-    interpret = cfg.pallas == "interpret"
-    cdtype = jnp.dtype(cfg.compute_dtype)
-    f32 = jnp.float32
-    ne_einsum = normal_eq_einsum(cdtype)
     solve_trace_s = 0.0  # host seconds building this side's solves
-
+    gram = None
     if cfg.implicit:
         # global Gram over real (non-sentinel-pad) opposing rows (f32: it
         # is summed into per-row partials that may accumulate across
         # segments)
         with scope("als.yty"):
-            op_c = opposing.astype(cdtype)
-            gram = ne_einsum("ck,cl->kl", op_c, op_c)
-
-    def partial_gram(cols_c, vals_c, mask_c):
-        """Raw per-row partial normal equations (no global Gram, no reg):
-        associative over any split of a row's entries, f32."""
-        y = _gather_rows(opposing, cols_c, mesh)  # [R, C, K]
-        # ym on BOTH einsum sides: the mask is 0/1 so m² == m, and keeping
-        # the raw `y` alive as a second operand forces XLA to materialize
-        # the gather for it (measured 15× slower at the hot-bucket shape)
-        ym = (y * mask_c[..., None]).astype(cdtype)
-        if cfg.implicit:
-            conf = cfg.alpha * vals_c  # C - I, zero at padding
-            a = ne_einsum("rck,rc,rcl->rkl", ym, conf.astype(cdtype), ym)
-            b = ne_einsum("rck,rc->rk", ym, (1.0 + conf).astype(cdtype))
-        else:
-            a = ne_einsum("rck,rcl->rkl", ym, ym)
-            b = ne_einsum("rck,rc->rk", ym, vals_c.astype(cdtype))
-        return a, b
+            op_c = opposing.astype(jnp.dtype(cfg.compute_dtype))
+            gram = normal_eq_einsum(op_c.dtype)(f"c{g}k,c{g}l->{g}kl",
+                                                op_c, op_c)
 
     def finalize(a, b, n, row_sharded=True):
-        """Partial (A, b, n) → solved factors (adds Gram/reg, f32 → dtype)."""
         nonlocal solve_trace_s
         t0 = time.monotonic()
-        if cfg.implicit:
-            a = a + gram[None]
-        reg = cfg.reg * (n if cfg.weighted_reg else jnp.ones_like(n))
-        a = (a + reg[:, None, None] * jnp.eye(k, dtype=f32)[None])
-        x = solve_spd(a.astype(opposing.dtype), b.astype(opposing.dtype),
-                      kernel=cfg.solver == "gj", interpret=interpret,
-                      mesh=mesh, row_sharded=row_sharded)
+        x = _regularise_and_solve(a, b, n, gram, cfg, reg, opposing.dtype,
+                                  mesh, row_sharded)
         solve_trace_s += time.monotonic() - t0
         return x
 
     def process(rows_c, cols_c, vals_c, mask_c, segmap_c, new, accs):
         with scope("als.gather_gram"):
             n = mask_c.sum(-1)
-            a, b = partial_gram(cols_c, vals_c, mask_c)
+            a, b = _partial_normal_eqs(_gather_rows(opposing, cols_c, mesh),
+                                       vals_c, mask_c, cfg, alpha)
         rows_eff = rows_c
         if segmap_c is not None:
             with scope("als.split_merge"):
@@ -921,10 +1003,11 @@ def _solve_buckets_device(
         return new, accs
 
     accs = (acc_a, acc_b, acc_n) if n_split else ()
+    width = int(np.prod(opposing.shape[1:]))  # a gathered row's: K, or G·K
     for bucket in buckets_dev:
         cap = bucket[1].shape[1]
         new, accs = _walk_bucket_chunks(
-            bucket, cap, k, row_multiple,
+            bucket, cap, width, row_multiple,
             lambda sliced, carry: process(*sliced, *carry), (new, accs))
 
     if n_split:
@@ -944,24 +1027,30 @@ def _solve_buckets_device(
 
 def _predict_sq_err(u_factors, i_factors, buckets_dev, row_multiple: int = 8,
                     mesh=None):
-    """Σ (uᵀv − r)² over all real entries (for RMSE history)."""
+    """Σ (uᵀv − r)² over all real entries (for RMSE history), and their
+    count; a grid's [V, G, K] tables give one sum a grid point, [G]."""
     import jax.numpy as jnp
+
+    grid = u_factors.shape[1:-1]  # () or (G,)
+    g = "g" if grid else ""
 
     def err_chunk(sliced, carry):
         rows_c, cols_c, vals_c, mask_c, _segmap = sliced
         total, count = carry
-        u = u_factors[rows_c.clip(0, u_factors.shape[0] - 1)]  # [R, K]
-        v = _gather_rows(i_factors, cols_c, mesh)  # [R, C, K]
-        pred = jnp.einsum("rk,rck->rc", u, v)
-        err = (pred - vals_c) * mask_c
-        return total + jnp.sum(err * err), count + jnp.sum(mask_c)
+        u = u_factors[rows_c.clip(0, u_factors.shape[0] - 1)]  # [R, (G,) K]
+        v = _gather_rows(i_factors, cols_c, mesh)  # [R, C, (G,) K]
+        pred = jnp.einsum(f"r{g}k,rc{g}k->rc{g}", u, v)
+        entries = (...,) + (None,) * len(grid)  # beside a grid's G axis
+        err = (pred - vals_c[entries]) * mask_c[entries]
+        return (total + jnp.sum(err * err, axis=(0, 1)),
+                count + jnp.sum(mask_c))
 
-    k = u_factors.shape[-1]
-    total = jnp.zeros((), dtype=jnp.float32)
+    total = jnp.zeros(grid, dtype=jnp.float32)
     count = jnp.zeros((), dtype=jnp.float32)
+    width = int(np.prod(u_factors.shape[1:]))
     for bucket in buckets_dev:
         cap = bucket[1].shape[1]
-        total, count = _walk_bucket_chunks(bucket, cap, k, row_multiple,
+        total, count = _walk_bucket_chunks(bucket, cap, width, row_multiple,
                                            err_chunk, (total, count))
     return total, count
 
@@ -1237,49 +1326,19 @@ def _als_train(user_idx, item_idx, ratings, n_users: int, n_items: int,
     row_shard = NamedSharding(mesh, P(DATA_AXIS))
     rep = NamedSharding(mesh, P())
 
-    def put_buckets(buckets: list[Bucket], n_rows: int, n_split: int):
-        out = []
-        for b in buckets:
-            r_total, cap = b.cols.shape
-            # pad rows to a chunk multiple so the fori_loop chunk walk in
-            # _solve_buckets_device covers the whole bucket exactly. In
-            # model-sharded mode the walk runs per device on local rows,
-            # so the alignment is computed in local units × n_data.
-            if model_sharded:
-                r_local = r_total // n_data
-                chunk = n_data * _bucket_chunk_rows(
-                    r_local, cap, cfg.rank, rm_local)
-            else:
-                chunk = _bucket_chunk_rows(r_total, cap, cfg.rank,
-                                           row_multiple)
-            pad = (-r_total) % chunk
-            arrs = dict(rows=b.rows, cols=b.cols, vals=b.vals, mask=b.mask,
-                        segmap=b.segmap)
-            if pad:
-                arrs["rows"] = np.concatenate(
-                    [b.rows, np.full(pad, n_rows, np.int32)])
-                for name in ("cols", "vals", "mask"):
-                    a = arrs[name]
-                    arrs[name] = np.concatenate(
-                        [a, np.zeros((pad, cap), a.dtype)])
-                if b.segmap is not None:
-                    arrs["segmap"] = np.concatenate(
-                        [b.segmap, np.full(pad, n_split, np.int32)])
-            out.append(tuple(
-                None if arrs[name] is None
-                else jax.device_put(arrs[name], row_shard)
-                for name in ("rows", "cols", "vals", "mask", "segmap")
-            ))
-        return out
+    def chunk_rows(r_total: int, cap: int) -> int:
+        # in model-sharded mode the walk runs per device on local rows,
+        # so the alignment is computed in local units × n_data
+        if model_sharded:
+            return n_data * _bucket_chunk_rows(r_total // n_data, cap,
+                                               cfg.rank, rm_local)
+        return _bucket_chunk_rows(r_total, cap, cfg.rank, row_multiple)
 
     with span("als.put_buckets"):
-        ub_dev = put_buckets(user_buckets, n_users, len(u_split))
-        ib_dev = put_buckets(item_buckets, n_items, len(i_split))
-        u_split_dev = jax.device_put(u_split, rep)
-        i_split_dev = jax.device_put(i_split, rep)
-    for side, placed in (("user", ub_dev), ("item", ib_dev)):
-        BUCKET_WALK_CELLS.labels(side=side).set(
-            sum(b[1].shape[0] * b[1].shape[1] for b in placed))
+        ub_dev, u_split_dev = place_buckets(
+            "user", user_buckets, n_users, u_split, chunk_rows, row_shard, rep)
+        ib_dev, i_split_dev = place_buckets(
+            "item", item_buckets, n_items, i_split, chunk_rows, row_shard, rep)
 
     # factor sharding: replicated on a data-only mesh; row-sharded over
     # the `model` axis otherwise (VERDICT r1 #3 — config 5's capability)

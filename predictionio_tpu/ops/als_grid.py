@@ -25,6 +25,12 @@ Design (why this is NOT a vmap of G independent trains):
 - λ and α enter as **traced `[G]` arrays**, not static config floats, so
   every grid over the same shapes shares one compiled program.
 
+None of that arithmetic is written here: a grid runs the train's
+half-iteration, `als._solve_buckets_device`, which tells a grid by the
+[V, G, K] tables it is handed, and its buckets are placed by
+`als.place_buckets`. This module holds what is a grid's own: which cells
+batch, the per-seed init, the per-cell horizon, the results' slicing.
+
 Sharding: bucket rows shard over the mesh `data` axis exactly as in
 `als_train`; factors are replicated ([V, G, K] is G× a single train's
 factors — at eval scale that is megabytes). The `model` factor-sharding
@@ -47,12 +53,12 @@ from predictionio_tpu.ops.als import (
     ALSResult,
     BucketCacheSave,
     _bucket_chunk_rows,
-    _walk_bucket_chunks,
+    _predict_sq_err,
+    _solve_buckets_device,
     bucketize_cached,
-    normal_eq_einsum,
+    place_buckets,
     resolve_solver,
 )
-from predictionio_tpu.ops.solve import solve_spd
 
 log = logging.getLogger(__name__)
 
@@ -101,142 +107,6 @@ def grid_groups(cfgs: Sequence[ALSConfig]) -> list[list[int]]:
     return list(groups.values())
 
 
-def _gather_rows_grid(table, cols, mesh=None):
-    """[R, C] row-id gather from [V, G, K] → [R, C, G, K].
-
-    Single device: the [V, G·K]-flattened `jnp.take` fast path — same
-    lowering als._gather_rows uses, rows just G× wider (free: the gather
-    is op-throughput-bound, not bandwidth-bound). Under a mesh the
-    indexed form shards cleanly over the row dim."""
-    import jax.numpy as jnp
-
-    if mesh is not None and mesh.size > 1:
-        return table[cols]
-    v, g, k = table.shape
-    r, c = cols.shape
-    return jnp.take(table.reshape(v, g * k), cols.reshape(-1), axis=0,
-                    mode="clip").reshape(r, c, g, k)
-
-
-def _solve_buckets_grid(
-    opposing,  # [V, G, K]
-    out_rows: int,
-    buckets_dev: Sequence[tuple],
-    cfg: ALSConfig,  # static fields only (reg/alpha read from arrays)
-    regs,  # [G] f32 traced
-    alphas,  # [G] f32 traced (implicit mode)
-    split_rows=None,
-    row_multiple: int = 8,
-    mesh=None,
-):
-    """One grid half-epoch: per row, solve G normal-equation systems that
-    share the row's gathered entries. Mirrors als._solve_buckets_device
-    with a batched `g` axis; see module docstring for the layout."""
-    import jax.numpy as jnp
-
-    v, g, k = opposing.shape
-    new = jnp.zeros((out_rows, g, k), dtype=opposing.dtype)
-    n_split = 0 if split_rows is None else split_rows.shape[0]
-    if n_split:
-        acc_a = jnp.zeros((n_split, g, k, k), dtype=jnp.float32)
-        acc_b = jnp.zeros((n_split, g, k), dtype=jnp.float32)
-        acc_n = jnp.zeros((n_split,), dtype=jnp.float32)
-
-    interpret = cfg.pallas == "interpret"
-    cdtype = jnp.dtype(cfg.compute_dtype)
-    f32 = jnp.float32
-    ne_einsum = normal_eq_einsum(cdtype)
-
-    if cfg.implicit:
-        op_c = opposing.astype(cdtype)
-        gram = ne_einsum("vgk,vgl->gkl", op_c, op_c)
-
-    def partial_gram(cols_c, vals_c, mask_c):
-        y = _gather_rows_grid(opposing, cols_c, mesh)  # [R, C, G, K]
-        # mask on both einsum sides (m² == m) — keeps XLA from
-        # materializing the raw gather twice (see als.partial_gram)
-        ym = (y * mask_c[..., None, None]).astype(cdtype)
-        if cfg.implicit:
-            conf = alphas[None, None, :] * vals_c[:, :, None]  # [R, C, G]
-            a = ne_einsum("rcgk,rcg,rcgl->rgkl", ym, conf.astype(cdtype), ym)
-            b = ne_einsum("rcgk,rcg->rgk", ym, (1.0 + conf).astype(cdtype))
-        else:
-            a = ne_einsum("rcgk,rcgl->rgkl", ym, ym)
-            b = ne_einsum("rcgk,rc->rgk", ym, vals_c.astype(cdtype))
-        return a, b
-
-    def finalize(a, b, n, row_sharded=True):
-        if cfg.implicit:
-            a = a + gram[None]
-        # [R, G] regularizer: per-row λ·n_r (ALS-WR) × per-grid-point λ
-        reg_rg = regs[None, :] * (n[:, None] if cfg.weighted_reg
-                                  else jnp.ones_like(n)[:, None])
-        a = a + reg_rg[..., None, None] * jnp.eye(k, dtype=f32)[None, None]
-        # flatten the (row, grid) batch into the row-batched solve
-        # als_train uses
-        r = a.shape[0]
-        x = solve_spd(a.astype(opposing.dtype).reshape(r * g, k, k),
-                      b.astype(opposing.dtype).reshape(r * g, k),
-                      kernel=cfg.solver == "gj", interpret=interpret,
-                      mesh=mesh, row_sharded=row_sharded)
-        return x.reshape(r, g, k)
-
-    def process(rows_c, cols_c, vals_c, mask_c, segmap_c, new, accs):
-        n = mask_c.sum(-1)
-        a, b = partial_gram(cols_c, vals_c, mask_c)
-        rows_eff = rows_c
-        if segmap_c is not None:
-            acc_a, acc_b, acc_n = accs
-            accs = (acc_a.at[segmap_c].add(a, mode="drop"),
-                    acc_b.at[segmap_c].add(b, mode="drop"),
-                    acc_n.at[segmap_c].add(n, mode="drop"))
-            rows_eff = jnp.where(segmap_c < n_split, out_rows, rows_c)
-        x = finalize(a, b, n)
-        new = new.at[rows_eff].set(x.astype(new.dtype), mode="drop")
-        return new, accs
-
-    accs = (acc_a, acc_b, acc_n) if n_split else ()
-    for bucket in buckets_dev:
-        cap = bucket[1].shape[1]
-        # chunk budget: the grid gather is [chunk, C, G, K] — G× a single
-        # train's block, so the budget arithmetic sees an effective rank
-        # of G·K
-        new, accs = _walk_bucket_chunks(
-            bucket, cap, g * k, row_multiple,
-            lambda sliced, carry: process(*sliced, *carry), (new, accs))
-
-    if n_split:
-        x_u = finalize(*accs, row_sharded=False)
-        new = new.at[split_rows].set(x_u.astype(new.dtype), mode="drop")
-    return new
-
-
-def _predict_sq_err_grid(u_factors, i_factors, buckets_dev,
-                         row_multiple: int = 8, mesh=None):
-    """Per-grid-point Σ (uᵀv − r)² over all real entries → ([G], count)."""
-    import jax.numpy as jnp
-
-    v, g, k = u_factors.shape
-
-    def err_chunk(sliced, carry):
-        rows_c, cols_c, vals_c, mask_c, _segmap = sliced
-        total, count = carry
-        u = u_factors[rows_c.clip(0, u_factors.shape[0] - 1)]  # [R, G, K]
-        y = _gather_rows_grid(i_factors, cols_c, mesh)  # [R, C, G, K]
-        pred = jnp.einsum("rgk,rcgk->rcg", u, y)
-        err = (pred - vals_c[:, :, None]) * mask_c[:, :, None]
-        return (total + jnp.sum(err * err, axis=(0, 1)),
-                count + jnp.sum(mask_c))
-
-    total = jnp.zeros((g,), dtype=jnp.float32)
-    count = jnp.zeros((), dtype=jnp.float32)
-    for bucket in buckets_dev:
-        cap = bucket[1].shape[1]
-        total, count = _walk_bucket_chunks(bucket, cap, g * k, row_multiple,
-                                           err_chunk, (total, count))
-    return total, count
-
-
 @functools.lru_cache(maxsize=32)
 def _get_grid_train_loop(n_users: int, n_items: int, cfg: ALSConfig,
                          n_grid: int, compute_rmse: bool, n_steps: int,
@@ -272,20 +142,21 @@ def _get_grid_train_loop(n_users: int, n_items: int, cfg: ALSConfig,
             # keep iterating. Finished lanes still compute (one program,
             # uniform shapes) and are discarded by the where.
             act = (t < iters)[None, :, None]
-            u_new = _solve_buckets_grid(item_f, n_users, ub_dev, cfg,
-                                        regs, alphas, u_split,
-                                        row_multiple, mesh)
+            u_new = _solve_buckets_device(item_f, n_users, ub_dev, cfg,
+                                          u_split, row_multiple, mesh,
+                                          reg=regs, alpha=alphas)
             user_f = jax.numpy.where(act, u_new, user_f)
-            i_new = _solve_buckets_grid(user_f, n_items, ib_dev, cfg,
-                                        regs, alphas, i_split,
-                                        row_multiple, mesh)
+            i_new = _solve_buckets_device(user_f, n_items, ib_dev, cfg,
+                                          i_split, row_multiple, mesh,
+                                          reg=regs, alpha=alphas)
             item_f = jax.numpy.where(act, i_new, item_f)
             if compute_rmse:
-                total, count = _predict_sq_err_grid(
-                    user_f, item_f, ub_dev, row_multiple, mesh)
-                rmse = jax.numpy.sqrt(
-                    jax.numpy.maximum(total, 0.0)
-                    / jax.numpy.maximum(count, 1.0))
+                with jax.named_scope("als.rmse"):
+                    total, count = _predict_sq_err(
+                        user_f, item_f, ub_dev, row_multiple, mesh)
+                    rmse = jax.numpy.sqrt(
+                        jax.numpy.maximum(total, 0.0)
+                        / jax.numpy.maximum(count, 1.0))
             else:
                 rmse = jax.numpy.zeros((n_grid,), dtype=jax.numpy.float32)
             return (user_f, item_f), rmse
@@ -380,39 +251,18 @@ def _als_train_grid(user_idx, item_idx, ratings, n_users: int, n_items: int,
         "-".join(map(str, sorted({c.iterations for c in cfgs}))),
         dict(mesh.shape))
 
-    dtype = jnp.dtype(cfg.dtype)
     row_shard = NamedSharding(mesh, P(DATA_AXIS))
     rep = NamedSharding(mesh, P())
 
-    def put_buckets(buckets, n_rows: int, n_split: int):
-        out = []
-        for b in buckets:
-            r_total, cap = b.cols.shape
-            chunk = _bucket_chunk_rows(r_total, cap, n_grid * cfg.rank,
-                                       row_multiple)
-            pad = (-r_total) % chunk
-            arrs = dict(rows=b.rows, cols=b.cols, vals=b.vals, mask=b.mask,
-                        segmap=b.segmap)
-            if pad:
-                arrs["rows"] = np.concatenate(
-                    [b.rows, np.full(pad, n_rows, np.int32)])
-                for name in ("cols", "vals", "mask"):
-                    a = arrs[name]
-                    arrs[name] = np.concatenate(
-                        [a, np.zeros((pad, cap), a.dtype)])
-                if b.segmap is not None:
-                    arrs["segmap"] = np.concatenate(
-                        [b.segmap, np.full(pad, n_split, np.int32)])
-            out.append(tuple(
-                None if arrs[name] is None
-                else jax.device_put(arrs[name], row_shard)
-                for name in ("rows", "cols", "vals", "mask", "segmap")))
-        return out
+    def chunk_rows(r_total: int, cap: int) -> int:
+        # a gathered row is n_grid x rank wide: G× a single train's block
+        return _bucket_chunk_rows(r_total, cap, n_grid * cfg.rank,
+                                  row_multiple)
 
-    ub_dev = put_buckets(user_buckets, n_users, len(u_split))
-    ib_dev = put_buckets(item_buckets, n_items, len(i_split))
-    u_split_dev = jax.device_put(u_split, rep)
-    i_split_dev = jax.device_put(i_split, rep)
+    ub_dev, u_split_dev = place_buckets(
+        "user", user_buckets, n_users, u_split, chunk_rows, row_shard, rep)
+    ib_dev, i_split_dev = place_buckets(
+        "item", item_buckets, n_items, i_split, chunk_rows, row_shard, rep)
 
     keys = jnp.stack([jax.random.key(c.seed) for c in cfgs])
     regs = jnp.asarray([c.reg for c in cfgs], jnp.float32)

@@ -36,13 +36,8 @@ from typing import Optional, Sequence
 
 import numpy as np
 
-from predictionio_tpu.ops.als import (
-    ALSConfig,
-    _bucket_chunk_rows,
-    _walk_bucket_chunks,
-    normal_eq_einsum,
-)
-from predictionio_tpu.ops.solve import solve_spd
+from predictionio_tpu.ops import als
+from predictionio_tpu.ops.als import ALSConfig, _walk_bucket_chunks
 from predictionio_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
 
 log = logging.getLogger(__name__)
@@ -97,8 +92,6 @@ def get_train_loop_sharded(
     n_model = mesh.shape[MODEL_AXIS]
     k = cfg.rank
     f32 = jnp.float32
-    cdtype = jnp.dtype(cfg.compute_dtype)
-    ne_einsum = normal_eq_einsum(cdtype)
     scope = jax.named_scope  # the same fixed names as ops/als.py's loop
 
     def bucket_specs(flags):
@@ -127,21 +120,18 @@ def get_train_loop_sharded(
                 acc_b = jnp.zeros((n_split, k), f32)
                 acc_n = jnp.zeros((n_split,), f32)
 
+        gram = None
         if cfg.implicit:
             with scope("als.yty"):
-                op_c = opposing_local.astype(cdtype)
+                op_c = opposing_local.astype(jnp.dtype(cfg.compute_dtype))
+                ne_einsum = als.normal_eq_einsum(op_c.dtype)
                 gram = lax.psum(ne_einsum("ck,cl->kl", op_c, op_c),
                                 MODEL_AXIS)
 
         def finalize(a, b, n):
-            if cfg.implicit:
-                a = a + gram[None]
-            reg = cfg.reg * (n if cfg.weighted_reg else jnp.ones_like(n))
-            a = a + reg[:, None, None] * jnp.eye(k, dtype=f32)[None]
-            # device-local: this is already inside `run`'s shard_map
-            return solve_spd(a.astype(dtype), b.astype(dtype),
-                             kernel=cfg.solver == "gj",
-                             interpret=cfg.pallas == "interpret")
+            # no mesh: device-local, already inside `run`'s shard_map
+            return als._regularise_and_solve(a, b, n, gram, cfg, cfg.reg,
+                                             dtype)
 
         def process(sliced, carry):
             rows_c, cols_c, vals_c, mask_c, segmap_c = sliced
@@ -150,17 +140,8 @@ def get_train_loop_sharded(
                 n = mask_c.sum(-1)
                 y, _ = _masked_local_gather(opposing_local, cols_c, opp_off,
                                             opp_size, k)
-                ym = (y * mask_c[..., None]).astype(cdtype)
-                if cfg.implicit:
-                    conf = cfg.alpha * vals_c
-                    a_part = ne_einsum("rck,rc,rcl->rkl", ym,
-                                       conf.astype(cdtype), ym)
-                    b_part = ne_einsum("rck,rc->rk", ym,
-                                       (1.0 + conf).astype(cdtype))
-                else:
-                    a_part = ne_einsum("rck,rcl->rkl", ym, ym)
-                    b_part = ne_einsum("rck,rc->rk", ym,
-                                       vals_c.astype(cdtype))
+                a_part, b_part = als._partial_normal_eqs(
+                    y, vals_c, mask_c, cfg, cfg.alpha)
             rows_eff = rows_c
             if segmap_c is not None:
                 with scope("als.split_merge"):

@@ -42,7 +42,7 @@ Sites in the tree:
 - `als.epoch_boundary` — between a training chunk's execution fence and
   its checkpoint save; armed per-rank it kills one member of a
   multi-process world at the worst moment (the elastic-recovery drill,
-  test_failure_paths.py::TestElasticRecovery)
+  test_failure_elastic_reform.py::TestElasticRecovery)
 - `w2v.step_boundary` / `logreg.step_boundary` — the same
   chunk-computed-but-not-saved moment for the segmented W2V SGNS and
   LogReg Adam trainers (workflow/segmented.py)

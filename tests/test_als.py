@@ -452,8 +452,9 @@ class TestChunkRule:
                                    rtol=1e-4)
 
     def test_grid_padded_heights_are_walked_exactly(self, monkeypatch):
-        """`als_grid.put_buckets` pads at rank n_grid x rank; the walk
-        recomputes the same chunk from the padded height."""
+        """A grid's buckets are placed at rank n_grid x rank; the walk
+        (traced in `ops/als.py` since a grid runs the train's
+        half-iteration) recomputes the same chunk from the padded height."""
         from predictionio_tpu.ops import als as als_mod, als_grid
 
         ui, ii, r, _ = synth_ratings(n_users=40, n_items=25, seed=4,
@@ -464,7 +465,7 @@ class TestChunkRule:
 
         monkeypatch.setattr(als_mod, "_CHUNK_BUDGET_BYTES", 1 << 12)
         als_grid._get_grid_train_loop.cache_clear()
-        walks = self._spy_walks(monkeypatch, als_grid)
+        walks = self._spy_walks(monkeypatch, als_mod)
         chunked = als_grid.als_train_grid(ui, ii, r, 40, 25, cfgs)
         als_grid._get_grid_train_loop.cache_clear()
         self._assert_exact(walks)
@@ -662,3 +663,235 @@ class TestModelShardedALS:
         assert out.user_factors.shape == (40, 128)
         assert np.isfinite(out.user_factors).all()
         assert np.isfinite(out.item_factors).all()
+
+
+def _run_loop(which):
+    """One small run of the named loop (train | fold | grid | sharded),
+    its compiled-program cache emptied first so that it traces here."""
+    import jax
+
+    from predictionio_tpu.online import foldin
+    from predictionio_tpu.ops import als as als_mod, als_grid, als_sharded
+    from predictionio_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS, make_mesh
+
+    for cache in (als_mod._get_train_loop, als_grid._get_grid_train_loop,
+                  als_sharded.get_train_loop_sharded, foldin._fold_solver):
+        cache.cache_clear()
+    ui, ii, r, _ = synth_ratings(n_users=40, n_items=24, seed=6)
+    r = np.abs(r) + 0.5  # confidences: positive
+    # implicit, with split rows: the YtY term and the accumulators' solve
+    cfg = ALSConfig(rank=4, iterations=2, reg=0.05, seed=1, implicit=True,
+                    alpha=2.0, solver="chol", split_cap=8)
+    one = make_mesh({DATA_AXIS: 1, MODEL_AXIS: 1}, devices=jax.devices()[:1])
+    if which == "train":
+        return als_train(ui, ii, r, 40, 24, cfg, mesh=one).user_factors
+    if which == "grid":
+        cfgs = [cfg, dataclasses.replace(cfg, reg=0.2)]
+        return als_grid.als_train_grid(ui, ii, r, 40, 24, cfgs,
+                                       mesh=one)[0].user_factors
+    if which == "sharded":
+        mesh = make_mesh({DATA_AXIS: 2, MODEL_AXIS: 2},
+                         devices=jax.devices()[:4])
+        return als_train(ui, ii, r, 40, 24, cfg, mesh=mesh).user_factors
+    assert which == "fold"
+    rng = np.random.default_rng(2)
+    opposing = rng.normal(size=(24, 4)).astype(np.float32)
+    entries = [(rng.choice(24, n, replace=False).astype(np.int32),
+                rng.uniform(1, 5, n).astype(np.float32)) for n in (3, 5, 2)]
+    return foldin.solve_rows(opposing, entries, cfg)
+
+
+LOOPS = pytest.mark.parametrize("which", ["train", "fold", "grid", "sharded"])
+
+
+class TestOneHalfIteration:
+    """A row's normal equations are formed (`als._partial_normal_eqs`) and
+    regularised and solved (`als._regularise_and_solve`) in one place for
+    the train, the fold, the grid and the model-sharded loop."""
+
+    @LOOPS
+    def test_every_loop_reaches_the_shared_functions(self, monkeypatch,
+                                                     which):
+        from predictionio_tpu.ops import als as als_mod
+
+        calls = {"_partial_normal_eqs": [], "_regularise_and_solve": []}
+        for name, seen in calls.items():
+            def spy(*args, _real=getattr(als_mod, name), _seen=seen, **kw):
+                _seen.append(args[0].ndim)
+                return _real(*args, **kw)
+
+            monkeypatch.setattr(als_mod, name, spy)
+        out = _run_loop(which)
+        assert np.isfinite(out).all()
+        formed, solved = calls.values()
+        assert formed and solved
+        # a grid hands them its G axis; the others are a train's shapes
+        assert set(formed) == ({4} if which == "grid" else {3})
+        assert set(solved) == ({4} if which == "grid" else {3})
+        if which != "fold":  # the split rows' accumulators: solved once more
+            assert len(solved) > len(formed)
+
+    @pytest.mark.parametrize("module", ["als_grid", "als_sharded"])
+    def test_the_other_loops_hold_no_normal_equations(self, module):
+        import importlib
+        import inspect
+
+        src = inspect.getsource(importlib.import_module(
+            f"predictionio_tpu.ops.{module}"))
+        assert "solve_spd" not in src and "_gather_rows_grid" not in src
+        assert "def put_buckets" not in src
+        if module == "als_grid":
+            assert "einsum(" not in src
+        else:  # YtY under its psum, and the prediction of `sq_err`
+            assert src.count("ne_einsum(") == 1
+            assert src.count("einsum(") == 3
+
+    @LOOPS
+    def test_one_precision_reaches_every_loop(self, monkeypatch, which):
+        """The way in of `perf/tests/control_precision.py::at_precision`:
+        replace `als.normal_eq_einsum`, clear the loop. The grid and the
+        model-sharded loop bound the name at import until PR 47 and kept
+        their own precision."""
+        import functools
+
+        import jax.numpy as jnp
+
+        from predictionio_tpu.ops import als as als_mod
+
+        subscripts = []
+
+        def recording(compute_dtype):
+            def einsum(spec, *operands, **kw):
+                subscripts.append(spec)
+                return jnp.einsum(spec, *operands, **kw)
+
+            return functools.partial(
+                einsum, preferred_element_type=jnp.float32, precision=None)
+
+        monkeypatch.setattr(als_mod, "normal_eq_einsum", recording)
+        _run_loop(which)
+        g = "g" if which == "grid" else ""
+        want = {f"rc{g}k,rc{g},rc{g}l->r{g}kl", f"rc{g}k,rc{g}->r{g}k",
+                f"c{g}k,c{g}l->{g}kl"}
+        if which == "fold":  # explicit or implicit, a fold's YtY is there too
+            assert want <= set(subscripts)
+        else:
+            assert set(subscripts) == want
+
+
+class TestPlaceBuckets:
+    """`als.place_buckets`: the one function that pads a side's buckets to
+    the walk and puts them on the device."""
+
+    @staticmethod
+    def _buckets(rows):
+        rng = np.random.default_rng(0)
+
+        def bucket(r, cap, split):
+            return Bucket(
+                rows=np.arange(r, dtype=np.int32),
+                cols=rng.integers(1, 50, (r, cap)).astype(np.int32),
+                vals=rng.uniform(1, 5, (r, cap)).astype(np.float32),
+                mask=np.ones((r, cap), np.float32),
+                segmap=(np.arange(r, dtype=np.int32) % 4 if split else None))
+
+        return [bucket(rows, 8, True), bucket(rows, 16, False),
+                bucket(8, 32, False)]
+
+    # rule -> (the chunk of (rows, cap), the budget, the rows of the two
+    # buckets that walk, the heights the three are placed with)
+    RULES = {
+        # 4 KiB at rank 4: 32 rows 8 wide (two trips of 24), 16 rows 16 wide
+        "train": (lambda als, r, cap: als._bucket_chunk_rows(r, cap, 4, 8),
+                  1 << 12, 40, [48, 48, 8]),
+        # 8 KiB at 3 x rank 4: 16 rows 8 wide (three trips), 8 rows 16 wide
+        "grid": (lambda als, r, cap: als._bucket_chunk_rows(r, cap, 3 * 4, 8),
+                 1 << 13, 40, [48, 40, 8]),
+        # two data shards of 22 rows, in units of 2: whole 8 wide, two
+        # trips of 12 each 16 wide
+        "model_sharded": (
+            lambda als, r, cap: 2 * als._bucket_chunk_rows(r // 2, cap, 4, 2),
+            1 << 12, 44, [44, 48, 8]),
+    }
+
+    @pytest.mark.parametrize("rule", list(RULES))
+    def test_pads_with_the_sentinels_under_each_chunk_rule(self, monkeypatch,
+                                                           rule):
+        import jax
+
+        from predictionio_tpu.ops import als as als_mod
+
+        chunk, budget, rows, heights = self.RULES[rule]
+        monkeypatch.setattr(als_mod, "_CHUNK_BUDGET_BYTES", budget)
+        buckets = self._buckets(rows)
+        split = np.asarray([3, 9, 11, 30], np.int32)
+        here = jax.sharding.SingleDeviceSharding(jax.devices()[0])
+        chunks = [chunk(als_mod, *b.cols.shape) for b in buckets]
+        assert any(c < b.cols.shape[0] for c, b in zip(chunks, buckets))
+        placed, split_dev = als_mod.place_buckets(
+            "user", buckets, 77, split,
+            lambda r, cap: chunk(als_mod, r, cap), here, here)
+        np.testing.assert_array_equal(np.asarray(split_dev), split)
+        assert [p[0].shape[0] for p in placed] == heights
+        for b, p, c in zip(buckets, placed, chunks):
+            rows, cols, vals, mask, segmap = (
+                None if a is None else np.asarray(a) for a in p)
+            r = b.cols.shape[0]
+            assert rows.shape[0] % c == 0 and rows.shape[0] - r < c
+            # the walk recomputes the chunk from the padded height
+            assert chunk(als_mod, rows.shape[0], b.cap) == c
+            for got, want in ((rows, b.rows), (cols, b.cols), (vals, b.vals),
+                              (mask, b.mask)):
+                np.testing.assert_array_equal(got[:r], want)
+                assert got.dtype == want.dtype
+            assert (rows[r:] == 77).all()  # dropped by the scatter
+            assert not cols[r:].any() and not vals[r:].any()
+            assert not mask[r:].any()
+            if b.segmap is None:
+                assert segmap is None
+            else:
+                np.testing.assert_array_equal(segmap[:r], b.segmap)
+                assert (segmap[r:] == len(split)).all()
+        assert als_mod.BUCKET_WALK_CELLS.labels(side="user").value == sum(
+            h * b.cap for h, b in zip(heights, buckets))
+
+    @pytest.mark.parametrize("which", ["train", "grid"])
+    def test_a_train_and_a_grid_set_the_walk_gauge(self, monkeypatch, which):
+        """A grid pads at n_grid x rank and reports what it placed (until
+        PR 47 only `als_train` set the gauge)."""
+        from predictionio_tpu.ops import als as als_mod, als_grid
+
+        ui, ii, r, _ = synth_ratings(n_users=40, n_items=25, seed=4,
+                                     density=0.4)
+        monkeypatch.setattr(als_mod, "_CHUNK_BUDGET_BYTES", 1 << 12)
+        for side in ("user", "item"):
+            als_mod.BUCKET_WALK_CELLS.labels(side=side).set(-1)
+        loops = (als_mod._get_train_loop, als_grid._get_grid_train_loop)
+        for loop in loops:
+            loop.cache_clear()
+        cfg = ALSConfig(rank=4, iterations=1, reg=0.05, seed=1)
+        placed = []
+        real = als_mod.place_buckets
+
+        def spy(side, *args):
+            out = real(side, *args)
+            placed.append((side, sum(
+                b[1].shape[0] * b[1].shape[1] for b in out[0])))
+            return out
+
+        monkeypatch.setattr(als_mod, "place_buckets", spy)
+        monkeypatch.setattr(als_grid, "place_buckets", spy)
+        if which == "train":
+            als_train(ui, ii, r, 40, 25, cfg)
+        else:
+            als_grid.als_train_grid(
+                ui, ii, r, 40, 25,
+                [cfg, dataclasses.replace(cfg, reg=0.2)])
+        for loop in loops:
+            loop.cache_clear()
+        assert [side for side, _ in placed] == ["user", "item"]
+        for side, cells in placed:
+            assert als_mod.BUCKET_WALK_CELLS.labels(
+                side=side).value == cells > 0
+            # padded to the walk: at least the bucketizer's own cells
+            assert cells >= als_mod.BUCKET_CELLS.labels(side=side).value > 0

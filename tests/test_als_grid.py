@@ -368,3 +368,113 @@ class TestEvalGridIntegration:
         assert engine.eval_grid(ctx, eps) is None
         result = MetricEvaluator.evaluate(ctx, self._evaluation(engine), eps)
         assert len(result.all_results) == 2
+
+
+CASES_OF_ONE = pytest.mark.parametrize("split_cap,budget", [
+    (64, None), (0, 1 << 14), (64, 1 << 14),
+], ids=["split_rows", "chunked_walk", "split_rows_chunked_walk"])
+BOTH_MODES = pytest.mark.parametrize("implicit", [False, True],
+                                     ids=["explicit", "implicit"])
+
+
+class TestGridOfOneIsTheTrain:
+    """A grid runs the train's half-iteration (`als._solve_buckets_device`
+    tells a grid by its [V, G, K] tables), so a grid of one point is the
+    train of that point. Bit for bit where the backend's sums do not
+    depend on their order: XLA's CPU dot sums `rcgk,rcgl->rgkl` at g = 1
+    in another order than `rck,rcl->rkl` (the last bits of A and b; the
+    solve of equal systems is equal), so the exact cases hand the
+    half-iteration factors, ratings, α and λ whose sums are exact in
+    float32 whatever their order, and the whole trains are held to what
+    reassociation leaves."""
+
+    @staticmethod
+    def _data():
+        u, i, v, n_u, n_i = coo(n=6000, n_u=120, n_i=80, seed=3)
+        u[:300] = 5  # a hot row: split into segments under split_cap 64
+        return u, i, np.round(v * 2) / 2, n_u, n_i  # half stars
+
+    @staticmethod
+    def _chunked(walks):
+        return any(chunk < rows for rows, chunk in walks)
+
+    @pytest.fixture
+    def walks(self, monkeypatch):
+        """(rows, chunk) of every bucket walk traced, both loops' caches
+        emptied before and after."""
+        from predictionio_tpu.ops import als as als_mod, als_grid
+
+        loops = (als_mod._get_train_loop, als_grid._get_grid_train_loop)
+        for loop in loops:
+            loop.cache_clear()
+        seen = []
+        real = als_mod._walk_bucket_chunks
+
+        def spy(arrays, cap, k, row_multiple, fn, carry):
+            rows = arrays[0].shape[0]
+            seen.append((rows, als_mod._bucket_chunk_rows(
+                rows, cap, k, row_multiple)))
+            return real(arrays, cap, k, row_multiple, fn, carry)
+
+        monkeypatch.setattr(als_mod, "_walk_bucket_chunks", spy)
+        yield seen
+        for loop in loops:
+            loop.cache_clear()
+
+    @BOTH_MODES
+    @CASES_OF_ONE
+    def test_half_iteration_bit_for_bit(self, monkeypatch, walks, implicit,
+                                        split_cap, budget):
+        import jax
+        import jax.numpy as jnp
+
+        from predictionio_tpu.ops import als as als_mod
+
+        if budget:
+            monkeypatch.setattr(als_mod, "_CHUNK_BUDGET_BYTES", budget)
+        u, i, v, n_u, n_i = self._data()
+        k = 8
+        cfg = ALSConfig(rank=k, reg=0.0625, alpha=3.0, implicit=implicit,
+                        solver="chol")
+        buckets, split = als_mod.bucket_ragged_split(
+            u, i, v.astype(np.float32), n_u, 8, split_cap or None)
+        assert bool(len(split)) == bool(split_cap)
+        here = jax.sharding.SingleDeviceSharding(jax.devices()[0])
+        placed, split_dev = als_mod.place_buckets(
+            "user", buckets, n_u, split,
+            lambda r, cap: als_mod._bucket_chunk_rows(r, cap, k, 8),
+            here, here)
+        # small integers: every product and sum below 2**24 is exact
+        opposing = jnp.asarray(np.random.default_rng(1).integers(
+            -3, 4, (n_i, k)).astype(np.float32))
+
+        train = jax.jit(lambda opp: als_mod._solve_buckets_device(
+            opp, n_u, placed, cfg, split_dev))(opposing)
+        grid = jax.jit(lambda opp, reg, alpha: als_mod._solve_buckets_device(
+            opp[:, None, :], n_u, placed, cfg, split_dev, reg=reg,
+            alpha=alpha))(opposing, jnp.asarray([cfg.reg], jnp.float32),
+                          jnp.asarray([cfg.alpha], jnp.float32))
+        assert self._chunked(walks) == bool(budget)
+        assert grid.shape == (n_u, 1, k)
+        np.testing.assert_array_equal(np.asarray(grid)[:, 0],
+                                      np.asarray(train))
+        assert np.abs(np.asarray(train)).max() > 0.1
+
+    @BOTH_MODES
+    @CASES_OF_ONE
+    def test_whole_train(self, monkeypatch, walks, implicit, split_cap,
+                         budget):
+        from predictionio_tpu.ops import als as als_mod
+
+        if budget:
+            monkeypatch.setattr(als_mod, "_CHUNK_BUDGET_BYTES", budget)
+        u, i, v, n_u, n_i = self._data()
+        cfg = ALSConfig(rank=8, iterations=3, reg=0.07, alpha=3.0, seed=4,
+                        implicit=implicit, split_cap=split_cap)
+        seq = als_train(u, i, v, n_u, n_i, cfg, compute_rmse=True)
+        (one,) = als_train_grid(u, i, v, n_u, n_i, [cfg], compute_rmse=True)
+        assert self._chunked(walks) == bool(budget)
+        assert rel_err(one.user_factors, seq.user_factors) < 2e-5
+        assert rel_err(one.item_factors, seq.item_factors) < 2e-5
+        assert one.rmse_history == pytest.approx(seq.rmse_history, rel=1e-6)
+        assert len(one.rmse_history) == 3

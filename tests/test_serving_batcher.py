@@ -385,13 +385,62 @@ class TestIsolation:
 
 # -- overhead bar -----------------------------------------------------------
 
-def test_batcher_overhead_under_5_percent_at_batch_of_1():
+def test_batcher_overhead_under_5_percent_at_batch_of_1(monkeypatch):
+    """What the ≤5% bar at batch-of-1 stands for, as counts: micro-batching
+    is free when there is nothing to batch because a lone admitted request
+    never meets the queue. It is dispatched inline on the caller's thread:
+    no hand-off record, no queue-wait observation, no batch taken by the
+    dispatcher thread, one batch of one a request. The timed ratio against
+    a loopback request's p50 is `test_batcher_overhead_ratio_timed` (slow):
+    on a CPU shared by the suite's workers it measured the machine
+    (ROADMAP.md D10)."""
+    from predictionio_tpu.serving import batcher as batcher_mod
+
+    dispatched = []  # (thread, batch size) of every predict call
+
+    def predict(queries):
+        dispatched.append((threading.get_ident(), len(queries)))
+        return queries
+
+    queued = []
+    real_pending = batcher_mod._Pending
+    monkeypatch.setattr(
+        batcher_mod, "_Pending",
+        lambda *a, **kw: queued.append(a) or real_pending(*a, **kw))
+    taken = []
+    real_dispatch = MicroBatcher._dispatch
+    monkeypatch.setattr(
+        MicroBatcher, "_dispatch",
+        lambda self, live: taken.append(len(live)) or real_dispatch(self, live))
+    plane = ServingPlane(predict,
+                         config=ServingConfig(
+                             admission=AdmissionConfig(max_queue=64)),
+                         name="batchbar")
+    headers = {"X-PIO-Deadline-Ms": "1000"}
+    n = 500
+    before = (batcher_mod._BATCHES.value, batcher_mod._BATCH_SIZE.count,
+              batcher_mod._BATCH_SIZE.sum, batcher_mod._QUEUE_WAIT.count)
+    try:
+        for i in range(n):
+            assert plane.handle_query(i, headers) == (i, False)
+    finally:
+        plane.close()
+    after = (batcher_mod._BATCHES.value, batcher_mod._BATCH_SIZE.count,
+             batcher_mod._BATCH_SIZE.sum, batcher_mod._QUEUE_WAIT.count)
+    assert dispatched == [(threading.get_ident(), 1)] * n
+    assert not queued and not taken
+    # one batch of one a request, and nothing waited in the queue
+    assert [b - a for a, b in zip(before, after)] == [n, n, n, 0]
+
+
+@pytest.mark.slow
+def test_batcher_overhead_ratio_timed():
     """The serving plane's per-request machinery (deadline parse, admit,
-    inline batcher dispatch, release) must cost ≤5% of a real loopback
-    request p50 at batch-of-1 — micro-batching must be free when there is
-    nothing to batch. Same methodology as the telemetry overhead bar:
-    machinery timed in-process against a measured HTTP p50 (an A/B of two
-    live servers at this tolerance would be noise-bound)."""
+    inline batcher dispatch, release) against a real loopback request p50
+    at batch-of-1: ≤5% on a quiet machine. Same methodology as the
+    telemetry overhead ratio: machinery timed in-process against a
+    measured HTTP p50 (an A/B of two live servers at this tolerance would
+    be noise-bound). Prints the ratio."""
     from predictionio_tpu.utils.http import HttpService, JsonRequestHandler
 
     class _PingHandler(JsonRequestHandler):
@@ -434,6 +483,8 @@ def test_batcher_overhead_under_5_percent_at_batch_of_1():
         gc.enable()
         plane.close()
     per_request = min(batches)
+    print(f"serving plane adds {per_request * 1e6:.1f}µs/request against a "
+          f"{request_p50 * 1e6:.1f}µs p50 ({per_request / request_p50:.1%})")
 
     assert per_request <= 0.05 * request_p50, (
         f"serving plane adds {per_request * 1e6:.1f}µs/request against a "
