@@ -9,8 +9,11 @@ scalar-decay state-space layer in its chunked matrix form, `ops/ssd.py`;
 plain softmax attention over grouped heads without positions) as the
 configuration says layer by layer, and a dense or a sparse-expert
 feed-forward, the router on the normed stream after the mixer or on the
-block's own input; an optional multi-token-prediction (MTP) module;
-trained on packed histories.
+block's own input; or, in a model whose layers are one sublayer each
+(Nemotron-H's pattern), a Mamba-2 layer with several B/C groups, an
+expert feed-forward of ungated squared-ReLU experts or attention, alone
+under its one norm and residual; an optional multi-token-prediction
+(MTP) module; trained on packed histories.
 
 One code path runs every size. A configuration file in the published
 model's own key names (`EncoderConfig.from_json`) gives the widths, the
@@ -64,7 +67,11 @@ Equations (the plain reference is `quality/encoder_reference.py`):
             S_t = exp(dt_t A) S_{t-1} + dt_t x_t (x) B_t, S [P, N] a
             head, zero entering a history's first token; y_t = S_t C_t +
             D x_t; out = (RMSNorm(y SiLU(z)) w) W_out: gate first, then
-            one norm over all channels
+            one norm over all channels. With `mamba_n_groups` G > 1: B
+            and C are G groups of N, head h reads group g(h) = h // (heads
+            / G) in the update and the read-out, and the norm runs over
+            each group's channels apart: out = (RMSNorm_per_group(y
+            SiLU(z)) w) W_out
     GQA     q, k, v = u W_q, u W_k, u W_v; query head j reads key and
             value head j // (heads / kv heads); o = softmax over the
             keys s <= t of the history of (q_t . k_s attention_multiplier)
@@ -80,6 +87,19 @@ Equations (the plain reference is `quality/encoder_reference.py`):
             GQA(RMSNorm(h)); out = a + sum over the held e in idx of w_e
             W_down,e (relu(W_gate,e x) * (W_up,e x)), x = RMSNorm(a). No
             bias on the router, no shared expert, no dense layer before
+    1xL     the layer of a model whose layers are one sublayer each
+            (`hybrid_override_pattern`, a letter a layer): h += Mixer_l(
+            RMSNorm_l(h)), one norm and one residual, Mixer_l by the
+            letter: M Mamba-2 as above (groups and all), * GQA as above
+            (no positional encoding, scores / sqrt(d)), E the expert
+            feed-forward: s = sigmoid(u W_g) in float32; idx = top-k of
+            s + bias (no group limit); w = routed_scaling_factor s[idx]
+            / sum(s[idx]); out = sum over the held e in idx of w_e
+            W_down,e relu(W_up,e u)^2 + W_down,s relu(W_up,s u)^2, the
+            shared expert of its own width
+            (`moe_shared_expert_intermediate_size`) and of the same
+            ungated form. After the last layer the final norm and an
+            untied head
     x4      the four multipliers of such a hybrid, each 1 where a
             configuration states none: h0 = embedding_multiplier
             E[token]; h += residual_multiplier Mixer(..) and h +=
@@ -124,6 +144,9 @@ _ALIASES = {"num_experts": "n_routed_experts",
 # a published `layer_types` entry -> the mixer kind here (Mamba-2's:
 # `mamba` there names the scalar-decay layer)
 _LAYER_TYPES = {"mamba": "ssd", "attention": "gqa"}
+# a letter of a published `hybrid_override_pattern` -> the one sublayer of
+# the layer (`-`, a dense feed-forward alone, is in no model run here)
+_PATTERN = {"M": "ssd", "E": "experts", "*": "gqa"}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -139,8 +162,10 @@ class EncoderConfig:
     q_lora_rank: int = 0             # 0 (null): x W_q, no low-rank query
     mla_use_nope: bool = False       # MLA without rotation
     # "mla" | "kda" | "mamba" | "swa" | "full" | "gmu" | "cross" | "ssd" |
-    # "gqa" a layer; (): all MLA
+    # "gqa" a layer; (): all MLA. With `single_sublayer` the layer is that
+    # mixer alone ("ssd" | "gqa") or an expert feed-forward alone ("experts")
     layer_kinds: tuple = ()
+    single_sublayer: bool = False
     num_key_value_heads: int = 0     # grouped K/V heads of swa/full/cross/gqa
     sliding_window: int = 0          # of the swa layers
     head_dim: int = 0                # gqa's head width; 0: hidden / heads
@@ -159,6 +184,7 @@ class EncoderConfig:
     mamba_n_heads: int = 0           # Mamba-2 (ssd): heads of mamba_d_head
     mamba_d_head: int = 0            # channels, one scalar decay a head
     mamba_conv_bias: bool = True
+    mamba_n_groups: int = 1          # B/C groups; the gated norm's too
     mamba_chunk_size: int = 256      # tokens a chunk of the ssd scan
     embedding_multiplier: float = 1.0
     residual_multiplier: float = 1.0  # of the carried kinds' blocks
@@ -178,10 +204,13 @@ class EncoderConfig:
     experts_total: int = 0           # the router's width
     expert_first: int = 0            # id of the first held expert
     n_shared_experts: int = 1
+    # the shared expert's width; 0: moe_intermediate_size x n_shared_experts
+    moe_shared_expert_intermediate_size: int = 0
     num_experts_per_tok: int = 0
     router_scoring: str = "sigmoid"  # | "softmax" (`ops/moe.py::route`)
     router_on_block_input: bool = False  # w_g reads h before the mixer
-    moe_gate: str = "silu"           # | "relu": the experts' gate
+    # the experts' gate, "silu" | "relu"; "relu2": ungated, relu(W_up x)^2
+    moe_gate: str = "silu"
     routed_scaling_factor: float = 1.0
     num_nextn_predict_layers: int = 0
     mtp_loss_weight: float = 0.3
@@ -208,7 +237,18 @@ class EncoderConfig:
 
     @property
     def n_moe(self) -> int:
+        """Layers with routed experts (and so a row of the router's bias)."""
+        if self.single_sublayer:
+            return self.kinds.count("experts")
         return self.num_hidden_layers - self.n_dense
+
+    @property
+    def expert_layers(self) -> tuple:
+        """The held layers that route, by their index among the held."""
+        if self.single_sublayer:
+            return tuple(n for n, kind in enumerate(self.kinds)
+                         if kind == "experts")
+        return tuple(range(self.n_dense, self.num_hidden_layers))
 
     @property
     def kinds(self) -> tuple:
@@ -274,6 +314,8 @@ class EncoderConfig:
             flat["layer_kinds"] = _held_layer_types(flat)
         if "moe_num_primary_experts" in flat:
             _routed_before_mixing(flat)
+        if "hybrid_override_pattern" in flat:
+            _one_sublayer_a_layer(flat)
         known = {f.name for f in dataclasses.fields(cls)}
         kw = {k: v for k, v in flat.items() if k in known and v is not None}
         kw["report_blocks"] = tuple(
@@ -289,6 +331,15 @@ class EncoderConfig:
             return cls.from_dict(json.load(f))
 
 
+def _held_slice(flat: dict, key: str) -> tuple:
+    """(first, entries) of the published per-layer list `key`: the
+    `num_hidden_layers` entries of the held layers, from `layer_first`
+    on where the list is the whole model's, else the list itself."""
+    listed, held = flat[key], int(flat["num_hidden_layers"])
+    first = int(flat.get("layer_first", 0)) if len(listed) > held else 0
+    return first, listed[first:first + held]
+
+
 def _held_layer_types(flat: dict) -> tuple:
     """The kinds of the held layers from a published `layer_types` list
     (the whole model's, or the held slice alone): `num_hidden_layers`
@@ -296,9 +347,8 @@ def _held_layer_types(flat: dict) -> tuple:
     shared one where it has no experts. What the two kinds here cannot
     express is refused: an entry of another type, several B/C groups, a
     bias on the state-space layer's projections, rotated attention."""
-    types, held = flat["layer_types"], int(flat["num_hidden_layers"])
-    first = int(flat.get("layer_first", 0)) if len(types) > held else 0
-    mine = types[first:first + held]
+    held = int(flat["num_hidden_layers"])
+    first, mine = _held_slice(flat, "layer_types")
     unknown = sorted(set(mine) - set(_LAYER_TYPES))
     if unknown or len(mine) != held:
         raise ValueError(
@@ -327,9 +377,8 @@ def _routed_before_mixing(flat: dict) -> None:
     held = int(flat["num_hidden_layers"])
     flags = {}
     for key in ("sliding_window_layout", "rope_layout"):
-        layout = list(flat[key])
-        first = int(flat.get("layer_first", 0)) if len(layout) > held else 0
-        mine = layout[first:first + held]
+        first, mine = _held_slice(flat, key)
+        mine = list(mine)
         if len(mine) != held or set(mine) - {0, 1}:
             raise ValueError(f"{key}[{first}:{first + held}] = {mine}: "
                              f"{held} entries of 0 and 1 wanted")
@@ -351,6 +400,54 @@ def _routed_before_mixing(flat: dict) -> None:
         n_routed_experts=flat["moe_num_primary_experts"],
         num_experts_per_tok=flat["moe_num_active_primary_experts"],
         router_scoring="softmax", router_on_block_input=True)
+
+
+def _one_sublayer_a_layer(flat: dict) -> None:
+    """The sizes here from Nemotron-H's key names: the held layers'
+    letters of `hybrid_override_pattern` (whole and sliced from
+    `layer_first`, or the held slice alone), a layer one sublayer;
+    Mamba-2 with `n_groups` B/C groups under a gated norm a group;
+    attention without positions (`rope_theta` and
+    `partial_rotary_factor` are read by nothing); a sigmoid router with
+    a bias that picks, its weights normalised and scaled, over ungated
+    squared-ReLU experts beside a shared one of its own width. The dense
+    feed-forward's `intermediate_size` is no size of such a program (0).
+    A letter, a group count, an activation, a bias or a group-limited
+    router the code does not know is refused by name."""
+    held = int(flat["num_hidden_layers"])
+    first, mine = _held_slice(flat, "hybrid_override_pattern")
+    unknown = sorted(set(mine) - set(_PATTERN))
+    if unknown or len(mine) != held:
+        raise ValueError(
+            f"hybrid_override_pattern[{first}:{first + held}] = {mine!r}: "
+            f"{held} letters of {sorted(_PATTERN)} wanted, {unknown} not "
+            f"known")
+    heads, groups = int(flat["mamba_num_heads"]), int(flat["n_groups"])
+    if groups < 1 or heads % groups:
+        raise ValueError(f"n_groups = {groups}: the B/C groups have to "
+                         f"divide mamba_num_heads = {heads}")
+    if flat.get("mlp_hidden_act") != "relu2":
+        raise ValueError(
+            f"mlp_hidden_act = {flat.get('mlp_hidden_act')!r}: the experts "
+            f"of a pattern-driven model are ungated 'relu2' alone")
+    for key, only in (("mamba_hidden_act", "silu"), ("mamba_proj_bias", False),
+                      ("attention_bias", False), ("mlp_bias", False),
+                      ("use_bias", False), ("n_group", 1), ("topk_group", 1),
+                      ("norm_topk_prob", True),
+                      ("tie_word_embeddings", False)):
+        if flat.get(key, only) != only:
+            raise ValueError(f"{key} = {flat[key]!r}: a pattern-driven "
+                             f"model runs with {only!r} alone")
+    flat.update(
+        layer_kinds=tuple(_PATTERN[c] for c in mine), single_sublayer=True,
+        intermediate_size=0, first_k_dense_replace=0,
+        mamba_n_heads=heads, mamba_d_head=flat["mamba_head_dim"],
+        mamba_d_state=flat["ssm_state_size"], mamba_n_groups=groups,
+        mamba_d_conv=flat["conv_kernel"],
+        mamba_conv_bias=flat.get("use_conv_bias", True),
+        mamba_chunk_size=flat["chunk_size"],
+        rms_norm_eps=flat["layer_norm_epsilon"],
+        router_scoring="sigmoid", moe_gate="relu2")
 
 
 def hybrid_decoder_kinds(first: int, held: int, total: int,
@@ -569,11 +666,13 @@ def ssd(p, cfg: EncoderConfig, x, seg, scope: str = "enc.ssd"):
     """Mamba-2's mixer on x [B, L, D] (already normed): one input
     projection [z | xBC | dt'], one convolution over x, B and C
     together, the chunked scan (`ops/ssd.py`), the gate and then one
-    norm over all channels. Scopes `proj`, `conv`, `dt`, `scan`, `norm`,
+    norm over all channels or, with `mamba_n_groups` groups of B and C,
+    over each group's. Scopes `proj`, `conv`, `dt`, `scan`, `norm`,
     `out` under `scope`."""
     b, l, _ = x.shape
-    h, dh, n = cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state
-    di = h * dh
+    h, dh = cfg.mamba_n_heads, cfg.mamba_d_head
+    groups = cfg.mamba_n_groups
+    di, n = h * dh, groups * cfg.mamba_d_state   # B's columns, and C's
     with jax.named_scope(f"{scope}.proj"):
         zxbcdt = _mm(cfg, x, p["w_in"])
     with jax.named_scope(f"{scope}.conv"):
@@ -582,14 +681,21 @@ def ssd(p, cfg: EncoderConfig, x, seg, scope: str = "enc.ssd"):
             p.get("conv_bias")))
     with jax.named_scope(f"{scope}.dt"):
         dt = jax.nn.softplus(zxbcdt[..., 2 * di + 2 * n:] + p["dt_bias"])
+    by_group = ((lambda v: v.reshape(b, l, groups, cfg.mamba_d_state))
+                if groups > 1 else (lambda v: v))
     y = ssd_ops.ssd_scan(
         xbc[..., :di].reshape(b, l, h, dh), dt, -jnp.exp(p["a_log"]),
-        xbc[..., di:di + n], xbc[..., di + n:], p["d_skip"], seg,
-        cfg.mamba_chunk_size, _dt(cfg.compute_dtype),
+        by_group(xbc[..., di:di + n]), by_group(xbc[..., di + n:]),
+        p["d_skip"], seg, cfg.mamba_chunk_size, _dt(cfg.compute_dtype),
         f"{scope}.scan")
     with jax.named_scope(f"{scope}.norm"):
-        y = rms_norm(y.reshape(b, l, di) * jax.nn.silu(zxbcdt[..., :di]),
-                     p["norm"], cfg.rms_norm_eps)
+        if groups > 1:  # one group keeps the ops it had
+            y = ssd_ops.gated_group_norm(y.reshape(b, l, di),
+                                         zxbcdt[..., :di], p["norm"], groups,
+                                         cfg.rms_norm_eps)
+        else:
+            y = rms_norm(y.reshape(b, l, di) * jax.nn.silu(zxbcdt[..., :di]),
+                         p["norm"], cfg.rms_norm_eps)
     with jax.named_scope(f"{scope}.out"):
         return _mm(cfg, y, p["w_out"])
 
@@ -819,6 +925,65 @@ def _feed_forward(p, bias, cfg: EncoderConfig, h, scope: str = "",
     return h + y.reshape(b, l, d), routed
 
 
+def ungated_ffn(cfg: "EncoderConfig", x, w1, w2):
+    """W_down relu(W_up x)^2: w1 [D, F], w2 [F, D]."""
+    r = jax.nn.relu(_mm(cfg, x, w1))
+    return _mm(cfg, r * r, w2)
+
+
+def ungated_expert_ffn(p, bias, cfg: EncoderConfig, x2d):
+    """The expert feed-forward of a pattern-driven model on x2d [T, D]
+    (already normed): the router (`enc.router`), the held ungated
+    experts' part of the routed result (`enc.experts`, the dispatch plan
+    under `enc.experts.plan`) and the shared ungated expert of its own
+    width (`enc.shared`). Returns (y, routed) as `expert_ffn`."""
+    with jax.named_scope("enc.router"):
+        idx, weights, load = _route(p, bias, cfg, x2d)
+    y, counts = moe.held_experts(
+        x2d, weights, idx, p["experts_w1"], p["experts_w2"], cfg.expert_first,
+        cfg.moe_block_rows, _dt(cfg.compute_dtype), "enc.experts",
+        cfg.moe_gate)
+    if "shared_w1" in p:
+        with jax.named_scope("enc.shared"):
+            y = y + ungated_ffn(cfg, x2d, p["shared_w1"], p["shared_w2"])
+    return y, {"counts": counts, "load": load, "picks": idx}
+
+
+def sublayer(p, bias, cfg: EncoderConfig, kind: str, h, seg, pos):
+    """One layer of a model whose layers are one sublayer each, on the
+    residual stream h [B, L, D]: h += Mixer(norm(h)), the mixer by
+    `kind`: "ssd" (traced under `enc.ssd`), "gqa" (`enc.gqa`) or
+    "experts" (`ungated_expert_ffn`; `bias` its router's). Returns (h,
+    routed), `routed` None for a layer that routes nothing."""
+    b, l, d = h.shape
+    x = _norm(cfg, h, p, "norm")
+    if kind == "ssd":
+        with jax.named_scope("enc.ssd"):
+            return h + ssd(p["ssd"], cfg, x, seg), None
+    if kind == "gqa":
+        with jax.named_scope("enc.gqa"):
+            return h + gqa(p["gqa"], cfg, x, seg, pos), None
+    y, routed = ungated_expert_ffn(p, bias, cfg, x.reshape(b * l, d))
+    return h + y.reshape(b, l, d), routed
+
+
+def _run_sublayers(params, cfg: EncoderConfig, h, seg, pos):
+    """`run_blocks` for a model whose layers are one sublayer each: a
+    list of unlike layers, each one recomputed function."""
+    routed = []
+    biases = iter(params.get("router_bias", ()))
+    for p, kind in zip(params["layers"], cfg.kinds):
+        bias = next(biases) if kind == "experts" else None
+        h, r = _maybe_remat(
+            lambda p, bias, h, kind=kind: sublayer(p, bias, cfg, kind, h, seg,
+                                                   pos), cfg)(p, bias, h)
+        if r is not None:
+            routed.append(r)
+    if not routed:
+        return h, None
+    return h, {k: jnp.stack([r[k] for r in routed]) for k in routed[0]}
+
+
 CARRIED_KINDS = ("mamba", "swa", "full", "gmu", "cross", "ssd", "gqa")
 
 
@@ -852,6 +1017,8 @@ def run_blocks(params, cfg: EncoderConfig, h, seg, pos):
     """`encode` from the residual stream h [B, L, D] that enters the
     first held block (the item embeddings, or what an earlier pipeline
     stage hands on)."""
+    if cfg.single_sublayer:
+        return _run_sublayers(params, cfg, h, seg, pos)
     carry: dict = {}
     for n, p in enumerate(params["dense"]):
         if cfg.kinds[n] in CARRIED_KINDS:
@@ -1021,9 +1188,10 @@ def _mamba_shapes(cfg: EncoderConfig) -> dict:
 
 def _ssd_shapes(cfg: EncoderConfig) -> dict:
     """Mamba-2's: w_in's columns are [z | x | B | C | dt'], the
-    convolution runs over [x | B | C]."""
-    d, h, n = cfg.hidden_size, cfg.mamba_n_heads, cfg.mamba_d_state
-    di = h * cfg.mamba_d_head
+    convolution runs over [x | B | C]; B and C are `mamba_n_groups`
+    groups of `mamba_d_state` columns each."""
+    d, h = cfg.hidden_size, cfg.mamba_n_heads
+    di, n = h * cfg.mamba_d_head, cfg.mamba_n_groups * cfg.mamba_d_state
     bias = {"conv_bias": (di + 2 * n,)} if cfg.mamba_conv_bias else {}
     return {"w_in": (d, 2 * di + 2 * n + h),
             "conv_w": (cfg.mamba_d_conv, di + 2 * n), **bias,
@@ -1097,12 +1265,32 @@ def _block_shapes(cfg: EncoderConfig, dense: bool, kind: str = "mla") -> dict:
     return out
 
 
+def _sublayer_shapes(cfg: EncoderConfig, kind: str) -> dict:
+    """A layer that is one sublayer: its norm and the mixer's own, or
+    the router, the held ungated experts (up and down, two matrices an
+    expert) and the shared one."""
+    d = cfg.hidden_size
+    if kind != "experts":
+        return {**_norm_shapes(cfg, "norm"), **_mixer_shapes(cfg, kind)}
+    f, e = cfg.moe_intermediate_size, cfg.n_routed_experts
+    fs = (cfg.moe_shared_expert_intermediate_size
+          or f * cfg.n_shared_experts) if cfg.n_shared_experts else 0
+    shared = {"shared_w1": (d, fs), "shared_w2": (fs, d)} if fs else {}
+    return {**_norm_shapes(cfg, "norm"), "w_g": (d, cfg.experts_total),
+            "experts_w1": (e, d, f), "experts_w2": (e, f, d), **shared}
+
+
 def param_shapes(cfg: EncoderConfig, vocab: int) -> dict:
     """The parameter tree as shapes. Expert blocks of one kind are
     stacked on a leading axis (`moe`), so that one scan runs them; of
-    two kinds (`EncoderConfig.moe_stacked`) `moe` is a list."""
+    two kinds (`EncoderConfig.moe_stacked`) `moe` is a list. A model
+    whose layers are one sublayer each holds them as the list `layers`."""
     d = cfg.hidden_size
     kinds = cfg.kinds
+    if cfg.single_sublayer:
+        head = {} if cfg.tie_word_embeddings else {"head": (d, vocab)}
+        return {"emb": (vocab, d), **_norm_shapes(cfg, "final_norm"), **head,
+                "layers": [_sublayer_shapes(cfg, kind) for kind in kinds]}
     shapes = {"emb": (vocab, d), **_norm_shapes(cfg, "final_norm"),
               "dense": [_block_shapes(cfg, True, kind)
                         for kind in kinds[:cfg.n_dense]]}

@@ -6,7 +6,8 @@ every token over all the experts (`route`: sigmoid scores, a load-balance
 bias that only picks, weights normalised over the picked and scaled; or
 the top-k of the logits and a softmax over the picked) and computes the
 part of the result that its own experts give (`held_experts`: gated
-experts, the gate's activation SiLU or ReLU). What the absent experts would have added is left out;
+experts, the gate's activation SiLU or ReLU, or ungated ones, W_down
+relu(W_up x)^2, two matrices an expert). What the absent experts would have added is left out;
 on one chip the layer runs without its exchange, and nothing here stands
 in for the chips that are not there.
 
@@ -120,10 +121,40 @@ def _relu_gate(g):
     return jnp.where(on, g, 0.0), lambda: on.astype(g.dtype)
 
 
-# the gate's activation: act(g) for the forward pass; for the hand-written
-# backward pass act(g) and, when it is called for, d act / d g
-GATES = {"silu": (jax.nn.silu, _silu_gate),
-          "relu": (jax.nn.relu, _relu_gate)}
+def _gated(act, act_and_slope):
+    """A gated expert's hidden activation from h = x [W_gate | W_up]
+    [rows, 2F]: (forward: act(g) * u; backward: the same and the
+    pullback of its cotangent to h's)."""
+    def hidden(h, f):
+        return act(h[:, :f]) * h[:, f:]
+
+    def hidden_and_pullback(h, f):
+        g, u = h[:, :f], h[:, f:]
+        a, slope = act_and_slope(g)
+        return a * u, lambda da: jnp.concatenate(
+            [da * u * slope(), da * a], axis=1)
+
+    return hidden, hidden_and_pullback
+
+
+def _relu2(h, f):
+    r = jnp.maximum(h, 0.0)
+    return r * r
+
+
+def _relu2_and_pullback(h, f):
+    r = jnp.maximum(h, 0.0)
+    return r * r, lambda da: da * (2.0 * r)
+
+
+# an expert's form, by the name of its activation: "silu" and "relu" gate
+# (w13 [D, 2F] holds gate and up side by side), "relu2" has no gate (w13
+# [D, F] is the up matrix alone: W_down relu(W_up x)^2). (the hidden
+# activation from x w13 for the forward pass; the same with the pullback
+# of its cotangent for the hand-written backward pass)
+GATES = {"silu": _gated(jax.nn.silu, _silu_gate),
+         "relu": _gated(jax.nn.relu, _relu_gate),
+         "relu2": (_relu2, _relu2_and_pullback)}
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
@@ -151,7 +182,7 @@ def _held_fwd_scoped(x, weights, idx, w13, w2, first, block, dtype, scope,
                      gate):
     held, d = w13.shape[0], x.shape[1]
     f = w2.shape[1]
-    act = GATES[gate][0]
+    hidden = GATES[gate][0]
     with jax.named_scope(f"{scope}.plan"):
         pos, tok_of_row, group_start, n_blocks, counts = _plan(
             idx, first, held, block)
@@ -164,7 +195,7 @@ def _held_fwd_scoped(x, weights, idx, w13, w2, first, block, dtype, scope,
             at = start + b * block
             xb = jnp.take(x_pad, _rows(tok_of_row, at, block), axis=0)
             h = jnp.dot(xb, w13_e, preferred_element_type=jnp.float32)
-            a = (act(h[:, :f]) * h[:, f:]).astype(dtype)
+            a = hidden(h, f).astype(dtype)
             yb = jnp.dot(a, w2_e, preferred_element_type=jnp.float32)
             return jax.lax.dynamic_update_slice_in_dim(
                 ys, yb.astype(dtype), at, 0)
@@ -189,7 +220,7 @@ def _held_bwd_scoped(first, block, dtype, scope, gate, res, cot):
     dy = cot[0]
     held, d = w13.shape[0], x.shape[1]
     f = w2.shape[1]
-    act_and_slope = GATES[gate][1]
+    hidden_and_pullback = GATES[gate][1]
     with jax.named_scope(f"{scope}.plan"):
         pos, tok_of_row, group_start, n_blocks, _ = _plan(idx, first, held,
                                                           block)
@@ -209,9 +240,8 @@ def _held_bwd_scoped(first, block, dtype, scope, gate, res, cot):
             xb, dyb = jnp.take(x_pad, rows, axis=0), jnp.take(dy_pad, rows,
                                                                axis=0)
             h = jnp.dot(xb, w13_e, preferred_element_type=jnp.float32)
-            g, u = h[:, :f], h[:, f:]
-            act, slope = act_and_slope(g)
-            a = (act * u).astype(dtype)
+            a, pullback = hidden_and_pullback(h, f)
+            a = a.astype(dtype)
             yb = jnp.dot(a, w2_e, preferred_element_type=jnp.float32)
             dwr = jnp.sum(yb * dyb.astype(jnp.float32), axis=-1)
             dyw = (dyb.astype(jnp.float32)
@@ -219,8 +249,7 @@ def _held_bwd_scoped(first, block, dtype, scope, gate, res, cot):
             dw2_e = dw2_e + jnp.dot(a.T, dyw,
                                     preferred_element_type=jnp.float32)
             da = jnp.dot(dyw, w2_e.T, preferred_element_type=jnp.float32)
-            dg = da * u * slope()
-            dh = jnp.concatenate([dg, da * act], axis=1).astype(dtype)
+            dh = pullback(da).astype(dtype)
             dw13_e = dw13_e + jnp.dot(xb.T, dh,
                                       preferred_element_type=jnp.float32)
             dxb = jnp.dot(dh, w13_e.T, preferred_element_type=jnp.float32)
@@ -258,14 +287,18 @@ def held_experts(x, weights, idx, w13, w2, first: int, block: int,
     [held, F, D] are the experts `first .. first + held - 1` of the
     layer. Returns (y [T, D] float32: sum over a token's picks that are
     held here of weight * W_down (act(W_gate x) * (W_up x)), act the
-    `gate` named ("silu": SwiGLU; "relu"); counts [held]: tokens each
-    held expert was sent). Products take `dtype` operands and accumulate
+    `gate` named ("silu": SwiGLU; "relu"), or with "relu2", w13 [held,
+    D, F] the up matrix alone, of weight * W_down relu(W_up x)^2;
+    counts [held]: tokens each held expert was sent). Products take `dtype` operands and accumulate
     in float32; the rows travel to the combine in `dtype` (see the
     module's docstring); the ops, those of the backward pass too, are traced
     under `scope`, the dispatch plan's under `<scope>.plan`."""
     if gate not in GATES:
-        raise ValueError(f"no gate activation {gate!r}: one of "
+        raise ValueError(f"no expert form {gate!r}: one of "
                          f"{sorted(GATES)}")
+    if w13.shape[2] != (1 if gate == "relu2" else 2) * w2.shape[1]:
+        raise ValueError(f"{gate!r} experts of width {w2.shape[1]} do not "
+                         f"take w13 of {w13.shape[2]} columns")
     y, counts = _held_experts(x, weights, idx, w13, w2, int(first),
                               int(block), jnp.dtype(dtype), scope, gate)
     return y, jax.lax.stop_gradient(counts)

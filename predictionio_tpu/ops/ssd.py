@@ -7,7 +7,9 @@ entering a history's first token) and one scalar decay a token:
     S_t = exp(dt_t a) S_{t-1} + (dt_t x_t) (x) B_t        a < 0, dt_t > 0
     y_t = S_t C_t + d x_t
 
-B_t and C_t [N] are shared by all heads (one group).
+B_t and C_t [N] are shared by all heads (one group), or by the heads of
+a group: with G groups head h reads B_{g(h),t} and C_{g(h),t}, g(h) =
+h // (heads / G), in the update and in the read-out alike.
 `quality/encoder_reference.py::ssd_recurrence` is that, a token at a
 time. `ssd_scan` computes the same with the sequence cut into chunks of
 Q tokens. With La the running sum of dt a inside a chunk and r_t the
@@ -17,9 +19,14 @@ number of first tokens at or before t in the chunk:
     Y_inter[i] = [r_i = 0] exp(La_i) S_0 C_i
     S_Q = [r_Q = 0] exp(La_Q) S_0 + sum_j [r_j = r_Q] exp(La_Q - La_j) dt_j x_j (x) B_j
 
-so the tokens of a chunk meet in one [Q, Q] product C B^T for all heads,
+so the tokens of a chunk meet in one [Q, Q] product C B^T for all heads
+(G of them with G groups, each read by its heads),
 masked head by head with the decays, and only `S_0 -> S_Q` runs chunk
-after chunk. Any Q gives the same result.
+after chunk. Any Q gives the same result. The state's build and its
+read-out take a head's own group of B and C.
+
+`gated_group_norm` is what follows the scan in a model with groups: the
+gate, then an RMSNorm over each group's channels and not over all.
 
 Resets are exact and are masks, not gates: a history's first token may
 stand anywhere in a chunk. A pair (i, j) counts where no first token
@@ -74,7 +81,8 @@ def ssd_scan(x, dt, a, b, c, d, seg, chunk: int = 128, dtype=jnp.float32,
              scope: str = "ssd.scan"):
     """The recurrence in chunks of `chunk` tokens. x [B, L, H, P] (the
     convolved input, through its SiLU); dt [B, L, H] (through its
-    softplus); a [H] (negative); b, c [B, L, N]; d [H]; seg [B, L].
+    softplus); a [H] (negative); b, c [B, L, N] (one group) or [B, L, G,
+    N] (G groups, G dividing H); d [H]; seg [B, L].
     Returns y [B, L, H, P], float32; `dtype` is the operands' in the
     four matrix products (see the module's docstring). Its ops are
     traced under `scope`, the backward pass's too. Which path was built
@@ -85,6 +93,8 @@ def ssd_scan(x, dt, a, b, c, d, seg, chunk: int = 128, dtype=jnp.float32,
     SCAN_CALLS.labels(path=path).inc()
     f32, chunk = jnp.float32, int(chunk)
     bsz, l, h, _ = x.shape
+    if b.ndim == 4 and h % b.shape[2]:
+        raise ValueError(f"{b.shape[2]} B/C groups do not divide {h} heads")
     n = -(-l // chunk)
     short = n * chunk - l
     with jax.named_scope(scope):
@@ -107,12 +117,23 @@ def ssd_scan(x, dt, a, b, c, d, seg, chunk: int = 128, dtype=jnp.float32,
 
 
 def _ssd_chunks(x, dt, a, b, c, r, dtype):
-    """x [B, n, H, Q, P]; dt [B, n, H, Q]; a [H]; b, c [B, n, Q, N];
-    r [B, n, Q]. Returns y [B, n, H, Q, P] without the skip."""
+    """x [B, n, H, Q, P]; dt [B, n, H, Q]; a [H]; b, c [B, n, Q, N] or,
+    with groups, [B, n, Q, G, N]; r [B, n, Q]. Returns y [B, n, H, Q, P]
+    without the skip. With groups the heads of the three products that
+    meet B or C stand as [G, H / G]; one group runs the program it ran
+    before groups were known here."""
     f32 = jnp.float32
     q = x.shape[3]
-    cb = jnp.einsum("bnik,bnjk->bnij", c.astype(dtype), b.astype(dtype),
-                    preferred_element_type=f32)                # [B, n, Q, Q]
+    grouped = b.ndim == 5
+    if grouped:
+        g = b.shape[3]
+        by_group = lambda v: v.reshape(  # noqa: E731
+            v.shape[:2] + (g, v.shape[2] // g) + v.shape[3:])
+        cb = jnp.einsum("bnigk,bnjgk->bngij", c.astype(dtype),
+                        b.astype(dtype), preferred_element_type=f32)
+    else:
+        cb = jnp.einsum("bnik,bnjk->bnij", c.astype(dtype), b.astype(dtype),
+                        preferred_element_type=f32)            # [B, n, Q, Q]
     la = jnp.cumsum(dt * a[:, None], axis=-1)                  # [B, n, H, Q]
     u = dt[..., None] * x                                      # dt x
     # pairs (i, j) of one history, j <= i                      [B, n, Q, Q]
@@ -120,16 +141,24 @@ def _ssd_chunks(x, dt, a, b, c, r, dtype):
         jnp.ones((q, q), bool))
     decay = jnp.exp(jnp.where(pair[:, :, None],
                               la[..., :, None] - la[..., None, :], -jnp.inf))
-    y = jnp.einsum("bnhij,bnhjp->bnhip",
-                   (cb[:, :, None] * decay).astype(dtype), u.astype(dtype),
-                   preferred_element_type=f32)
+    masked = ((cb[:, :, :, None] * by_group(decay)).reshape(decay.shape)
+              if grouped else cb[:, :, None] * decay)
+    y = jnp.einsum("bnhij,bnhjp->bnhip", masked.astype(dtype),
+                   u.astype(dtype), preferred_element_type=f32)
     # what a chunk leaves: the tokens of its last history, decayed to its end
     la_end = la[..., -1:]
     to_end = jnp.where((r == r[..., -1:])[:, :, None],
                        jnp.exp(la_end - la), 0.0)
-    local = jnp.einsum("bnhjp,bnjk->bnhpk",
-                       (to_end[..., None] * u).astype(dtype), b.astype(dtype),
-                       preferred_element_type=f32)             # [B, n, H, P, N]
+    if grouped:
+        local = jnp.einsum(
+            "bngrjp,bnjgk->bngrpk",
+            by_group((to_end[..., None] * u).astype(dtype)), b.astype(dtype),
+            preferred_element_type=f32)
+        local = local.reshape(x.shape[:3] + local.shape[-2:])
+    else:
+        local = jnp.einsum(
+            "bnhjp,bnjk->bnhpk", (to_end[..., None] * u).astype(dtype),
+            b.astype(dtype), preferred_element_type=f32)       # [B, n, H, P, N]
     carried = jnp.where((r[..., -1] == 0)[..., None],
                         jnp.exp(la_end[..., 0]), 0.0)          # [B, n, H]
 
@@ -143,6 +172,22 @@ def _ssd_chunks(x, dt, a, b, c, r, dtype):
     s0 = jnp.moveaxis(s0, 0, 1)                                # [B, n, H, P, N]
     # S_0 reaches the tokens before the chunk's first first token
     reach = jnp.where((r == 0)[:, :, None], jnp.exp(la), 0.0)  # [B, n, H, Q]
+    if grouped:
+        read = jnp.einsum("bnigk,bngrpk->bngrip", c.astype(dtype),
+                          by_group(s0.astype(dtype)),
+                          preferred_element_type=f32).reshape(y.shape)
+        return y + reach[..., None] * read
     return y + reach[..., None] * jnp.einsum(
         "bnik,bnhpk->bnhip", c.astype(dtype), s0.astype(dtype),
         preferred_element_type=f32)
+
+
+def gated_group_norm(y, z, w, groups: int, eps: float):
+    """RMSNorm_per_group(y SiLU(z)) w on y, z [.., C]: the gate first,
+    then one norm over each of the `groups` runs of C / groups channels
+    (a group's heads), float32; w [C]."""
+    gated = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+    by_group = gated.reshape(gated.shape[:-1] + (groups, -1))
+    normed = by_group * jax.lax.rsqrt(
+        jnp.mean(by_group * by_group, axis=-1, keepdims=True) + eps)
+    return normed.reshape(gated.shape) * w

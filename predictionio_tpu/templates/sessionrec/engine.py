@@ -368,7 +368,9 @@ PACK_FILL = REGISTRY.gauge(
     "the last sessionrec train")
 EXPERT_TOKENS = REGISTRY.gauge(
     "encoder_expert_tokens", "Tokens the last train step sent to each "
-    "held expert, by expert layer (mtp = the MTP module's) and expert id",
+    "held expert, by expert layer (its place among the expert layers; among "
+    "the held layers where a layer is one sublayer; mtp = the MTP module's) "
+    "and expert id",
     labelnames=("layer", "expert"))
 EXPERT_BLOCK_ROWS = REGISTRY.gauge(
     "encoder_expert_block_rows", "Rows the grouped products of the last "
@@ -542,7 +544,11 @@ class SessionRecAlgorithm(Algorithm):
         if metrics is not None:
             for name in ("counts", "mtp_counts"):
                 rows = np.atleast_2d(metrics.get(name, np.zeros((0, 0))))
-                for layer, row in enumerate(rows):
+                # a model whose layers are one sublayer each names an
+                # expert layer by its place among the held layers
+                held_at = (cfg.expert_layers if cfg.single_sublayer
+                           else range(len(rows)))
+                for layer, row in zip(held_at, rows):
                     for e, count in enumerate(row):
                         labels = {"layer": "mtp" if name == "mtp_counts"
                                   else str(layer),

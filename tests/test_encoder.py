@@ -378,16 +378,19 @@ def test_the_softmax_router_picks_by_the_logits_and_weighs_the_picked(what):
         close(got, want)
 
 
-def plain_experts(x, w, idx, w13, w2, first, act):
+def plain_experts(x, w, idx, w13, w2, first, gate):
     """`held_experts` in its plain form: every held expert on every
     token, weighted by the token's weight for it (zero where it did not
-    pick the expert), under autodiff."""
+    pick the expert), under autodiff. "relu2": no gate, w13 the up
+    matrix alone."""
     f = w2.shape[1]
+    act = {"silu": jax.nn.silu, "relu": jax.nn.relu}.get(gate)
     out = 0.0
     for e in range(w13.shape[0]):
         h = x @ w13[e]
-        out = out + ((w * (idx == first + e)).sum(-1)[:, None]
-                     * ((act(h[:, :f]) * h[:, f:]) @ w2[e]))
+        a = (jax.nn.relu(h) ** 2 if gate == "relu2"
+             else act(h[:, :f]) * h[:, f:])
+        out = out + (w * (idx == first + e)).sum(-1)[:, None] * (a @ w2[e])
     return out
 
 
@@ -398,13 +401,12 @@ def both_gradients(gate):
     rng = np.random.default_rng(12)
     args = {"x": jnp.asarray(rng.standard_normal((40, 8)), jnp.float32),
             "weights": jnp.asarray(rng.random((40, 3)), jnp.float32),
-            "w13": jnp.asarray(rng.standard_normal((3, 8, 10)), jnp.float32),
+            "w13": jnp.asarray(rng.standard_normal(
+                (3, 8, 5 if gate == "relu2" else 10)), jnp.float32),
             "w2": jnp.asarray(rng.standard_normal((3, 5, 8)), jnp.float32)}
     idx = jnp.asarray(np.stack([rng.permutation(6)[:3] for _ in range(40)]),
                       jnp.int32)
     target = jnp.asarray(rng.standard_normal((40, 8)), jnp.float32)
-    act = {"silu": jax.nn.silu, "relu": jax.nn.relu}[gate]
-
     def held(a):
         return (moe.held_experts(a["x"], a["weights"], idx, a["w13"],
                                  a["w2"], first=1, block=4, gate=gate)[0]
@@ -412,17 +414,19 @@ def both_gradients(gate):
 
     def plain(a):
         return (plain_experts(a["x"], a["weights"], idx, a["w13"], a["w2"],
-                              1, act) * target).sum()
+                              1, gate) * target).sum()
 
     return (jax.jit(jax.value_and_grad(held))(args),
             jax.jit(jax.value_and_grad(plain))(args))
 
 
 @pytest.mark.parametrize("leaf", ["x", "weights", "w13", "w2"])
-@pytest.mark.parametrize("gate", ["silu", "relu"])
+@pytest.mark.parametrize("gate", ["silu", "relu", "relu2"])
 def test_a_gates_backward_pass_equals_autodiff_of_the_plain_form(gate, leaf):
-    """The hand-written backward pass with the activation as its
-    parameter: SiLU as it was, ReLU with dg = da * u * (g > 0)."""
+    """The hand-written backward pass with the expert's form as its
+    parameter: SiLU as it was, ReLU with dg = da * u * (g > 0), and the
+    ungated W_down relu(W_up x)^2 (two matrices an expert, through the
+    same plan and dispatch) with d pre = d a * 2 relu(pre)."""
     (value, grads), (want_value, want_grads) = both_gradients(gate)
     close(value, want_value)
     close(grads[leaf], want_grads[leaf])
@@ -431,6 +435,56 @@ def test_a_gates_backward_pass_equals_autodiff_of_the_plain_form(gate, leaf):
         moe.held_experts(x, jnp.ones((4, 1)), jnp.zeros((4, 1), jnp.int32),
                          jnp.zeros((1, 8, 4)), jnp.zeros((1, 2, 8)), first=0,
                          block=4, gate="gelu")
+
+
+# -- an expert's form and the scaled sigmoid router (`ops/moe.py`) -----------------
+
+def test_an_expert_form_and_its_matrices_have_to_agree():
+    x, w, idx = (jnp.zeros((4, 8)), jnp.ones((4, 1)),
+                 jnp.zeros((4, 1), jnp.int32))
+    with pytest.raises(ValueError, match="relu2"):  # a fused gate | up
+        moe.held_experts(x, w, idx, jnp.zeros((1, 8, 4)),
+                         jnp.zeros((1, 2, 8)), first=0, block=4, gate="relu2")
+    with pytest.raises(ValueError, match="silu"):   # an up matrix alone
+        moe.held_experts(x, w, idx, jnp.zeros((1, 8, 2)),
+                         jnp.zeros((1, 2, 8)), first=0, block=4, gate="silu")
+
+
+@pytest.mark.parametrize("what", ["picks", "weights", "sum", "gradient"])
+def test_the_sigmoid_router_at_scale_2_5(what):
+    """Nemotron-H's published rule: the top-k of sigmoid(x w_g) + bias,
+    the bias in the pick alone, the picked scores normalised and times
+    `routed_scaling_factor` 2.5."""
+    rng = np.random.default_rng(14)
+    x = jnp.asarray(rng.standard_normal((40, 8)), jnp.float32)
+    w_g = jnp.asarray(rng.standard_normal((8, 6)), jnp.float32)
+    bias = jnp.asarray(0.3 * rng.standard_normal(6), jnp.float32)
+    idx, weights, _ = moe.route(x, w_g, bias, 3, 2.5, "sigmoid")
+    s = 1.0 / (1.0 + np.exp(-(np.asarray(x, np.float64)
+                              @ np.asarray(w_g, np.float64))))
+    order = np.argsort(-(s + np.asarray(bias, np.float64)), axis=-1)[:, :3]
+    picked = np.take_along_axis(s, order, axis=-1)
+    if what == "picks":
+        assert np.array_equal(idx, order)
+        assert not np.array_equal(order, np.argsort(-s, axis=-1)[:, :3])
+    elif what == "weights":
+        close(weights, 2.5 * picked / picked.sum(-1, keepdims=True))
+    elif what == "sum":
+        close(weights.sum(-1), np.full(40, 2.5))
+    else:  # the bias carries no gradient, the weights do
+        target = jnp.asarray(rng.standard_normal((40, 3)), jnp.float32)
+        d_bias = jax.grad(lambda b: (moe.route(x, w_g, b, 3, 2.5)[1]
+                                     * target).sum())(bias)
+        assert not np.asarray(d_bias).any()
+        got = jax.grad(lambda w: (moe.route(x, w, bias, 3, 2.5)[1]
+                                  * target).sum())(w_g)
+
+        def plain(w):
+            p = jnp.take_along_axis(jax.nn.sigmoid(x @ w),
+                                    jnp.asarray(order), axis=-1)
+            return (2.5 * p / p.sum(-1, keepdims=True) * target).sum()
+
+        close(got, jax.grad(plain)(w_g))
 
 
 # -- the train step, the scorer, the configuration file -----------------------------

@@ -53,12 +53,13 @@ def recurrence(args, seg):
         for n in range(seg.shape[0])])
 
 
-def both(chunk, lengths=LENGTHS, l=L):
+def both(chunk, lengths=LENGTHS, l=L, args=None):
     """{name: (got, want)} for the output `y` and each gradient of a
-    weighted sum of it."""
-    args, seg = inputs(lengths, l)
+    weighted sum of it; `args`: other inputs than `inputs` makes."""
+    made, seg = inputs(lengths, l)
+    args = made if args is None else args
     weight = jnp.asarray(np.random.default_rng(5).standard_normal(
-        (len(lengths), l, H, P)), jnp.float32)
+        args["x"].shape), jnp.float32)
 
     def program(args):
         with jax.default_matmul_precision("highest"):
@@ -104,6 +105,70 @@ def test_any_chunk_gives_the_same_result(chunk):
         one = ssd_scan(*(args[k] for k in NAMES), seg, 32)
         other = ssd_scan(*(args[k] for k in NAMES), seg, chunk)
     close((other, one))
+
+
+def grouped(groups, chunk=64, heads=8):
+    """`both` with `heads` heads over `groups` groups of B and C ([B, L,
+    G, N]; one group as the plain [B, L, N]), first tokens anywhere in a
+    chunk."""
+    rng = np.random.default_rng(20 + groups)
+    b, l = len(LENGTHS), L
+    f = lambda *s: jnp.asarray(rng.standard_normal(s), jnp.float32)  # noqa: E731
+    bc = (b, l, N) if groups == 1 else (b, l, groups, N)
+    return both(chunk, args={
+        "x": f(b, l, heads, P), "dt": jax.nn.softplus(f(b, l, heads) - 1.0),
+        "a": -jnp.arange(1.0, heads + 1.0), "b": f(*bc), "c": f(*bc),
+        "d": f(heads)})
+
+
+@pytest.fixture(scope="module", params=[1, 2, 8],
+                ids=lambda groups: f"groups{groups}")
+def grouped_pairs(request):
+    return grouped(request.param)
+
+
+@pytest.mark.parametrize("name", ("y",) + NAMES)
+def test_groups_of_b_and_c_equal_the_recurrence(grouped_pairs, name):
+    """Head h reads B and C of group h // (heads / groups) in the update
+    and in the read-out, with resets anywhere in a chunk: 1, 2 and 8
+    groups of 8 heads against the recurrence a token at a time, the
+    output and the gradient of every input."""
+    close(grouped_pairs[name])
+
+
+def test_one_group_given_as_a_group_axis_is_the_plain_scan():
+    args, seg = inputs()
+    with jax.default_matmul_precision("highest"):
+        plain = ssd_scan(*(args[k] for k in NAMES), seg, 64)
+        one = ssd_scan(*(args[k][:, :, None] if k in "bc" else args[k]
+                         for k in NAMES), seg, 64)
+    close((one, plain))
+
+
+def test_groups_that_do_not_divide_the_heads_are_refused():
+    args, seg = inputs()
+    three = {**args, "b": jnp.zeros((2, L, 3, N)), "c": jnp.zeros((2, L, 3, N))}
+    with pytest.raises(ValueError, match="3 B/C groups do not divide 4"):
+        ssd_scan(*(three[k] for k in NAMES), seg, 64)
+
+
+def test_the_gated_group_norm_is_a_norm_a_group_after_the_gate():
+    from predictionio_tpu.ops.ssd import gated_group_norm
+
+    rng = np.random.default_rng(3)
+    y, z = (jnp.asarray(rng.standard_normal((5, 24)), jnp.float32)
+            for _ in range(2))
+    w = jnp.asarray(rng.standard_normal(24), jnp.float32)
+    got = gated_group_norm(y, z, w, 4, 1e-5)
+    gated = np.asarray(y * jax.nn.silu(z), np.float64).reshape(5, 4, 6)
+    want = (gated / np.sqrt((gated ** 2).mean(-1, keepdims=True) + 1e-5)
+            ).reshape(5, 24) * np.asarray(w, np.float64)
+    close((got, want))
+    # one group is the norm over all channels; four are not
+    whole = gated.reshape(5, 24)
+    whole = whole / np.sqrt((whole ** 2).mean(-1, keepdims=True) + 1e-5) * w
+    close((gated_group_norm(y, z, w, 1, 1e-5), whole))
+    assert np.abs(np.asarray(got) - whole).max() > 1e-2
 
 
 def test_a_sequence_no_chunk_divides():

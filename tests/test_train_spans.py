@@ -687,3 +687,65 @@ def test_the_benchmarks_st_metrics_read_what_the_program_has(
     else:  # the whole step's share of the peak: its operations' file
         assert os.path.exists(os.path.join(root, "perf", "ops",
                                            spec["ops"] + ".py"))
+
+
+# -- the encoder whose layers are one sublayer each: what the benchmark's twelve read --
+
+NH_METRICS = ("fit.nh_ssd_s", "fit.nh_ssd_scan_s", "fit.nh_ssd_scan_roofline",
+              "fit.nh_attn_s", "fit.nh_router_s", "fit.nh_experts_s",
+              "fit.nh_shared_s", "fit.nh_head_loss_s", "fit.nh_adam_s",
+              "fit.nh_step_mfu", "fit.nh_expert_load_max_over_mean",
+              "fit.nh_moe_block_fill")
+
+
+@pytest.fixture(scope="module")
+def sublayer_step_text():
+    """Lowered text, with locations, of one train step of the tiny
+    configuration in Nemotron-H's key names the benchmark's tests run."""
+    import jax
+    import jax.numpy as jnp
+
+    from predictionio_tpu.models import encoder as enc
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    cfg = enc.EncoderConfig.from_json(os.path.join(
+        root, "perf", "tests", "tiny", "nemotron3_nano_30b_1of16.json"))
+    state = jax.eval_shape(
+        lambda k: enc.init_state(cfg, cfg.vocab_size, k), jax.random.key(0))
+    batch = jax.ShapeDtypeStruct((cfg.seqs_per_step, cfg.pack_len), jnp.int32)
+    return jax.jit(enc.train_step(cfg, 1e-3)).lower(
+        state, batch, batch, batch).as_text(debug_info=True)
+
+
+@pytest.mark.parametrize("name", NH_METRICS)
+def test_the_benchmarks_nh_metrics_read_what_the_program_has(
+        sublayer_step_text, name):
+    """Each of the twelve is a data file of the benchmark that names the
+    program's scopes, its step's module or its gauges: a rename here
+    would make it fall silent there. Every `known` list names all
+    seventeen scopes the step opens (the scan's six below `enc.ssd`,
+    attention's three, the router's, the plan's below the experts', the
+    shared expert's), so that no op falls to an enclosing scope by
+    omission."""
+    from predictionio_tpu.templates.sessionrec import engine  # noqa: F401
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        entry = {m["name"]: m for m in json.load(f)["per_layer"]}[name]
+    with open(os.path.join(root, "perf", "layers", name + ".json")) as f:
+        spec = json.load(f)
+    assert (entry["unit"], entry["moves"], entry["layer"]) == (
+        spec["unit"], spec["moves"], spec["layer"])
+    assert entry["workloads"] == ["nemotron3nano.fit16_pack8k"]
+    if spec["reader"] in ("gauge_ratio", "gauge_max_over_mean"):
+        for key in ("gauge", "numerator", "denominator"):
+            assert key not in spec or REGISTRY.get(spec[key]) is not None
+        return
+    assert "@jit_sessionrec_train_step" in sublayer_step_text
+    opened = set(re.findall(r'["/(](enc\.[a-z_.]+)(?=[/)])',
+                            sublayer_step_text))
+    if "known" in spec:
+        assert set(spec["known"]) == opened and len(opened) == 17
+        assert set(spec["scopes"]) <= opened
+    assert os.path.exists(os.path.join(
+        root, "perf", "ops", spec.get("ops", "nemotron_h_step") + ".py"))
