@@ -50,11 +50,23 @@ layer. (Heads a pass at a time under a `lax.map`, as `ops/ssm.py` takes
 its channels, made the compiler's plan for the whole step larger, not
 smaller: 16.8 GiB for 13.5 at the benchmark's size.)
 
-One `ssd_scan`, which decides its path from what it can observe, as
-`ops/kda.py::kda_scan` does. Today every shape takes the plain
-`jax.numpy` below, differentiated by autodiff; a kernel that keeps a
-chunk's decay matrix and the state in VMEM would replace the inside and
-nothing above it.
+Two paths, one `ssd_scan`, which decides from what it can observe, as
+`ops/kda.py::kda_scan` does: on a TPU, where
+`pallas_ssd.applicable(chunk, P, N, H, G)` holds (chunks of whole lane
+tiles up to 256, heads of 64 channels, a state width of whole lane
+tiles, G dividing H into whole pairs of heads, states that leave VMEM
+room), the Pallas kernels of `ops/pallas_ssd.py`. There a grid step is
+one chunk of a block of heads of one group: the pair mask, C B^T, a
+head's decay matrix and its masked product never leave VMEM, the
+states [P, N] are carried in scratch from chunk to chunk, the skip d x
+is added there, and what is
+saved for the hand-written backward pass is the [B, chunks, heads, P,
+N] states that enter the chunks and nothing of [Q, Q]; a tail that no
+chunk holds is padded, every padded token a history of its own. Else
+the plain `jax.numpy` below, differentiated by autodiff: the CPU's
+path, the path of every other shape (a head of another width, an odd
+chunk), and the kernels' oracle in the tests. What is held above is
+that path's.
 """
 
 from __future__ import annotations
@@ -64,6 +76,7 @@ import time
 import jax
 import jax.numpy as jnp
 
+from predictionio_tpu.ops import pallas_ssd
 from predictionio_tpu.ops.kda import history_starts
 from predictionio_tpu.telemetry.registry import REGISTRY
 from predictionio_tpu.telemetry.spans import record as record_span
@@ -73,7 +86,8 @@ from predictionio_tpu.telemetry.spans import record as record_span
 SCAN_CALLS = REGISTRY.counter(
     "encoder_ssd_scan_calls_total",
     "ssd_scan calls traced into a program, by the path built for them "
-    "(jnp: plain jax.numpy, chunked)",
+    "(kernel: the Pallas kernels of ops/pallas_ssd.py | jnp: plain "
+    "jax.numpy, chunked)",
     labelnames=("path",))
 
 
@@ -89,31 +103,46 @@ def ssd_scan(x, dt, a, b, c, d, seg, chunk: int = 128, dtype=jnp.float32,
     is counted in `encoder_ssd_scan_calls_total{path}` and left in the
     timeline as `enc.ssd.scan.<path>` (the host seconds spent building
     it)."""
-    t0, path = time.monotonic(), "jnp"
+    t0, f32, chunk = time.monotonic(), jnp.float32, int(chunk)
+    h, p = x.shape[2:]
+    groups = b.shape[2] if b.ndim == 4 else 1
+    if h % groups:
+        raise ValueError(f"{groups} B/C groups do not divide {h} heads")
+    path = ("kernel" if jax.default_backend() == "tpu"
+            and pallas_ssd.applicable(chunk, p, b.shape[-1], h, groups)
+            else "jnp")
     SCAN_CALLS.labels(path=path).inc()
-    f32, chunk = jnp.float32, int(chunk)
-    bsz, l, h, _ = x.shape
-    if b.ndim == 4 and h % b.shape[2]:
-        raise ValueError(f"{b.shape[2]} B/C groups do not divide {h} heads")
-    n = -(-l // chunk)
-    short = n * chunk - l
     with jax.named_scope(scope):
-        x, dt, b, c = (v.astype(f32) for v in (x, dt, b, c))
-        first = history_starts(seg)
-        if short:  # a tail that adds nothing: x = dt = 0
-            pad = lambda v: jnp.pad(  # noqa: E731
-                v, ((0, 0), (0, short)) + ((0, 0),) * (v.ndim - 2))
-            x, dt, b, c, first = (pad(v) for v in (x, dt, b, c, first))
-        chunks = lambda v: v.reshape((bsz, n, chunk) + v.shape[2:])  # noqa: E731
-        heads_first = lambda v: jnp.moveaxis(chunks(v), 3, 2)  # noqa: E731
-        # first tokens at or before a token of its chunk        [B, n, Q]
-        r = jnp.cumsum(chunks(first).astype(jnp.int32), axis=-1)
-        y = _ssd_chunks(heads_first(x), heads_first(dt), a.astype(f32),
-                        chunks(b), chunks(c), r, jnp.dtype(dtype))
-        y = jnp.moveaxis(y, 2, 3).reshape(bsz, n * chunk, h, -1)[:, :l]
-        y = y + d.astype(f32)[:, None] * x[:, :l]
+        if path == "kernel":
+            y = pallas_ssd.ssd_chunks(x, dt, a, b, c, d, history_starts(seg),
+                                      chunk, dtype, scope)
+        else:
+            y = _ssd_scan(x, dt, a, b, c, history_starts(seg), chunk,
+                          jnp.dtype(dtype)) \
+                + d.astype(f32)[:, None] * x.astype(f32)
     record_span(f"enc.ssd.scan.{path}", time.monotonic() - t0)
     return y
+
+
+def _ssd_scan(x, dt, a, b, c, first, chunk, dtype):
+    """The three sums as plain `jax.numpy`, without the skip: the
+    arguments of `ssd_scan`, `first` [B, L] its first tokens."""
+    f32 = jnp.float32
+    bsz, l, h, _ = x.shape
+    n = -(-l // chunk)
+    short = n * chunk - l
+    x, dt, b, c = (v.astype(f32) for v in (x, dt, b, c))
+    if short:  # a tail that adds nothing: x = dt = 0
+        pad = lambda v: jnp.pad(  # noqa: E731
+            v, ((0, 0), (0, short)) + ((0, 0),) * (v.ndim - 2))
+        x, dt, b, c, first = (pad(v) for v in (x, dt, b, c, first))
+    chunks = lambda v: v.reshape((bsz, n, chunk) + v.shape[2:])  # noqa: E731
+    heads_first = lambda v: jnp.moveaxis(chunks(v), 3, 2)  # noqa: E731
+    # first tokens at or before a token of its chunk        [B, n, Q]
+    r = jnp.cumsum(chunks(first).astype(jnp.int32), axis=-1)
+    y = _ssd_chunks(heads_first(x), heads_first(dt), a.astype(f32),
+                    chunks(b), chunks(c), r, dtype)
+    return jnp.moveaxis(y, 2, 3).reshape(bsz, n * chunk, h, -1)[:, :l]
 
 
 def _ssd_chunks(x, dt, a, b, c, r, dtype):

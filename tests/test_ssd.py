@@ -206,11 +206,30 @@ def test_lower_precision_operands_stay_near():
     assert 0 < err <= 0.03 * np.abs(np.asarray(want)).max()
 
 
-def test_the_path_built_is_counted():
+def test_the_path_built_is_counted(monkeypatch):
+    """`jnp` on a CPU, whatever the shape; `kernel` on a TPU at a shape
+    the kernels take (here the test says "tpu" and nothing runs: the
+    kernels' call is what is counted, not what it computes)."""
+    from predictionio_tpu.ops import pallas_ssd
+
     family = REGISTRY.get("encoder_ssd_scan_calls_total")
-    before = dict(family.collect())
+    built = lambda path: family.labels(path=path).value  # noqa: E731
+    before = {path: built(path) for path in ("jnp", "kernel")}
     args, seg = inputs([[10, 6]], 16)
     ssd_scan(*(args[k] for k in NAMES), seg, 16)
-    after = dict(family.collect())
-    key = next(k for k in after if "jnp" in str(k))
-    assert after[key] == before.get(key, 0) + 1
+    assert (built("jnp"), built("kernel")) == (before["jnp"] + 1,
+                                               before["kernel"])
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(pallas_ssd, "ssd_chunks",
+                        lambda x, *a, **kw: x.astype(jnp.float32))
+    rng = np.random.default_rng(0)
+    wide = {**args, "x": jnp.zeros((1, 16, H, 64)),
+            "b": jnp.asarray(rng.standard_normal((1, 16, 128)), jnp.float32)}
+    wide["c"] = wide["b"]
+    # a CPU's shape on a TPU: still jnp; the kernels' widths: kernel
+    ssd_scan(*(args[k] for k in NAMES), seg, 128)
+    assert (built("jnp"), built("kernel")) == (before["jnp"] + 2,
+                                               before["kernel"])
+    ssd_scan(*(wide[k] for k in NAMES), seg, 128)
+    assert (built("jnp"), built("kernel")) == (before["jnp"] + 2,
+                                               before["kernel"] + 1)

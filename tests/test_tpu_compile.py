@@ -145,6 +145,62 @@ def test_a_scan_the_kernels_decline_takes_the_jnp_path(one_chip,
     assert "tpu_custom_call" not in text and "enc.kda.scan" in text
 
 
+def _ssd_pass(one_chip, monkeypatch, groups, chunk, p=64):
+    """`ssd_scan`, forward and all six gradients, compiled for the
+    described chip at the size of one pass of a Mamba-2 mixer of
+    `granite4h.fit16_pack8k` or `nemotron3nano.fit16_pack8k` (1 x 8192
+    tokens, 4096 channels in heads of p, a state of 128, bfloat16
+    operands), the test saying "tpu" where `ssd_scan` asks."""
+    from predictionio_tpu.ops import ssd
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    shape = lambda *s: jax.ShapeDtypeStruct(  # noqa: E731
+        s, jnp.float32, sharding=one_chip)
+    h = 4096 // p
+    bc = shape(1, 8192, 128) if groups == 1 else shape(1, 8192, groups, 128)
+    seg = jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=one_chip)
+
+    def loss(x, dt, a, b, c, d, seg):
+        return jnp.sum(ssd.ssd_scan(x, dt, a, b, c, d, seg, chunk,
+                                    jnp.bfloat16, "enc.ssd.scan"))
+
+    return jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4, 5))).lower(
+        shape(1, 8192, h, p), shape(1, 8192, h), shape(h), bc, bc, shape(h),
+        seg).compile()
+
+
+@pytest.mark.parametrize("groups,chunk", [(1, 256), (8, 128)])
+def test_a_pass_of_the_ssd_scan_compiles_for_v5e_at_the_cells_sizes(
+        one_chip, monkeypatch, groups, chunk):
+    """Two kernels (`ops/pallas_ssd.py`), one group at Granite's chunk
+    of 256 and eight at Nemotron's 128 through the same bodies: the
+    forward pass that keeps each chunk's incoming states, and the
+    backward pass; both under the scope the benchmark's readers book the
+    scan's time to. What Mosaic refuses or the chip cannot fit fails
+    here."""
+    compiled = _ssd_pass(one_chip, monkeypatch, groups, chunk)
+    calls = [line for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 2
+    assert all("enc.ssd.scan" in line for line in calls)
+    # by the kernel's own name on the op's path (a backward call's
+    # operands are named after the forward kernel that made them)
+    assert sum("ssd_chunks_fwd/pallas_call" in line for line in calls) == 1
+    assert sum("ssd_chunks_bwd/pallas_call" in line for line in calls) == 1
+    # the saved states (8192 / chunk x 64 heads x 32 KiB) and the flat
+    # tensors round them; the [chunks, heads, Q, Q] tensors alone were
+    # 0.5 GiB each
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
+
+
+def test_an_ssd_scan_the_kernels_decline_takes_the_jnp_path(one_chip,
+                                                           monkeypatch):
+    """Heads of 128 channels are not the pairs the body takes:
+    `ssd_scan` builds the plain `jax.numpy` scan, on a TPU too."""
+    text = _ssd_pass(one_chip, monkeypatch, 1, 256, p=128).as_text()
+    assert "tpu_custom_call" not in text and "enc.ssd.scan" in text
+
+
 # the three encoder cells' attentions: joyai.fit8_pack8k's and
 # kimi_linear.fit8_pack8k's MLA heads, phi4flash.fit8_pack8k's stacked
 # differential heads with its window and without
@@ -248,3 +304,9 @@ def test_the_mamba_2_hybrids_step_compiles_for_v5e_at_the_cells_size(
     calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
     assert sum("segment_attention_fwd" in line for line in calls) == 2
     assert sum("segment_attention_bwd" in line for line in calls) == 1
+    # nine Mamba-2 layers: forward, the block's recomputation (which keeps
+    # the chunks' incoming states) and backward, all under the scan's scope
+    scans = [line for line in calls if "ssd_chunks" in line]
+    assert sum("ssd_chunks_fwd/pallas_call" in line for line in scans) == 18
+    assert sum("ssd_chunks_bwd/pallas_call" in line for line in scans) == 9
+    assert all("enc.ssd.scan" in line for line in scans)
