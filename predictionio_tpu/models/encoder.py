@@ -605,13 +605,14 @@ def kda(p, cfg: EncoderConfig, x, seg, scope: str = "enc.kda"):
         with jax.named_scope(f"{scope}.proj"):
             q, k, v = (_mm(cfg, x, w) for w in (w_q, w_k, w_v))
         with jax.named_scope(f"{scope}.conv"):
+            # q and k leave with every head of unit length, q over sqrt(dh)
+            unit = lambda scale: (dh, cfg.l2_norm_eps, scale)  # noqa: E731
             q, k, v = (
-                jax.nn.silu(kda_ops.causal_conv(a, w, seg)).reshape(
-                    b, l, hb, dh)
-                for a, w in ((q, conv_q), (k, conv_k), (v, conv_v)))
-            unit = lambda a: a * jax.lax.rsqrt(  # noqa: E731
-                jnp.sum(a * a, axis=-1, keepdims=True) + cfg.l2_norm_eps)
-            q, k = unit(q) * dh ** -0.5, unit(k)
+                kda_ops.causal_conv(a, w, seg, silu=True, unit=norm,
+                                    scope=f"{scope}.conv").reshape(
+                                        b, l, hb, dh)
+                for a, w, norm in ((q, conv_q, unit(dh ** -0.5)),
+                                   (k, conv_k, unit(1.0)), (v, conv_v, None)))
         with jax.named_scope(f"{scope}.gate"):
             log_a = (-jnp.exp(a_log)[:, None] * jax.nn.softplus(
                 (_mm(cfg, f_low, w_fb) + dt_bias).reshape(b, l, hb, dh)))
@@ -648,8 +649,8 @@ def mamba(p, cfg: EncoderConfig, x, seg, scope: str = "enc.mamba"):
         # two products: [x | z] [B, L, 2 channels] is never held whole
         xi, z = _mm(cfg, x, p["w_in"][:, :di]), _mm(cfg, x, p["w_in"][:, di:])
     with jax.named_scope(f"{scope}.conv"):
-        xi = jax.nn.silu(kda_ops.causal_conv(xi, p["conv_x"], seg,
-                                             p["conv_bias"]))
+        xi = kda_ops.causal_conv(xi, p["conv_x"], seg, p["conv_bias"],
+                                 silu=True, scope=f"{scope}.conv")
     with jax.named_scope(f"{scope}.dt"):
         low = _mm(cfg, xi, p["w_x"])
         dt = jax.nn.softplus(_mm(cfg, low[..., :rank], p["w_dt"])
@@ -676,9 +677,10 @@ def ssd(p, cfg: EncoderConfig, x, seg, scope: str = "enc.ssd"):
     with jax.named_scope(f"{scope}.proj"):
         zxbcdt = _mm(cfg, x, p["w_in"])
     with jax.named_scope(f"{scope}.conv"):
-        xbc = jax.nn.silu(kda_ops.causal_conv(
-            zxbcdt[..., di:2 * di + 2 * n], p["conv_w"], seg,
-            p.get("conv_bias")))
+        # xBC is read where it stands in [z | xBC | dt]
+        xbc = kda_ops.causal_conv(zxbcdt, p["conv_w"], seg,
+                                  p.get("conv_bias"), first=di, silu=True,
+                                  scope=f"{scope}.conv")
     with jax.named_scope(f"{scope}.dt"):
         dt = jax.nn.softplus(zxbcdt[..., 2 * di + 2 * n:] + p["dt_bias"])
     by_group = ((lambda v: v.reshape(b, l, groups, cfg.mamba_d_state))

@@ -60,7 +60,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from predictionio_tpu.ops import pallas_kda
+from predictionio_tpu.ops import pallas_conv, pallas_kda
 from predictionio_tpu.telemetry.spans import record as record_span
 
 _SUB = 16  # sub-block of a chunk inside which pairs are summed by channel
@@ -87,10 +87,58 @@ def chunk_stats(seg, chunk: int) -> tuple[int, int, int]:
             int((starts & (seg != 0)).sum()))
 
 
-def causal_conv(x, w, seg, bias=None):
+def causal_conv(x, w, seg, bias=None, *, first: int = 0, silu: bool = False,
+                unit=None, scope: str = "conv"):
     """Causal depthwise convolution along L of x [B, L, C] with taps w
     [W, C] (and `bias` [C]), the last tap on the token itself. A tap that
-    would read another history reads zero."""
+    would read another history reads zero. Where x is wider than the
+    taps, the channels first .. first + C of it are convolved (a window
+    of Mamba-2's [z | xBC | dt]); `silu` applies SiLU to the sum; `unit`
+    (a head's channels, epsilon, scale) then gives every head of that
+    many channels unit length, times the scale (KDA's q and k).
+
+    It decides its path from what it can observe: on a TPU, with
+    channels (and `first`) a multiple of the lane width, L a multiple of
+    a token tile and at most eight taps, the kernels of
+    `ops/pallas_conv.py` (x read once a pass, a hand-written backward
+    pass, traced under `scope`); else the plain `jax.numpy` below under
+    autodiff, which is the CPU's path and the kernels' oracle in the
+    tests. Which was built is counted in
+    `encoder_causal_conv_calls_total{path}` and left in the timeline as
+    `enc.conv.<path>` (the host seconds spent building it)."""
+    t0, c = time.monotonic(), w.shape[1]
+    path = ("kernel" if jax.default_backend() == "tpu" and x.ndim == 3
+            and pallas_conv.applicable(x.shape[1], c, w.shape[0], first,
+                                       unit)
+            else "jnp")
+    pallas_conv.CONV_CALLS.labels(path=path).inc()
+    if path == "kernel":
+        out = pallas_conv.causal_conv(x, w, seg, bias, first, silu, unit,
+                                      scope)
+    else:
+        if x.shape[-1] != c:
+            x = x[..., first:first + c]
+        out = _causal_conv(x, w, seg, bias)
+        if silu:
+            out = jax.nn.silu(out)
+        if unit is not None:
+            out = _unit_heads(out, *unit)
+    record_span(f"enc.conv.{path}", time.monotonic() - t0)
+    return out
+
+
+def _unit_heads(a, head: int, eps: float, scale: float):
+    """Each run of `head` channels of a [.., C] over its length, times
+    `scale`: a rsqrt(sum a^2 + eps) scale."""
+    heads = a.reshape(a.shape[:-1] + (a.shape[-1] // head, head))
+    heads = heads * jax.lax.rsqrt(
+        jnp.sum(heads * heads, axis=-1, keepdims=True) + eps)
+    if scale != 1.0:
+        heads = heads * scale
+    return heads.reshape(a.shape)
+
+
+def _causal_conv(x, w, seg, bias):
     width = w.shape[0]
     out = x * w[width - 1] if bias is None else x * w[width - 1] + bias
     for back in range(1, width):

@@ -295,8 +295,9 @@ def test_the_four_shares_add_up_to_the_uncut_layer():
 @pytest.mark.parametrize("name,digest", [
     ("joyai_llm_flash_1of16",
      "067646000b180604c0d687997fb8db4b7abf48a36553445cf6caf7c2ac82c931"),
+    # taken anew in PR 50 (`tests/test_encoder_nemotron.py` says why)
     ("kimi_linear_48b_1of32",
-     "1be859bc5a8ceb526feace7ef1b674c20c06e334887900fe2f13e8b80f4b0093"),
+     "162a502c2a097af9d65b597b36623633cd5e61b85010e992fa864a6795bebb50"),
     ("phi4_mini_flash_1of8",
      "53c111155ae096f41c67f9aeb11cc1bec2b98f7a5e912c3850ae3022c7f91e84"),
     ("granite_4_0_h_micro_1of8",
@@ -306,7 +307,7 @@ def test_the_old_cells_step_programs_lower_to_the_parents_text(name, digest):
     `gqa`, `rope`, `ops/moe.py` and the loss with this model. Each tiny
     configuration's whole step (loss, gradients, Adam), lowered without
     debug info, is the text the parent of PR 43 (f5073d5) lowers, sha256
-    taken there with this same code: the same program, so the same loss
+    taken there with this same code (Kimi's at PR 50): the same program, so the same loss
     and gradients bit for bit (the gate and the router rule default to
     what they were)."""
     cfg = enc.EncoderConfig.from_json(os.path.join(TINY, name + ".json"))

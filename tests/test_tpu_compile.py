@@ -201,6 +201,48 @@ def test_an_ssd_scan_the_kernels_decline_takes_the_jnp_path(one_chip,
     assert "tpu_custom_call" not in text and "enc.ssd.scan" in text
 
 
+# a pass of each cell's convolution: Kimi's q (its heads normed) or v of
+# four heads, Phi's Mamba layer, Granite's and Nemotron's xBC where it stands in
+# [z | xBC | dt] (total, first, channels, a bias)
+@pytest.mark.parametrize("seqs,total,first,c,bias,unit", [
+    (2, 512, 0, 512, False, (128, 1e-6, 128 ** -0.5)),
+    (2, 512, 0, 512, False, None), (2, 5120, 0, 5120, True, None),
+    (1, 8512, 4096, 4352, True, None), (1, 10304, 4096, 6144, True, None)])
+def test_a_pass_of_the_convolution_compiles_for_v5e_at_the_cells_sizes(
+        one_chip, monkeypatch, seqs, total, first, c, bias, unit):
+    """Two kernels (`ops/pallas_conv.py`), the forward pass and the
+    hand-written backward pass, the second under the scope it is handed:
+    what Mosaic refuses (a shift off the sublane tiling, a block off the
+    lane tiles) fails here. A window's slice is not copied: beside the
+    output and the cotangents the program holds no array of x's size."""
+    from predictionio_tpu.ops import kda
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    shape = lambda *s: jax.ShapeDtypeStruct(  # noqa: E731
+        s, jnp.float32, sharding=one_chip)
+    seg = jax.ShapeDtypeStruct((seqs, 8192), jnp.int32, sharding=one_chip)
+
+    def loss(x, w, seg, *b):
+        with jax.named_scope("enc.ssd.conv"):
+            return jnp.sum(kda.causal_conv(
+                x, w, seg, b[0] if b else None, first=first, silu=True,
+                unit=unit, scope="enc.ssd.conv") ** 2)
+
+    compiled = jax.jit(jax.grad(
+        loss, argnums=(0, 1, 3) if bias else (0, 1))).lower(
+            shape(seqs, 8192, total), shape(4, c), seg,
+            *((shape(c),) if bias else ())).compile()
+    calls = [line for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 2
+    assert all("enc.ssd.conv" in line for line in calls)
+    assert sum("causal_conv_fwd/pallas_call" in line for line in calls) == 1
+    assert sum("causal_conv_bwd/pallas_call" in line for line in calls) == 1
+    # y, dy and dx (and dx padded to the array's width): no copy of x
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 4 * seqs * 8192 * (3 * c + total) + 2 ** 24
+
+
 # the three encoder cells' attentions: joyai.fit8_pack8k's and
 # kimi_linear.fit8_pack8k's MLA heads, phi4flash.fit8_pack8k's stacked
 # differential heads with its window and without
@@ -310,3 +352,8 @@ def test_the_mamba_2_hybrids_step_compiles_for_v5e_at_the_cells_size(
     assert sum("ssd_chunks_fwd/pallas_call" in line for line in scans) == 18
     assert sum("ssd_chunks_bwd/pallas_call" in line for line in scans) == 9
     assert all("enc.ssd.scan" in line for line in scans)
+    # and their convolutions, xBC read in place
+    convs = [line for line in calls if "causal_conv" in line]
+    assert sum("causal_conv_fwd/pallas_call" in line for line in convs) == 18
+    assert sum("causal_conv_bwd/pallas_call" in line for line in convs) == 9
+    assert all("enc.ssd.conv" in line for line in convs)
