@@ -12,8 +12,12 @@ feed-forward, the router on the normed stream after the mixer or on the
 block's own input; or, in a model whose layers are one sublayer each
 (Nemotron-H's pattern), a Mamba-2 layer with several B/C groups, an
 expert feed-forward of ungated squared-ReLU experts or attention, alone
-under its one norm and residual; an optional multi-token-prediction
-(MTP) module; trained on packed histories.
+under its one norm and residual; or, in a model of short convolutions
+and attention (LFM2's `layer_types`), a doubly gated short convolution
+that is the whole mixer, or rotated grouped-query attention under a
+norm a head on q and k, beside a dense or a sigmoid-routed expert
+feed-forward without a shared expert; an optional
+multi-token-prediction (MTP) module; trained on packed histories.
 
 One code path runs every size. A configuration file in the published
 model's own key names (`EncoderConfig.from_json`) gives the widths, the
@@ -100,6 +104,25 @@ Equations (the plain reference is `quality/encoder_reference.py`):
             (`moe_shared_expert_intermediate_size`) and of the same
             ungated form. After the last layer the final norm and an
             untied head
+    SConv   the doubly gated short convolution (`layer_types` entry
+            `conv`): [B | C | x] = u W_in, no bias; v = B * x; c_t =
+            sum_{j < taps} w_j v_{t - taps + 1 + j} a channel (depthwise,
+            causal, the last tap on the token itself, a tap that would
+            read another history reads zero, no activation); out = (C *
+            c) W_out. No recurrence and no state: the convolution is
+            the mixer, a multiplicative gate before and after it
+    CxA     the block of such a model (LFM2's): h += Op_l(RMSNorm(h)); h
+            += FFN_l(RMSNorm(h)), Op_l SConv or, at a `full_attention`
+            entry, GQA with q = RMSNorm_d(q) g_q and k = RMSNorm_d(k) g_k
+            a head before the rotation (`qk_norm`), then RoPE in
+            half-rotation pairs, scores / sqrt(d). FFN_l, l <
+            `num_dense_layers`: SwiGLU; else s = sigmoid(x W_g) in
+            float32, idx = top-k of s + bias, w =
+            routed_scaling_factor s[idx] / (sum(s[idx]) + 1e-6), out =
+            sum over the held e in idx of w_e W_2,e (SiLU(W_1,e x) *
+            (W_3,e x)); no shared expert, so a token none of whose picks
+            is held here adds nothing. After the last layer one RMSNorm
+            and the tied head
     x4      the four multipliers of such a hybrid, each 1 where a
             configuration states none: h0 = embedding_multiplier
             E[token]; h += residual_multiplier Mixer(..) and h +=
@@ -144,6 +167,8 @@ _ALIASES = {"num_experts": "n_routed_experts",
 # a published `layer_types` entry -> the mixer kind here (Mamba-2's:
 # `mamba` there names the scalar-decay layer)
 _LAYER_TYPES = {"mamba": "ssd", "attention": "gqa"}
+# the same of a model of short convolutions and attention (LFM2's)
+_CONV_LAYER_TYPES = {"conv": "sconv", "full_attention": "gqa"}
 # a letter of a published `hybrid_override_pattern` -> the one sublayer of
 # the layer (`-`, a dense feed-forward alone, is in no model run here)
 _PATTERN = {"M": "ssd", "E": "experts", "*": "gqa"}
@@ -162,8 +187,9 @@ class EncoderConfig:
     q_lora_rank: int = 0             # 0 (null): x W_q, no low-rank query
     mla_use_nope: bool = False       # MLA without rotation
     # "mla" | "kda" | "mamba" | "swa" | "full" | "gmu" | "cross" | "ssd" |
-    # "gqa" a layer; (): all MLA. With `single_sublayer` the layer is that
-    # mixer alone ("ssd" | "gqa") or an expert feed-forward alone ("experts")
+    # "gqa" | "sconv" a layer; (): all MLA. With `single_sublayer` the layer
+    # is that mixer alone ("ssd" | "gqa") or an expert feed-forward alone
+    # ("experts")
     layer_kinds: tuple = ()
     single_sublayer: bool = False
     num_key_value_heads: int = 0     # grouped K/V heads of swa/full/cross/gqa
@@ -174,6 +200,8 @@ class EncoderConfig:
     layer_windowed: tuple = ()
     layer_rotated: tuple = ()
     rope_interleave: bool = True     # pairs (2i, 2i+1); False: (i, i + d/2)
+    qk_norm: bool = False            # gqa: RMSNorm a head on q, k, unrotated
+    sconv_kernel: int = 3            # taps of the gated short convolution
     layer_first: int = 0             # published index of the first held layer
     mamba_expand: int = 2            # channels / hidden_size
     mamba_d_state: int = 16
@@ -208,6 +236,7 @@ class EncoderConfig:
     moe_shared_expert_intermediate_size: int = 0
     num_experts_per_tok: int = 0
     router_scoring: str = "sigmoid"  # | "softmax" (`ops/moe.py::route`)
+    router_norm_eps: float = 0.0     # added to the picked sigmoid scores' sum
     router_on_block_input: bool = False  # w_g reads h before the mixer
     # the experts' gate, "silu" | "relu"; "relu2": ungated, relu(W_up x)^2
     moe_gate: str = "silu"
@@ -310,7 +339,9 @@ class EncoderConfig:
                 int(flat["num_hidden_layers"]),
                 int(flat.get("layers_total", flat["num_hidden_layers"])),
                 int(flat["mb_per_layer"]))
-        if flat.get("layer_types"):
+        if "conv_L_cache" in flat:
+            _convolutions_and_attention(flat)
+        elif flat.get("layer_types"):
             flat["layer_kinds"] = _held_layer_types(flat)
         if "moe_num_primary_experts" in flat:
             _routed_before_mixing(flat)
@@ -364,6 +395,64 @@ def _held_layer_types(flat: dict) -> tuple:
     if flat.get("shared_intermediate_size"):
         flat["intermediate_size"] = flat["shared_intermediate_size"]
     return tuple(_LAYER_TYPES[t] for t in mine)
+
+
+def _convolutions_and_attention(flat: dict) -> None:
+    """The sizes here from LFM2's key names (`lfm2_moe`): the held
+    layers' entries of `layer_types` (whole and sliced from
+    `layer_first`, or the held slice alone), `conv` the doubly gated
+    short convolution of `conv_L_cache` taps and `full_attention`
+    grouped-query attention under a norm a head on q and k, rotated in
+    half-rotation pairs by `rope_parameters.rope_theta`; the published
+    layers before `num_dense_layers` a dense SwiGLU of
+    `intermediate_size`, the rest `num_experts` (held here) sigmoid-routed
+    SiLU-gated experts of `moe_intermediate_size` picked by a bias
+    buffer, their weights normalised over the picked scores' sum plus
+    1e-6 and scaled, and no shared expert; RMSNorm with `norm_eps`; a
+    tied head where the file does not say otherwise. What the code
+    cannot run is refused by name: an entry it does not know, a bias on
+    the convolution or its projections, a router without the picking
+    bias or without the normalisation, a rotation of another type, more
+    picks than experts, attention in a dense layer."""
+    held = int(flat["num_hidden_layers"])
+    at, mine = _held_slice(flat, "layer_types")
+    unknown = sorted(set(mine) - set(_CONV_LAYER_TYPES))
+    if unknown or len(mine) != held:
+        raise ValueError(
+            f"layer_types[{at}:{at + held}] = {mine}: {held} entries of "
+            f"{sorted(_CONV_LAYER_TYPES)} wanted, {unknown} not known")
+    for key, only in (("conv_bias", False), ("use_expert_bias", True),
+                      ("norm_topk_prob", True)):
+        if flat.get(key, only) is not only:
+            raise ValueError(f"{key} = {flat[key]!r}: a model of short "
+                             f"convolutions and attention runs with "
+                             f"{only!r} alone")
+    turning = flat.get("rope_parameters") or {}
+    if turning.get("rope_type", "default") != "default":
+        raise ValueError(f"rope_parameters.rope_type = "
+                         f"{turning['rope_type']!r}: 'default' alone")
+    total = int(flat.get("experts_total") or flat["num_experts"])
+    if int(flat["num_experts_per_tok"]) > total:
+        raise ValueError(f"num_experts_per_tok = "
+                         f"{flat['num_experts_per_tok']}: over the "
+                         f"{total} experts the router scores")
+    kinds = tuple(_CONV_LAYER_TYPES[t] for t in mine)
+    dense = max(0, min(held, int(flat.get("num_dense_layers", 0))
+                       - int(flat.get("layer_first", 0))))
+    if "gqa" in kinds[:dense]:
+        raise ValueError(
+            f"layer_types: a full_attention layer before num_dense_layers "
+            f"= {flat['num_dense_layers']}: a dense layer's mixer is a "
+            f"convolution here")
+    flat.setdefault("tie_word_embeddings", True)
+    flat.update(
+        layer_kinds=kinds, first_k_dense_replace=dense,
+        layer_rotated=tuple(kind == "gqa" for kind in kinds),
+        rope_interleave=False, qk_norm=True,
+        rope_theta=turning.get("rope_theta", flat.get("rope_theta", 10000.0)),
+        sconv_kernel=flat["conv_L_cache"], rms_norm_eps=flat["norm_eps"],
+        experts_total=total, n_shared_experts=0, router_scoring="sigmoid",
+        router_norm_eps=1e-6, moe_gate="silu")
 
 
 def _routed_before_mixing(flat: dict) -> None:
@@ -702,23 +791,51 @@ def ssd(p, cfg: EncoderConfig, x, seg, scope: str = "enc.ssd"):
         return _mm(cfg, y, p["w_out"])
 
 
+def short_conv(p, cfg: EncoderConfig, x, seg, scope: str = "enc.sconv"):
+    """The doubly gated short convolution on x [B, L, D] (already
+    normed): [B | C | x] = u W_in, (C * conv(B * x)) W_out, the
+    convolution (`ops/kda.py::causal_conv`) depthwise and causal inside
+    a history, bare: no bias, no activation. Scopes `proj`, `gate` (both
+    products), `conv`, `out` under `scope`."""
+    d = p["w_out"].shape[0]
+    with jax.named_scope(f"{scope}.proj"):
+        bcx = _mm(cfg, x, p["w_in"])
+    with jax.named_scope(f"{scope}.gate"):
+        v = bcx[..., :d] * bcx[..., 2 * d:]
+    with jax.named_scope(f"{scope}.conv"):
+        c = kda_ops.causal_conv(v, p["taps"], seg, scope=f"{scope}.conv")
+    with jax.named_scope(f"{scope}.gate"):
+        y = bcx[..., d:2 * d] * c
+    with jax.named_scope(f"{scope}.out"):
+        return _mm(cfg, y, p["w_out"])
+
+
 def gqa(p, cfg: EncoderConfig, x, seg, pos, scope: str = "enc.gqa",
         window=None, rotate: bool = False):
     """Plain softmax attention over grouped heads on x [B, L, D]
     (already normed): query head j reads key and value head j // (heads
-    / kv heads). With `rotate` RoPE on q and k, with a `window` the keys
+    / kv heads). Where the block holds `q_norm` and `k_norm`, an RMSNorm
+    a head on q and on k first (scope `qk_norm`, opened by no other
+    model); with `rotate` RoPE on q and k then, with a `window` the keys
     t - s < window alone. Scopes `proj`, `pairs`, `out`."""
     cd = _dt(cfg.compute_dtype)
     b, l, _ = x.shape
     h, hk = cfg.num_attention_heads, cfg.num_key_value_heads
     dh = p["w_q"].shape[1] // h
-    turn = ((lambda t: rope(t, pos, cfg.rope_theta, cfg.rope_interleave))
-            if rotate else (lambda t: t))
+
+    def turn(t, norm=None):
+        if norm in p:
+            with jax.named_scope(f"{scope}.qk_norm"):
+                t = rms_norm(t, p[norm], cfg.rms_norm_eps)
+        return (rope(t, pos, cfg.rope_theta, cfg.rope_interleave)
+                if rotate else t)
+
     with jax.named_scope(f"{scope}.proj"):
-        q = turn(_mm(cfg, x, p["w_q"]).reshape(b, l, h, dh))
+        q = turn(_mm(cfg, x, p["w_q"]).reshape(b, l, h, dh), "q_norm")
         k, v = (jnp.repeat(f(_mm(cfg, x, p[w]).reshape(b, l, hk, dh)),
                            h // hk, axis=2)
-                for w, f in (("w_k", turn), ("w_v", lambda t: t)))
+                for w, f in (("w_k", lambda t: turn(t, "k_norm")),
+                             ("w_v", lambda t: t)))
         q, k, v = (t.astype(cd).transpose(0, 2, 1, 3) for t in (q, k, v))
     o = segment_attention(q, k, v, seg, pos, block=cfg.attention_block,
                           scale=cfg.attention_multiplier or dh ** -0.5,
@@ -840,7 +957,8 @@ def carried_block(p, cfg: EncoderConfig, kind: str, layer: int, h, seg, pos,
 
 def _route(p, bias, cfg: EncoderConfig, x2d):
     return moe.route(x2d, p["w_g"], bias, cfg.num_experts_per_tok,
-                     cfg.routed_scaling_factor, cfg.router_scoring)
+                     cfg.routed_scaling_factor, cfg.router_scoring,
+                     cfg.router_norm_eps)
 
 
 def expert_ffn(p, bias, cfg: EncoderConfig, x2d, scope: str = "enc.moe",
@@ -866,14 +984,19 @@ def expert_ffn(p, bias, cfg: EncoderConfig, x2d, scope: str = "enc.moe",
 
 def _mix(p, cfg: EncoderConfig, h, seg, pos, scope: str = "", n: int = 0):
     """The first half of `block`: h += Mixer(norm(h)), the mixer the
-    one whose parameters the block holds (`attn`: MLA, `kda`: KDA, `gqa`:
-    grouped-query attention, windowed and rotated as the configuration
-    says of the held layer `n`, traced under `enc.gqa_swa` with a window
-    and `enc.gqa_full` without)."""
+    one whose parameters the block holds (`attn`: MLA, `kda`: KDA,
+    `sconv`: the gated short convolution, `gqa`: grouped-query
+    attention, windowed and rotated as the configuration says of the
+    held layer `n`, traced under `enc.gqa_swa` with a window and
+    `enc.gqa_full` without)."""
     x = _norm(cfg, h, p, "norm1")
     if "kda" in p:
         with jax.named_scope(scope or "enc.kda"):
             return h + kda(p["kda"], cfg, x, seg, scope or "enc.kda")
+    if "sconv" in p:
+        with jax.named_scope(scope or "enc.sconv"):
+            return h + short_conv(p["sconv"], cfg, x, seg,
+                                  scope or "enc.sconv")
     if "gqa" in p:
         windowed = bool(cfg.layer_windowed) and cfg.layer_windowed[n]
         scope = scope or ("enc.gqa_swa" if windowed else "enc.gqa_full")
@@ -896,7 +1019,8 @@ def block(p, bias, cfg: EncoderConfig, h, seg, pos, scope: str = "",
     and `enc.moe`, or all under `scope` where one is given (the MTP
     module's block). With `router_on_block_input` the router reads h as
     it enters, before the mixer (`enc.router`), and the experts run
-    under `enc.experts`."""
+    under `enc.experts`; so they do, the router on the normed stream,
+    in a block that holds no shared expert (`_feed_forward`)."""
     routing = None
     if cfg.router_on_block_input and "w_g" in p:
         with jax.named_scope(scope or "enc.router"):
@@ -911,9 +1035,16 @@ def block(p, bias, cfg: EncoderConfig, h, seg, pos, scope: str = "",
 def _feed_forward(p, bias, cfg: EncoderConfig, h, scope: str = "",
                   scale: float = 1.0, routing=None):
     """The second half of `block`: h += FFN(norm(h)), a dense one times
-    `scale` (a carried block's residual multiplier)."""
+    `scale` (a carried block's residual multiplier). An expert block
+    that holds no shared expert has two parts and not three, traced
+    apart: the router under `enc.router`, the held experts under
+    `enc.experts` (as a block that routes on its input has them)."""
     b, l, d = h.shape
     x2d = _norm(cfg, h, p, "norm2").reshape(b * l, d)
+    if "w_g" in p and "shared_w13" not in p and routing is None and not scope:
+        with jax.named_scope("enc.router"):
+            routing = _route(p, bias, cfg, x2d)
+        scope = "enc.experts"
     if "w_g" not in p:
         with jax.named_scope(scope or "enc.dense_ffn"):
             y = _by_rows(cfg, lambda x: swiglu(cfg, x, p["w13"], p["w2"]),
@@ -1031,7 +1162,8 @@ def run_blocks(params, cfg: EncoderConfig, h, seg, pos):
             h, _ = _kda_block(p, None, cfg, h, seg, pos)
             continue
         h = _maybe_remat(
-            lambda p, h: block(p, None, cfg, h, seg, pos)[0], cfg)(p, h)
+            lambda p, h, n=n: block(p, None, cfg, h, seg, pos, n=n)[0],
+            cfg)(p, h)
     if not cfg.n_moe:
         return h, None
 
@@ -1044,7 +1176,7 @@ def run_blocks(params, cfg: EncoderConfig, h, seg, pos):
                             (params["moe"], params["router_bias"]))
     routed = []
     biases = params.get("router_bias", (None,) * cfg.n_moe)
-    for n, layer in enumerate(zip(params["moe"], biases)):
+    for n, layer in enumerate(zip(params["moe"], biases), cfg.n_dense):
         h, r = (_kda_block(*layer, cfg, h, seg, pos) if "kda" in layer[0]
                 else _maybe_remat(functools.partial(one, n=n), cfg)(h, layer))
         routed.append(r)
@@ -1204,8 +1336,17 @@ def _ssd_shapes(cfg: EncoderConfig) -> dict:
 def _gqa_shapes(cfg: EncoderConfig) -> dict:
     d, h = cfg.hidden_size, cfg.num_attention_heads
     dh = cfg.head_dim or d // h
+    norms = {"q_norm": (dh,), "k_norm": (dh,)} if cfg.qk_norm else {}
     return {"w_q": (d, h * dh), "w_k": (d, cfg.num_key_value_heads * dh),
-            "w_v": (d, cfg.num_key_value_heads * dh), "w_o": (h * dh, d)}
+            "w_v": (d, cfg.num_key_value_heads * dh), "w_o": (h * dh, d),
+            **norms}
+
+
+def _sconv_shapes(cfg: EncoderConfig) -> dict:
+    """The gated short convolution's: w_in's columns are [B | C | x]."""
+    d = cfg.hidden_size
+    return {"w_in": (d, 3 * d), "taps": (cfg.sconv_kernel, d),
+            "w_out": (d, d)}
 
 
 def _diff_shapes(cfg: EncoderConfig, cross: bool) -> dict:
@@ -1237,6 +1378,8 @@ def _mixer_shapes(cfg: EncoderConfig, kind: str) -> dict:
         return {"ssd": _ssd_shapes(cfg)}
     if kind == "gqa":
         return {"gqa": _gqa_shapes(cfg)}
+    if kind == "sconv":
+        return {"sconv": _sconv_shapes(cfg)}
     if kind == "mla":
         return {"attn": _attn_shapes(cfg)}
     raise ValueError(f"no mixer of kind {kind!r}")
@@ -1322,7 +1465,8 @@ def init_params(cfg: EncoderConfig, vocab: int, key):
     """Weights normal(0, init_std), norms one, biases zero; a KDA
     layer's decay rates A = exp(a_log) uniform in [1, 16], a `dt_bias`
     the inverse softplus of a step log-uniform in [0.001, 0.1], a
-    convolution's taps uniform within 1 / sqrt(width); a Mamba layer's
+    convolution's taps uniform within 1 / sqrt(width) (a gated short
+    convolution's `taps` normal like a matrix); a Mamba layer's
     A = 1..states a channel (Mamba-2's: 1..heads, one a head) and its
     skip D one; differential
     attention's four lambda vectors normal(0, 0.1). Made where `key`

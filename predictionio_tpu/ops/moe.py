@@ -3,8 +3,10 @@ layer that is told which of them it holds.
 
 A deployment divides the experts of a layer over chips. Every chip routes
 every token over all the experts (`route`: sigmoid scores, a load-balance
-bias that only picks, weights normalised over the picked and scaled; or
-the top-k of the logits and a softmax over the picked) and computes the
+bias that only picks, weights normalised over the picked, the sum of
+the picked scores alone or, as LFM2's published rule has it, that sum
+plus a small epsilon, and scaled; or the top-k of the logits and a
+softmax over the picked) and computes the
 part of the result that its own experts give (`held_experts`: gated
 experts, the gate's activation SiLU or ReLU, or ungated ones, W_down
 relu(W_up x)^2, two matrices an expert). What the absent experts would have added is left out;
@@ -39,22 +41,26 @@ import jax.numpy as jnp
 
 
 def route(x, w_g, bias, top_k: int, scale: float,
-          scoring: str = "sigmoid"):
+          scoring: str = "sigmoid", eps: float = 0.0):
     """Scores of x [T, D] over all the experts of w_g [D, E].
 
     Returns (idx [T, k] int32, weights [T, k] float32, load [E] int32):
     the picks, their weights, and how many tokens picked each expert.
     `scoring` "sigmoid": the top-k of `sigmoid(x w_g) + bias`, the
-    picked scores normalised to sum to one and scaled; the bias decides
-    the pick only (`noaux_tc`). "softmax": the top-k of the logits
+    picked scores normalised to sum to one and scaled, w = scale s[idx] /
+    (sum(s[idx]) + eps), `eps` 0 for every model but the one whose
+    published rule states one (0 adds no op: the weights are the bare
+    quotient's bit for bit); the bias decides the pick only
+    (`noaux_tc`). "softmax": the top-k of the logits
     x w_g and a softmax over the picked (a softmax over all, picked and
     renormalised, is the same); no bias, no scale. The scores are
     float32 at the highest matmul precision: a pick turns on their last
     digits."""
     logits = jnp.dot(x.astype(jnp.float32), w_g, precision="highest")
     if scoring == "softmax":
-        if bias is not None or scale != 1.0:
-            raise ValueError("a softmax router takes no bias and no scale")
+        if bias is not None or scale != 1.0 or eps:
+            raise ValueError("a softmax router takes no bias, no scale and "
+                             "no epsilon")
         picked, idx = jax.lax.top_k(logits, top_k)
         weights = jax.nn.softmax(picked, axis=-1)
     elif scoring == "sigmoid":
@@ -62,7 +68,9 @@ def route(x, w_g, bias, top_k: int, scale: float,
         _, idx = jax.lax.top_k(s + jax.lax.stop_gradient(bias)[None, :],
                                top_k)
         picked = jnp.take_along_axis(s, idx, axis=-1)
-        weights = scale * picked / jnp.sum(picked, axis=-1, keepdims=True)
+        scaled = scale * picked  # before the sum: the order the ops had
+        total = jnp.sum(picked, axis=-1, keepdims=True)
+        weights = scaled / (total + eps if eps else total)
     else:
         raise ValueError(f"no router scoring {scoring!r}")
     load = jnp.zeros(w_g.shape[1], jnp.int32).at[idx.reshape(-1)].add(1)

@@ -487,6 +487,94 @@ def test_the_sigmoid_router_at_scale_2_5(what):
         close(got, jax.grad(plain)(w_g))
 
 
+@pytest.mark.parametrize("what", ["zero", "weights", "sum", "softmax"])
+def test_the_sigmoid_routers_epsilon(what):
+    """LFM2's published rule: w = s[idx] / (sum(s[idx]) + 1e-6). An
+    epsilon of 0, every other model's, adds no op: the weights are the
+    bare quotient's bit for bit and the traced program is what it was."""
+    rng = np.random.default_rng(15)
+    x = jnp.asarray(rng.standard_normal((40, 8)), jnp.float32)
+    # a row of scores near 1e-4, where 1e-6 is no rounding error
+    x = x.at[:20].set(jnp.asarray([9.0] + [0.0] * 7))
+    w_g = jnp.asarray(0.05 * rng.standard_normal((8, 6)), jnp.float32
+                      ).at[0].add(-1.0)
+    bias = jnp.asarray(0.3 * rng.standard_normal(6), jnp.float32)
+    bare = moe.route(x, w_g, bias, 3, 2.5, "sigmoid")
+    if what == "zero":
+        same = moe.route(x, w_g, bias, 3, 2.5, "sigmoid", 0.0)
+        assert all(np.array_equal(a, b) for a, b in zip(bare, same))
+        assert str(jax.make_jaxpr(lambda x: moe.route(
+            x, w_g, bias, 3, 2.5))(x)) == str(jax.make_jaxpr(
+                lambda x: moe.route(x, w_g, bias, 3, 2.5, "sigmoid", 0.0))(x))
+        return
+    if what == "softmax":
+        with pytest.raises(ValueError, match="no epsilon"):
+            moe.route(x, w_g, None, 3, 1.0, "softmax", 1e-6)
+        return
+    idx, weights, _ = moe.route(x, w_g, bias, 3, 2.5, "sigmoid", 1e-6)
+    assert np.array_equal(idx, bare[0])  # the pick does not know it
+    s = 1.0 / (1.0 + np.exp(-(np.asarray(x, np.float64)
+                              @ np.asarray(w_g, np.float64))))
+    picked = np.take_along_axis(s, np.asarray(idx), axis=-1)
+    total = picked.sum(-1, keepdims=True)
+    if what == "weights":
+        close(weights, 2.5 * picked / (total + 1e-6), 1e-6)
+    else:  # the small rows lose a share of their sum that is no rounding
+        lost = 1.0 - np.asarray(weights).sum(-1) / 2.5
+        close(lost[:20], (1e-6 / (total + 1e-6))[:20, 0], 1e-3)
+        assert lost[:20].min() > 1e-3 > 1e-5 > np.abs(lost[20:]).max()
+        assert np.abs(np.asarray(bare[1]) - np.asarray(weights))[:20].max() \
+            > 1e-3 * np.asarray(weights)[:20].max()
+
+
+@pytest.mark.parametrize("what", ["normed", "before_rotation", "unnormed"])
+def test_grouped_query_attention_with_and_without_the_norm_a_head(what):
+    """`gqa` norms q and k a head where the block holds `q_norm` and
+    `k_norm` (LFM2's), before the rotation; a block without them traces
+    the program it traced."""
+    from predictionio_tpu.quality import encoder_reference as plain
+
+    cfg = dataclasses.replace(
+        CFG, num_key_value_heads=1, head_dim=8, rope_interleave=False,
+        rms_norm_eps=1e-5, attention_block=16)
+    rng = np.random.default_rng(16)
+    d, h, dh = cfg.hidden_size, cfg.num_attention_heads, 8
+    x = jnp.asarray(rng.standard_normal((2, 64, d)), jnp.float32)
+    p = {k: jnp.asarray(0.3 * rng.standard_normal(s), jnp.float32)
+         for k, s in (("w_q", (d, h * dh)), ("w_k", (d, dh)),
+                      ("w_v", (d, dh)), ("w_o", (h * dh, d)))}
+    norms = {k: jnp.asarray(1.0 + 0.3 * rng.standard_normal(dh), jnp.float32)
+             for k in ("q_norm", "k_norm")}
+    _, seg, pos = packed()
+    got = enc.gqa({**p, **norms}, cfg, x, seg, pos, rotate=True)
+
+    def reference(tree, **wrong):
+        with jax.default_matmul_precision("highest"):
+            return jnp.stack([plain.gqa(tree, cfg, x[b], seg[b], None,
+                                        lambda f: f, pos[b], None, True,
+                                        **wrong) for b in range(2)])
+
+    if what == "normed":
+        close(got, reference({**p, **norms}), 1e-4)
+    elif what == "before_rotation":
+        late = reference({**p, **norms}, wrong=("norm_after_rotation",))
+        assert float(jnp.abs(got - late).max()) > 1e-2 * float(
+            jnp.abs(got).max())
+    else:
+        bare = enc.gqa(p, cfg, x, seg, pos, rotate=True)
+        close(bare, reference(p), 1e-4)
+        assert float(jnp.abs(got - bare).max()) > 1e-2 * float(
+            jnp.abs(got).max())
+        text = jax.jit(lambda p: enc.gqa(p, cfg, x, seg, pos, "enc.gqa",
+                                         rotate=True)).lower(p).as_text(
+                                             debug_info=True)
+        assert "qk_norm" not in text and "enc.gqa.proj" in text
+        normed = jax.jit(lambda p: enc.gqa(p, cfg, x, seg, pos, "enc.gqa",
+                                           rotate=True)).lower(
+            {**p, **norms}).as_text(debug_info=True)
+        assert "enc.gqa.qk_norm" in normed
+
+
 # -- the train step, the scorer, the configuration file -----------------------------
 
 def test_the_step_lowers_the_loss_and_moves_the_bias():

@@ -212,6 +212,31 @@ def test_the_convolution_reads_zero_before_a_historys_first_token(what):
             at += ln
 
 
+@pytest.mark.parametrize("rows", [[[1, 2, 3, 20, 14], [40]],
+                                  [[40], [3, 3, 3, 31]],
+                                  [[2] * 20, [39, 1]]])
+def test_three_bare_taps_are_three_shifted_sums(rows):
+    """The convolution as a mixer of its own (LFM2's): three taps, no
+    bias, no SiLU, no unit norm behind it, a history's first token
+    anywhere: y_t = w_2 x_t + w_1 x_{t-1} + w_0 x_{t-2}, a shifted term
+    kept where the earlier token is of the same history."""
+    rng = np.random.default_rng(len(rows[0]))
+    length, c = 40, 6
+    x = rng.standard_normal((2, length, c)).astype(np.float32)
+    w = rng.standard_normal((3, c)).astype(np.float32)
+    seg = np.asarray(segments(length, rows))
+    got = kda.causal_conv(jnp.asarray(x), jnp.asarray(w), jnp.asarray(seg),
+                          first=0)
+    want = x * w[2]
+    for back in (1, 2):
+        same = seg[:, back:] == seg[:, :-back]
+        want[:, back:] += np.where(same[..., None], x[:, :-back], 0) * w[2 - back]
+    close(got, want, 1e-6)
+    # bare: the same call with the activation on is another function
+    assert not np.allclose(got, kda.causal_conv(
+        jnp.asarray(x), jnp.asarray(w), jnp.asarray(seg), silu=True))
+
+
 def test_the_convolution_adds_a_bias_where_one_is_given():
     """Mamba's: a bias a channel on every token, a history's first too;
     without one (the KDA layers) the traced program is what it was."""

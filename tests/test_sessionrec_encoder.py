@@ -374,14 +374,15 @@ class TestTheEncoderAsADecoderHybridDecoder:
 
 
 @pytest.mark.parametrize("copy", ["kimi_linear.py", "phi4_flash.py",
-                                  "granite_hybrid.py", "smallthinker.py"])
+                                  "granite_hybrid.py", "smallthinker.py",
+                                  "nemotron_h.py"])
 def test_the_benchmarks_reference_is_a_copy_of_the_packages(copy):
-    """The newest copy (`perf/reference/nemotron_h.py`) is held equal
-    in `tests/test_encoder_nemotron.py`. The Kimi, Phi-4-mini-flash,
-    Granite and SmallThinker cells' copies are the package's reference
-    as PRs 32, 37, 41 and 43 left it, and the benchmark's files: the package's still
-    defines every function they have, with the arguments they have, in
-    their order."""
+    """The newest copy (`perf/reference/lfm2_moe.py`) is held equal
+    in `tests/test_encoder_lfm2.py`. The Kimi, Phi-4-mini-flash,
+    Granite, SmallThinker and Nemotron cells' copies are the package's
+    reference as PRs 32, 37, 41, 43 and 48 left it, and the benchmark's
+    files: the package's still defines every function they have, with
+    the arguments they have, in their order."""
     import ast
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))

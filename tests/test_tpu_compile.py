@@ -243,6 +243,33 @@ def test_a_pass_of_the_convolution_compiles_for_v5e_at_the_cells_sizes(
         < 4 * seqs * 8192 * (3 * c + total) + 2 ** 24
 
 
+def test_the_bare_three_tap_convolution_compiles_for_v5e_at_lfm2s_size(
+        one_chip, monkeypatch):
+    """`lfm2.fit8_pack8k`'s convolution, a mixer of its own: three taps
+    over 2048 channels of 2 x 8192 tokens, no bias, no SiLU, no unit
+    norm, the gates' products XLA's on either side: the same two
+    kernels under the mixer's scope."""
+    from predictionio_tpu.ops import kda
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    shape = lambda *s: jax.ShapeDtypeStruct(  # noqa: E731
+        s, jnp.float32, sharding=one_chip)
+    seg = jax.ShapeDtypeStruct((2, 8192), jnp.int32, sharding=one_chip)
+
+    def loss(b, x, c, w, seg):
+        with jax.named_scope("enc.sconv.conv"):
+            mixed = kda.causal_conv(b * x, w, seg, scope="enc.sconv.conv")
+        return jnp.sum(c * mixed)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))).lower(
+        *(shape(2, 8192, 2048),) * 3, shape(3, 2048), seg).compile()
+    calls = [line for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert sum("causal_conv_fwd/pallas_call" in line for line in calls) == 1
+    assert sum("causal_conv_bwd/pallas_call" in line for line in calls) == 1
+    assert all("enc.sconv.conv" in line for line in calls)
+
+
 # the three encoder cells' attentions: joyai.fit8_pack8k's and
 # kimi_linear.fit8_pack8k's MLA heads, phi4flash.fit8_pack8k's stacked
 # differential heads with its window and without

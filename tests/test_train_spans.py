@@ -698,10 +698,9 @@ NH_METRICS = ("fit.nh_ssd_s", "fit.nh_ssd_scan_s", "fit.nh_ssd_scan_roofline",
               "fit.nh_moe_block_fill")
 
 
-@pytest.fixture(scope="module")
-def sublayer_step_text():
+def _tiny_step_text(config: str) -> str:
     """Lowered text, with locations, of one train step of the tiny
-    configuration in Nemotron-H's key names the benchmark's tests run."""
+    configuration `config` the benchmark's tests run."""
     import jax
     import jax.numpy as jnp
 
@@ -709,12 +708,46 @@ def sublayer_step_text():
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     cfg = enc.EncoderConfig.from_json(os.path.join(
-        root, "perf", "tests", "tiny", "nemotron3_nano_30b_1of16.json"))
+        root, "perf", "tests", "tiny", config + ".json"))
     state = jax.eval_shape(
         lambda k: enc.init_state(cfg, cfg.vocab_size, k), jax.random.key(0))
     batch = jax.ShapeDtypeStruct((cfg.seqs_per_step, cfg.pack_len), jnp.int32)
     return jax.jit(enc.train_step(cfg, 1e-3)).lower(
         state, batch, batch, batch).as_text(debug_info=True)
+
+
+def _reads_what_the_program_has(text: str, name: str, cell: str,
+                                scopes: int, ops: str) -> None:
+    """The metric `name` is a data file of the benchmark that names the
+    program's scopes, its step's module or its gauges; its `known` list
+    names all `scopes` scopes the step `text` opens; it lists `cell`
+    alone; a file without `ops` is read with the step's `ops` file."""
+    from predictionio_tpu.templates.sessionrec import engine  # noqa: F401
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        entry = {m["name"]: m for m in json.load(f)["per_layer"]}[name]
+    with open(os.path.join(root, "perf", "layers", name + ".json")) as f:
+        spec = json.load(f)
+    assert (entry["unit"], entry["moves"], entry["layer"]) == (
+        spec["unit"], spec["moves"], spec["layer"])
+    assert entry["workloads"] == [cell]
+    if spec["reader"] in ("gauge_ratio", "gauge_max_over_mean"):
+        for key in ("gauge", "numerator", "denominator"):
+            assert key not in spec or REGISTRY.get(spec[key]) is not None
+        return
+    assert "@jit_sessionrec_train_step" in text
+    opened = set(re.findall(r'["/(](enc\.[a-z_.]+)(?=[/)])', text))
+    if "known" in spec:
+        assert set(spec["known"]) == opened and len(opened) == scopes
+        assert set(spec["scopes"]) <= opened
+    assert os.path.exists(os.path.join(
+        root, "perf", "ops", spec.get("ops", ops) + ".py"))
+
+
+@pytest.fixture(scope="module")
+def sublayer_step_text():
+    return _tiny_step_text("nemotron3_nano_30b_1of16")
 
 
 @pytest.mark.parametrize("name", NH_METRICS)
@@ -727,25 +760,33 @@ def test_the_benchmarks_nh_metrics_read_what_the_program_has(
     attention's three, the router's, the plan's below the experts', the
     shared expert's), so that no op falls to an enclosing scope by
     omission."""
-    from predictionio_tpu.templates.sessionrec import engine  # noqa: F401
+    _reads_what_the_program_has(sublayer_step_text, name,
+                                "nemotron3nano.fit16_pack8k", 17,
+                                "nemotron_h_step")
 
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "BENCHMARK.json")) as f:
-        entry = {m["name"]: m for m in json.load(f)["per_layer"]}[name]
-    with open(os.path.join(root, "perf", "layers", name + ".json")) as f:
-        spec = json.load(f)
-    assert (entry["unit"], entry["moves"], entry["layer"]) == (
-        spec["unit"], spec["moves"], spec["layer"])
-    assert entry["workloads"] == ["nemotron3nano.fit16_pack8k"]
-    if spec["reader"] in ("gauge_ratio", "gauge_max_over_mean"):
-        for key in ("gauge", "numerator", "denominator"):
-            assert key not in spec or REGISTRY.get(spec[key]) is not None
-        return
-    assert "@jit_sessionrec_train_step" in sublayer_step_text
-    opened = set(re.findall(r'["/(](enc\.[a-z_.]+)(?=[/)])',
-                            sublayer_step_text))
-    if "known" in spec:
-        assert set(spec["known"]) == opened and len(opened) == 17
-        assert set(spec["scopes"]) <= opened
-    assert os.path.exists(os.path.join(
-        root, "perf", "ops", spec.get("ops", "nemotron_h_step") + ".py"))
+
+# -- the encoder of short convolutions and attention: what the benchmark's thirteen read --
+
+LFM_METRICS = ("fit.lfm_sconv_s", "fit.lfm_sconv_gated_s",
+               "fit.lfm_sconv_gated_roofline", "fit.lfm_attn_s",
+               "fit.lfm_qk_norm_s", "fit.lfm_dense_ffn_s", "fit.lfm_router_s",
+               "fit.lfm_experts_s", "fit.lfm_head_loss_s", "fit.lfm_adam_s",
+               "fit.lfm_step_mfu", "fit.lfm_expert_load_max_over_mean",
+               "fit.lfm_moe_block_fill")
+
+
+@pytest.fixture(scope="module")
+def conv_step_text():
+    return _tiny_step_text("lfm2_24b_a2b_1of8")
+
+
+@pytest.mark.parametrize("name", LFM_METRICS)
+def test_the_benchmarks_lfm_metrics_read_what_the_program_has(
+        conv_step_text, name):
+    """The thirteen, likewise. Every `known` list names all sixteen
+    scopes the step opens (the mixer's four below `enc.sconv`,
+    attention's four below `enc.gqa_full` with the norm a head on q and
+    k among them, the dense feed-forward's, the router's, the plan's
+    below the experts')."""
+    _reads_what_the_program_has(conv_step_text, name, "lfm2.fit8_pack8k",
+                                16, "lfm2_step")
